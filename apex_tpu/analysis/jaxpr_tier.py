@@ -325,8 +325,8 @@ def rank0_across_shard_map(ctx: JaxprCtx):
         eqn = site.eqn
         if eqn.primitive.name != "shard_map":
             continue
-        sides = (("in", eqn.invars, eqn.params.get("in_names")),
-                 ("out", eqn.outvars, eqn.params.get("out_names")))
+        sides = (("in", eqn.invars, eqn.params.get("in_specs")),
+                 ("out", eqn.outvars, eqn.params.get("out_specs")))
         for side, vars_, names in sides:
             for i, v in enumerate(vars_):
                 if side == "in" and isinstance(v, core.Literal):
@@ -342,7 +342,7 @@ def rank0_across_shard_map(ctx: JaxprCtx):
                 yield Finding(
                     rule="APX101", severity=ERROR,
                     location=f"{ctx.program.name}: shard_map {side}var "
-                             f"[{i}] ({aval.dtype}[], names={spec}) @ "
+                             f"[{i}] ({aval.dtype}[], spec={spec}) @ "
                              f"{_source(eqn)}",
                     message="rank-0 inexact value crosses a shard_map "
                             "boundary on a differentiated path; old-jax "
@@ -416,7 +416,8 @@ def _predicate_agreement(site: Site) -> Tuple[Set[str], bool]:
     Agreement sources: pmin/pmax/psum reductions in the predicate's
     backward slice (uniform over their axes), and — when the slice
     reaches the enclosing shard_map body's *inputs* — any input whose
-    in_names mark it fully replicated (uniform over the whole mesh)."""
+    in_specs entry names no mesh axis (fully replicated, hence uniform
+    over the whole mesh)."""
     eqn, jx = site.eqn, site.jaxpr
     pred = eqn.invars[0]
     eqns, escaped = backward_slice(jx, pred)
@@ -428,19 +429,20 @@ def _predicate_agreement(site: Site) -> Tuple[Set[str], bool]:
     if escaped:
         scope = site.shard_map_scope()
         # The predicate (partially) comes from outside this jaxpr.  When
-        # this jaxpr IS the shard_map body, the body's in_names say
+        # this jaxpr IS the shard_map body, the body's in_specs say
         # exactly how each escaped input varies: all-replicated inputs
         # are mesh-uniform (agreement over every axis), while a SHARDED
         # input means the predicate provably depends on rank-varying
         # data — the slice is conclusive either way.  Escapes the walk
         # cannot attribute (consts, deeper call scopes) stay unresolved.
         if scope is not None and scope.eqn.params.get("jaxpr") is jx:
-            in_names = scope.eqn.params.get("in_names", ())
+            in_specs = scope.eqn.params.get("in_specs", ())
             known = [idx for idx in escaped
-                     if 0 <= idx < len(in_names)]
+                     if 0 <= idx < len(in_specs)]
             if len(known) == len(escaped):
                 resolved = True
-                if all(not in_names[idx] for idx in known):
+                # a PartitionSpec whose every entry is None names no axis
+                if all(not any(in_specs[idx]) for idx in known):
                     agreed.update(scope.mesh_axes)
     return agreed, resolved
 

@@ -294,33 +294,42 @@ def default_registry() -> MetricRegistry:
 
 # --- MFU -----------------------------------------------------------------
 
-# bf16 peak FLOP/s per chip by device kind (public TPU specs — the same
-# table bench.py uses for its MFU rows).
-_PEAK_FLOPS = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5", 197e12),
-    ("v4", 275e12),
-)
+# bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` string JAX
+# reports (the one table; bench.py reads it too).  Source: Google Cloud TPU
+# documentation, the per-chip "peak compute (bf16)" figure of each
+# generation's system-architecture page (v4 275, v5e 197, v5p 459, v6e 918
+# TFLOP/s); the ``device_kind`` spellings are those of
+# ``jax/_src/pallas/mosaic/tpu_info.py``.  Only "TPU v5 lite" has been seen
+# on a chip by this repo (chip_smoke.py, PR 21).
+_PEAK_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
+}
 
 
 def peak_flops_reason(device):
     """``(peak_bf16_flops, reason)`` for a jax device — exactly one of
     the pair is ``None``.  The reason string names *why* MFU is
-    undefined (unknown platform vs missing device) instead of the old
-    silent ``None``, so a report can print "MFU: n/a (<reason>)" rather
-    than dropping the row (ISSUE 10 satellite)."""
+    undefined (no device, not a TPU, or a TPU whose ``device_kind`` is
+    not in the table) so a report can print "MFU: n/a (<reason>)".  No
+    peak is ever assumed for a device the table does not name."""
     if device is None:
         return None, "no device given (peak FLOP/s unknown)"
-    kind = getattr(device, "device_kind", "").lower()
+    kind = getattr(device, "device_kind", "")
     platform = getattr(device, "platform", "")
     if platform != "tpu":
         return None, (f"no peak-FLOPs table entry for platform "
                       f"{platform!r} (MFU is defined against a TPU peak)")
-    for tag, peak in _PEAK_FLOPS:
-        if tag in kind:
-            return peak, None
-    return 197e12, None  # conservative default (v5e)
+    peak = _PEAK_FLOPS.get(kind)
+    if peak is None:
+        return None, (f"device_kind {kind!r} is not in the peak-FLOPs "
+                      f"table (apex_tpu/observability/metrics.py)")
+    return peak, None
 
 
 def peak_flops_for(device) -> Optional[float]:

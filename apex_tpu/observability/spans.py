@@ -17,8 +17,8 @@ different clocks:
   as a range in a captured trace (the ``nvtx.range_push`` analog,
   ``apex/parallel/distributed.py:363``).
 
-Plus the two step-level tools the real-TPU ``overlap_comm`` A/B runbook
-needs (ROADMAP; ``docs/tpu_capture_runbook.md``):
+Plus the two step-level tools the real-TPU ``overlap_comm`` A/B needs
+(ROADMAP S8/D7):
 
 - :func:`step_trace` — ``jax.profiler.StepTraceAnnotation`` wrapper, so
   xprof's step-time view segments by training step;

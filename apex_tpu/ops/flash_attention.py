@@ -48,7 +48,8 @@ Design (the standard flash decomposition, mapped to TPU):
   produce **zero** output and ``lse = -1e30``: the running max is clamped
   before the exp so masked-out scores can never contribute unit mass
   (the ``exp(NEG_INF - NEG_INF) = 1`` failure mode).
-- ``interpret=True`` is selected automatically off-TPU so the same code runs
+- ``interpret=True`` is selected on the CPU backend
+  (:func:`apex_tpu.utils.platform.pallas_interpret`) so the same code runs
   in the CPU test mesh.
 
 Layouts: ``q, k, v: [batch, heads, seq, head_dim]`` (BHSD).  ``lse`` rides
@@ -65,8 +66,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
 from jax.experimental.pallas import tpu as pltpu
+
+from apex_tpu.utils import platform
 
 __all__ = [
     "flash_attention",
@@ -148,10 +150,6 @@ _LANES = 128   # TPU lane count: minor-dim tile
 _SUBLANES = 8  # fp32 sublane tile
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
@@ -162,10 +160,7 @@ def _flash_compiler_params():
     independent, so tell Mosaic: it may split them across cores (megacore
     on v4/v5p) and reorder for pipelining; the innermost stays sequential
     (init-at-0 / finalize-at-last scratch carry)."""
-    # jax >= 0.7 spells it CompilerParams; earlier releases TPUCompilerParams
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return params_cls(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
@@ -653,7 +648,7 @@ def _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale, block_q, block_k,
             _scratch((bq, d)),
         ],
         compiler_params=_flash_compiler_params(),
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(*args)
     return out[:, :, :sq], lse4[:, :, :sq, 0]
 
@@ -706,7 +701,7 @@ def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
         out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[_scratch((bq, d))],
         compiler_params=_flash_compiler_params(),
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(*args)
     return dq[:, :, :sq]
 
@@ -757,7 +752,7 @@ def dkv_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
         ],
         scratch_shapes=[_scratch((bk, d)), _scratch((bk, d))],
         compiler_params=_flash_compiler_params(),
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(*args)
     return dk[:, :, :sk], dv[:, :, :sk]
 

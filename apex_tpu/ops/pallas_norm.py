@@ -31,20 +31,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
 
 __all__ = ["pallas_layer_norm", "pallas_rms_norm", "is_available"]
 
 
 def is_available(hidden: int) -> bool:
     """Shape gate for the Pallas path (lane-width aligned)."""
-    return PALLAS_AVAILABLE and hidden % 128 == 0
+    return hidden % 128 == 0
 
 
 def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps: float):

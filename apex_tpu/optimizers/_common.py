@@ -88,9 +88,11 @@ def tree_f32(tree):
 
 
 def tree_zeros_f32(params):
-    """fp32 zero slots shaped like ``params`` (optimizer state init)."""
+    """fp32 zero slots shaped like ``params`` (optimizer state init).
+    ``zeros_like`` keeps a sharded parameter's placement, so the state of
+    a model spread over a mesh is not born whole on device 0."""
     return jax.tree_util.tree_map(
-        lambda x: jnp.zeros(jnp.shape(x), jnp.float32), params
+        lambda x: jnp.zeros_like(x, dtype=jnp.float32), params
     )
 
 

@@ -53,19 +53,6 @@ def axis_index(axis: AxisName):
     return lax.axis_index(axis)
 
 
-def _axis_size(axis: AxisName) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    # jax < 0.4.38 has no lax.axis_size; psum of a unit constant folds to
-    # the static size (the documented psum(1, axis) idiom)
-    if isinstance(axis, (tuple, list)):
-        size = 1
-        for a in axis:
-            size *= int(lax.psum(1, a))
-        return size
-    return int(lax.psum(1, axis))
-
-
 def bound_axis_size(axis: Optional[AxisName]) -> int:
     """Size of ``axis`` if it is bound by an enclosing ``shard_map``/``pmap``,
     else 1.  Lets axis-parameterized modules degrade to their single-rank
@@ -73,14 +60,14 @@ def bound_axis_size(axis: Optional[AxisName]) -> int:
     if axis is None:
         return 1
     try:
-        return _axis_size(axis)
+        return lax.axis_size(axis)
     except NameError:
         return 1
 
 
 def axis_size(axis: AxisName) -> int:
     """World size along a mesh axis (inside shard_map)."""
-    return _axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def all_reduce(x, axis: AxisName, op: str = "sum"):
@@ -194,7 +181,7 @@ def ring_chunks(x, axis: Union[AxisName, int], dim: int = 0):
     chunks with ``lax.dynamic_index_in_dim`` at a traced rank offset.
     ``axis`` may be a bound mesh axis name or an explicit chunk count.
     """
-    n = axis if isinstance(axis, int) else _axis_size(axis)
+    n = axis if isinstance(axis, int) else lax.axis_size(axis)
     dim = dim % x.ndim
     if x.shape[dim] % n:
         raise ValueError(
@@ -214,14 +201,14 @@ def send_recv_next(x, axis: AxisName):
     edge (last→first) carries data the consumer must mask/ignore, matching the
     reference where first stage never reads a recv'd activation.
     """
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     return lax.ppermute(x, axis, [(i, (i + 1) % n) for i in range(n)])
 
 
 def send_recv_prev(x, axis: AxisName):
     """Send to rank-1, receive from rank+1 (pipeline backward direction,
     ``p2p_communication.send_backward`` ``:469``)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     return lax.ppermute(x, axis, [(i, (i - 1) % n) for i in range(n)])
 
 
@@ -270,18 +257,9 @@ def shard_over(
     """
     if mesh is None:
         mesh = mesh_lib.get_mesh()
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    # jax < 0.5: shard_map lives in jax.experimental and the replication
-    # check is spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 

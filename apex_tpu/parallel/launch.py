@@ -74,8 +74,7 @@ def initialize_distributed(
         _INITIALIZED = True
         return
     if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        # Pin at the *config* level too (a sitecustomize may force another
-        # plugin over the env var), and enable the Gloo cross-process
+        # Pin at the *config* level too, and enable the Gloo cross-process
         # collective backend — without it multi-process CPU collectives
         # deadlock.
         from apex_tpu.utils.platform import pin_cpu

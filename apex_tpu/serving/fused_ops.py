@@ -31,11 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu.utils import platform
+
 __all__ = ["fused_residual_norm", "residual_norm_unfused"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _kernel(x_ref, res_ref, b_ref, w_ref, beta_ref, y_ref, new_res_ref, *,
@@ -89,7 +87,7 @@ def fused_residual_norm(x, residual, weight, bias_ln, *, bias=None,
             jax.ShapeDtypeStruct((rows, hidden), x.dtype),
             jax.ShapeDtypeStruct((rows, hidden), residual.dtype),
         ],
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(x2, res2, b, weight, bias_ln)
     return y.reshape(orig_shape), new_res.reshape(orig_shape)
 

@@ -446,15 +446,20 @@ def _delta_kernel(slots_ref, x_ref, a_ref, b_ref, o_ref):
     """One batch slot's rank-r bypass: ``(x @ A[slot]) @ B[slot]`` in
     fp32 on the MXU.  ``slots_ref`` is the scalar-prefetch vector the
     index maps consumed; the body never reads it."""
+    import jax
     import jax.numpy as jnp
 
     del slots_ref
-    x = x_ref[...][:, 0, :].astype(jnp.float32)        # [S, in]
+    x = x_ref[0].astype(jnp.float32)                   # [S, in]
     a = a_ref[0].astype(jnp.float32)                   # [in, r]
     b = b_ref[0].astype(jnp.float32)                   # [r, out]
-    t = jnp.dot(x, a, preferred_element_type=jnp.float32)
-    o_ref[:, 0, :] = jnp.dot(
-        t, b, preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    # HIGHEST pins the fp32 contraction the twin (and interpret mode on
+    # the CPU) computes, instead of the compiler's default precision
+    t = jnp.dot(x, a, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+    o_ref[0] = jnp.dot(
+        t, b, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def lora_delta_fused(x, a, b, slots):
@@ -470,14 +475,15 @@ def lora_delta_fused(x, a, b, slots):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    from apex_tpu.serving.paged_attention import _interpret, pltpu
+    from apex_tpu.utils import platform
 
     S, B, IN = x.shape
     r, out = b.shape[1], b.shape[2]
 
     def x_idx(i, slots_ref):
-        return (0, i, 0)
+        return (i, 0, 0)
 
     def ab_idx(i, slots_ref):
         return (slots_ref[i], 0, 0)
@@ -485,23 +491,26 @@ def lora_delta_fused(x, a, b, slots):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
+        # x and out ride batch-major [B, S, .]: one slot's (1, S, .)
+        # block keeps its last two dims full, which the TPU tiling rule
+        # needs and an (S, 1, .) block of the seq-major array breaks
         in_specs=[
-            pl.BlockSpec((S, 1, IN), x_idx),
+            pl.BlockSpec((1, S, IN), x_idx),
             pl.BlockSpec((1, IN, r), ab_idx),
             pl.BlockSpec((1, r, out), ab_idx),
         ],
-        out_specs=pl.BlockSpec((S, 1, out), x_idx),
+        out_specs=pl.BlockSpec((1, S, out), x_idx),
     )
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return pl.pallas_call(
+    delta = pl.pallas_call(
         _delta_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, B, out), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, S, out), x.dtype),
         # batch slots are independent (parallel, megacore-splittable)
-        compiler_params=params_cls(dimension_semantics=("parallel",)),
-        interpret=_interpret(),
-    )(slots.astype(jnp.int32), x, a, b)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=platform.pallas_interpret(),
+    )(slots.astype(jnp.int32), x.transpose(1, 0, 2), a, b)
+    return delta.transpose(1, 0, 2)
 
 
 def lora_delta_unfused(x, a, b, slots):

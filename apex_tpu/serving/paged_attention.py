@@ -52,9 +52,14 @@ attention in one pass**:
   quarter the HBM bytes of fp32, half of bf16, for one extra
   ``1/head_dim``-sized read.
 - grouped-query attention: the arena stores the compact ``kv_heads``
-  (= query groups); the kernel broadcasts each group across its query
-  heads *in VMEM* — the GQA bandwidth saving is precisely the point of
-  storing groups, not heads.
+  (= query groups); the kernel reads each KV head once *in VMEM* for
+  its whole group of query heads — the GQA bandwidth saving is
+  precisely the point of storing groups, not heads.
+- shaped for the TPU compiler: a static loop over KV heads of 2-D fp32
+  contractions (Mosaic refuses a ``dot_general`` with a batch dimension
+  in the middle, which a per-head einsum over the ``[block, heads,
+  dim]`` arena block is); the multi-query sweep carries q/out head-major
+  and ``limits`` as ``[b, T, 1]`` inside, behind unchanged signatures.
 
 Layouts::
 
@@ -70,8 +75,9 @@ Layouts::
                   cache positions < limit; 0 = padding token)
     out:          same leading shape as q  (zeros for length/limit 0)
 
-``interpret=True`` is selected automatically off-TPU so the same code
-runs on the CPU test mesh (the flash-attention convention).
+``interpret=True`` is selected on the CPU backend
+(:func:`apex_tpu.utils.platform.pallas_interpret`) so the same code runs
+on the CPU test mesh.
 """
 
 from __future__ import annotations
@@ -84,6 +90,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.utils import platform
+
 __all__ = [
     "paged_attention_decode",
     "paged_attention_decode_unfused",
@@ -95,20 +103,43 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _resolve(scale: Optional[float], d: int) -> float:
     return (1.0 / (d ** 0.5)) if scale is None else scale
 
 
-def _dequant(k_ref, scale_ref):
-    """Storage dtype -> fp32 in VMEM; int8 multiplies its row scales."""
-    k = k_ref[0].astype(jnp.float32)            # [bs, g, d]
+def _head_rows(ref, scale_ref, h: int):
+    """KV head ``h`` of one block: storage dtype -> fp32 in VMEM
+    (``[bs, d]``); an int8 cache multiplies its per-row scales."""
+    x = ref[0, :, h, :].astype(jnp.float32)
     if scale_ref is not None:
-        k = k * scale_ref[0][..., None]         # [bs, g] row scales
-    return k
+        x = x * scale_ref[0, :, h:h + 1]
+    return x
+
+
+def _dot(a, b, contract):
+    """2-D fp32 matmul on the MXU.  Mosaic only takes a ``dot_general``
+    whose batch dimensions lead, so the kernels loop over heads with
+    plain 2-D contractions; ``HIGHEST`` pins the fp32 contraction the
+    module promises (and that interpret mode on the CPU computes)
+    instead of leaving the precision to the compiler's default."""
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _online_softmax(s, m_prev, l_prev):
+    """One block of the flash recurrence.  ``s [rows, bs]`` are the
+    masked scores, ``m_prev``/``l_prev [rows, 1]`` the running max and
+    normaliser.  Returns ``(p, alpha, m_new, l_new)``."""
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # all-masked-row guard (flash convention): exp against a NEG_INF
+    # max must yield 0 mass, not exp(0)=1 per masked entry
+    m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+    p = jnp.exp(s - m_safe)
+    alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    return p, alpha, m_new, l_new
 
 
 def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
@@ -123,6 +154,7 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     num_blocks = pl.num_programs(1)
     length = len_ref[i]
+    kv_heads = k_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -132,38 +164,30 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * block_size < length)
     def _body():
-        q = q_ref[0].astype(jnp.float32)            # [n, d]
-        # in-kernel dequant: storage dtype (bf16/int8 cache) -> fp32
-        k = _dequant(k_ref, ks_ref)                 # [bs, g, d]
-        v = _dequant(v_ref, vs_ref)
-        if hpg > 1:                                  # GQA broadcast in VMEM
-            k = jnp.repeat(k, hpg, axis=1)           # [bs, n, d]
-            v = jnp.repeat(v, hpg, axis=1)
-        s = jnp.einsum("nd,tnd->nt", q, k) * scale   # [n, bs]
         cols = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
-        s = jnp.where(cols < length, s, NEG_INF)
-
-        m = m_sc[:, 0]
-        l = l_sc[:, 0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        # all-masked-row guard (flash convention): exp against a NEG_INF
-        # max must yield 0 mass, not exp(0)=1 per masked entry
-        m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
-        p = jnp.exp(s - m_safe[:, None])
-        alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        acc_new = acc_sc[...] * alpha[:, None] + jnp.einsum(
-            "nt,tnd->nd", p, v)
-        m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new[:, None], l_sc.shape)
-        acc_sc[...] = acc_new
+        live = cols < length
+        for h in range(kv_heads):
+            # GQA: the group's hpg query heads share KV head h, read
+            # once from VMEM
+            rows = slice(h * hpg, (h + 1) * hpg)
+            q = q_ref[0, rows, :].astype(jnp.float32)    # [hpg, d]
+            k = _head_rows(k_ref, ks_ref, h)             # [bs, d]
+            v = _head_rows(v_ref, vs_ref, h)
+            s = _dot(q, k, ((1,), (1,))) * scale         # [hpg, bs]
+            s = jnp.where(live, s, NEG_INF)
+            p, alpha, m_new, l_new = _online_softmax(
+                s, m_sc[rows, :1], l_sc[rows, :1])
+            acc_sc[rows, :] = acc_sc[rows, :] * alpha + _dot(
+                p, v, ((1,), (0,)))
+            m_sc[rows, :] = jnp.broadcast_to(m_new, (hpg, _LANES))
+            l_sc[rows, :] = jnp.broadcast_to(l_new, (hpg, _LANES))
 
     @pl.when(j == num_blocks - 1)
     def _finalize():
-        l_fin = l_sc[:, 0]
+        l_fin = l_sc[:, :1]
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0] = (acc_sc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
 
 
 def _check_arena(q_d, k_arena, n, g, k_scales, v_scales):
@@ -263,7 +287,7 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, d), q.dtype),
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
 
@@ -271,9 +295,8 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
 def _compiler_params():
     """Batch dim is independent (parallel, megacore-splittable); the
     block sweep carries the online-softmax scratch (arbitrary)."""
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return params_cls(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def _gathered_kv(q, k_arena, v_arena, block_tables, k_scales, v_scales):
@@ -351,6 +374,8 @@ def _prefill_kernel(tab_ref, len_ref, q_ref, lim_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     num_blocks = pl.num_programs(1)
     length = len_ref[i]
+    kv_heads = k_ref.shape[2]
+    n_heads = kv_heads * hpg
 
     @pl.when(j == 0)
     def _init():
@@ -360,38 +385,30 @@ def _prefill_kernel(tab_ref, len_ref, q_ref, lim_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * block_size < length)
     def _body():
-        q = q_ref[0].astype(jnp.float32)            # [T, n, d]
-        lim = lim_ref[0]                            # [T] per-token limits
-        k = _dequant(k_ref, ks_ref)                 # [bs, g, d]
-        v = _dequant(v_ref, vs_ref)
-        if hpg > 1:
-            k = jnp.repeat(k, hpg, axis=1)           # [bs, n, d]
-            v = jnp.repeat(v, hpg, axis=1)
-        s = jnp.einsum("tnd,snd->tns", q, k) * scale  # [T, n, bs]
         cols = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_size), 2)
+            jnp.int32, (1, block_size), 1)
         # per-token causal limit: token t sees cache positions < lim[t]
         # (its own row, scattered before the call, is position lim[t]-1)
-        s = jnp.where(cols < lim[:, None, None], s, NEG_INF)
-
-        m = m_sc[...]                                # [T, n]
-        l = l_sc[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=2))
-        m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
-        p = jnp.exp(s - m_safe[..., None])
-        alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
-        l_new = l * alpha + jnp.sum(p, axis=2)
-        acc_new = acc_sc[...] * alpha[..., None] + jnp.einsum(
-            "tns,snd->tnd", p, v)
-        m_sc[...] = m_new
-        l_sc[...] = l_new
-        acc_sc[...] = acc_new
+        live = cols < lim_ref[0]                         # [T, bs]
+        for h in range(kv_heads):
+            k = _head_rows(k_ref, ks_ref, h)             # [bs, d]
+            v = _head_rows(v_ref, vs_ref, h)
+            for n in range(h * hpg, (h + 1) * hpg):      # GQA group
+                q = q_ref[0, n].astype(jnp.float32)          # [T, d]
+                s = _dot(q, k, ((1,), (1,))) * scale         # [T, bs]
+                s = jnp.where(live, s, NEG_INF)
+                p, alpha, m_new, l_new = _online_softmax(
+                    s, m_sc[:, n:n + 1], l_sc[:, n:n + 1])
+                acc_sc[n] = acc_sc[n] * alpha + _dot(p, v, ((1,), (0,)))
+                m_sc[:, n:n + 1] = m_new
+                l_sc[:, n:n + 1] = l_new
 
     @pl.when(j == num_blocks - 1)
     def _finalize():
-        l_fin = l_sc[...]
-        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0] = (acc_sc[...] / l_safe[..., None]).astype(o_ref.dtype)
+        for n in range(n_heads):
+            l_fin = l_sc[:, n:n + 1]
+            l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+            o_ref[0, n] = (acc_sc[n] / l_safe).astype(o_ref.dtype)
 
 
 def paged_prefill_attention(q, k_arena, v_arena, block_tables, lengths,
@@ -435,18 +452,25 @@ def _multi_query_attention(q, k_arena, v_arena, block_tables, lengths,
         return (tab_ref[i, jnp.minimum(j, live)], 0, 0)
 
     def row_idx(i, j, tab_ref, len_ref):
-        return (i, 0)
+        return (i, 0, 0)
 
     def q_idx(i, j, tab_ref, len_ref):
         return (i, 0, 0, 0)
 
+    # q and out ride head-major [b, n, T, d]: the kernel loops over
+    # heads, and a [T, d] head slice of a [T, n, d] block is a strided
+    # sublane access Mosaic refuses to store for packed (bf16) dtypes.
+    # The transposes fuse with the ones the callers already do.
     in_specs = [
-        pl.BlockSpec((1, T, n, d), q_idx),
-        pl.BlockSpec((1, T), row_idx),
+        pl.BlockSpec((1, n, T, d), q_idx),
+        # [b, T, 1]: a (1, T) block over [b, T] breaks the TPU tiling
+        # rule (last two block dims full or (8, 128)-aligned)
+        pl.BlockSpec((1, T, 1), row_idx),
         pl.BlockSpec((1, bs, g, d), kv_idx),
         pl.BlockSpec((1, bs, g, d), kv_idx),
     ]
-    operands = [q, limits.astype(jnp.int32), k_arena, v_arena]
+    operands = [q.transpose(0, 2, 1, 3),
+                limits.astype(jnp.int32)[..., None], k_arena, v_arena]
     if has_scales:
         in_specs += [pl.BlockSpec((1, bs, g), sc_idx),
                      pl.BlockSpec((1, bs, g), sc_idx)]
@@ -455,24 +479,25 @@ def _multi_query_attention(q, k_arena, v_arena, block_tables, lengths,
         num_scalar_prefetch=2,
         grid=(b, max_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T, n, d), q_idx),
+        out_specs=pl.BlockSpec((1, n, T, d), q_idx),
         scratch_shapes=[
             pltpu.VMEM((T, n), jnp.float32),
             pltpu.VMEM((T, n), jnp.float32),
-            pltpu.VMEM((T, n, d), jnp.float32),
+            pltpu.VMEM((n, T, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_prefill_kernel, scale=_resolve(scale, d),
                                block_size=bs, hpg=hpg,
                                has_scales=has_scales)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, T, n, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n, T, d), q.dtype),
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=platform.pallas_interpret(),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
+    return out.transpose(0, 2, 1, 3)
 
 
 def paged_prefill_attention_unfused(q, k_arena, v_arena, block_tables,

@@ -263,7 +263,8 @@ def main(argv=None) -> int:
 
     # Platform pinning must precede any backend use (same contract as
     # __graft_entry__.dryrun_multichip).
-    from apex_tpu.utils.platform import force_host_device_count, pin_cpu
+    from apex_tpu.utils.platform import (
+        enable_compilation_cache, force_host_device_count, pin_cpu)
 
     force_host_device_count(max(args.devices, 1))
     pin_cpu()
@@ -271,18 +272,10 @@ def main(argv=None) -> int:
     import numpy as np
 
     # The smoke scripts launch this trainer several times (reference,
-    # crash, resume) with identical programs: a persistent compilation
-    # cache next to the checkpoint dir keeps later runs warm, which is
-    # what keeps the whole save->SIGKILL->resume proof in the fast tier.
-    try:
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.abspath(args.ckpt_dir)), ".xla_cache")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # cache is an optimization, never a failure
-        print(f"crash_resume: compilation cache unavailable ({e!r})",
-              file=sys.stderr)
+    # crash, resume) with identical programs: the persistent compilation
+    # cache keeps later runs warm, which is what keeps the whole
+    # save->SIGKILL->resume proof in the fast tier.
+    enable_compilation_cache()
 
     from apex_tpu.parallel import mesh as mesh_lib
     from apex_tpu.resilience import CheckpointManager, PreemptionGuard

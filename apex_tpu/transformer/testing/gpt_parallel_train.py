@@ -23,7 +23,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.ops.softmax import AttnMaskType
 from apex_tpu.parallel import collectives as cc
@@ -202,10 +202,12 @@ def build_gpt_3d(
         ), None)  # [L, ...] replicated stack dim at init time
         ln_specs = jax.tree_util.tree_map(lambda _: P(), shapes[2])
 
-        e, stacked, ln = cc.shard_over(
+        # jitted: run eagerly, a shard_map dispatches (and compiles) every
+        # initializer op by itself — over a thousand tiny programs
+        e, stacked, ln = jax.jit(cc.shard_over(
             local_init, mesh=mesh, in_specs=(P(),),
             out_specs=(e_specs, l_specs, ln_specs),
-        )(mb_tokens)
+        ))(mb_tokens)
 
         # [L, ...] virtual-stage major -> [vpp, pp, ...]; pp dim shards.
         stacked = jax.tree_util.tree_map(
@@ -215,6 +217,11 @@ def build_gpt_3d(
             jax.tree_util.tree_map(lambda l: l[0, 0], stacked),
             axis=tp_axis, ep_axis=cfg.expert_axis
         ), None, pp_axis)
+        # the eager reshape leaves the stack replicated over pp; place it
+        # as its spec says, so init holds one stage's layers per device
+        stacked = jax.device_put(stacked, jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), layer_specs,
+            is_leaf=lambda x: isinstance(x, P)))
 
         params = GPT3DParams(embedding=e, layers=stacked, final_ln=ln)
         specs = GPT3DParams(embedding=e_specs, layers=layer_specs,

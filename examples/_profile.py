@@ -28,8 +28,9 @@ def init_bench_backend():
         pin_cpu()
 
     import bench
+    from apex_tpu.utils.platform import enable_compilation_cache
 
-    bench.enable_compilation_cache(jax)
+    enable_compilation_cache()
     dev = jax.devices()[0]
     return jax, bench, dev, dev.platform == "tpu"
 

@@ -1,8 +1,8 @@
 """Shared subprocess-sweep driver for the hardware tuning harnesses.
 
 Each grid point runs the real flagship train step in its own subprocess
-(fresh backend: a wedge/OOM cannot kill the sweep) with the persistent
-XLA compile cache on; the child prints one JSON record line, which the
+(it holds the chip alone, and a hang or OOM cannot kill the sweep; this
+parent never touches JAX) with the persistent XLA compile cache on; the child prints one JSON record line, which the
 driver appends to a jsonl and ranks by ``tokens_per_sec``.  Used by
 ``tune_flash_blocks.py`` (block_q/block_k knob) and ``tune_gpt_batch.py``
 (batch knob).

@@ -6,8 +6,7 @@ was measured only on the virtual CPU mesh
 This harness compiles the same interleaved forward+backward program **for
 the attached TPU** (pp=1 on a single chip — the rotation scan, virtual
 stages, and remat grouping are all still present) and records the
-compiled executable's XLA memory analysis.  Compile-only: nothing runs,
-so one wedge-free backend init is enough.
+compiled executable's XLA memory analysis.  Compile-only: nothing runs.
 
     python examples/measure_remat_memory.py            # default shapes
     python examples/measure_remat_memory.py --width 1024 --m 64

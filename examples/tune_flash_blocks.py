@@ -3,9 +3,8 @@
 Round 2 shipped DEFAULT_BLOCK_Q=256 / DEFAULT_BLOCK_K=512 unswept; GPT-124M
 MFU stalled at 0.436 while BERT hit 0.488.  This harness times the *actual
 flagship train step* (the ``gpt_flash`` bench config) across a
-(block_q, block_k) grid, each point in its own subprocess (fresh backend —
-a wedge or OOM cannot kill the sweep) with the persistent compilation
-cache on.
+(block_q, block_k) grid, each point in its own subprocess (a hang or OOM
+cannot kill the sweep) with the persistent compilation cache on.
 
     python examples/tune_flash_blocks.py                 # full grid
     python examples/tune_flash_blocks.py --seq 2048      # long-seq grid
@@ -51,8 +50,9 @@ def run_point(block_q: int, block_k: int, seq: int, steps: int) -> None:
         pin_cpu()
 
     import bench
+    from apex_tpu.utils.platform import enable_compilation_cache
 
-    bench.enable_compilation_cache(jax)
+    enable_compilation_cache()
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
     if not on_tpu:  # CPU smoke: tiny shapes, still exercises the plumbing
@@ -111,11 +111,9 @@ def main():
         # Land the winner automatically: a TPU sweep at the flagship seq
         # (1024) writes the tuned-defaults file that
         # apex_tpu.ops.flash_attention consults lazily at first kernel
-        # call, gated on matching device_kind (env overrides still win) —
-        # so an unattended chip-return capture upgrades the shipped
-        # defaults without a source edit.
+        # call, gated on matching device_kind (env overrides still win).
         # >1 successful point required: a lone survivor (others
-        # wedged/OOMed) is no comparison.
+        # hung/OOMed) is no comparison.
         if (best["platform"] == "tpu" and args.seq == 1024
                 and not args.one and len(records) > 1):
             tuned_path = os.path.join(REPO, "bench_results",

@@ -50,8 +50,9 @@ def run_point(base_batch: int, seq: int, steps: int) -> None:
         pin_cpu()
 
     import bench
+    from apex_tpu.utils.platform import enable_compilation_cache
 
-    bench.enable_compilation_cache(jax)
+    enable_compilation_cache()
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
     if not on_tpu:
@@ -132,7 +133,7 @@ def main():
         # consults, gated on device_kind (env override still wins) — so
         # an unattended capture upgrades the bench batch with the sweep
         # itself as recorded provenance.  Gated on >1 *successful* point:
-        # a lone survivor (others wedged/OOMed) is no comparison.
+        # a lone survivor (others hung/OOMed) is no comparison.
         if (best["platform"] == "tpu" and args.seq == 1024
                 and len(records) > 1 and not args.no_land):
             tuned = os.path.join(REPO, "bench_results",

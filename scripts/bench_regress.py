@@ -2,19 +2,19 @@
 """Bench regression gate (ISSUE 10): newest BENCH/MULTICHIP record vs
 history, exit nonzero on regression.
 
-Five driver rounds of evidence (``BENCH_r01..r05.json``,
-``MULTICHIP_r01..r05.json``) sit in the repo with no automated check —
-a PR that halves ``gpt_flash`` throughput or breaks the multichip
-dryrun would only be caught by a human reading JSON.  This gate
-mechanizes the comparison on the **compact-record whitelist** (the
-per-row ``{value, unit, platform, vs_*}`` dicts ``bench.compact_record``
-emits — the only fields every round durably carries):
+The driver rounds still in the repo (``BENCH_r02..r05.json``,
+``MULTICHIP_r02..r05.json``; all predate PR 1 and ran on the CPU) are the
+history this gate reads.  It compares **compact records**: a header
+(``metric``/``value``/``unit``/``platform``) plus ``rows`` of per-bench
+``{value, unit, platform, vs_*}`` dicts — the form those rounds' last
+stdout line has.  ``bench.py`` no longer prints that form (PR 21 removed
+it with the CPU fallback), so the gate judges recorded rounds only, until
+ROADMAP S1 moves its floors and gates into per-cell ledger bounds:
 
 - each round's compact record is taken from the driver's ``parsed``
-  field, falling back to the last parseable JSON line of the 2000-byte
-  stdout ``tail`` (rounds 1–4 predate the compact-line fix and may
-  yield nothing — a round with no usable record contributes no
-  baseline, exactly like an errored row);
+  field, falling back to the last parseable JSON line of the stdout
+  ``tail`` (a round with no usable record contributes no baseline,
+  exactly like an errored row);
 - rows are compared **only against history measured on the same
   platform** (a CPU fallback round must never be judged against a TPU
   round);
@@ -36,7 +36,7 @@ emits — the only fields every round durably carries):
 
 Exit status: 0 = no regression, 1 = regression (each printed with its
 row, baseline, and tolerance), 2 = usage/IO error.  Wired fast-tier in
-``tests/test_bench_regress.py``: exit 0 on the real r01→r05 history,
+``tests/test_bench_regress.py``: exit 0 on the real r02→r05 history,
 nonzero on a fixture with an injected >tolerance regression.
 
 Usage::
@@ -137,7 +137,7 @@ FLOORS = {
 
 def lower_is_better(unit: Optional[str]) -> Optional[bool]:
     """Regression direction from the row's unit; ``None`` (skip) when
-    the unit is unknown (a size-degraded compact record drops units)."""
+    the unit is unknown."""
     if not unit:
         return None
     return "/sec" not in unit
@@ -195,9 +195,7 @@ def _rows_of(compact: Optional[dict]) -> dict:
             "unit": compact.get("unit"),
             "platform": compact.get("platform"),
         }
-    # a size-degraded compact record flattens rows to bare numbers
-    return {name: (row if isinstance(row, dict) else {"value": row})
-            for name, row in rows.items()}
+    return rows
 
 
 def check_bench(rounds: List[dict], tolerance: float,

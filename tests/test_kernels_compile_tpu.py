@@ -1,0 +1,87 @@
+"""Every Pallas kernel compiles for a v5e, checked without a chip; and the
+chip smoke's own phases run, at a toy size, on the CPU mesh.
+
+The installed libtpu compiles for a TPU topology with no device attached
+(``jax.experimental.topologies``), so a kernel Mosaic would refuse on the
+chip fails here first — in under a second per kernel, inside tier-1.  The
+kernel cases and their shapes are ``chip_smoke.kernel_cases``: what is
+compiled here is what ``python chip_smoke.py`` runs on the chip.
+"""
+
+import os
+import sys
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+from apex_tpu.utils import platform  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    """One device of a detached v5e 2x2 topology.  A failure to build it
+    is a failure of the test, not a skip: without it nothing in tier-1
+    says whether the kernels still compile for the chip."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert len(topo.devices) == 4
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices[0]
+
+
+def _cases():
+    """The smoke's kernel cases at the one-chip head count, plus those
+    whose shapes depend on it at one tp=4 rank's share."""
+    full = chip_smoke.FULL
+    names = [case.name for case in chip_smoke.kernel_cases(full)]
+    return [pytest.param(name, full.heads, id=name) for name in names] + [
+        pytest.param(name, full.heads // 4, id=f"{name}-tp4")
+        for name in names if name.startswith(("paged_", "flash_"))]
+
+
+@pytest.mark.parametrize("name,heads", _cases())
+def test_kernel_compiles_for_v5e(name, heads, v5e_device, monkeypatch):
+    # compiled, not interpreted, although the default backend is the CPU
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    case = next(c for c in chip_smoke.kernel_cases(chip_smoke.FULL, heads)
+                if c.name == name)
+    sharding = SingleDeviceSharding(v5e_device)
+    args, kwargs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        jax.eval_shape(case.make_args))
+    lowered = jax.jit(case.kernel).lower(*args, **kwargs)
+    assert "tpu_custom_call" in lowered.as_text()
+    lowered.compile()
+
+
+TOY = chip_smoke.Sizes(
+    hidden=64, layers=4, heads=4, vocab=256, positions=64, batch=4,
+    microbatches=2, train_steps=3, lr=1e-3, max_batch=4, max_seq=64,
+    prefill_len=16, requests=((0, 40), (0, 5), (1, 33), (3, 18)),
+    new_tokens=6, verify_k=2, lora_rank=4)
+
+
+def test_chip_smoke_phases_at_toy_size():
+    """The phase functions ``chip_smoke.main`` calls, unchanged, on four
+    virtual CPU devices: the four-chip host's layout (pp2 x tp2 trainer,
+    tp4 server) with the kernels interpreted."""
+    devices = jax.devices()[:4]
+    train = chip_smoke.train_phase(TOY, devices)
+    assert train["layout"] == {"dp": 1, "pp": 2, "vpp": 2, "tp": 2}
+    assert train["losses"][-1] < train["losses"][0]
+    serve = chip_smoke.serve_phase(TOY, devices)
+    assert serve["tokens_out"] == len(TOY.requests) * TOY.new_tokens
+    errors = chip_smoke.kernel_phase(TOY, TOY.heads // len(devices))
+    assert len(errors) == len(chip_smoke.kernel_cases(TOY))
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    with pytest.raises(SystemExit, match="no TPU"):
+        chip_smoke.main()

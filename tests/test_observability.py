@@ -609,6 +609,13 @@ class TestMfu:
 
         peak, reason = peak_flops_reason(_TpuDevice())
         assert peak == 275e12 and reason is None
+        # the table is keyed by the exact device_kind: what the v5e chip
+        # reports is in it, and an unknown TPU gets a reason, not a guess
+        _TpuDevice.device_kind = "TPU v5 lite"
+        assert peak_flops_reason(_TpuDevice()) == (197e12, None)
+        _TpuDevice.device_kind = "TPU v9 nano"
+        peak, reason = peak_flops_reason(_TpuDevice())
+        assert peak is None and "'TPU v9 nano'" in reason
 
 
 # ---------------------------------------------------------------------------

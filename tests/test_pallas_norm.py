@@ -3,13 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from apex_tpu.normalization import fused_layer_norm_affine, fused_rms_norm_affine
 from apex_tpu.ops import pallas_norm
 
 
-@pytest.mark.skipif(not pallas_norm.PALLAS_AVAILABLE, reason="pallas missing")
 class TestPallasNorm:
     def test_layer_norm_matches_reference(self):
         x = jnp.asarray(np.random.RandomState(0).randn(64, 128), jnp.float32)
