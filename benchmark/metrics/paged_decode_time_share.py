@@ -1,0 +1,7 @@
+"""The paged decode attention kernel's share of the device's busy time."""
+
+from metrics import _common
+
+
+def read(view):
+    return _common.share_of_busy(view, _common.paged_decode_kernel(view))
