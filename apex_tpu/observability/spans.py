@@ -10,12 +10,14 @@ different clocks:
   metadata — the instrumented/bare HLO-parity test in
   ``tests/test_observability.py`` depends on this), so it is safe on any
   hot path.
-- :func:`span` — for **host** code (checkpoint save/verify/restore,
-  data loading, the step dispatch loop): wall-clock timing recorded into
-  a :class:`~apex_tpu.observability.metrics.MetricRegistry` histogram
+- :class:`span` — for **host** code (checkpoint save/verify/restore,
+  data loading, the phases of a serving tick): wall-clock timing recorded
+  into a :class:`~apex_tpu.observability.metrics.MetricRegistry` histogram
   plus a ``jax.profiler.TraceAnnotation`` so the same interval shows up
   as a range in a captured trace (the ``nvtx.range_push`` analog,
-  ``apex/parallel/distributed.py:363``).
+  ``apex/parallel/distributed.py:363``).  Every span is also kept, with
+  its start, end, parent and integer fields, in one bounded process-wide
+  ring that :func:`recorded` reads and :func:`self_ms` reduces.
 
 Plus the two step-level tools the real-TPU ``overlap_comm`` A/B needs
 (ROADMAP S8/D7):
@@ -34,15 +36,21 @@ in ``docs/observability.md``.
 
 from __future__ import annotations
 
-import contextlib
+import collections
+import itertools
 import logging
 import os
+import threading
 import time
-from typing import Optional
+import weakref
+from typing import Dict, Iterable, List, Optional
 
 import jax
 
-__all__ = ["named_span", "span", "step_trace", "TraceWindow"]
+from apex_tpu.observability.metrics import default_registry
+
+__all__ = ["named_span", "span", "recorded", "self_ms", "step_trace",
+           "TraceWindow"]
 
 logger = logging.getLogger(__name__)
 
@@ -61,28 +69,104 @@ def named_span(name: str):
     return jax.named_scope(f"{_PREFIX}/{name}")
 
 
-@contextlib.contextmanager
-def span(name: str, *, registry=None):
-    """Host wall-clock span: times the block, records
-    ``span_ms/<name>`` into the registry's histogram, and opens a
-    ``jax.profiler.TraceAnnotation`` so captured traces carry the range.
+# Every finished host span of the process, oldest first.  Bounded, so a
+# server that runs for weeks keeps the last few hundred ticks and no more;
+# ``deque.append`` is atomic, so threads share it without a lock.
+_RING: "collections.deque[span]" = collections.deque(maxlen=8192)
+_IDS = itertools.count(1)
+_LOCAL = threading.local()          # .stack: the thread's open spans
+# registry -> {span name: its ``span_ms/<name>`` histogram}: a span runs
+# about nine times a serving tick, so it does not pay the registry's lock
+# and a formatted name each time
+_HISTOGRAMS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+class span:
+    """Host wall-clock span: ``with span("checkpoint/save", step=7) as s``.
+
+    Times the block on ``time.perf_counter``, observes ``span_ms/<name>``
+    in the registry, opens a ``jax.profiler.TraceAnnotation("apex/<name>")``
+    (a flag test while no profiler session runs; a range on the profiler's
+    host plane while one does) and, on exit, joins the process-wide ring
+    with ``name``, ``start``, ``end``, ``id``, ``parent`` (the ``id`` of the
+    span open round it on the same thread, 0 at the top) and ``fields``
+    (integers known at entry as keywords, later ones through :meth:`note`).
 
     NOTE: host spans measure *dispatch* unless the block itself blocks
-    (``jax.block_until_ready``, file I/O) — time jitted work with
-    :func:`step_trace` + a trace window, not with a host span around an
-    async dispatch.
+    (``jax.block_until_ready``, ``np.asarray``, file I/O) — time jitted
+    work with :func:`step_trace` + a trace window, not with a host span
+    around an async dispatch.
     """
-    if registry is None:
-        from apex_tpu.observability.metrics import default_registry
 
-        registry = default_registry()
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(f"{_PREFIX}/{name}"):
-            yield
-    finally:
-        registry.histogram(f"span_ms/{name}").observe(
-            (time.perf_counter() - t0) * 1e3)
+    __slots__ = ("name", "fields", "start", "end", "id", "parent",
+                 "_histogram", "_annotation")
+
+    def __init__(self, name: str, *, registry=None, **fields: int):
+        self.name = name
+        self.fields = fields
+        self.start = self.end = 0.0
+        self.id = self.parent = 0
+        if registry is None:
+            registry = default_registry()
+        try:
+            self._histogram = _HISTOGRAMS[registry][name]
+        except KeyError:
+            self._histogram = _HISTOGRAMS.setdefault(registry, {})[name] = \
+                registry.histogram(f"span_ms/{name}")
+        self._annotation = jax.profiler.TraceAnnotation(f"{_PREFIX}/{name}")
+
+    def note(self, **fields: int) -> None:
+        """Add fields that are known only once the block has run."""
+        self.fields.update(fields)
+
+    @property
+    def ms(self) -> float:
+        return (self.end - self.start) * 1e3
+
+    def __enter__(self) -> "span":
+        try:
+            stack = _LOCAL.stack
+        except AttributeError:
+            stack = _LOCAL.stack = []
+        self.id = next(_IDS)
+        self.parent = stack[-1].id if stack else 0
+        stack.append(self)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        _LOCAL.stack.pop()
+        _RING.append(self)
+        self._histogram.observe(self.ms)
+
+    def __repr__(self) -> str:
+        return (f"span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"ms={self.ms:.3f}, fields={self.fields})")
+
+
+def recorded(since: Optional[float] = None) -> List[span]:
+    """The ring's finished spans, oldest first; with ``since`` (a
+    ``time.perf_counter`` reading) only those that started at or after
+    it."""
+    records = list(_RING)
+    if since is None:
+        return records
+    return [s for s in records if s.start >= since]
+
+
+def self_ms(records: Iterable[span]) -> Dict[int, float]:
+    """Each span's own milliseconds by ``id``: its duration less what its
+    children among ``records`` cover."""
+    records = list(records)
+    own = {s.id: s.ms for s in records}
+    for s in records:
+        if s.parent in own:
+            own[s.parent] -= s.ms
+    return own
 
 
 def step_trace(step_num: int, name: str = "train_step"):

@@ -61,6 +61,25 @@ docs/serving.md):
   (the drafting hit rate the adaptive back-off steers on)
 - ``serving/mfu``          gauge — decode-step MFU when the device peak
   is known (``introspect()["mfu_reason"]`` says why otherwise)
+- ``serving/ticks`` / ``serving/prefill_calls`` /
+  ``serving/decode_calls`` counters — ``step()`` calls and the device
+  calls they made (ISSUE 25)
+- ``serving/prefill_tokens`` / ``serving/prefill_capacity_tokens``
+  counters — prompt tokens the prefill calls advanced, and the
+  ``max_batch x prefill_len`` their fixed shape has room for
+- ``serving/decode_slot_steps`` counter — slots that took part in a
+  decode call, summed over calls
+- ``serving/queue_wait_ms`` histogram (sampled) — submit to first
+  admission, per request
+
+Host spans (ISSUE 25): every :meth:`ServingEngine.step` is one
+``serving/tick`` span of :mod:`apex_tpu.observability.spans` with one
+child per phase that ran (``admit``, ``prefill_plan``,
+``prefill_dispatch``, ``prefill_fetch``, ``prefill_deliver``,
+``decode_plan``, ``decode_dispatch``, ``decode_fetch``, ``deliver``) —
+in the span ring, in ``span_ms/*`` of the engine's registry, and on the
+profiler's host plane while a session runs; fields and coverage are in
+docs/observability.md.
 
 Run-timeline (ISSUE 10): with a flight recorder armed
 (:mod:`apex_tpu.observability.timeline`) the engine additionally logs
@@ -72,13 +91,20 @@ see the class docstring and docs/observability.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
+import types
 import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from apex_tpu.observability import timeline
+from apex_tpu.observability import spans, timeline
+from apex_tpu.observability.metrics import (
+    compiled_flops,
+    default_registry,
+    mfu_or_reason,
+)
 from apex_tpu.parallel import collectives as cc
 from apex_tpu.parallel.mesh import TENSOR_AXIS, get_mesh
 from apex_tpu.serving.kv_cache import (
@@ -196,7 +222,6 @@ class ServingEngine:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from apex_tpu.observability.metrics import default_registry
         from apex_tpu.transformer.tensor_parallel import infer_param_specs
 
         self.mesh = mesh if mesh is not None else get_mesh()
@@ -379,9 +404,24 @@ class ServingEngine:
             (serving.max_batch, self.cache.max_blocks_per_request),
             np.int32)
         self._steps = 0
+        # the tick's host spans (serving/tick and its phases) land in this
+        # engine's registry as span_ms/* and in the process-wide ring
+        self._span = functools.partial(spans.span, registry=self.registry)
+        counter = self.registry.counter
+        self._counters = types.SimpleNamespace(
+            ticks=counter("serving/ticks"),
+            prefill_calls=counter("serving/prefill_calls"),
+            prefill_tokens=counter("serving/prefill_tokens"),
+            prefill_capacity=counter("serving/prefill_capacity_tokens"),
+            decode_calls=counter("serving/decode_calls"),
+            decode_slot_steps=counter("serving/decode_slot_steps"))
+        self._queue_wait = self.registry.histogram(
+            "serving/queue_wait_ms", keep_samples=4096)
         self._decode_calls = 0         # device decode/verify invocations
         self._slot_steps = 0           # per-slot verify participations
         #                                (mean accept length denominator)
+        # the last decode call's logits and the slots that decoded in it
+        self._last_logits: Optional[Tuple[Any, Tuple[int, ...]]] = None
         self._counted_preempts = 0     # flushed-so-far deltas
         self._counted_hits = 0
         self._counted_evictions = 0
@@ -392,11 +432,12 @@ class ServingEngine:
         # on /fleet/statusz instead of hidden inside the fleet mean
         self.spec_by_adapter: Dict[str, List[int]] = {}
         # MFU bookkeeping (ISSUE 10 satellite): FLOPs of the decode
-        # program probed once (lazily, pre-donation), last decode wall
-        # time measured each step; serving/mfu flushed as a gauge when
+        # program probed once (lazily, pre-donation); the last decode
+        # call's wall time is that of its decode_dispatch and
+        # decode_fetch spans; serving/mfu flushed as a gauge when
         # defined, else the reason string is kept for /statusz.
         self._decode_flops: Optional[float] = None
-        self._last_decode_s: Optional[float] = None
+        self._decode_ms: Optional[float] = None
         self._flops_probed = False
         self._probe_fail_reason: Optional[str] = None
         self.mfu: Optional[float] = None
@@ -704,31 +745,54 @@ class ServingEngine:
 
     def step(self) -> None:
         """One engine tick: admit, advance prefill chunks, one decode
-        step."""
-        if (self.guard is not None and self.guard.triggered
-                and not self.draining):
-            self.drain()
-        admitted = self.scheduler.admit()
-        for req in admitted:
-            timeline.emit("request_admit", rid=req.rid, slot=req.slot,
-                          blocks=len(req.blocks),
-                          hit_blocks=req.hit_blocks,
-                          **trace_fields(req))
-        self._prefill_tick()
-        self._decode_once()
-        self._steps += 1
-        self.registry.gauge("serving/active_slots").set(
-            len(self.scheduler.running()))
-        self.registry.gauge("serving/free_blocks").set(
-            self.scheduler.allocator.n_free)
-        self.registry.gauge("serving/kv_occupancy").set(
-            self.scheduler.kv_occupancy())
-        self._flush_occupancy_counters()
-        # the beat lands only after this tick's device work materialized
-        # — a wedged decode stops the beats and the monitor fires the
-        # guard, turning a scheduler wedge into an ordinary drain
-        if self.heartbeat is not None:
-            self.heartbeat.beat(self._steps)
+        step.  The tick and its phases are host spans
+        (:class:`~apex_tpu.observability.spans.span`; the span map is in
+        docs/observability.md): disjoint, in this order, and a phase
+        that did not run records none."""
+        sched = self.scheduler
+        with self._span("serving/tick", step=self._steps,
+                        live=len(sched.running()),
+                        waiting=len(sched.waiting), prefill_rows=0,
+                        prefill_tokens=0, prefill_capacity=0,
+                        decode_slots=0) as tick:
+            with self._span("serving/tick/admit") as phase:
+                if (self.guard is not None and self.guard.triggered
+                        and not self.draining):
+                    self.drain()
+                admitted = sched.admit()
+                now = time.monotonic()
+                for req in admitted:
+                    if req.t_admit is None:
+                        # the first admission: a re-admitted request
+                        # waited for blocks, not in the queue
+                        self._queue_wait.observe(
+                            (now - req.t_submit) * 1e3)
+                    req.t_admit = now
+                    timeline.emit("request_admit", rid=req.rid,
+                                  slot=req.slot, blocks=len(req.blocks),
+                                  hit_blocks=req.hit_blocks,
+                                  **trace_fields(req))
+                phase.note(admitted=len(admitted))
+            self._prefill_tick(tick)
+            decoded = self._decode_once(tick)
+            with self._span("serving/tick/deliver") as phase:
+                tokens = self._deliver(*decoded) if decoded else 0
+                phase.note(tokens=tokens)
+                self._steps += 1
+                self._counters.ticks.inc()
+                self.registry.gauge("serving/active_slots").set(
+                    len(sched.running()))
+                self.registry.gauge("serving/free_blocks").set(
+                    sched.allocator.n_free)
+                self.registry.gauge("serving/kv_occupancy").set(
+                    sched.kv_occupancy())
+                self._flush_occupancy_counters()
+                # the beat lands only after this tick's device work
+                # materialized — a wedged decode stops the beats and the
+                # monitor fires the guard, turning a scheduler wedge into
+                # an ordinary drain
+                if self.heartbeat is not None:
+                    self.heartbeat.beat(self._steps)
 
     def _flush_occupancy_counters(self) -> None:
         sched = self.scheduler
@@ -852,85 +916,97 @@ class ServingEngine:
             steps[req.slot] = s.step_offset + len(req.output_tokens)
         return temp, top_k, top_p, seeds, steps
 
-    def _prefill_tick(self) -> None:
+    def _prefill_tick(self, tick: spans.span) -> None:
         """Advance every prefilling slot by at most one chunk
         (``prefill_len`` tokens) in ONE fixed-shape device call; slots
         whose prompt completes this chunk sample their first token
         in-graph."""
+        cands = [r for r in self.scheduler.running() if r.prefilling]
+        if not cands:
+            return      # no chunk to plan: the scan is the tick's own time
         B, T = self.serving.max_batch, self.prefill_len
         bs = self.cache.block_size
-        cands = sorted(
-            (r for r in self.scheduler.running() if r.prefilling),
-            key=lambda r: r.admit_seq)
-        plan: List[Tuple[Request, int]] = []
-        for req in cands:
-            if req.slot is None or not req.prefilling:
-                continue    # preempted by an older request's growth
-            chunk = min(req.prefill_target - req.cache_len, T)
-            if self.live_prefill_chunk is not None:
-                # live retune (ISSUE 18): the cap is data — the device
-                # call keeps its compiled [B, T] shape and fills less
-                chunk = min(chunk, self.live_prefill_chunk)
-            covered = self.scheduler.try_grow_to(
-                req, req.cache_len + chunk)
-            chunk = min(chunk, covered - req.cache_len)
-            if chunk > 0:
-                plan.append((req, chunk))
-        if not plan:
-            return
+        with self._span("serving/tick/prefill_plan"):
+            cands.sort(key=lambda r: r.admit_seq)
+            plan: List[Tuple[Request, int]] = []
+            for req in cands:
+                if req.slot is None or not req.prefilling:
+                    continue    # preempted by an older request's growth
+                chunk = min(req.prefill_target - req.cache_len, T)
+                if self.live_prefill_chunk is not None:
+                    # live retune (ISSUE 18): the cap is data — the device
+                    # call keeps its compiled [B, T] shape and fills less
+                    chunk = min(chunk, self.live_prefill_chunk)
+                covered = self.scheduler.try_grow_to(
+                    req, req.cache_len + chunk)
+                chunk = min(chunk, covered - req.cache_len)
+                if chunk > 0:
+                    plan.append((req, chunk))
+            if not plan:
+                return
 
-        tokens = np.zeros((B, T), np.int32)
-        pos_ids = np.zeros((B, T), np.int32)
-        limits = np.zeros((B, T), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        dest_b = np.full((B, T), self.cache.n_blocks, np.int32)  # OOB=drop
-        dest_o = np.zeros((B, T), np.int32)
-        sample_index = np.full((B,), T, np.int32)                # OOB=none
-        for req, chunk in plan:
-            s = req.slot
-            wire = req.sequence_tokens()
-            lo = req.cache_len
-            tokens[s, :chunk] = wire[lo:lo + chunk]
-            pos_ids[s, :chunk] = np.arange(lo, lo + chunk)
-            limits[s, :chunk] = np.arange(lo + 1, lo + chunk + 1)
-            lengths[s] = lo + chunk
-            dest_b[s, :chunk] = [req.blocks[(lo + t) // bs]
-                                 for t in range(chunk)]
-            dest_o[s, :chunk] = [(lo + t) % bs for t in range(chunk)]
-            if lo + chunk == req.prefill_target:
-                sample_index[s] = chunk - 1
-        self._refresh_tables()
-        samp = self._sampling_arrays()
+            tokens = np.zeros((B, T), np.int32)
+            pos_ids = np.zeros((B, T), np.int32)
+            limits = np.zeros((B, T), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            dest_b = np.full((B, T), self.cache.n_blocks, np.int32)  # OOB=drop
+            dest_o = np.zeros((B, T), np.int32)
+            sample_index = np.full((B,), T, np.int32)                # OOB=none
+            for req, chunk in plan:
+                s = req.slot
+                wire = req.sequence_tokens()
+                lo = req.cache_len
+                tokens[s, :chunk] = wire[lo:lo + chunk]
+                pos_ids[s, :chunk] = np.arange(lo, lo + chunk)
+                limits[s, :chunk] = np.arange(lo + 1, lo + chunk + 1)
+                lengths[s] = lo + chunk
+                dest_b[s, :chunk] = [req.blocks[(lo + t) // bs]
+                                     for t in range(chunk)]
+                dest_o[s, :chunk] = [(lo + t) % bs for t in range(chunk)]
+                if lo + chunk == req.prefill_target:
+                    sample_index[s] = chunk - 1
+            self._refresh_tables()
+            samp = self._sampling_arrays()
+            args = (tokens, pos_ids, self._jnp.asarray(self._tables),
+                    lengths, limits, dest_b, dest_o, sample_index)
+            if self.adapter_arena is None:
+                args = (self.arenas, self.params) + args + samp
+            else:
+                args = (self.arenas, self.adapters, self.params) + args \
+                    + (self._adapter_slot_array(),) + samp
+            n_tokens = int(sum(c for _, c in plan))
 
         with timeline.scope("prefill", rids=[r.rid for r, _ in plan],
-                            tokens=int(sum(c for _, c in plan))):
-            if self.adapter_arena is None:
-                self.arenas, next_tokens, _ = self._prefill(
-                    self.arenas, self.params, tokens, pos_ids,
-                    self._jnp.asarray(self._tables), lengths, limits,
-                    dest_b, dest_o, sample_index, *samp)
-            else:
-                self.arenas, self.adapters, next_tokens, _ = \
-                    self._prefill(
-                        self.arenas, self.adapters, self.params, tokens,
-                        pos_ids, self._jnp.asarray(self._tables),
-                        lengths, limits, dest_b, dest_o, sample_index,
-                        self._adapter_slot_array(), *samp)
-            next_np = np.asarray(next_tokens)
+                            tokens=n_tokens):
+            with self._span("serving/tick/prefill_dispatch"):
+                if self.adapter_arena is None:
+                    self.arenas, next_tokens, _ = self._prefill(*args)
+                else:
+                    self.arenas, self.adapters, next_tokens, _ = \
+                        self._prefill(*args)
+            with self._span("serving/tick/prefill_fetch"):
+                next_np = np.asarray(next_tokens)
 
-        now = time.monotonic()
-        for req, chunk in plan:
-            self.scheduler.note_prefilled(req, chunk)
-            if not req.prefilling:
-                # prompt complete: the in-graph sample at its last
-                # prompt position is the request's next output token.
-                # The prefilled marker is the trace walk's prefill →
-                # decode boundary (ISSUE 15) — re-emitted per admission
-                # (a preempted request's recompute prefill ends here too)
-                timeline.emit("request_prefilled", rid=req.rid,
-                              tokens=req.prefill_target,
-                              **trace_fields(req))
-                self._emit(req, int(next_np[req.slot]), now)
+        with self._span("serving/tick/prefill_deliver"):
+            self._counters.prefill_calls.inc()
+            self._counters.prefill_tokens.inc(n_tokens)
+            self._counters.prefill_capacity.inc(B * T)
+            tick.note(prefill_rows=len(plan), prefill_tokens=n_tokens,
+                      prefill_capacity=B * T)
+            now = time.monotonic()
+            for req, chunk in plan:
+                self.scheduler.note_prefilled(req, chunk)
+                if not req.prefilling:
+                    # prompt complete: the in-graph sample at its last
+                    # prompt position is the request's next output token.
+                    # The prefilled marker is the trace walk's prefill →
+                    # decode boundary (ISSUE 15) — re-emitted per
+                    # admission (a preempted request's recompute prefill
+                    # ends here too)
+                    timeline.emit("request_prefilled", rid=req.rid,
+                                  tokens=req.prefill_target,
+                                  **trace_fields(req))
+                    self._emit(req, int(next_np[req.slot]), now)
 
     # -------------------------------------------------------------- decode
 
@@ -952,85 +1028,109 @@ class ServingEngine:
             return []
         return list(self.proposer.propose(req, max_k))[:max_k]
 
-    def _decode_once(self) -> None:
+    def _decode_once(self, tick: spans.span):
+        """Plan, dispatch and fetch this tick's one decode call; returns
+        what :meth:`_deliver` takes, or ``None`` where no slot decodes."""
+        if not self.scheduler.running():
+            return None
         B, S = self.serving.max_batch, self.spec_width
-        # a request at the context cap cannot write another token:
-        # deliver what it has (truncation is a response, not a hang)
-        for req in list(self.scheduler.running()):
-            if not req.prefilling and req.cache_len >= self.cache.max_seq:
-                self._finish(req)
-        # grow this tick's write blocks oldest-first (evict cached LRU,
-        # then preempt strictly newer requests); a newer request that
-        # cannot grow just sits this tick out — it keeps its cache
-        decoding = sorted(
-            (r for r in self.scheduler.running() if not r.prefilling),
-            key=lambda r: r.admit_seq)
-        reqs: List[Request] = []
-        drafts: dict = {}
-        for req in decoding:
-            if req.slot is None or req.state is not RequestState.RUNNING:
-                continue    # preempted by an older request's growth
-            covered = self.scheduler.try_grow_to(req, req.cache_len + 1)
-            if covered < req.cache_len + 1:
-                continue
-            draft = self._propose_drafts(req)
-            if draft:
-                # blocks for drafted rows come from the free list or the
-                # cache LRU only, NEVER preemption: speculation is an
-                # optimization and must not evict a neighbour's real KV.
-                # A short grow just truncates the draft (data, not shape).
+        with self._span("serving/tick/decode_plan") as phase:
+            preempted = self.scheduler.preemptions
+            # a request at the context cap cannot write another token:
+            # deliver what it has (truncation is a response, not a hang)
+            for req in list(self.scheduler.running()):
+                if (not req.prefilling
+                        and req.cache_len >= self.cache.max_seq):
+                    self._finish(req)
+            # grow this tick's write blocks oldest-first (evict cached
+            # LRU, then preempt strictly newer requests); a newer request
+            # that cannot grow just sits this tick out — it keeps its cache
+            decoding = sorted(
+                (r for r in self.scheduler.running() if not r.prefilling),
+                key=lambda r: r.admit_seq)
+            reqs: List[Request] = []
+            drafts: dict = {}
+            for req in decoding:
+                if (req.slot is None
+                        or req.state is not RequestState.RUNNING):
+                    continue    # preempted by an older request's growth
                 covered = self.scheduler.try_grow_to(
-                    req, req.cache_len + 1 + len(draft), preempt=False)
-                draft = draft[:max(0, covered - (req.cache_len + 1))]
-            drafts[req.rid] = draft
-            reqs.append(req)
-        if not reqs:
-            return
-        tokens = np.zeros((B, S), np.int32)
-        positions = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        n_draft = np.zeros((B,), np.int32)
-        for req in reqs:
-            d = drafts[req.rid]
-            tokens[req.slot, 0] = req.last_token
-            if d:
-                tokens[req.slot, 1:1 + len(d)] = d
-            positions[req.slot] = req.cache_len
-            active[req.slot] = True
-            n_draft[req.slot] = len(d)
-        self._refresh_tables()
-        samp = self._sampling_arrays()
+                    req, req.cache_len + 1)
+                if covered < req.cache_len + 1:
+                    continue
+                draft = self._propose_drafts(req)
+                if draft:
+                    # blocks for drafted rows come from the free list or
+                    # the cache LRU only, NEVER preemption: speculation is
+                    # an optimization and must not evict a neighbour's real
+                    # KV.  A short grow just truncates the draft (data, not
+                    # shape).
+                    covered = self.scheduler.try_grow_to(
+                        req, req.cache_len + 1 + len(draft),
+                        preempt=False)
+                    draft = draft[:max(0, covered - (req.cache_len + 1))]
+                drafts[req.rid] = draft
+                reqs.append(req)
+            phase.note(preempted=self.scheduler.preemptions - preempted)
+            if not reqs:
+                return None
+            tokens = np.zeros((B, S), np.int32)
+            positions = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            n_draft = np.zeros((B,), np.int32)
+            for req in reqs:
+                d = drafts[req.rid]
+                tokens[req.slot, 0] = req.last_token
+                if d:
+                    tokens[req.slot, 1:1 + len(d)] = d
+                positions[req.slot] = req.cache_len
+                active[req.slot] = True
+                n_draft[req.slot] = len(d)
+            self._refresh_tables()
+            samp = self._sampling_arrays()
 
-        tables = self._jnp.asarray(self._tables)
-        if self.adapter_arena is None:
-            args = (self.arenas, self.params, tokens, positions, tables,
-                    active, n_draft) + samp
-        else:
-            args = (self.arenas, self.adapters, self.params, tokens,
-                    positions, tables, active, n_draft,
-                    self._adapter_slot_array()) + samp
-        if not self._flops_probed:
-            # One-time FLOPs probe for the MFU gauge: lowering traces
-            # the decode body (no second XLA compile, no execution —
-            # the arenas are not donated by a trace) and the HLO cost
-            # pass reports the program's FLOPs.  Must happen BEFORE the
-            # call below consumes the donated arenas.
-            self._probe_decode_flops(args)
-        t0 = time.perf_counter()
-        if self.adapter_arena is None:
-            self.arenas, out_tokens, accepted, _ = self._decode(*args)
-        else:
-            self.arenas, self.adapters, out_tokens, accepted, _ = \
-                self._decode(*args)
-        out_np = np.asarray(out_tokens)
-        acc_np = np.asarray(accepted)
-        self._last_decode_s = time.perf_counter() - t0
+            tables = self._jnp.asarray(self._tables)
+            if self.adapter_arena is None:
+                args = (self.arenas, self.params, tokens, positions,
+                        tables, active, n_draft) + samp
+            else:
+                args = (self.arenas, self.adapters, self.params, tokens,
+                        positions, tables, active, n_draft,
+                        self._adapter_slot_array()) + samp
+            if not self._flops_probed:
+                # One-time FLOPs probe for the MFU gauge: lowering traces
+                # the decode body (no second XLA compile, no execution —
+                # the arenas are not donated by a trace) and the HLO cost
+                # pass reports the program's FLOPs.  Must happen BEFORE
+                # the call below consumes the donated arenas.
+                self._probe_decode_flops(args)
+        with self._span("serving/tick/decode_dispatch") as dispatch:
+            if self.adapter_arena is None:
+                self.arenas, out_tokens, accepted, logits = \
+                    self._decode(*args)
+            else:
+                self.arenas, self.adapters, out_tokens, accepted, logits = \
+                    self._decode(*args)
+            # replaces, and so frees, the call before's
+            self._last_logits = (logits, tuple(r.slot for r in reqs))
+        with self._span("serving/tick/decode_fetch") as fetch:
+            out_np = np.asarray(out_tokens)
+            acc_np = np.asarray(accepted)
+        tick.note(decode_slots=len(reqs))
+        return reqs, drafts, out_np, acc_np, dispatch.ms + fetch.ms
+
+    def _deliver(self, reqs: List[Request], drafts: dict, out_np, acc_np,
+                 decode_ms: float) -> int:
+        """Hand the decode call's tokens to their requests (the accepted
+        prefix of each slot's verify); returns how many were emitted."""
         self._decode_calls += 1
         self._slot_steps += len(reqs)
-        self._refresh_mfu()
+        self._counters.decode_calls.inc()
+        self._counters.decode_slot_steps.inc(len(reqs))
+        self._refresh_mfu(decode_ms)
 
         now = time.monotonic()
-        proposed_total = accepted_total = 0
+        emitted = proposed_total = accepted_total = 0
         for req in reqs:
             d = drafts[req.rid]
             acc = int(acc_np[req.slot])
@@ -1059,6 +1159,7 @@ class ServingEngine:
                     req.cache_len += 1    # draft j == the token just
                     #                       emitted — its row is real
                 self._emit(req, int(out_np[req.slot, j]), now)
+                emitted += 1
                 if req.state is not RequestState.RUNNING:
                     break                 # eos/budget: drop the rest
         if proposed_total:
@@ -1072,13 +1173,12 @@ class ServingEngine:
         if self.spec_proposed:
             self.registry.gauge("serving/spec_acceptance").set(
                 self.spec_accepted / self.spec_proposed)
+        return emitted
 
     # ------------------------------------------------------------------ mfu
 
     def _probe_decode_flops(self, args) -> None:
         """Fill ``self._decode_flops`` (or the reason it is unknown)."""
-        from apex_tpu.observability.metrics import compiled_flops
-
         self._flops_probed = True
         try:
             lowered = self._decode.lower(*args)
@@ -1089,14 +1189,12 @@ class ServingEngine:
             return
         self._decode_flops = compiled_flops(lowered)
 
-    def _refresh_mfu(self) -> None:
-        """Derive MFU from the last decode's wall time; flush the gauge
-        when defined, keep the None-reason (unknown device peak vs
-        missing cost analysis) for ``/statusz`` and logs otherwise."""
-        from apex_tpu.observability.metrics import mfu_or_reason
-
-        if self._last_decode_s is None:
-            return
+    def _refresh_mfu(self, decode_ms: float) -> None:
+        """Derive MFU from the last decode call's wall time (its
+        dispatch and fetch spans); flush the gauge when defined, keep the
+        None-reason (unknown device peak vs missing cost analysis) for
+        ``/statusz`` and logs otherwise."""
+        self._decode_ms = decode_ms
         if self._probe_fail_reason is not None:
             # keep the specific probe failure — the generic "no
             # cost-analysis FLOPs" message would misdiagnose it
@@ -1104,7 +1202,7 @@ class ServingEngine:
             return
         n_devices = self.mesh.devices.size
         value, reason = mfu_or_reason(
-            self._decode_flops, self._last_decode_s,
+            self._decode_flops, decode_ms / 1e3,
             device=self.mesh.devices.flat[0], n_devices=n_devices)
         self.mfu, self.mfu_reason = value, reason
         if value is not None:
@@ -1160,11 +1258,38 @@ class ServingEngine:
                                   if self.adapter_arena is not None
                                   else None),
             "cache_dtype": str(np.dtype(self.cache.dtype)),
-            "last_decode_ms": (round(self._last_decode_s * 1e3, 3)
-                               if self._last_decode_s is not None else None),
+            "last_decode_ms": (round(self._decode_ms, 3)
+                               if self._decode_ms is not None else None),
+            "slowest_tick": self._slowest_tick(),
             "mfu": self.mfu,
             "mfu_reason": self.mfu_reason,
         }
+
+    def last_logits(self) -> Optional[Tuple[Any, Tuple[int, ...]]]:
+        """The last decode call's logits (the device array
+        ``[max_batch, spec_width, vocab]``, as the program returned it)
+        and the slots that decoded in that call (the other rows are
+        padding); ``None`` before the first call.  Held by reference
+        until the next decode call replaces it, never copied."""
+        return self._last_logits
+
+    @staticmethod
+    def _slowest_tick() -> Optional[dict]:
+        """The phase split, in ms, of the longest ``serving/tick`` in the
+        span ring (the process's: a replica runs one engine) — what
+        places a stalled tick in the scheduler, the dispatch or the
+        fetch.  ``own`` is what no phase covers, so the phases sum to
+        ``ms``."""
+        records = spans.recorded()
+        ticks = [s for s in records if s.name == "serving/tick"]
+        if not ticks:
+            return None
+        worst = max(ticks, key=lambda s: s.ms)
+        children = [s for s in records if s.parent == worst.id]
+        phases = {s.name.rpartition("/")[2]: s.ms for s in children}
+        phases["own"] = spans.self_ms([worst] + children)[worst.id]
+        return {"step": worst.fields["step"], "ms": round(worst.ms, 3),
+                "phases": {k: round(v, 3) for k, v in phases.items()}}
 
     # ---------------------------------------------------------- bookkeeping
 

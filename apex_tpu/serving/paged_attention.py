@@ -90,6 +90,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.spans import named_span
 from apex_tpu.utils import platform
 
 __all__ = [
@@ -282,14 +283,19 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
     kernel = functools.partial(_decode_kernel, scale=_resolve(scale, d),
                                block_size=bs, hpg=hpg,
                                has_scales=has_scales)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, d), q.dtype),
-        compiler_params=_compiler_params(),
-        interpret=platform.pallas_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+    # the scope names the Mosaic custom call in a device trace
+    # (``%paged_decode.<n>``): XLA names an instruction after the innermost
+    # scope round it, which is otherwise the layer scan's ``closed_call``
+    with named_span("paged_decode"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n, d), q.dtype),
+            compiler_params=_compiler_params(),
+            interpret=platform.pallas_interpret(),
+            name="paged_decode",
+        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+          *operands)
 
 
 def _compiler_params():
@@ -489,14 +495,16 @@ def _multi_query_attention(q, k_arena, v_arena, block_tables, lengths,
     kernel = functools.partial(_prefill_kernel, scale=_resolve(scale, d),
                                block_size=bs, hpg=hpg,
                                has_scales=has_scales)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, T, d), q.dtype),
-        compiler_params=_compiler_params(),
-        interpret=platform.pallas_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+    with named_span("paged_prefill"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n, T, d), q.dtype),
+            compiler_params=_compiler_params(),
+            interpret=platform.pallas_interpret(),
+            name="paged_prefill",
+        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+          *operands)
     return out.transpose(0, 2, 1, 3)
 
 

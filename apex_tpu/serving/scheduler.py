@@ -144,6 +144,7 @@ class Request:
 
     # wall-clock marks for the latency metrics (engine-stamped)
     t_submit: float = 0.0
+    t_admit: Optional[float] = None     # the latest admission
     t_first_token: Optional[float] = None
     t_last_token: Optional[float] = None
 

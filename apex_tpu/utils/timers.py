@@ -6,9 +6,9 @@ TensorBoard writer hook; accessor ``get_timers``
 (``pipeline_parallel/utils.py:146-157``).
 
 TPU version synchronizes via ``jax.block_until_ready`` on a token the caller
-passes (or ``jax.effects_barrier``), and also exposes
-``jax.profiler.TraceAnnotation`` context managers as the NVTX-range analog
-(``apex/parallel/distributed.py:363`` ``nvtx.range_push``).
+passes (or ``jax.effects_barrier``).  The NVTX-range analog
+(``apex/parallel/distributed.py:363`` ``nvtx.range_push``) is
+``apex_tpu.observability.spans.span``.
 """
 
 from __future__ import annotations
@@ -18,7 +18,7 @@ from typing import Dict, Optional
 
 import jax
 
-__all__ = ["Timers", "get_timers", "trace_annotation"]
+__all__ = ["Timers", "get_timers"]
 
 
 class _Timer:
@@ -113,8 +113,3 @@ def get_timers() -> Timers:
     if _GLOBAL_TIMERS is None:
         _GLOBAL_TIMERS = Timers()
     return _GLOBAL_TIMERS
-
-
-def trace_annotation(name: str):
-    """Profiler range context — the NVTX ``range_push/pop`` analog."""
-    return jax.profiler.TraceAnnotation(name)
