@@ -69,6 +69,10 @@ docs/serving.md):
   ``max_batch x prefill_len`` their fixed shape has room for
 - ``serving/decode_slot_steps`` counter — slots that took part in a
   decode call, summed over calls
+- ``serving/decode_kv_tokens`` / ``serving/decode_kv_pages`` counters —
+  cache rows the decode calls' slots attended, and the KV blocks that
+  hold them (``ceil(rows / block_size)`` per slot): what the paged
+  decode kernel had to read (ISSUE 26)
 - ``serving/queue_wait_ms`` histogram (sampled) — submit to first
   admission, per request
 
@@ -414,7 +418,9 @@ class ServingEngine:
             prefill_tokens=counter("serving/prefill_tokens"),
             prefill_capacity=counter("serving/prefill_capacity_tokens"),
             decode_calls=counter("serving/decode_calls"),
-            decode_slot_steps=counter("serving/decode_slot_steps"))
+            decode_slot_steps=counter("serving/decode_slot_steps"),
+            decode_kv_tokens=counter("serving/decode_kv_tokens"),
+            decode_kv_pages=counter("serving/decode_kv_pages"))
         self._queue_wait = self.registry.histogram(
             "serving/queue_wait_ms", keep_samples=4096)
         self._decode_calls = 0         # device decode/verify invocations
@@ -1071,7 +1077,17 @@ class ServingEngine:
                     draft = draft[:max(0, covered - (req.cache_len + 1))]
                 drafts[req.rid] = draft
                 reqs.append(req)
-            phase.note(preempted=self.scheduler.preemptions - preempted)
+            # what the attention kernel meets this tick: each decoding
+            # slot attends its history, the row it writes and its drafts
+            bs = self.cache.block_size
+            history = [req.cache_len + 1 + len(drafts[req.rid])
+                       for req in reqs]
+            kv_tokens = sum(history)
+            kv_pages = sum(-(-h // bs) for h in history)
+            phase.note(preempted=self.scheduler.preemptions - preempted,
+                       kv_tokens=kv_tokens, kv_pages=kv_pages)
+            self._counters.decode_kv_tokens.inc(kv_tokens)
+            self._counters.decode_kv_pages.inc(kv_pages)
             if not reqs:
                 return None
             tokens = np.zeros((B, S), np.int32)

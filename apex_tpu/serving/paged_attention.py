@@ -30,14 +30,32 @@ the operation-fusion paper (PAPERS.md, arxiv 2502.17728) measures as
 the dominant cost.  The fused kernels do **gather + online-softmax
 attention in one pass**:
 
-- grid ``(batch, max_blocks)`` with the block index innermost; the
-  K/V **index maps read the block table** (scalar prefetch —
-  ``pltpu.PrefetchScalarGridSpec``), so each grid step's HBM→VMEM copy
-  pulls the right physical block directly.  No gathered K/V copy ever
-  exists in HBM.
-- blocks past the request's length are skipped with ``pl.when`` (no
-  MXU/VPU work) and their index maps **clamp to the last live block**,
-  so Pallas elides the HBM copy too — the paged analog of the flash
+- the K/V **index maps read scalar-prefetched tables**
+  (``pltpu.PrefetchScalarGridSpec``), so each HBM→VMEM copy pulls the
+  right physical block directly.  No gathered K/V copy ever exists in
+  HBM.
+- **decode**: a grid step is a *group of P pages of one slot and all
+  heads in one pass* (ISSUE 26), and the grid is as long as the lengths
+  need (a dynamic bound): one step per ``P`` live pages of each slot, one
+  for an empty slot's zero row, at most ``batch * ceil(max_blocks / P)``.
+  Each arena is bound to the call ``P`` times, page ``p`` of the group
+  each, through an index map into a **step plan** (``_step_plan``): a
+  live page is its block-table entry, any other page names the block
+  its operand needs at its next live step (or held at its last).  So a
+  dead page is neither copied (the pipeline sees an unchanged block
+  index) nor computed (``pl.when``), a live page's copy starts as soon
+  as its buffer is free, groups past a slot's length have no step, and
+  table columns past the live range never reach a DMA.  ``P`` is
+  derived, not set (``_pages_per_step``): the VMEM bytes of a page
+  against a fixed budget for the double-buffered K and V tiles, at most
+  ``_MAX_PAGES`` operands per arena and at most ``max_blocks``.  (The
+  arenas cannot stay in HBM with the kernel issuing the page copies
+  itself: Mosaic refuses to slice a ``pl.ANY`` ref whose minor
+  dimension, ``head_dim`` 64, is not a multiple of 128.)
+- **multi-query** (prefill, verify): grid ``(batch, max_blocks)``, one
+  page a step; blocks past the request's length are skipped with
+  ``pl.when`` and their index maps **clamp to the last live block**, so
+  Pallas elides the HBM copy too — the paged analog of the flash
   kernel's causal block skipping (``ops/flash_attention.py``).
 - running ``(m, l, acc)`` online-softmax state lives in VMEM scratch
   across the block sweep (the flash decomposition), so VMEM holds
@@ -48,18 +66,23 @@ attention in one pass**:
   **int8 cache** passes the per-vector scale arenas
   (``k_scales``/``v_scales``, one fp32 scale per cached row, stored
   block-major beside the block): the scale blocks ride the same
-  table-indexed index maps and the dequant is a VMEM multiply —
-  quarter the HBM bytes of fp32, half of bf16, for one extra
-  ``1/head_dim``-sized read.
+  index maps and the dequant is a VMEM multiply — quarter the HBM
+  bytes of fp32, half of bf16, for one extra ``1/head_dim``-sized read.
 - grouped-query attention: the arena stores the compact ``kv_heads``
   (= query groups); the kernel reads each KV head once *in VMEM* for
   its whole group of query heads — the GQA bandwidth saving is
   precisely the point of storing groups, not heads.
-- shaped for the TPU compiler: a static loop over KV heads of 2-D fp32
-  contractions (Mosaic refuses a ``dot_general`` with a batch dimension
-  in the middle, which a per-head einsum over the ``[block, heads,
-  dim]`` arena block is); the multi-query sweep carries q/out head-major
-  and ``limits`` as ``[b, T, 1]`` inside, behind unchanged signatures.
+- shaped for the TPU compiler.  Decode with one query head per KV head
+  is a matrix-vector product per head: all heads of a page at once on
+  the VPU in exact fp32 (a multiply of the ``[block, heads, dim]`` page
+  by ``q [heads, dim]`` and a reduction over ``dim``; ``p v`` is a
+  multiply and a reduction over the page's rows), one update of the
+  running max, sum and accumulator per page.  With GQA, and in the
+  multi-query sweep, a static loop over KV heads of 2-D fp32
+  contractions on the MXU (Mosaic refuses a ``dot_general`` with a
+  batch dimension in the middle, which a per-head einsum over the
+  arena block is); the multi-query sweep carries q/out head-major and
+  ``limits`` as ``[b, T, 1]`` inside, behind unchanged signatures.
 
 Layouts::
 
@@ -69,7 +92,8 @@ Layouts::
     k/v arena:    [n_blocks, block_size, kv_heads, head_dim]
     k/v scales:   [n_blocks, block_size, kv_heads]  fp32 (int8 cache)
     block_tables: [batch, max_blocks]  int32  (entries past the live
-                  range may be anything in-range; they are clamped)
+                  range are never used to fetch: decode takes them for
+                  nothing at all, the multi-query sweep clamps them)
     lengths:      [batch] int32  (tokens in cache; 0 = inactive slot)
     limits:       [batch, chunk] int32 (prefill: each token attends
                   cache positions < limit; 0 = padding token)
@@ -143,19 +167,92 @@ def _online_softmax(s, m_prev, l_prev):
     return p, alpha, m_new, l_new
 
 
-def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                   scale: float, block_size: int, hpg: int,
+# VMEM the decode kernel gives to the double-buffered K and V pages of one
+# grid step (scale pages included), and the most pages it binds: each is
+# one operand of the call and one unrolled page of the kernel body.  On a
+# v5e the pipeline's bookkeeping costs 0.04 us per operand per step, so a
+# partly filled group costs with its width: 8 pages measured 4-6% faster
+# than 16 at gpt2-medium's shapes, and no slower than 4 (PERF.md §6).
+_KV_TILE_BYTES = 4 * 1024 * 1024
+_MAX_PAGES = 8
+
+
+def _pages_per_step(page_bytes: int, max_blocks: int) -> int:
+    """How many pages one grid step of the decode kernel handles (``P``):
+    as many as fit the VMEM budget twice over for K and for V, at least
+    one, at most ``_MAX_PAGES`` and a slot's whole table.  ``page_bytes``
+    is what one page (with its scale page, for an int8 cache) occupies in
+    VMEM."""
+    return max(1, min(_KV_TILE_BYTES // (4 * page_bytes), _MAX_PAGES,
+                      max_blocks))
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of a VMEM array: the last two dimensions are padded to the
+    dtype's ``(sublanes, 128)`` tile (8 rows of 32 bits, packed rows for
+    narrower types)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * (4 // itemsize)
+    *lead, rows, cols = shape
+    n = -(-rows // sublanes) * sublanes * -(-cols // _LANES) * _LANES
+    for dim in lead:
+        n *= dim
+    return n * itemsize
+
+
+def _step_plan(block_tables, lengths, block_size: int, pages: int):
+    """The decode kernel's sweep, from the table and the lengths: which
+    ``(slot, group)`` each grid step is, how many steps there are, and
+    which arena block each of the ``pages`` page operands holds at each.
+
+    A slot takes one step per ``pages`` live pages (an empty slot one, to
+    write its zero row), so the grid has no step for a group past a
+    slot's length.  In ``plan``, flat ``[step * pages + page]``, a live
+    page (``group * pages + page`` below the slot's page count) is its
+    table entry.  Any other page names the block the operand needs at its
+    *next* live step (the pipeline copies a block when its index changes,
+    so that copy starts as early as the buffer is free, under the compute
+    of the steps between), or, after its last, the block it held: table
+    columns past the live range never reach a DMA, and a dead page costs
+    no copy."""
+    b, max_blocks = block_tables.shape
+    n_groups = pl.cdiv(max_blocks, pages)
+    steps = jnp.arange(b * n_groups)
+    n_live = -(-lengths // block_size)                  # pages of a slot
+    groups = jnp.maximum(-(-n_live // pages), 1)        # steps of a slot
+    ends = jnp.cumsum(groups)
+    slot = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1), b - 1)
+    group = steps - (ends - groups)[slot]
+    cols = group[:, None] * pages + jnp.arange(pages)[None, :]
+    live = (cols < n_live[slot][:, None]) & (steps < ends[-1])[:, None]
+    own = block_tables[slot[:, None], jnp.minimum(cols, max_blocks - 1)]
+    at = steps[:, None]
+    after = jax.lax.cummin(jnp.where(live, at, steps.size), axis=0,
+                           reverse=True)
+    before = jax.lax.cummax(jnp.where(live, at, -1), axis=0)
+    src = jnp.where(after < steps.size, after, before)
+    held = jnp.take_along_axis(own, jnp.maximum(src, 0), axis=0)
+    plan = jnp.where(src >= 0, held, 0).reshape(-1)
+    return tuple(x.astype(jnp.int32) for x in (ends[-1], plan, slot, group))
+
+
+def _decode_kernel(plan_ref, slot_ref, group_ref, len_ref, q_ref, *rest,
+                   scale: float, block_size: int, pages: int, hpg: int,
                    has_scales: bool):
-    if has_scales:
-        ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_sc, l_sc, acc_sc = rest
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    num_blocks = pl.num_programs(1)
-    length = len_ref[i]
-    kv_heads = k_ref.shape[2]
+    """One grid step = one group of ``pages`` pages of one slot, all heads.
+    ``rest``: the K arena bound ``pages`` times (page ``p`` of the group
+    each), the V arena likewise, the scale arenas likewise for an int8
+    cache, then the output and the softmax state."""
+    del plan_ref                    # read by the index maps only
+    k_refs, v_refs, ks_refs, vs_refs = (
+        rest[a * pages:(a + 1) * pages] for a in range(4))
+    if not has_scales:
+        ks_refs = vs_refs = (None,) * pages
+    o_ref, m_sc, l_sc, acc_sc = rest[-4:]
+    step = pl.program_id(0)
+    j = group_ref[step]
+    length = len_ref[slot_ref[step]]
 
     @pl.when(j == 0)
     def _init():
@@ -163,19 +260,42 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    @pl.when(j * block_size < length)
-    def _body():
-        cols = j * block_size + jax.lax.broadcasted_iota(
+    def all_heads(k_ref, v_ref, ks_ref, vs_ref, first):
+        """``hpg == 1``: the scores are a matrix-vector product per head.
+        All heads of the page at once on the VPU, in exact fp32: heads on
+        sublanes, head_dim on lanes."""
+        q = q_ref[0].astype(jnp.float32) * scale                 # [g, d]
+        k = k_ref[0].astype(jnp.float32)                         # [bs, g, d]
+        v = v_ref[0].astype(jnp.float32)
+        if has_scales:
+            k = k * ks_ref[0][:, :, None]
+            v = v * vs_ref[0][:, :, None]
+        s = jnp.sum(k * q[None], axis=2, keepdims=True)          # [bs, g, 1]
+        rows = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(rows < length, s, NEG_INF)
+        # a live page holds a live row: m_new is finite, and a row past
+        # the length weighs exp(NEG_INF - m_new), an exact 0
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))          # [g, 1]
+        p = jnp.exp(s - m_new[None])
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_sc[:, :1] * alpha + jnp.sum(p, axis=0)
+        acc_sc[...] = acc_sc[...] * alpha + jnp.sum(p * v, axis=0)
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+
+    def per_kv_head(k_ref, v_ref, ks_ref, vs_ref, first):
+        """GQA: an MXU contraction per KV head with its ``hpg`` query rows;
+        the KV head is read once from VMEM for its whole group."""
+        cols = first + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
         live = cols < length
-        for h in range(kv_heads):
-            # GQA: the group's hpg query heads share KV head h, read
-            # once from VMEM
+        for h in range(k_ref.shape[2]):
             rows = slice(h * hpg, (h + 1) * hpg)
-            q = q_ref[0, rows, :].astype(jnp.float32)    # [hpg, d]
-            k = _head_rows(k_ref, ks_ref, h)             # [bs, d]
+            q = q_ref[0, rows, :].astype(jnp.float32)            # [hpg, d]
+            k = _head_rows(k_ref, ks_ref, h)                     # [bs, d]
             v = _head_rows(v_ref, vs_ref, h)
-            s = _dot(q, k, ((1,), (1,))) * scale         # [hpg, bs]
+            s = _dot(q, k, ((1,), (1,))) * scale                 # [hpg, bs]
             s = jnp.where(live, s, NEG_INF)
             p, alpha, m_new, l_new = _online_softmax(
                 s, m_sc[rows, :1], l_sc[rows, :1])
@@ -184,7 +304,16 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             m_sc[rows, :] = jnp.broadcast_to(m_new, (hpg, _LANES))
             l_sc[rows, :] = jnp.broadcast_to(l_new, (hpg, _LANES))
 
-    @pl.when(j == num_blocks - 1)
+    page_step = all_heads if hpg == 1 else per_kv_head
+    for p in range(pages):
+        first = (j * pages + p) * block_size
+
+        # pages past the slot's length were not copied and are not computed
+        @pl.when(first < length)
+        def _page(p=p, first=first):
+            page_step(k_refs[p], v_refs[p], ks_refs[p], vs_refs[p], first)
+
+    @pl.when((j + 1) * pages * block_size >= length)    # the slot's last
     def _finalize():
         l_fin = l_sc[:, :1]
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
@@ -211,10 +340,10 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
                            scale: Optional[float] = None):
     """One fused gather+dequant+attention pass over the paged cache.
 
-    See the module docstring for layouts.  ``block_tables`` entries are
-    clamped into the live range, so unused table columns may hold any
-    value (the scheduler leaves them 0); a slot with ``lengths == 0``
-    produces a zero output row.  ``k_scales``/``v_scales`` (int8 cache)
+    See the module docstring for layouts.  ``block_tables`` columns past
+    a slot's live pages are never fetched, so they may hold any value
+    (the scheduler leaves them 0); a slot with ``lengths == 0`` produces
+    a zero output row.  ``k_scales``/``v_scales`` (int8 cache)
     are the per-row fp32 scale arenas.
 
     **Speculative k+1 verify** (ISSUE 13): with ``q`` of shape
@@ -245,33 +374,34 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
     max_blocks = block_tables.shape[1]
     has_scales = k_scales is not None
 
-    def kv_idx(i, j, tab_ref, len_ref):
-        # clamp skipped blocks to the last live one: Pallas re-references
-        # the previous block and elides the HBM copy (flash's causal
-        # skip); length 0 clamps to logical block 0 -> table entry 0.
-        live = jnp.maximum((len_ref[i] - 1) // bs, 0)
-        return (tab_ref[i, jnp.minimum(j, live)], 0, 0, 0)
-
-    def sc_idx(i, j, tab_ref, len_ref):
-        live = jnp.maximum((len_ref[i] - 1) // bs, 0)
-        return (tab_ref[i, jnp.minimum(j, live)], 0, 0)
-
-    def q_idx(i, j, tab_ref, len_ref):
-        return (i, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, n, d), q_idx),
-        pl.BlockSpec((1, bs, g, d), kv_idx),
-        pl.BlockSpec((1, bs, g, d), kv_idx),
-    ]
-    operands = [q, k_arena, v_arena]
+    page_bytes = _vmem_bytes((bs, g, d), k_arena.dtype)
     if has_scales:
-        in_specs += [pl.BlockSpec((1, bs, g), sc_idx),
-                     pl.BlockSpec((1, bs, g), sc_idx)]
-        operands += [k_scales, v_scales]
+        page_bytes += _vmem_bytes((bs, g), k_scales.dtype)
+    pages = _pages_per_step(page_bytes, max_blocks)
+    lengths = lengths.astype(jnp.int32)
+    n_steps, plan, slot, group = _step_plan(block_tables, lengths, bs, pages)
+
+    def q_idx(s, plan_ref, slot_ref, group_ref, len_ref):
+        return (slot_ref[s], 0, 0)
+
+    def page_spec(p, block):
+        def idx(s, plan_ref, slot_ref, group_ref, len_ref):
+            return (plan_ref[s * pages + p],) + (0,) * (len(block) - 1)
+        return pl.BlockSpec(block, idx)
+
+    # each arena is bound once per page of a group: page p of the step is
+    # the block the plan names, copied (double-buffered) by the pipeline
+    arenas = [(k_arena, (1, bs, g, d)), (v_arena, (1, bs, g, d))]
+    if has_scales:
+        arenas += [(k_scales, (1, bs, g)), (v_scales, (1, bs, g))]
+    in_specs = [pl.BlockSpec((1, n, d), q_idx)]
+    operands = [q]
+    for arena, block in arenas:
+        in_specs += [page_spec(p, block) for p in range(pages)]
+        operands += [arena] * pages
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_blocks),
+        num_scalar_prefetch=4,
+        grid=(n_steps,),        # as many steps as the lengths need
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n, d), q_idx),
         scratch_shapes=[
@@ -281,7 +411,7 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
         ],
     )
     kernel = functools.partial(_decode_kernel, scale=_resolve(scale, d),
-                               block_size=bs, hpg=hpg,
+                               block_size=bs, pages=pages, hpg=hpg,
                                has_scales=has_scales)
     # the scope names the Mosaic custom call in a device trace
     # (``%paged_decode.<n>``): XLA names an instruction after the innermost
@@ -291,11 +421,12 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n, d), q.dtype),
-            compiler_params=_compiler_params(),
+            # in order: a slot's steps carry its softmax state
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=platform.pallas_interpret(),
             name="paged_decode",
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          *operands)
+        )(plan, slot, group, lengths, *operands)
 
 
 def _compiler_params():

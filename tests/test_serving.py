@@ -13,6 +13,7 @@ Slow tier: the tp=2 parity leg and the train-mesh -> serve-mesh
 restore.
 """
 
+import inspect
 import time
 
 import jax
@@ -33,6 +34,7 @@ from apex_tpu.serving.fused_ops import (
     fused_residual_norm,
     residual_norm_unfused,
 )
+from apex_tpu.serving import paged_attention as paged_attention_module
 from apex_tpu.serving.paged_attention import (
     paged_attention_decode,
     paged_attention_decode_unfused,
@@ -152,6 +154,150 @@ class TestPagedAttentionKernel:
             paged_attention_decode(q, jnp.asarray(qk), jnp.asarray(qv),
                                    tables, lengths,
                                    k_scales=jnp.asarray(sk))
+
+    # ---- ISSUE 26: a grid step is a group of P pages, all heads at once
+
+    def _group_case(self, *, n, g, d, bs, mb, cache_dtype, seed=7):
+        """Eight slots whose histories sit on every boundary of the new
+        sweep: 1 token, one page, one short of / exactly / one past a full
+        group of ``P`` pages, the whole table; a length-0 slot between live
+        slots and as the last slot.  Tables are a permutation of the arena
+        (non-contiguous), and every column past a slot's live pages is
+        poisoned with an id far outside it."""
+        rng = np.random.RandomState(seed)
+        arena = np.zeros((1, bs, g, d), cache_dtype)
+        page_bytes = paged_attention_module._vmem_bytes(
+            arena.shape[1:], arena.dtype)
+        if cache_dtype == np.int8:
+            page_bytes += paged_attention_module._vmem_bytes(
+                (bs, g), np.float32)
+        P = paged_attention_module._pages_per_step(page_bytes, mb)
+        assert 1 < P < mb and mb % P, (P, mb)      # a ragged last group
+        lengths = np.array([1, bs, 0, P * bs - 1, P * bs, P * bs + 1,
+                            mb * bs, 0], np.int32)
+        b = len(lengths)
+        live = -(-lengths // bs)
+        n_blocks = int(live.sum()) + 3
+        perm = rng.permutation(n_blocks)
+        tables = np.full((b, mb), 10_000, np.int32)
+        at = 0
+        for i in range(b):
+            tables[i, :live[i]] = perm[at:at + live[i]]
+            at += live[i]
+        q = rng.randn(b, n, d).astype(np.float32)
+        ka = rng.randn(n_blocks, bs, g, d).astype(np.float32)
+        va = rng.randn(n_blocks, bs, g, d).astype(np.float32)
+        return q, ka, va, tables, lengths, bs, P
+
+    def _check_group_case(self, q, ka, va, tables, lengths, bs,
+                          cache_dtype):
+        kwargs = {}
+        if cache_dtype == np.int8:
+            ka, sk = _int8_quantize(ka)
+            va, sv = _int8_quantize(va)
+            kwargs = dict(k_scales=jnp.asarray(sk), v_scales=jnp.asarray(sv))
+            dense_k = ka.astype(np.float32) * sk[..., None]
+            dense_v = va.astype(np.float32) * sv[..., None]
+        else:
+            ka = jnp.asarray(ka, cache_dtype)
+            va = jnp.asarray(va, cache_dtype)
+            dense_k, dense_v = (np.asarray(x, np.float32) for x in (ka, va))
+        args = (jnp.asarray(q), jnp.asarray(ka), jnp.asarray(va))
+        fused = paged_attention_decode(
+            *args, jnp.asarray(tables), jnp.asarray(lengths), **kwargs)
+        # the twin gathers whole tables: give it the poisoned columns
+        # clamped into the arena (it masks what it gathered there)
+        clean = np.where(tables == 10_000, 0, tables)
+        unfused = paged_attention_decode_unfused(
+            *args, jnp.asarray(clean), jnp.asarray(lengths), **kwargs)
+        np.testing.assert_allclose(np.asarray(fused), np.asarray(unfused),
+                                   atol=2e-5)
+        ref = _dense_paged_reference(q, dense_k, dense_v, clean, lengths, bs)
+        np.testing.assert_allclose(np.asarray(fused), ref, atol=2e-5)
+        for i in np.flatnonzero(lengths == 0):
+            assert np.abs(np.asarray(fused[i])).max() == 0.0
+
+    @pytest.mark.parametrize("cache_dtype", [np.float32, jnp.bfloat16,
+                                             np.int8], ids=lambda t:
+                             jnp.dtype(t).name)
+    @pytest.mark.parametrize("hpg", [1, 2, 4])
+    def test_page_group_boundaries(self, hpg, cache_dtype):
+        q, ka, va, tables, lengths, bs, _ = self._group_case(
+            n=8, g=8 // hpg, d=32, bs=4, mb=20, cache_dtype=cache_dtype)
+        self._check_group_case(q, ka, va, tables, lengths, bs, cache_dtype)
+
+    def test_page_group_boundaries_at_the_cell_page_shape(self):
+        """gpt2-medium's page (16 tokens, 16 heads of 64), a small arena."""
+        q, ka, va, tables, lengths, bs, P = self._group_case(
+            n=16, g=16, d=64, bs=16, mb=20, cache_dtype=np.float32)
+        assert P == 8
+        self._check_group_case(q, ka, va, tables, lengths, bs, np.float32)
+
+    @pytest.mark.parametrize("page_bytes,max_blocks,want", [
+        (128 * 1024, 64, 8),       # the cell: fp32, 16 x 16 x 64 -> 128 lanes
+        (64 * 1024, 64, 8),        # bf16: the operand cap, not the budget
+        (128 * 1024, 5, 5),        # a short table
+        (512 * 1024, 64, 2),       # a wide page: the VMEM budget
+        (64 * 1024 * 1024, 64, 1),  # a page over the budget: still one
+        (1024, 1, 1),
+    ])
+    def test_pages_per_step_is_what_the_shapes_give(self, page_bytes,
+                                                    max_blocks, want):
+        P = paged_attention_module._pages_per_step(page_bytes, max_blocks)
+        assert P == want and 1 <= P <= max_blocks
+        # pure: no state, no environment
+        assert P == paged_attention_module._pages_per_step(
+            page_bytes, max_blocks)
+
+    def test_decode_takes_no_new_argument(self):
+        assert list(inspect.signature(paged_attention_decode).parameters) \
+            == ["q", "k_arena", "v_arena", "block_tables", "lengths",
+                "limits", "k_scales", "v_scales", "block_size", "scale"]
+
+    def test_vmem_page_bytes_count_the_tile_padding(self):
+        vmem = paged_attention_module._vmem_bytes
+        assert vmem((16, 16, 64), np.float32) == 16 * 16 * 128 * 4
+        assert vmem((16, 12, 64), np.float32) == 16 * 16 * 128 * 4
+        assert vmem((16, 16, 64), jnp.bfloat16) == 16 * 16 * 128 * 2
+        assert vmem((16, 16, 64), np.int8) == 16 * 32 * 128
+        assert vmem((16, 16), np.float32) == 16 * 128 * 4
+
+    def test_step_plan_copies_live_pages_only(self):
+        """The sweep the kernel is given: a step per group of live pages
+        (one for an empty slot), and per step the block each page operand
+        holds: a live page's table entry, else the block the operand needs
+        next (else held last), so that a change of block index (= one copy
+        by the pipeline) happens for live pages only and no stale column
+        is ever named."""
+        _, _, _, tables, lengths, bs, P = self._group_case(
+            n=8, g=8, d=32, bs=4, mb=20, cache_dtype=np.float32)
+        n_steps, plan, slot, group = (np.asarray(x) for x in (
+            paged_attention_module._step_plan(
+                jnp.asarray(tables), jnp.asarray(lengths), bs, P)))
+        live = -(-lengths // bs)
+        want = [(i, j) for i, n in enumerate(live)
+                for j in range(max(-(-n // P), 1))]
+        assert n_steps == len(want) < len(slot)
+        assert list(zip(slot[:n_steps], group[:n_steps])) == want
+        plan = plan.reshape(-1, P)
+        assert plan.max() < 10_000                  # no poisoned column
+        for s, (i, j) in enumerate(want):
+            for p in range(P):
+                if j * P + p < live[i]:
+                    assert plan[s, p] == tables[i, j * P + p]
+        # an index changes between two steps only to bring a live page,
+        # and the step past the last (which the pipeline looks at) is valid
+        swept = plan[:n_steps + 1]
+        assert (np.diff(swept, axis=0) != 0).sum() <= live.sum()
+        assert ((0 <= swept) & (swept < 10_000)).all()
+        # every live page is in place one step early where its operand
+        # was idle the step before: the copy runs under that step
+        for s, (i, j) in enumerate(want):
+            for p in range(P):
+                if s and j * P + p < live[i]:
+                    pi, pj = want[s - 1]
+                    if pj * P + p >= live[pi]:
+                        assert plan[s - 1, p] == plan[s, p]
 
 
 class TestPagedPrefillKernel:

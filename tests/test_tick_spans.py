@@ -268,6 +268,50 @@ def test_tick_without_a_chunk_has_no_prefill_span(served):
         assert {"decode_plan", "decode_dispatch", "decode_fetch"} <= names
 
 
+def test_decode_plan_counts_the_kv_the_kernel_meets(model):
+    """ISSUE 26: a decode tick's ``decode_plan`` span carries ``kv_tokens``
+    (the history each decoding slot attends, its new row included) and
+    ``kv_pages`` (the KV blocks that hold it), equal to the sums over the
+    scheduler's running requests at the moment of the call and to the
+    lengths the device program is given; the two counters grow by them."""
+    engine = build_engine(model)
+    bs = engine.cache.block_size
+    real, seen = engine._decode, []
+
+    class Spy:
+        def __call__(self, *args):
+            decoding = [r for r in engine.scheduler.running()
+                        if not r.prefilling]
+            history = [r.cache_len + 1 for r in decoding]
+            positions, active = np.asarray(args[3]), np.asarray(args[5])
+            lengths = np.where(active, positions + 1, 0)
+            assert sorted(lengths[active]) == sorted(history)
+            seen.append((sum(history), sum(-(-h // bs) for h in history)))
+            return real(*args)
+
+        def __getattr__(self, name):          # lower, for the FLOPs probe
+            return getattr(real, name)
+
+    engine._decode = Spy()
+    _, ticks, children = serve(engine)
+    assert engine.scheduler.preemptions == 0
+    plans = [k for t in ticks for k in children[t.id]
+             if k.name == "serving/tick/decode_plan"]
+    assert len(plans) == len(seen) > 4
+    for plan, (tokens, pages) in zip(plans, seen):
+        assert set(plan.fields) == {"preempted", "kv_tokens", "kv_pages"}
+        assert (plan.fields["kv_tokens"], plan.fields["kv_pages"]) == \
+            (tokens, pages)
+        assert 0 < pages <= tokens <= pages * bs
+    snap = engine.registry.snapshot()
+    assert snap["serving/decode_kv_tokens"] == sum(t for t, _ in seen)
+    assert snap["serving/decode_kv_pages"] == sum(p for _, p in seen)
+    # the live share of the old (slots, max_blocks) grid, from inside
+    grid = snap["serving/decode_slot_steps"] * \
+        engine.cache.max_blocks_per_request
+    assert 0 < snap["serving/decode_kv_pages"] <= grid
+
+
 def test_counters_equal_what_was_submitted(served):
     engine, reqs, ticks, children = served
     snap = engine.registry.snapshot()
