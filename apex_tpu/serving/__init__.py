@@ -74,6 +74,7 @@ See ``docs/serving.md`` for the architecture and cookbook.
 
 from apex_tpu.serving.kv_cache import (
     BlockAllocator,
+    CacheGroup,
     KVCacheConfig,
     OutOfBlocksError,
     PrefixCache,
@@ -92,6 +93,7 @@ from apex_tpu.serving.lora import (
     init_adapter_weights,
     restore_adapter_for_serving,
 )
+from apex_tpu.serving.model import HybridParams
 from apex_tpu.serving.sampling import SamplingParams
 from apex_tpu.serving.scheduler import Request, RequestState, Scheduler
 from apex_tpu.serving.speculative import (
@@ -120,9 +122,11 @@ __all__ = [
     "AdapterArena",
     "AutopilotConfig",
     "BlockAllocator",
+    "CacheGroup",
     "FleetAutopilot",
     "FleetRequest",
     "FleetRouter",
+    "HybridParams",
     "KVCacheConfig",
     "LoRAConfig",
     "NGramProposer",

@@ -75,6 +75,12 @@ docs/serving.md):
   decode kernel had to read (ISSUE 26)
 - ``serving/queue_wait_ms`` histogram (sampled) — submit to first
   admission, per request
+- ``serving/moe_pairs`` / ``serving/moe_experts_hit`` counters — the
+  ``(token, expert)`` pairs the decode and prefill calls routed to held
+  experts, and the (layer, expert) entries they hit (ISSUE 27; a model
+  with expert layers only)
+- ``serving/window_blocks_freed`` counter — blocks a window cache group
+  handed back behind the window
 
 Host spans (ISSUE 25): every :meth:`ServingEngine.step` is one
 ``serving/tick`` span of :mod:`apex_tpu.observability.spans` with one
@@ -112,9 +118,11 @@ from apex_tpu.observability.metrics import (
 from apex_tpu.parallel import collectives as cc
 from apex_tpu.parallel.mesh import TENSOR_AXIS, get_mesh
 from apex_tpu.serving.kv_cache import (
+    CacheGroup,
     ExportLedger,
     KVCacheConfig,
     arena_partition_spec,
+    init_group_arenas,
     init_kv_arena,
     scale_partition_spec,
 )
@@ -126,7 +134,7 @@ from apex_tpu.serving.lora import (
     init_adapter_weights,
     pack_adapter_values,
 )
-from apex_tpu.serving.model import DecodeModel
+from apex_tpu.serving.model import decode_model
 from apex_tpu.serving.sampling import SamplingParams
 from apex_tpu.serving.scheduler import (
     Request,
@@ -162,6 +170,12 @@ class ServingConfig:
     paged adapter arena inside the same compiled step — rank and slot
     count pin the compile; which adapter each slot runs is data.
     ``None`` keeps the engine byte-identical to the bare path.
+    A model whose layers are of more than one kind
+    (``TransformerConfig.hybrid``) gets one cache group per attention
+    kind: ``n_blocks`` sizes the groups without a window, and a group
+    with one gets what ``max_batch`` slots need for the window and one
+    chunk; it takes ``prefix_caching=False`` and none of ``speculative``,
+    ``lora`` or an int8 cache yet.
     """
 
     max_batch: int = 8           # concurrent decode slots
@@ -222,11 +236,7 @@ class ServingEngine:
     def __init__(self, config, serving: ServingConfig, params, *,
                  mesh=None, tp_axis: str = TENSOR_AXIS, registry=None,
                  guard=None, heartbeat=None, timeline_tick_every: int = 8):
-        import jax
         import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from apex_tpu.transformer.tensor_parallel import infer_param_specs
 
         self.mesh = mesh if mesh is not None else get_mesh()
         self.tp_axis = tp_axis
@@ -255,13 +265,22 @@ class ServingEngine:
             block_size=serving.block_size, kv_heads=config.query_groups,
             head_dim=config.head_dim, max_seq=serving.max_seq,
             dtype=cache_dtype)
-        self.cache = dataclasses.replace(
-            probe,
-            n_blocks=serving.resolve_n_blocks(probe.max_blocks_per_request))
-        self.model = DecodeModel(
+        self.prefill_len = serving.prefill_len or serving.max_seq
+        n_blocks = serving.resolve_n_blocks(probe.max_blocks_per_request)
+        self.hybrid = config.hybrid is not None
+        if self.hybrid:
+            if self.spec is not None or self.mesh.devices.size > 1:
+                raise NotImplementedError(
+                    "hybrid layers: one chip, no speculation yet")
+            groups = self._cache_groups(config.hybrid, serving, n_blocks)
+            probe = dataclasses.replace(
+                probe, groups=groups, n_layers=len(groups[0].layers),
+                kv_heads=groups[0].kv_heads, head_dim=groups[0].k_dim)
+            n_blocks = groups[0].n_blocks
+        self.cache = dataclasses.replace(probe, n_blocks=n_blocks)
+        self.model = decode_model(
             config, self.cache, fused_attention=serving.fused_attention,
             fuse_epilogue=serving.fuse_epilogue, lora=serving.lora)
-        self.prefill_len = serving.prefill_len or serving.max_seq
         # Live-retunable knobs (ISSUE 18): data-only caps an autopilot
         # can actuate at runtime over the command wire.  Neither touches
         # a compiled shape — the prefill call keeps its [B, T] program
@@ -271,6 +290,59 @@ class ServingEngine:
         self.live_prefill_chunk: Optional[int] = None
         self.live_spec_k: Optional[int] = None
 
+        self._jnp = jnp
+        if self.hybrid:
+            self._init_hybrid(params)
+        else:
+            self._init_uniform(config, params)
+        self._finish_init(serving, registry, guard, heartbeat,
+                          timeline_tick_every)
+
+    def _cache_groups(self, hybrid, serving: ServingConfig, n_blocks: int):
+        """One cache group per attention kind that has layers: those
+        without a window get ``n_blocks``, a window group what
+        ``max_batch`` slots need for the window and one chunk, wherever
+        they lie against the block edges."""
+        groups = []
+        for ki, kind in enumerate(hybrid.kinds):
+            layers = hybrid.layers_of(ki)
+            if not layers:
+                continue
+            group = CacheGroup(
+                layers=layers, kv_heads=kind.kv_heads, k_dim=kind.k_dim,
+                v_dim=kind.v_dim, n_blocks=n_blocks, window=kind.window)
+            if kind.window is not None:
+                per_slot = group.blocks_spanned(
+                    self.prefill_len, serving.block_size, n_blocks)
+                group = dataclasses.replace(
+                    group, n_blocks=serving.max_batch * per_slot)
+            groups.append(group)
+        return tuple(groups)
+
+    def _init_hybrid(self, params) -> None:
+        """Layers of several kinds: per-layer arenas in cache groups, one
+        jit per entry point (one chip: no shard_map to bind)."""
+        import jax
+
+        self.params = params
+        self.param_specs = None
+        self.arenas = init_group_arenas(self.cache)
+        self.lora = None
+        self.adapter_arena = None
+        self.adapters = None
+        self._decode = jax.jit(self.model.decode_step, donate_argnums=(0,))
+        self._prefill = jax.jit(self.model.prefill, donate_argnums=(0,))
+
+    def _init_uniform(self, config, params) -> None:
+        """Every layer alike: the stacked arena and the tensor-parallel
+        shard_map bodies."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from apex_tpu.transformer.tensor_parallel import infer_param_specs
+
+        serving, tp_axis = self.serving, self.tp_axis
         # [vpp, pp, ...] -> [L, ...] (row-major merge == virtual-stage
         # major == plain layer order; gpt3d_logical_folds rationale)
         L = config.num_layers
@@ -384,8 +456,9 @@ class ServingEngine:
             lambda arenas, idx, vals: tuple(
                 a.at[:, idx].set(v) for a, v in zip(arenas, vals)),
             donate_argnums=(0,))
-        self._jnp = jnp
 
+    def _finish_init(self, serving, registry, guard, heartbeat,
+                     timeline_tick_every) -> None:
         self.scheduler = Scheduler(
             self.cache, serving.max_batch, chunk_tokens=self.prefill_len,
             admission=serving.admission,
@@ -404,9 +477,13 @@ class ServingEngine:
                 f"timeline_tick_every must be >= 1, got "
                 f"{timeline_tick_every}")
         self.timeline_tick_every = timeline_tick_every
-        self._tables = np.zeros(
-            (serving.max_batch, self.cache.max_blocks_per_request),
-            np.int32)
+        # one block table per cache group; ``_tables`` is group 0's
+        self._group_tables = [
+            np.zeros((serving.max_batch, self.cache.max_blocks_per_request),
+                     np.int32) for _ in self.cache.cache_groups]
+        self._tables = self._group_tables[0]
+        self._windowed = any(g.window is not None
+                             for g in self.cache.cache_groups)
         self._steps = 0
         # the tick's host spans (serving/tick and its phases) land in this
         # engine's registry as span_ms/* and in the process-wide ring
@@ -421,6 +498,13 @@ class ServingEngine:
             decode_slot_steps=counter("serving/decode_slot_steps"),
             decode_kv_tokens=counter("serving/decode_kv_tokens"),
             decode_kv_pages=counter("serving/decode_kv_pages"))
+        if self.hybrid:
+            self._counters.moe_pairs = counter("serving/moe_pairs")
+            self._counters.moe_experts_hit = counter(
+                "serving/moe_experts_hit")
+            self._counters.window_blocks_freed = counter(
+                "serving/window_blocks_freed")
+        self._counted_window_freed = 0
         self._queue_wait = self.registry.histogram(
             "serving/queue_wait_ms", keep_samples=4096)
         self._decode_calls = 0         # device decode/verify invocations
@@ -428,6 +512,7 @@ class ServingEngine:
         #                                (mean accept length denominator)
         # the last decode call's logits and the slots that decoded in it
         self._last_logits: Optional[Tuple[Any, Tuple[int, ...]]] = None
+        self._tick_choices: List[Tuple[Any, Tuple[Tuple[int, ...], ...]]] = []
         self._counted_preempts = 0     # flushed-so-far deltas
         self._counted_hits = 0
         self._counted_evictions = 0
@@ -611,6 +696,9 @@ class ServingEngine:
         request is not in an exportable state (still prefilling, no
         token emitted yet, already exporting) — the caller degrades to
         letting it keep decoding locally."""
+        if self.hybrid:
+            raise NotImplementedError(
+                "KV migration moves one pooled arena, not cache groups")
         if req.state is not RequestState.RUNNING or req.slot is None:
             raise ValueError(
                 f"request {req.rid} is {req.state}, not exportable")
@@ -756,6 +844,7 @@ class ServingEngine:
         docs/observability.md): disjoint, in this order, and a phase
         that did not run records none."""
         sched = self.scheduler
+        self._tick_choices = []
         with self._span("serving/tick", step=self._steps,
                         live=len(sched.running()),
                         waiting=len(sched.waiting), prefill_rows=0,
@@ -897,10 +986,32 @@ class ServingEngine:
         requests (preemption and growth both rewrite block lists; the
         rebuild is max_batch * max_blocks ints — noise next to a device
         step)."""
-        self._tables[:] = 0
+        for table in self._group_tables:
+            table[:] = 0
         for req in self.scheduler.running():
-            row = self._tables[req.slot]
-            row[:len(req.blocks)] = req.blocks
+            for table, held in zip(self._group_tables, req.group_blocks()):
+                table[req.slot, :len(held)] = held
+        if self._windowed:
+            # an entry handed back behind the window still has to index
+            # the arena; no kernel reaches it
+            for table in self._group_tables:
+                np.maximum(table, 0, out=table)
+
+    def _device_tables(self):
+        """The block tables as the compiled steps take them: group 0's
+        array, or for a model with cache groups a tuple of them."""
+        if not self.hybrid:
+            return self._jnp.asarray(self._tables)
+        return tuple(self._jnp.asarray(t) for t in self._group_tables)
+
+    def _note_routed(self, phase: spans.span, pairs) -> None:
+        """Record what a call routed to the held experts (``pairs [expert
+        layers, held experts]``) on its fetch span and the counters."""
+        total, hit = int(pairs.sum()), int(np.count_nonzero(pairs))
+        phase.note(moe_pairs=total, moe_experts_hit=hit,
+                   moe_peak_pairs=int(pairs.max()) if pairs.size else 0)
+        self._counters.moe_pairs.inc(total)
+        self._counters.moe_experts_hit.inc(hit)
 
     def _sampling_arrays(self):
         """Per-slot sampling-policy data ([max_batch] each, rebuilt per
@@ -943,6 +1054,10 @@ class ServingEngine:
                     # live retune (ISSUE 18): the cap is data — the device
                     # call keeps its compiled [B, T] shape and fills less
                     chunk = min(chunk, self.live_prefill_chunk)
+                if self._windowed:
+                    # nothing behind the window of the chunk's first token
+                    # is read again
+                    self.scheduler.free_behind_window(req, req.cache_len)
                 covered = self.scheduler.try_grow_to(
                     req, req.cache_len + chunk)
                 chunk = min(chunk, covered - req.cache_len)
@@ -955,8 +1070,10 @@ class ServingEngine:
             pos_ids = np.zeros((B, T), np.int32)
             limits = np.zeros((B, T), np.int32)
             lengths = np.zeros((B,), np.int32)
-            dest_b = np.full((B, T), self.cache.n_blocks, np.int32)  # OOB=drop
-            dest_o = np.zeros((B, T), np.int32)
+            if not self.hybrid:     # else read from the tables in-graph
+                dest_b = np.full((B, T), self.cache.n_blocks,
+                                 np.int32)                          # OOB=drop
+                dest_o = np.zeros((B, T), np.int32)
             sample_index = np.full((B,), T, np.int32)                # OOB=none
             for req, chunk in plan:
                 s = req.slot
@@ -966,32 +1083,50 @@ class ServingEngine:
                 pos_ids[s, :chunk] = np.arange(lo, lo + chunk)
                 limits[s, :chunk] = np.arange(lo + 1, lo + chunk + 1)
                 lengths[s] = lo + chunk
-                dest_b[s, :chunk] = [req.blocks[(lo + t) // bs]
-                                     for t in range(chunk)]
-                dest_o[s, :chunk] = [(lo + t) % bs for t in range(chunk)]
+                if not self.hybrid:
+                    dest_b[s, :chunk] = [req.blocks[(lo + t) // bs]
+                                         for t in range(chunk)]
+                    dest_o[s, :chunk] = [(lo + t) % bs
+                                         for t in range(chunk)]
                 if lo + chunk == req.prefill_target:
                     sample_index[s] = chunk - 1
             self._refresh_tables()
             samp = self._sampling_arrays()
-            args = (tokens, pos_ids, self._jnp.asarray(self._tables),
-                    lengths, limits, dest_b, dest_o, sample_index)
-            if self.adapter_arena is None:
-                args = (self.arenas, self.params) + args + samp
+            args = (tokens, pos_ids, self._device_tables(), lengths, limits)
+            if self.hybrid:
+                args = (self.arenas, self.params) + args \
+                    + (sample_index,) + samp
+            elif self.adapter_arena is None:
+                args = (self.arenas, self.params) + args \
+                    + (dest_b, dest_o, sample_index) + samp
             else:
                 args = (self.arenas, self.adapters, self.params) + args \
-                    + (self._adapter_slot_array(),) + samp
+                    + (dest_b, dest_o, sample_index,
+                       self._adapter_slot_array()) + samp
             n_tokens = int(sum(c for _, c in plan))
 
         with timeline.scope("prefill", rids=[r.rid for r, _ in plan],
                             tokens=n_tokens):
+            routed = None
             with self._span("serving/tick/prefill_dispatch"):
-                if self.adapter_arena is None:
+                if self.hybrid:
+                    self.arenas, next_tokens, _, routed, chosen = \
+                        self._prefill(*args)
+                    self._tick_choices.append((chosen, tuple(
+                        (req.rid, req.slot * T, req.cache_len, chunk)
+                        for req, chunk in plan)))
+                elif self.adapter_arena is None:
                     self.arenas, next_tokens, _ = self._prefill(*args)
                 else:
                     self.arenas, self.adapters, next_tokens, _ = \
                         self._prefill(*args)
-            with self._span("serving/tick/prefill_fetch"):
-                next_np = np.asarray(next_tokens)
+            with self._span("serving/tick/prefill_fetch") as fetch:
+                if routed is None:
+                    next_np = np.asarray(next_tokens)
+                else:
+                    # one transfer brings the tokens and the routing counts
+                    next_np, routed = self._fetch((next_tokens, routed))
+                    self._note_routed(fetch, routed)
 
         with self._span("serving/tick/prefill_deliver"):
             self._counters.prefill_calls.inc()
@@ -1060,6 +1195,8 @@ class ServingEngine:
                 if (req.slot is None
                         or req.state is not RequestState.RUNNING):
                     continue    # preempted by an older request's growth
+                if self._windowed:
+                    self.scheduler.free_behind_window(req, req.cache_len)
                 covered = self.scheduler.try_grow_to(
                     req, req.cache_len + 1)
                 if covered < req.cache_len + 1:
@@ -1088,6 +1225,8 @@ class ServingEngine:
                        kv_tokens=kv_tokens, kv_pages=kv_pages)
             self._counters.decode_kv_tokens.inc(kv_tokens)
             self._counters.decode_kv_pages.inc(kv_pages)
+            if self.hybrid:
+                self._note_group_reads(phase, history)
             if not reqs:
                 return None
             tokens = np.zeros((B, S), np.int32)
@@ -1105,7 +1244,7 @@ class ServingEngine:
             self._refresh_tables()
             samp = self._sampling_arrays()
 
-            tables = self._jnp.asarray(self._tables)
+            tables = self._device_tables()
             if self.adapter_arena is None:
                 args = (self.arenas, self.params, tokens, positions,
                         tables, active, n_draft) + samp
@@ -1120,8 +1259,14 @@ class ServingEngine:
                 # pass reports the program's FLOPs.  Must happen BEFORE
                 # the call below consumes the donated arenas.
                 self._probe_decode_flops(args)
+        routed = None
         with self._span("serving/tick/decode_dispatch") as dispatch:
-            if self.adapter_arena is None:
+            if self.hybrid:
+                self.arenas, out_tokens, accepted, logits, routed, chosen = \
+                    self._decode(*args)
+                self._tick_choices.append((chosen, tuple(
+                    (req.rid, req.slot, req.cache_len, 1) for req in reqs)))
+            elif self.adapter_arena is None:
                 self.arenas, out_tokens, accepted, logits = \
                     self._decode(*args)
             else:
@@ -1130,10 +1275,46 @@ class ServingEngine:
             # replaces, and so frees, the call before's
             self._last_logits = (logits, tuple(r.slot for r in reqs))
         with self._span("serving/tick/decode_fetch") as fetch:
-            out_np = np.asarray(out_tokens)
-            acc_np = np.asarray(accepted)
+            if routed is None:
+                out_np = np.asarray(out_tokens)
+                acc_np = np.asarray(accepted)
+            else:
+                out_np, acc_np, routed = self._fetch(
+                    (out_tokens, accepted, routed))
+                self._note_routed(fetch, routed)
         tick.note(decode_slots=len(reqs))
         return reqs, drafts, out_np, acc_np, dispatch.ms + fetch.ms
+
+    @staticmethod
+    def _fetch(arrays):
+        """Device arrays to the host in one round trip."""
+        import jax
+
+        return jax.device_get(arrays)
+
+    def _note_group_reads(self, phase: spans.span, history) -> None:
+        """What each kind of cache group must read this tick, on the
+        ``decode_plan`` span: rows (the history, or what of it lies inside
+        the window) and the blocks that hold them, summed over the groups
+        of a kind, and what the window groups hold and handed back."""
+        bs = self.cache.block_size
+        reads = {"full": [0, 0], "window": [0, 0]}
+        for g in self.cache.cache_groups:
+            kind = reads["full" if g.window is None else "window"]
+            for h in history:
+                first = g.first_needed_block(h - 1, bs)
+                kind[0] += h if g.window is None else min(h, g.window)
+                kind[1] += -(-h // bs) - first
+        sched = self.scheduler
+        freed = sched.window_blocks_freed - self._counted_window_freed
+        self._counted_window_freed = sched.window_blocks_freed
+        self._counters.window_blocks_freed.inc(freed)
+        phase.note(kv_tokens_full=reads["full"][0],
+                   kv_pages_full=reads["full"][1],
+                   kv_tokens_window=reads["window"][0],
+                   kv_pages_window=reads["window"][1],
+                   window_blocks_held=sched.window_blocks_held(),
+                   window_blocks_freed=freed)
 
     def _deliver(self, reqs: List[Request], drafts: dict, out_np, acc_np,
                  decode_ms: float) -> int:
@@ -1289,6 +1470,18 @@ class ServingEngine:
         until the next decode call replaces it, never copied."""
         return self._last_logits
 
+    def last_expert_choices(self) -> List[Tuple[Any, Tuple[Tuple[int, ...],
+                                                         ...]]]:
+        """What the routers of the last tick's calls chose (a model with
+        expert layers; else empty): per call, prefill before decode, the
+        device array ``[expert layers, rows of the call, top_k]`` of expert
+        ids as the program returned it, and which of its rows were tokens:
+        ``(request id, first row, first position, count)`` per request.
+        Held by reference until the next tick, never fetched: a
+        comparison with another precision reads them afterwards, since
+        scores near the cut lie closer than bfloat16 rounds."""
+        return self._tick_choices
+
     @staticmethod
     def _slowest_tick() -> Optional[dict]:
         """The phase split, in ms, of the longest ``serving/tick`` in the
@@ -1332,7 +1525,8 @@ class ServingEngine:
             self._finish(req)
 
     def _finish(self, req: Request) -> None:
-        self._tables[req.slot][:] = 0
+        for table in self._group_tables:
+            table[req.slot][:] = 0
         self.scheduler.finish(req)
         self._unpin_adapter(req)
         self.registry.counter("serving/requests_finished").inc()

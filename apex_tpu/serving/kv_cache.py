@@ -44,6 +44,23 @@ The vLLM paging model mapped onto the repo's sharded-state conventions:
   eviction sweep (:meth:`PrefixCache.evict_one`, LRU) is what finally
   returns them to the free list when the pool runs dry.
 
+**Cache groups** (ISSUE 27): a model whose layers are not all alike keeps
+more than one kind of state.  ``KVCacheConfig.groups`` lists them, one
+:class:`CacheGroup` per kind of attention layer: the layers it serves, its
+K/V head count, a K row width beside a V row width, and an optional
+``window``.  Each group has its own pool of blocks, its own
+:class:`BlockAllocator` and its own block table per request, all indexed by
+the same logical block (``position // block_size``).  A window group keeps
+only the blocks a later query can still read: the scheduler hands back the
+blocks that lie wholly behind the window (their table entries go stale and
+the kernels never reach them).  A group's arenas are one ``(k, v)`` pair
+**per layer**, ``[n_blocks, block_size, kv_heads * k_dim]`` beside
+``[..., kv_heads * v_dim]``: rows dense on the lanes whatever the head
+width, so the kernels read them as they lie and no layer slices or
+converts an arena it shares with another (:func:`init_group_arenas`).  A
+configuration without ``groups`` is the one pooled arena described above,
+unchanged.
+
 The per-request *block table* (logical block index -> physical block
 id) lives with the scheduler's request records; the engine packs the
 tables of the active slots into one ``[max_batch, max_blocks]`` int32
@@ -61,6 +78,8 @@ import numpy as np
 
 __all__ = [
     "KVCacheConfig",
+    "CacheGroup",
+    "FREED",
     "BlockAllocator",
     "OutOfBlocksError",
     "PrefixCache",
@@ -69,6 +88,7 @@ __all__ = [
     "KVExport",
     "ExportLedger",
     "init_kv_arena",
+    "init_group_arenas",
     "arena_partition_spec",
     "scale_partition_spec",
 ]
@@ -83,6 +103,44 @@ CACHE_OWNER = "<prefix-cache>"
 # CACHE_OWNER, so the source request can finish (its own refs free) while
 # the exported run stays pinned until the decode side acks receipt
 EXPORT_OWNER = "<kv-export>"
+
+
+# a block-table entry whose block was handed back behind the window
+FREED = -1
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGroup:
+    """One kind of cached state: the model ``layers`` it serves, ``kv_heads``
+    rows of ``k_dim`` (keys) beside ``v_dim`` (values) per token and layer,
+    its pool of ``n_blocks`` blocks, and ``window``: ``None`` keeps every
+    token, ``w`` only what a query at position ``i`` reads,
+    ``i - w < j <= i``."""
+
+    layers: Tuple[int, ...]
+    kv_heads: int
+    k_dim: int
+    v_dim: int
+    n_blocks: int
+    window: Optional[int] = None
+
+    def first_needed_block(self, query_pos: int, block_size: int) -> int:
+        """The first logical block a query at ``query_pos`` (and so any
+        later one) can read."""
+        if self.window is None:
+            return 0
+        return max(query_pos - self.window + 1, 0) // block_size
+
+    def blocks_spanned(self, chunk_tokens: int, block_size: int,
+                       most: int) -> int:
+        """The most blocks one request holds here while a chunk of
+        ``chunk_tokens`` lands: ``most`` (its whole table) without a
+        window, else what the window and the chunk span wherever they lie
+        against the block edges."""
+        if self.window is None:
+            return most
+        return min(most,
+                   (self.window + chunk_tokens - 2) // block_size + 2)
 
 
 class OutOfBlocksError(RuntimeError):
@@ -114,6 +172,9 @@ class KVCacheConfig:
     head_dim: int
     max_seq: int
     dtype: Any = np.float32
+    # more than one kind of state: see :class:`CacheGroup`.  When given,
+    # ``n_layers``/``n_blocks``/``kv_heads``/``head_dim`` describe group 0.
+    groups: Tuple[CacheGroup, ...] = ()
 
     def __post_init__(self):
         if self.block_size < 1 or self.n_blocks < 1:
@@ -135,6 +196,15 @@ class KVCacheConfig:
     def blocks_for(self, n_tokens: int) -> int:
         """Number of blocks a sequence of ``n_tokens`` occupies."""
         return -(-n_tokens // self.block_size)
+
+    @property
+    def cache_groups(self) -> Tuple[CacheGroup, ...]:
+        """The groups, a model of identical layers being the case of one."""
+        if self.groups:
+            return self.groups
+        return (CacheGroup(layers=tuple(range(self.n_layers)),
+                           kv_heads=self.kv_heads, k_dim=self.head_dim,
+                           v_dim=self.head_dim, n_blocks=self.n_blocks),)
 
 
 def arena_partition_spec(tp_axis: Optional[str]):
@@ -196,6 +266,25 @@ def init_kv_arena(cfg: KVCacheConfig, mesh=None, tp_axis: Optional[str] = "tp"
         arenas = [jax.device_put(a, NamedSharding(mesh, s))
                   for a, s in zip(arenas, specs)]
     return tuple(arenas)
+
+
+def init_group_arenas(cfg: KVCacheConfig) -> Tuple[Any, ...]:
+    """The zeroed arenas of a configuration with ``groups``: for each group
+    a tuple, over its layers, of ``(k [n_blocks, block_size, kv_heads *
+    k_dim], v [n_blocks, block_size, kv_heads * v_dim])``.  One pair per
+    layer, each an array of its own: a layer's kernel call takes its arena
+    whole and its appended rows land in place in the donated buffer."""
+    import jax.numpy as jnp
+
+    if cfg.quantized:
+        raise NotImplementedError("cache groups hold no int8 arenas yet")
+    return tuple(
+        tuple((jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * g.k_dim),
+                         cfg.dtype),
+               jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * g.v_dim),
+                         cfg.dtype))
+              for _ in g.layers)
+        for g in cfg.groups)
 
 
 class BlockAllocator:

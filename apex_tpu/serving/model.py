@@ -58,12 +58,35 @@ Both entry points are **shard_map bodies**: run them under
 ``collectives.shard_over`` with the tensor axis bound (the engine does
 this) — the parallel linears then shard exactly as in training, and
 the K/V arena rows a rank touches are the heads it owns.
+
+**Layers of more than one kind** (ISSUE 27).  :class:`DecodeModel` above
+is the case of one group: every layer alike, one ``lax.scan`` over the
+stacked layers, one arena.  A configuration with a
+:class:`~apex_tpu.transformer.testing.standalone_transformer_lm.HybridSpec`
+(``TransformerConfig.hybrid``) is served by :class:`HybridDecodeModel`,
+the same two entry points over a **walk of the layers in their published
+order**: each layer names its attention kind (full or sliding-window, with
+its own head counts, q/k width beside v width, rotary base and optional
+sink logits) and its feed-forward (the dense SwiGLU or the top-k expert
+layer of :func:`apex_tpu.transformer.moe.held_experts_ffn`, which is told
+which experts this process holds).  Each attention kind is a cache group
+(:class:`~apex_tpu.serving.kv_cache.CacheGroup`) with its own block table,
+and each layer owns its ``(k, v)`` arena pair whole, so the walk is
+unrolled: a layer's kernel call takes its arena as it lies and its
+appended rows land in place in the donated buffer.  The blocks are
+RMSNorm, bias-free, with every GEMM's operands, the activations between
+them and the cache in the model's dtype (bf16) and the residual stream,
+the norm statistics, the accumulation, the router and the logits in fp32;
+tokens are flattened to ``[tokens, hidden]``.
+:func:`decode_model` picks the class from the configuration;
+:func:`serving_config` no longer refuses experts, only the Switch layer,
+which remains a training dry run.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +97,7 @@ from apex_tpu.serving.fused_ops import (
     fused_residual_norm,
     residual_norm_unfused,
 )
+from apex_tpu.normalization.fused_layer_norm import fused_rms_norm_affine
 from apex_tpu.serving.kv_cache import KVCacheConfig
 from apex_tpu.serving.lora import LoRAConfig, lora_delta
 from apex_tpu.serving.paged_attention import (
@@ -84,6 +108,7 @@ from apex_tpu.serving.paged_attention import (
 )
 from apex_tpu.serving.sampling import sample_tokens
 from apex_tpu.transformer.layers.layer_norm import FusedLayerNorm
+from apex_tpu.transformer import moe
 from apex_tpu.transformer.rope import (
     apply_rotary_decode,
     apply_rotary_packed,
@@ -101,7 +126,8 @@ from apex_tpu.transformer.testing.standalone_transformer_lm import (
     parallel_lm_logits,
 )
 
-__all__ = ["DecodeModel", "serving_config"]
+__all__ = ["DecodeModel", "HybridDecodeModel", "HybridParams",
+           "decode_model", "serving_config"]
 
 
 def serving_config(config: TransformerConfig) -> TransformerConfig:
@@ -119,7 +145,8 @@ def serving_config(config: TransformerConfig) -> TransformerConfig:
             "apply_residual_connection_post_layernorm is not wired")
     if config.num_experts is not None:
         raise NotImplementedError(
-            "MoE serving is not wired yet (the EP roadmap item)")
+            "the Switch top-1 layer (num_experts) is a training dry run; "
+            "serving takes its experts from TransformerConfig.hybrid")
     return dataclasses.replace(
         config, hidden_dropout=0.0, attention_dropout=0.0,
         sequence_parallel=False, overlap_comm=False, context_axis=None,
@@ -570,3 +597,248 @@ class DecodeModel:
         if adapters is not None:
             return arenas, adapters, next_tokens, logits
         return arenas, next_tokens, logits
+
+
+# ------------------------------------------------ layers of several kinds
+
+
+class HybridParams(NamedTuple):
+    """Parameters of a :class:`HybridDecodeModel`, in the model's dtype.
+
+    ``embedding [vocab, hidden]``; ``layers``: one dict per layer, in
+    order (``norm1``, ``wq [hidden, heads * k_dim]``, ``wk [hidden,
+    kv_heads * k_dim]``, ``wv [hidden, kv_heads * v_dim]``, ``wo [heads *
+    v_dim, hidden]``, ``sinks [heads]`` where the layer's kind has them,
+    ``norm2``, then ``ffn_gate_up [hidden, 2 f]`` and ``ffn_down [f,
+    hidden]`` for a dense layer or ``router [hidden, E]``, ``router_bias
+    [E]``, ``experts_gate_up [held, hidden, 2 f]`` and ``experts_down
+    [held, f, hidden]`` for an expert layer; gate columns come first);
+    ``final_norm [hidden]``; ``head [hidden, vocab]`` (untied)."""
+
+    embedding: Any
+    layers: Tuple[dict, ...]
+    final_norm: Any
+    head: Any
+
+
+def decode_model(config: TransformerConfig, cache: KVCacheConfig, **kwargs):
+    """The serving forward of ``config``: :class:`HybridDecodeModel` where
+    it describes its layers one by one, else :class:`DecodeModel`."""
+    if config.hybrid is not None:
+        return HybridDecodeModel(config, cache, **kwargs)
+    return DecodeModel(config, cache, **kwargs)
+
+
+class HybridDecodeModel:
+    """Prefill/decode forward over layers of more than one kind (module
+    docstring).  Stateless like :class:`DecodeModel`; ``arenas`` is
+    ``init_group_arenas``'s tree (per group, per layer, ``(k, v)``) and
+    ``block_tables`` a tuple, one table per cache group."""
+
+    def __init__(self, config: TransformerConfig, cache: KVCacheConfig, *,
+                 fused_attention: bool = True, fuse_epilogue: bool = True,
+                 lora: Optional[LoRAConfig] = None):
+        del fuse_epilogue       # the LayerNorm epilogue kernel: not these
+        cfg = serving_config(config)
+        if lora is not None:
+            raise NotImplementedError("no LoRA over hybrid layers yet")
+        if cfg.tensor_axis is not None \
+                and cc.bound_axis_size(cfg.tensor_axis) > 1:
+            raise NotImplementedError(
+                "hybrid layers are served on one chip's share: no tp yet")
+        self.cfg, self.cache = cfg, cache
+        self.spec = cfg.hybrid
+        self.fused_attention = fused_attention
+        # layer -> (cache group, index among the group's layers)
+        self.place = {}
+        for gi, group in enumerate(cache.groups):
+            kind = self.spec.kinds[self.spec.layer_kinds[group.layers[0]]]
+            if (group.kv_heads, group.k_dim, group.v_dim, group.window) != (
+                    kind.kv_heads, kind.k_dim, kind.v_dim, kind.window):
+                raise ValueError(
+                    f"cache group {gi} {group} does not hold the rows of "
+                    f"attention kind {kind}")
+            for li, layer in enumerate(group.layers):
+                self.place[layer] = (gi, li)
+        if sorted(self.place) != list(range(cfg.num_layers)):
+            raise ValueError("the cache groups do not cover the layers")
+        self.n_expert_layers = sum(self.spec.layer_experts)
+
+    # ----------------------------------------------------------------- util
+
+    def _norm(self, x, weight):
+        return fused_rms_norm_affine(x, weight, self.cfg.hidden_size,
+                                     self.cfg.layernorm_epsilon)
+
+    def _mm(self, x, w):
+        return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+    def _rotate(self, x, positions, kind):
+        """Half rotation of the leading ``rotary_dim`` channels of
+        ``x [tokens, heads, k_dim]`` at ``positions [tokens]``, in fp32."""
+        cos, sin = rotary_cos_sin(positions, kind.rotary_dim,
+                                  kind.rotary_base, jnp.float32)
+        return apply_rotary_packed(x.astype(jnp.float32)[:, None],
+                                   cos[:, None], sin[:, None])[:, 0]
+
+    def _walk(self, params, x, positions, live, arenas, attend):
+        """The layers in order on ``x [tokens, hidden]`` (``live [tokens]``:
+        the rows that are tokens, not padding).  ``attend(kind,
+        group, q, k, v, layer_arenas, sinks) -> (ctx [tokens, heads *
+        v_dim], layer_arenas)`` appends the rows and runs the paged
+        kernel.  Returns ``(x, arenas, pairs [expert layers, held], chosen
+        [expert layers, tokens, top_k])``."""
+        cfg, spec = self.cfg, self.spec
+        dtype = cfg.dtype
+        # the residual stream stays in fp32 (each layer adds a small term to
+        # it; in bf16 every add would round the whole stream); the GEMMs'
+        # operands are the model's dtype
+        x = x.astype(jnp.float32)
+        arenas = [list(group) for group in arenas]
+        pairs, chosen = [], []
+        for layer, lp in enumerate(params.layers):
+            kind = spec.kinds[spec.layer_kinds[layer]]
+            gi, li = self.place[layer]
+            n, g = kind.num_heads, kind.kv_heads
+            h1 = self._norm(x, lp["norm1"]).astype(dtype)
+            q = self._rotate(self._mm(h1, lp["wq"]).reshape(-1, n, kind.k_dim),
+                             positions, kind).astype(dtype)
+            k = self._rotate(self._mm(h1, lp["wk"]).reshape(-1, g, kind.k_dim),
+                             positions, kind).astype(dtype)
+            v = (self._mm(h1, lp["wv"]) * spec.value_scale).astype(dtype)
+            ctx, arenas[gi][li] = attend(
+                kind, gi, q, k.reshape(-1, g * kind.k_dim), v,
+                arenas[gi][li], lp["sinks"] if kind.sink else None)
+            x = x + self._mm(ctx, lp["wo"])
+            h2 = self._norm(x, lp["norm2"]).astype(dtype)
+            if spec.layer_experts[layer]:
+                y, routed, experts = moe.held_experts_ffn(
+                    h2, lp["router"], lp["router_bias"],
+                    lp["experts_gate_up"], lp["experts_down"],
+                    top_k=spec.experts.top_k, held=spec.experts.held,
+                    live=live)
+                pairs.append(routed)
+                chosen.append(experts)
+            else:
+                f = lp["ffn_down"].shape[0]
+                gate_up = self._mm(h2, lp["ffn_gate_up"])
+                mid = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:])
+                y = self._mm(mid.astype(dtype), lp["ffn_down"])
+            x = x + y
+        held, top_k = ((spec.experts.held[1], spec.experts.top_k)
+                       if spec.experts is not None else (0, 0))
+        pairs = (jnp.stack(pairs) if pairs
+                 else jnp.zeros((0, held), jnp.int32))
+        chosen = (jnp.stack(chosen) if chosen
+                  else jnp.zeros((0, x.shape[0], top_k), jnp.int32))
+        return x, tuple(tuple(group) for group in arenas), pairs, chosen
+
+    def _logits(self, params, x):
+        """fp32 logits ``[rows, vocab]`` of ``x [rows, hidden]``."""
+        return self._mm(
+            self._norm(x, params.final_norm).astype(self.cfg.dtype),
+            params.head)
+
+    def _kernel_kwargs(self, kind, sinks):
+        return dict(kv_heads=kind.kv_heads, window=kind.window, sinks=sinks)
+
+    # ---------------------------------------------------------------- entry
+
+    def decode_step(self, arenas, params, tokens, positions, block_tables,
+                    active, n_draft, temperature, top_k, top_p, seeds,
+                    steps):
+        """One continuously-batched decode step: :meth:`DecodeModel.
+        decode_step`'s contract at ``spec_width`` 1 (``n_draft`` is taken
+        and must be zero), with a tuple of block tables and two more
+        results: ``pairs [expert layers, held experts]`` int32, the
+        ``(token, expert)`` pairs this step routed to each held expert,
+        and ``chosen [expert layers, max_batch, top_k]`` int32, the experts
+        each slot's router chose.  Returns ``(arenas, out_tokens
+        [max_batch, 1], accepted [max_batch], logits [max_batch, 1, vocab],
+        pairs, chosen)``."""
+        del n_draft
+        bs = self.cache.block_size
+        B = tokens.shape[0]
+        positions = positions.astype(jnp.int32)
+        lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
+        logical = positions // bs
+        dest_offsets = positions % bs
+        attend = (paged_attention_decode if self.fused_attention
+                  else paged_attention_decode_unfused)
+
+        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+            table = block_tables[gi]
+            phys = jnp.take_along_axis(
+                table, jnp.clip(logical, 0, table.shape[1] - 1)[:, None],
+                axis=1)[:, 0]
+            # inactive slots write out of range and the scatter drops them
+            dest = jnp.where(active, phys,
+                             self.cache.groups[gi].n_blocks)
+            k_arena, v_arena = layer_arenas
+            k_arena = k_arena.at[dest, dest_offsets].set(
+                k.astype(k_arena.dtype), mode="drop")
+            v_arena = v_arena.at[dest, dest_offsets].set(
+                v.astype(v_arena.dtype), mode="drop")
+            ctx = attend(q, k_arena, v_arena, table, lengths,
+                         **self._kernel_kwargs(kind, sinks))
+            return ctx.reshape(B, -1).astype(q.dtype), (k_arena, v_arena)
+
+        x = params.embedding[tokens[:, 0]]
+        x, arenas, pairs, chosen = self._walk(params, x, positions, active,
+                                              arenas, attn_core)
+        logits = self._logits(params, x)                       # [B, vocab]
+        sampled = sample_tokens(logits, temperature, top_k, top_p, seeds,
+                                steps)
+        out = jnp.where(active, sampled, 0).astype(jnp.int32)[:, None]
+        return (arenas, out, jnp.zeros((B,), jnp.int32), logits[:, None],
+                pairs, chosen)
+
+    def prefill(self, arenas, params, tokens, position_ids, block_tables,
+                lengths, limits, sample_index, temperature, top_k, top_p,
+                seeds, steps):
+        """Batched chunked prefill of one ``[max_batch, chunk]`` slice:
+        :meth:`DecodeModel.prefill`'s contract with a tuple of block
+        tables, the rows' destinations read from them in the graph
+        (``limits == 0`` marks padding), and the logits of the sampled row
+        only.  Returns ``(arenas, next_tokens [max_batch], logits
+        [max_batch, 1, vocab], pairs, chosen [expert layers, max_batch *
+        chunk, top_k])``."""
+        bs = self.cache.block_size
+        B, T = tokens.shape
+        position_ids = position_ids.astype(jnp.int32)
+        limits = limits.astype(jnp.int32)
+        lengths = lengths.astype(jnp.int32)
+        real = limits > 0
+        logical = position_ids // bs
+        dest_offsets = position_ids % bs
+        attend = (paged_prefill_attention if self.fused_attention
+                  else paged_prefill_attention_unfused)
+
+        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+            table = block_tables[gi]
+            phys = jnp.take_along_axis(
+                table, jnp.clip(logical, 0, table.shape[1] - 1), axis=1)
+            dest = jnp.where(real, phys, self.cache.groups[gi].n_blocks)
+            k_arena, v_arena = layer_arenas
+            k_arena = k_arena.at[dest, dest_offsets].set(
+                k.reshape(B, T, -1).astype(k_arena.dtype), mode="drop")
+            v_arena = v_arena.at[dest, dest_offsets].set(
+                v.reshape(B, T, -1).astype(v_arena.dtype), mode="drop")
+            ctx = attend(q.reshape(B, T, kind.num_heads, kind.k_dim),
+                         k_arena, v_arena, table, lengths, limits,
+                         **self._kernel_kwargs(kind, sinks))
+            return (ctx.reshape(B * T, -1).astype(q.dtype),
+                    (k_arena, v_arena))
+
+        x = params.embedding[tokens.reshape(-1)]
+        x, arenas, pairs, chosen = self._walk(
+            params, x, position_ids.reshape(-1), real.reshape(-1), arenas,
+            attn_core)
+        idx = sample_index.astype(jnp.int32)
+        last = x.reshape(B, T, -1)[jnp.arange(B), jnp.clip(idx, 0, T - 1)]
+        logits = self._logits(params, last)                    # [B, vocab]
+        sampled = sample_tokens(logits, temperature, top_k, top_p, seeds,
+                                steps)
+        valid = (idx >= 0) & (idx < T)
+        next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
+        return arenas, next_tokens, logits[:, None], pairs, chosen

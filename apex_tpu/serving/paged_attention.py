@@ -200,7 +200,8 @@ def _vmem_bytes(shape, dtype) -> int:
     return n * itemsize
 
 
-def _step_plan(block_tables, lengths, block_size: int, pages: int):
+def _step_plan(block_tables, lengths, block_size: int, pages: int,
+               first_page=None):
     """The decode kernel's sweep, from the table and the lengths: which
     ``(slot, group)`` each grid step is, how many steps there are, and
     which arena block each of the ``pages`` page operands holds at each.
@@ -214,17 +215,27 @@ def _step_plan(block_tables, lengths, block_size: int, pages: int):
     so that copy starts as early as the buffer is free, under the compute
     of the steps between), or, after its last, the block it held: table
     columns past the live range never reach a DMA, and a dead page costs
-    no copy."""
+    no copy.
+
+    ``first_page [b]`` (a window): the sweep of a slot starts at that page
+    and the pages before it are dropped from the plan like those past the
+    length, so a window layer's steps follow the window, not the history."""
     b, max_blocks = block_tables.shape
     n_groups = pl.cdiv(max_blocks, pages)
     steps = jnp.arange(b * n_groups)
     n_live = -(-lengths // block_size)                  # pages of a slot
-    groups = jnp.maximum(-(-n_live // pages), 1)        # steps of a slot
+    if first_page is None:
+        groups = jnp.maximum(-(-n_live // pages), 1)    # steps of a slot
+    else:
+        first_page = jnp.minimum(first_page, n_live)
+        groups = jnp.maximum(-(-(n_live - first_page) // pages), 1)
     ends = jnp.cumsum(groups)
     slot = jnp.minimum(
         jnp.sum(steps[:, None] >= ends[None, :], axis=1), b - 1)
     group = steps - (ends - groups)[slot]
     cols = group[:, None] * pages + jnp.arange(pages)[None, :]
+    if first_page is not None:
+        cols = cols + first_page[slot][:, None]
     live = (cols < n_live[slot][:, None]) & (steps < ends[-1])[:, None]
     own = block_tables[slot[:, None], jnp.minimum(cols, max_blocks - 1)]
     at = steps[:, None]
@@ -337,8 +348,19 @@ def _check_arena(q_d, k_arena, n, g, k_scales, v_scales):
 def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
                            limits=None, k_scales=None, v_scales=None,
                            block_size: Optional[int] = None,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None,
+                           kv_heads: Optional[int] = None,
+                           window: Optional[int] = None, sinks=None):
     """One fused gather+dequant+attention pass over the paged cache.
+
+    A 3-D ``k_arena`` is a cache group's flat arena (``[n_blocks, block,
+    kv_heads * d_k]`` beside ``[..., kv_heads * d_v]``, ``kv_heads`` given):
+    ``d_k`` may differ from ``d_v``, ``window`` drops the pages wholly
+    behind it from the step plan and masks inside the edge pages, ``sinks
+    [n_heads]`` joins the softmax as one more logit with no value row.  In a
+    device trace the call is ``paged_decode_window`` where it has a window
+    and ``paged_decode_full`` where not; the pooled arena's is
+    ``paged_decode``.
 
     See the module docstring for layouts.  ``block_tables`` columns past
     a slot's live pages are never fetched, so they may hold any value
@@ -364,6 +386,13 @@ def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths, *,
             k_scales=k_scales, v_scales=v_scales, scale=scale)
     if limits is not None:
         raise ValueError("limits only apply to a 4-D (multi-query) q")
+    if k_arena.ndim == 3:
+        return _decode_flat(q, k_arena, v_arena, block_tables, lengths,
+                            kv_heads=kv_heads, window=window, sinks=sinks,
+                            scale=scale)
+    if window is not None or sinks is not None:
+        raise NotImplementedError(
+            "a window or sinks need a cache group's flat arena")
     b, n, d = q.shape
     n_blocks, bs, g, dk = k_arena.shape
     if block_size is not None and block_size != bs:
@@ -436,10 +465,48 @@ def _compiler_params():
         dimension_semantics=("parallel", "arbitrary"))
 
 
+def _unflat(arena, kv_heads):
+    """A cache group's flat arena as ``[n_blocks, block, kv_heads, d]``."""
+    if arena.ndim == 4:
+        return arena
+    if not kv_heads:
+        raise ValueError("a flat arena needs kv_heads")
+    return arena.reshape(arena.shape[:2] + (kv_heads, -1))
+
+
+def _window_mask(mask, cols, horizon, window):
+    """``mask`` and ``cols >= horizon - window``: a query whose horizon is
+    ``horizon`` (it sits at ``horizon - 1``) reads keys ``j > horizon - 1 -
+    window``."""
+    if window is None:
+        return mask
+    return mask & (cols >= horizon - window)
+
+
+def _sink_softmax(s, sinks, head_axis):
+    """Softmax weights of masked scores ``s`` over its last axis with an
+    optional per-head sink logit in the denominator (``sinks [n]`` along
+    ``head_axis``).  Rows with no live key weigh nothing."""
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sinks is not None:
+        shape = [1] * s.ndim
+        shape[head_axis] = -1
+        sink = sinks.astype(jnp.float32).reshape(shape)
+        dead = m <= NEG_INF * 0.5
+        m = jnp.maximum(m, sink)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m)
+        return jnp.where(dead, 0.0, p / l)
+    m_safe = jnp.where(m <= NEG_INF * 0.5, 0.0, m)
+    p = jnp.exp(s - m_safe)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    return p / jnp.where(l == 0.0, 1.0, l)
+
+
 def _gathered_kv(q, k_arena, v_arena, block_tables, k_scales, v_scales):
     """The unfused twins' shared gather: materialise per-slot K/V (and
     apply int8 row scales) in HBM — the cost the fused kernels avoid."""
-    b, n, d = q.shape
+    b, n = q.shape[:2]
     _, bs, g, _ = k_arena.shape
     hpg = n // g
     k = jnp.take(k_arena, block_tables, axis=0).astype(jnp.float32)
@@ -450,8 +517,8 @@ def _gathered_kv(q, k_arena, v_arena, block_tables, k_scales, v_scales):
         k = k * ks[..., None]
         v = v * vs[..., None]
     t = block_tables.shape[1] * bs
-    k = k.reshape(b, t, g, d)
-    v = v.reshape(b, t, g, d)
+    k = k.reshape(b, t, g, k.shape[-1])
+    v = v.reshape(b, t, g, v.shape[-1])
     if hpg > 1:
         k = jnp.repeat(k, hpg, axis=2)
         v = jnp.repeat(v, hpg, axis=2)
@@ -461,7 +528,10 @@ def _gathered_kv(q, k_arena, v_arena, block_tables, k_scales, v_scales):
 def paged_attention_decode_unfused(q, k_arena, v_arena, block_tables,
                                    lengths, *, limits=None, k_scales=None,
                                    v_scales=None,
-                                   scale: Optional[float] = None):
+                                   scale: Optional[float] = None,
+                                   kv_heads: Optional[int] = None,
+                                   window: Optional[int] = None,
+                                   sinks=None):
     """The plain-XLA lowering of the same computation — the A/B baseline
     (bench ``serving.vs_unfused``) and the parity reference.
 
@@ -481,19 +551,26 @@ def paged_attention_decode_unfused(q, k_arena, v_arena, block_tables,
     if limits is not None:
         raise ValueError("limits only apply to a 4-D (multi-query) q")
     b, n, d = q.shape
+    k_arena, v_arena = _unflat(k_arena, kv_heads), _unflat(v_arena, kv_heads)
     _check_arena(d, k_arena, n, k_arena.shape[2], k_scales, v_scales)
     k, v, t = _gathered_kv(q, k_arena, v_arena, block_tables,
                            k_scales, v_scales)
     s = jnp.einsum("bnd,btnd->bnt", q.astype(jnp.float32), k)
     s = s * _resolve(scale, d)
-    mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
+    cols = jnp.arange(t)[None, None, :]
+    mask = _window_mask(cols < lengths[:, None, None], cols,
+                        lengths[:, None, None], window)
     s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    m_safe = jnp.where(m <= NEG_INF * 0.5, 0.0, m)
-    p = jnp.exp(s - m_safe)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bnt,btnd->bnd", p, v) / jnp.where(l == 0.0, 1.0, l)
-    return out.astype(q.dtype)
+    if window is None and sinks is None:
+        m = jnp.max(s, axis=-1, keepdims=True)
+        m_safe = jnp.where(m <= NEG_INF * 0.5, 0.0, m)
+        p = jnp.exp(s - m_safe)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        out = jnp.einsum("bnt,btnd->bnd", p, v) / jnp.where(
+            l == 0.0, 1.0, l)
+        return out.astype(q.dtype)
+    p = _sink_softmax(s, sinks, head_axis=1)
+    return jnp.einsum("bnt,btnd->bnd", p, v).astype(q.dtype)
 
 
 # --------------------------------------------------- chunked prefill
@@ -550,7 +627,9 @@ def _prefill_kernel(tab_ref, len_ref, q_ref, lim_ref, k_ref, v_ref, *rest,
 
 def paged_prefill_attention(q, k_arena, v_arena, block_tables, lengths,
                             limits, *, k_scales=None, v_scales=None,
-                            scale: Optional[float] = None):
+                            scale: Optional[float] = None,
+                            kv_heads: Optional[int] = None,
+                            window: Optional[int] = None, sinks=None):
     """Fused chunked-prefill attention: each slot's ``[chunk]`` query
     tokens attend over the slot's paged context in one block sweep.
 
@@ -561,7 +640,20 @@ def paged_prefill_attention(q, k_arena, v_arena, block_tables, lengths,
     row, which produces zeros).  History blocks and the chunk's own
     destination blocks are all just table entries — prefix-cache hits,
     earlier chunks, and in-chunk causality need no separate paths.
+
+    A 3-D ``k_arena`` is a cache group's flat arena (see
+    :func:`paged_attention_decode`): ``window`` then starts the block
+    sweep at the first block the chunk's first token can read, ``sinks`` as
+    there; the call's name in a trace is ``paged_prefill_window`` or
+    ``paged_prefill_full``.
     """
+    if k_arena.ndim == 3:
+        return _prefill_flat(q, k_arena, v_arena, block_tables, lengths,
+                             limits, kv_heads=kv_heads, window=window,
+                             sinks=sinks, scale=scale)
+    if window is not None or sinks is not None:
+        raise NotImplementedError(
+            "a window or sinks need a cache group's flat arena")
     return _multi_query_attention(
         q, k_arena, v_arena, block_tables, lengths, limits,
         k_scales=k_scales, v_scales=v_scales, scale=scale)
@@ -642,17 +734,26 @@ def _multi_query_attention(q, k_arena, v_arena, block_tables, lengths,
 def paged_prefill_attention_unfused(q, k_arena, v_arena, block_tables,
                                     lengths, limits, *, k_scales=None,
                                     v_scales=None,
-                                    scale: Optional[float] = None):
+                                    scale: Optional[float] = None,
+                                    kv_heads: Optional[int] = None,
+                                    window: Optional[int] = None,
+                                    sinks=None):
     """Plain-XLA chunked-prefill lowering (A/B baseline + parity
     reference): gather each slot's whole table, mask per token."""
     b, T, n, d = q.shape
+    k_arena, v_arena = _unflat(k_arena, kv_heads), _unflat(v_arena, kv_heads)
     _check_arena(d, k_arena, n, k_arena.shape[2], k_scales, v_scales)
     k, v, t = _gathered_kv(q[:, 0], k_arena, v_arena, block_tables,
                            k_scales, v_scales)
     s = jnp.einsum("btnd,bsnd->btns", q.astype(jnp.float32), k)
     s = s * _resolve(scale, d)
-    mask = jnp.arange(t)[None, None, None, :] < limits[:, :, None, None]
+    cols = jnp.arange(t)[None, None, None, :]
+    mask = _window_mask(cols < limits[:, :, None, None], cols,
+                        limits[:, :, None, None], window)
     s = jnp.where(mask, s, NEG_INF)
+    if window is not None or sinks is not None:
+        p = _sink_softmax(s, sinks, head_axis=2)
+        return jnp.einsum("btns,bsnd->btnd", p, v).astype(q.dtype)
     m = jnp.max(s, axis=-1, keepdims=True)
     m_safe = jnp.where(m <= NEG_INF * 0.5, 0.0, m)
     p = jnp.exp(s - m_safe)
@@ -660,3 +761,311 @@ def paged_prefill_attention_unfused(q, k_arena, v_arena, block_tables,
     out = jnp.einsum("btns,bsnd->btnd", p, v) / \
         jnp.where(l == 0.0, 1.0, l)
     return out.astype(q.dtype)
+
+
+# ------------------------------------- cache groups: flat arenas (ISSUE 27)
+#
+# The arenas of a cache group are ``[n_blocks, block, kv_heads * d]``: the
+# rows of all KV heads side by side on the lanes, dense whatever ``d`` is
+# (``4 * 192 = 768`` and ``8 * 192 = 1536`` lanes at the widths that
+# brought them), ``d_k`` beside another ``d_v``.  The kernels below are the
+# step-plan decode sweep and the multi-query block sweep again, reading a
+# KV head as a lane slice of the page, with two more mechanisms: a sliding
+# ``window`` (pages wholly behind it are dropped from the decode plan and
+# from the prefill sweep, the edge pages are masked) and per-head ``sinks``
+# (one more logit of the softmax denominator, folded in when a row's sweep
+# ends).  A bfloat16 arena is multiplied as it lies (bf16 operands, fp32
+# accumulation: the products are exact, the probabilities are rounded to
+# bf16 for ``p v`` as the flash kernels do); any other dtype goes the fp32
+# ``HIGHEST`` way of the kernels above.
+
+
+def _mxu(a, b, contract, exact: bool):
+    """``a . b`` with fp32 accumulation: bf16 operands in one MXU pass, or
+    the fp32 ``HIGHEST`` contraction of :func:`_dot`."""
+    if exact:
+        return _dot(a.astype(jnp.float32), b.astype(jnp.float32), contract)
+    return jax.lax.dot_general(
+        a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+        (contract, ((), ())), preferred_element_type=jnp.float32)
+
+
+def _fold_sink(m, l, acc, sink):
+    """The sink logit as one more term of a finished row's softmax:
+    ``m``/``l [rows, 1]``, ``sink`` broadcastable to them.  A row with no
+    live key (``m`` at ``NEG_INF``) ends with ``l = 1`` and a zero ``acc``."""
+    m_fin = jnp.maximum(m, sink)
+    alpha = jnp.exp(m - m_fin)
+    return l * alpha + jnp.exp(sink - m_fin), acc * alpha
+
+
+def _check_flat(q, k_arena, v_arena, kv_heads, sinks):
+    n, dk = q.shape[-2:]
+    if not kv_heads or n % kv_heads:
+        raise ValueError(
+            f"n_heads ({n}) not a multiple of kv_heads ({kv_heads})")
+    if k_arena.shape[-1] != kv_heads * dk:
+        raise ValueError(
+            f"K rows of {k_arena.shape[-1]} lanes hold no {kv_heads} heads "
+            f"of q's width {dk}")
+    if v_arena.shape[-1] % kv_heads or v_arena.shape[:2] != k_arena.shape[:2]:
+        raise ValueError(
+            f"V arena {v_arena.shape} does not go with K {k_arena.shape}")
+    if sinks is not None and sinks.shape != (n,):
+        raise ValueError(f"sinks {sinks.shape} are not one per head ({n})")
+    return dk, v_arena.shape[-1] // kv_heads
+
+
+def _decode_flat_kernel(plan_ref, slot_ref, group_ref, len_ref, first_ref,
+                        q_ref, *rest, scale: float, block_size: int,
+                        pages: int, g: int, hpg: int, dk: int, dv: int,
+                        window: Optional[int], has_sinks: bool,
+                        exact: bool):
+    """One grid step = ``pages`` pages of one slot, all heads; ``rest``: the
+    sinks (if any), the K arena bound ``pages`` times, the V arena
+    likewise, the output and the softmax state."""
+    del plan_ref
+    if has_sinks:
+        sink_ref, rest = rest[0], rest[1:]
+    k_refs, v_refs = rest[:pages], rest[pages:2 * pages]
+    o_ref, m_sc, l_sc, acc_sc = rest[-4:]
+    step = pl.program_id(0)
+    slot = slot_ref[step]
+    j = group_ref[step]
+    length = len_ref[slot]
+    first_page = first_ref[slot]
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def page_step(k_ref, v_ref, first):
+        cols = first + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        live = cols < length
+        if window is not None:
+            live = live & (cols >= length - window)
+        for h in range(g):
+            rows = slice(h * hpg, (h + 1) * hpg)
+            q = q_ref[0, rows, :]                                # [hpg, dk]
+            k = k_ref[0, :, h * dk:(h + 1) * dk]                 # [bs, dk]
+            v = v_ref[0, :, h * dv:(h + 1) * dv]                 # [bs, dv]
+            s = _mxu(q, k, ((1,), (1,)), exact) * scale          # [hpg, bs]
+            s = jnp.where(live, s, NEG_INF)
+            p, alpha, m_new, l_new = _online_softmax(
+                s, m_sc[rows, :1], l_sc[rows, :1])
+            acc_sc[rows, :] = acc_sc[rows, :] * alpha + _mxu(
+                p, v, ((1,), (0,)), exact)
+            m_sc[rows, :] = jnp.broadcast_to(m_new, (hpg, _LANES))
+            l_sc[rows, :] = jnp.broadcast_to(l_new, (hpg, _LANES))
+
+    for p in range(pages):
+        first = (first_page + j * pages + p) * block_size
+
+        @pl.when(first < length)
+        def _page(p=p, first=first):
+            page_step(k_refs[p], v_refs[p], first)
+
+    @pl.when((first_page + (j + 1) * pages) * block_size >= length)
+    def _finalize():
+        l_fin, acc = l_sc[:, :1], acc_sc[...]
+        if has_sinks:
+            l_fin, acc = _fold_sink(m_sc[:, :1], l_fin, acc, sink_ref[...])
+        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+
+
+def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
+                 window, sinks, scale):
+    name = "paged_decode_full" if window is None else "paged_decode_window"
+    b, n, _ = q.shape
+    dk, dv = _check_flat(q, k_arena, v_arena, kv_heads, sinks)
+    bs = k_arena.shape[1]
+    g, hpg = kv_heads, n // kv_heads
+    max_blocks = block_tables.shape[1]
+    page_bytes = max(_vmem_bytes((bs, g * dk), k_arena.dtype),
+                     _vmem_bytes((bs, g * dv), v_arena.dtype))
+    pages = _pages_per_step(page_bytes, max_blocks)
+    lengths = lengths.astype(jnp.int32)
+    if window is None:
+        first_page = jnp.zeros((b,), jnp.int32)
+    else:
+        first_page = jnp.maximum(lengths - window, 0) // bs
+    n_steps, plan, slot, group = _step_plan(
+        block_tables, lengths, bs, pages, first_page)
+    # a block handed back behind the window is never in the plan; an entry
+    # that says so must still index the arena
+    plan = jnp.maximum(plan, 0)
+
+    def row_idx(s, plan_ref, slot_ref, group_ref, len_ref, first_ref):
+        return (slot_ref[s], 0, 0)
+
+    def page_spec(p, lanes):
+        def idx(s, plan_ref, slot_ref, group_ref, len_ref, first_ref):
+            return (plan_ref[s * pages + p], 0, 0)
+        return pl.BlockSpec((1, bs, lanes), idx)
+
+    in_specs = [pl.BlockSpec((1, n, dk), row_idx)]
+    operands = [q]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec(
+            (n, 1), lambda s, *refs: (0, 0)))
+        operands.append(sinks.astype(jnp.float32)[:, None])
+    in_specs += [page_spec(p, g * dk) for p in range(pages)]
+    in_specs += [page_spec(p, g * dv) for p in range(pages)]
+    operands += [k_arena] * pages + [v_arena] * pages
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n_steps,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n, dv), row_idx),
+        scratch_shapes=[
+            pltpu.VMEM((n, _LANES), jnp.float32),
+            pltpu.VMEM((n, _LANES), jnp.float32),
+            pltpu.VMEM((n, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _decode_flat_kernel, scale=_resolve(scale, dk), block_size=bs,
+        pages=pages, g=g, hpg=hpg, dk=dk, dv=dv, window=window,
+        has_sinks=sinks is not None,
+        exact=k_arena.dtype != jnp.bfloat16)
+    with named_span(name):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n, dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=platform.pallas_interpret(),
+            name=name,
+        )(plan, slot, group, lengths, first_page, *operands)
+
+
+def _prefill_flat_kernel(tab_ref, len_ref, first_ref, q_ref, lim_ref, *rest,
+                         scale: float, block_size: int, g: int, hpg: int,
+                         dk: int, dv: int, window: Optional[int],
+                         has_sinks: bool, exact: bool):
+    del tab_ref
+    if has_sinks:
+        sink_ref, rest = rest[0], rest[1:]
+    k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = rest
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[i]
+    page = first_ref[i] + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(page * block_size < length)
+    def _body():
+        cols = page * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        lim = lim_ref[0]                                     # [T, 1]
+        live = cols < lim                                    # [T, bs]
+        if window is not None:
+            live = live & (cols >= lim - window)
+        for h in range(g):
+            k = k_ref[0, :, h * dk:(h + 1) * dk]             # [bs, dk]
+            v = v_ref[0, :, h * dv:(h + 1) * dv]
+            for n in range(h * hpg, (h + 1) * hpg):
+                s = _mxu(q_ref[0, n], k, ((1,), (1,)), exact) * scale
+                s = jnp.where(live, s, NEG_INF)              # [T, bs]
+                p, alpha, m_new, l_new = _online_softmax(
+                    s, m_sc[:, n:n + 1], l_sc[:, n:n + 1])
+                acc_sc[n] = acc_sc[n] * alpha + _mxu(
+                    p, v, ((1,), (0,)), exact)
+                m_sc[:, n:n + 1] = m_new
+                l_sc[:, n:n + 1] = l_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        for n in range(g * hpg):
+            l_fin, acc = l_sc[:, n:n + 1], acc_sc[n]
+            if has_sinks:
+                l_fin, acc = _fold_sink(m_sc[:, n:n + 1], l_fin, acc,
+                                        sink_ref[:, n:n + 1])
+            l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+            o_ref[0, n] = (acc / l_safe).astype(o_ref.dtype)
+
+
+def _prefill_flat(q, k_arena, v_arena, block_tables, lengths, limits, *,
+                  kv_heads, window, sinks, scale):
+    name = ("paged_prefill_full" if window is None
+            else "paged_prefill_window")
+    b, T, n, _ = q.shape
+    dk, dv = _check_flat(q, k_arena, v_arena, kv_heads, sinks)
+    bs = k_arena.shape[1]
+    g, hpg = kv_heads, n // kv_heads
+    max_blocks = block_tables.shape[1]
+    lengths = lengths.astype(jnp.int32)
+    limits = limits.astype(jnp.int32)
+    if window is None:
+        first_page = jnp.zeros((b,), jnp.int32)
+        sweep = max_blocks
+    else:
+        # the chunk's first token (horizon ``limits[:, 0]``) reads nothing
+        # before ``limits[:, 0] - window``; the sweep spans the window and
+        # the chunk, wherever they lie against the block edges
+        first_page = jnp.maximum(limits[:, 0] - window, 0) // bs
+        sweep = min(max_blocks, (window + T - 2) // bs + 2)
+
+    def kv_idx(i, j, tab_ref, len_ref, first_ref):
+        live = jnp.maximum((len_ref[i] - 1) // bs, 0)
+        entry = tab_ref[i, jnp.minimum(first_ref[i] + j, live)]
+        return (jnp.maximum(entry, 0), 0, 0)
+
+    def row_idx(i, j, *refs):
+        return (i, 0, 0)
+
+    def q_idx(i, j, *refs):
+        return (i, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, n, T, dk), q_idx),
+                pl.BlockSpec((1, T, 1), row_idx)]
+    operands = [q.transpose(0, 2, 1, 3), limits[..., None]]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec((1, n), lambda i, j, *refs: (0, 0)))
+        operands.append(sinks.astype(jnp.float32)[None, :])
+    in_specs += [pl.BlockSpec((1, bs, g * dk), kv_idx),
+                 pl.BlockSpec((1, bs, g * dv), kv_idx)]
+    operands += [k_arena, v_arena]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, sweep),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n, T, dv), q_idx),
+        scratch_shapes=[
+            pltpu.VMEM((T, n), jnp.float32),
+            pltpu.VMEM((T, n), jnp.float32),
+            pltpu.VMEM((n, T, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _prefill_flat_kernel, scale=_resolve(scale, dk), block_size=bs,
+        g=g, hpg=hpg, dk=dk, dv=dv, window=window,
+        has_sinks=sinks is not None,
+        exact=k_arena.dtype != jnp.bfloat16)
+    # q and out blocks of all heads (double-buffered) and the fp32
+    # accumulator: more than the default scoped VMEM at 64 heads x 128
+    vmem = (2 * _vmem_bytes((n, T, dk), q.dtype)
+            + 2 * _vmem_bytes((n, T, dv), q.dtype)
+            + _vmem_bytes((n, T, dv), jnp.float32) + (8 << 20))
+    with named_span(name):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n, T, dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=max(vmem, 32 << 20)),
+            interpret=platform.pallas_interpret(),
+            name=name,
+        )(block_tables.astype(jnp.int32), lengths, first_page, *operands)
+    return out.transpose(0, 2, 1, 3)

@@ -47,6 +47,21 @@ that gap the way production engines do:
   baseline (bench ``serving_occupancy.vs_reserve``): no sharing, no
   growth, no preemption — admission is the whole horizon or nothing.
 
+**Cache groups** (ISSUE 27): with more than one
+:class:`~apex_tpu.serving.kv_cache.CacheGroup` a request holds one block
+list per group (``Request.blocks`` for group 0, ``Request.more_blocks`` for
+the rest), all indexed by the same logical block.  Admission, growth and
+preemption count every group: a request is admitted or grown only when each
+group's pool can serve it, and a victim gives back its blocks of every
+group.  A window group also gives back, at each plan, the blocks that lie
+wholly behind what the request's next query can read
+(:meth:`Scheduler.free_behind_window`; their entries read
+:data:`~apex_tpu.serving.kv_cache.FREED`), so it holds ``window +
+chunk`` tokens a slot however long the history.  Prefix sharing is refused
+at construction for such a cache: a shared prefix would have to outlive the
+window that hands its blocks back.  :meth:`Scheduler.check` holds the
+invariants of every group.
+
 **Slots** are indices into the engine's fixed ``[max_batch]`` decode
 arrays; a request keeps one slot from admission to finish or
 preemption.  Churn rewrites the slot's row of the block-table/length
@@ -76,6 +91,7 @@ from typing import Deque, List, Optional, Sequence
 import numpy as np
 
 from apex_tpu.serving.kv_cache import (
+    FREED,
     BlockAllocator,
     KVCacheConfig,
     PrefixCache,
@@ -122,6 +138,12 @@ class Request:
     state: RequestState = RequestState.WAITING
     output_tokens: List[int] = dataclasses.field(default_factory=list)
     blocks: List[int] = dataclasses.field(default_factory=list)
+    # the block lists of cache groups 1.. (same logical index as ``blocks``;
+    # FREED where a window group handed a block back)
+    more_blocks: List[List[int]] = dataclasses.field(default_factory=list)
+    # per cache group, how many leading entries of its list are FREED (what
+    # a window hands back is always a prefix)
+    freed_prefix: List[int] = dataclasses.field(default_factory=list)
     slot: Optional[int] = None
     cache_len: int = 0                  # tokens currently in the paged cache
     prefill_target: int = 0             # tokens the prefill must cover
@@ -170,6 +192,10 @@ class Request:
         readmission wire, and the content key of its cache blocks)."""
         return list(map(int, self.prompt)) + self.output_tokens
 
+    def group_blocks(self) -> List[List[int]]:
+        """The block list of every cache group, group 0 first."""
+        return [self.blocks] + self.more_blocks
+
 
 class Scheduler:
     """Slot + block bookkeeping for the continuous batch."""
@@ -183,10 +209,22 @@ class Scheduler:
                 f"admission must be 'occupancy' or 'reserve', got "
                 f"{admission!r}")
         self.cache = cache
+        self.groups = cache.cache_groups
+        if len(self.groups) > 1 or self.groups[0].window is not None:
+            if prefix_caching:
+                raise ValueError(
+                    "prefix caching is not available with cache groups: a "
+                    "window group hands back the blocks a shared prefix "
+                    "would need; pass prefix_caching=False")
+            if admission != "occupancy":
+                raise ValueError(
+                    "cache groups are admitted by occupancy only")
         self.max_batch = max_batch
         self.admission = admission
         self.chunk_tokens = chunk_tokens or cache.max_seq
-        self.allocator = BlockAllocator(cache.n_blocks)
+        self.allocators = [BlockAllocator(g.n_blocks) for g in self.groups]
+        self.allocator = self.allocators[0]
+        self.window_blocks_freed = 0    # lifetime count (engine flushes)
         # reserve mode cannot share (a reservation is exclusive by
         # definition), so the cache only exists under occupancy admission
         self.prefix_cache: Optional[PrefixCache] = (
@@ -218,7 +256,7 @@ class Scheduler:
                       sampling=sampling or SamplingParams(),
                       t_submit=time.monotonic())
         need = self._worst_case_blocks(req)
-        if need > self.allocator.n_blocks:
+        if need > self.allocator.n_blocks or not self._windows_fit():
             # the one reservation rule occupancy admission keeps: a
             # request the WHOLE pool cannot cover would either starve
             # the FIFO head forever (reserve mode) or preempt every
@@ -248,17 +286,28 @@ class Scheduler:
                       self.cache.max_seq)
         return self.cache.blocks_for(horizon)
 
+    def _windows_fit(self) -> bool:
+        """Whether each further group's pool covers one request's worst
+        case: its whole horizon, or for a window group the window and one
+        chunk wherever they lie against the block edges."""
+        return all(
+            g.blocks_spanned(self.chunk_tokens, self.cache.block_size,
+                             self.cache.max_blocks_per_request)
+            <= alloc.n_blocks
+            for g, alloc in zip(self.groups[1:], self.allocators[1:]))
+
     def free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
 
     def _ensure_free(self, n: int) -> bool:
         """Raise ``n_free`` to ``n`` by evicting prefix-cache LRU blocks
         (capacity held only as an optimization — the whole deficit is
-        swept in one pass); False when the cache runs out first."""
+        swept in one pass); False when the cache runs out first.  With
+        cache groups every group's pool must have ``n`` free."""
         deficit = n - self.allocator.n_free
         if deficit > 0 and self.prefix_cache is not None:
             self.prefix_cache.evict_many(deficit)
-        return self.allocator.n_free >= n
+        return all(a.n_free >= n for a in self.allocators)
 
     def admit(self) -> List[Request]:
         """Move WAITING requests into free slots while capacity lasts
@@ -309,6 +358,9 @@ class Scheduler:
                     break
             self.waiting.popleft()
             req.blocks = shared + self.allocator.alloc(need, owner=req.rid)
+            req.more_blocks = [a.alloc(need, owner=req.rid)
+                               for a in self.allocators[1:]]
+            req.freed_prefix = [0] * len(self.groups)
             req.hit_blocks = len(shared)
             req.pc_blocks = 0
             req.pc_hash = 0
@@ -347,6 +399,9 @@ class Scheduler:
         like :meth:`submit`."""
         from apex_tpu.serving.kv_cache import OutOfBlocksError
 
+        if len(self.groups) > 1:
+            raise NotImplementedError(
+                "KV migration moves one cache group's blocks only")
         prompt = np.asarray(prompt, np.int32)
         if prompt.ndim != 1 or prompt.size == 0:
             raise ValueError("prompt must be a non-empty 1-D token list")
@@ -431,9 +486,13 @@ class Scheduler:
         while len(req.blocks) < target:
             want = target - len(req.blocks)
             if self._ensure_free(1):
-                got = self.allocator.alloc(
-                    min(want, self.allocator.n_free), owner=req.rid)
-                req.blocks.extend(got)
+                # every group's list grows in step: as many blocks as each
+                # pool has free
+                take = min([want] + [a.n_free for a in self.allocators])
+                req.blocks.extend(self.allocator.alloc(take, owner=req.rid))
+                for held, alloc in zip(req.more_blocks,
+                                       self.allocators[1:]):
+                    held.extend(alloc.alloc(take, owner=req.rid))
                 continue
             if not preempt:
                 break
@@ -442,6 +501,36 @@ class Scheduler:
                 break
             self.preempt(victim)
         return len(req.blocks) * self.cache.block_size
+
+    def free_behind_window(self, req: Request, query_pos: int) -> int:
+        """Hand back the blocks of ``req``'s window groups that lie wholly
+        behind what a query at ``query_pos`` can read (and so behind what
+        any later query can).  Returns how many went back to their pools."""
+        freed = 0
+        for gi, (g, alloc, held) in enumerate(zip(
+                self.groups, self.allocators, req.group_blocks())):
+            if g.window is None:
+                continue
+            done = req.freed_prefix[gi]
+            upto = min(g.first_needed_block(query_pos,
+                                            self.cache.block_size),
+                       len(held))
+            if upto > done:
+                alloc.free(held[done:upto], owner=req.rid)
+                held[done:upto] = [FREED] * (upto - done)
+                req.freed_prefix[gi] = upto
+                freed += upto - done
+        self.window_blocks_freed += freed
+        return freed
+
+    def _release_all(self, req: Request) -> None:
+        """Give back every block ``req`` holds, in every group."""
+        for alloc, held, done in zip(self.allocators, req.group_blocks(),
+                                     req.freed_prefix or [0]):
+            alloc.free(held[done:], owner=req.rid)
+        req.blocks = []
+        req.more_blocks = []
+        req.freed_prefix = []
 
     def _pick_victim(self, exclude: Request) -> Optional[Request]:
         """Newest-admitted running request other than ``exclude`` —
@@ -467,8 +556,7 @@ class Scheduler:
         from apex_tpu.observability import timeline
 
         self._index_into_cache(req)
-        self.allocator.free(req.blocks, owner=req.rid)
-        req.blocks = []
+        self._release_all(req)
         self.slots[req.slot] = None
         req.slot = None
         req.cache_len = 0
@@ -516,8 +604,7 @@ class Scheduler:
         if req.state is not RequestState.RUNNING:
             raise ValueError(f"finish() on {req.state} request {req.rid}")
         self._index_into_cache(req)
-        self.allocator.free(req.blocks, owner=req.rid)
-        req.blocks = []
+        self._release_all(req)
         self.slots[req.slot] = None
         req.slot = None
         req.state = RequestState.FINISHED
@@ -544,5 +631,57 @@ class Scheduler:
 
     def kv_occupancy(self) -> float:
         """Fraction of the pool holding live or cached KV (the number
-        worst-case reservation kept artificially low)."""
-        return 1.0 - self.allocator.n_free / self.allocator.n_blocks
+        worst-case reservation kept artificially low); with cache groups,
+        of the fullest group's pool."""
+        return max(1.0 - a.n_free / a.n_blocks for a in self.allocators)
+
+    def window_blocks_held(self) -> int:
+        """Blocks the running requests hold in window groups."""
+        return sum(len(held) - done
+                   for req in self.running()
+                   for g, held, done in zip(self.groups, req.group_blocks(),
+                                            req.freed_prefix)
+                   if g.window is not None)
+
+    def check(self) -> None:
+        """The invariants of every cache group: each pool's free and held
+        blocks partition it, every block a running request lists is held
+        by it and by it alone among the requests' lists, the lists of a
+        request are equally long, and a window group still holds every
+        block the request's next query can read."""
+        for alloc in self.allocators:
+            alloc.check()
+        bs = self.cache.block_size
+        for gi, (g, alloc) in enumerate(zip(self.groups, self.allocators)):
+            seen = {}
+            for req in self.running():
+                held = req.group_blocks()[gi]
+                if len(held) != len(req.blocks):
+                    raise AssertionError(
+                        f"request {req.rid}: group {gi} lists {len(held)} "
+                        f"blocks, group 0 {len(req.blocks)}")
+                done = req.freed_prefix[gi] if req.freed_prefix else 0
+                if any((b == FREED) != (i < done)
+                       for i, b in enumerate(held)):
+                    raise AssertionError(
+                        f"request {req.rid}: group {gi}'s freed entries are "
+                        f"not its first {done}")
+                first = g.first_needed_block(req.cache_len, bs)
+                need = range(first, self.cache.blocks_for(req.cache_len))
+                lost = [i for i in need if held[i] == FREED]
+                if lost:
+                    raise AssertionError(
+                        f"request {req.rid}: group {gi} gave back blocks "
+                        f"{lost} that position {req.cache_len} still reads")
+                for b in held:
+                    if b == FREED:
+                        continue
+                    if req.rid not in alloc._holders.get(b, ()):
+                        raise AssertionError(
+                            f"request {req.rid} lists block {b} of group "
+                            f"{gi} and does not hold it")
+                    if self.prefix_cache is None and seen.setdefault(
+                            b, req.rid) != req.rid:
+                        raise AssertionError(
+                            f"block {b} of group {gi} is listed by "
+                            f"requests {seen[b]} and {req.rid}")

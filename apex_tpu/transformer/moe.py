@@ -25,6 +25,20 @@ dispatch builds ``[E, C, h]``, one ``all_to_all`` regroups to
 tokens, and the reverse ``all_to_all`` brings outputs home — numerically
 identical to the dense path (tested).
 
+**Top-k dropless routing over a share of the experts**
+(:func:`route_topk`, :func:`held_experts_ffn`; ISSUE 27): sigmoid scores,
+a selection bias that chooses and does not weigh, the ``top_k`` chosen
+scores normalised, no capacity and no token dropped.  The layer is *told
+which experts it holds* (``held = (first, count)`` of ``n_experts``, the
+chip's share under wide expert parallelism): it routes over all of them,
+sorts the tick's ``(token, expert)`` pairs that fall on held experts by
+expert, runs one grouped matmul per projection over the held experts'
+stacked weights, and scatters the weighted rows back.  What the absent
+experts would add is left out; nothing stands in for the other chips or
+their exchange.  Shapes are fixed by the token count, so churn in what is
+routed where never recompiles.  :class:`SwitchMLP` (top-1, capacity drop,
+training dry run) stays as it is beside it.
+
 Memory honesty: under EP the expert stacks are declared at their **local**
 shape ``[E/ep, ...]`` (the same rank-folded-init convention as the
 tensor-parallel linears), with init rng folded by ``axis_index`` so expert
@@ -36,7 +50,7 @@ owns its experts).  The router stays replicated.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -44,7 +58,8 @@ import jax.numpy as jnp
 
 from apex_tpu.parallel import collectives as cc
 
-__all__ = ["SwitchMLP", "collect_moe_aux", "switch_route"]
+__all__ = ["SwitchMLP", "collect_moe_aux", "switch_route", "route_topk",
+           "held_experts_ffn", "grouped_matmul"]
 
 
 def collect_moe_aux(mutated_collections) -> jnp.ndarray:
@@ -180,3 +195,137 @@ class SwitchMLP(nn.Module):
         y = y * gate.astype(self.dtype)[:, None]
         self.sow("losses", "moe_aux", aux)
         return y.reshape(s, b, h), aux
+
+
+# ------------------------------------------- top-k routing, held experts
+
+
+def route_topk(logits32, bias, top_k: int):
+    """Sigmoid-scored top-k routing from fp32 router logits ``[T, E]``.
+
+    The ``top_k`` experts with the largest ``sigmoid(logit) + bias`` are
+    chosen (``bias [E]`` selects and does not weigh; ties go to the lower
+    expert id, ``lax.top_k``'s order); their weights are their scores
+    normalised to sum to one.  Returns ``(experts [T, k] int32, weights
+    [T, k] f32)``."""
+    scores = jax.nn.sigmoid(logits32)
+    _, experts = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, experts, axis=1)
+    return (experts.astype(jnp.int32),
+            picked / jnp.sum(picked, axis=1, keepdims=True))
+
+
+# row tile of the Pallas grouped matmul; its k and n tiles are the weight
+# block a grid step reads (2 MB of bf16: 2.5 us of HBM time beside 0.35 us
+# of step overhead)
+_GMM_TILES = (128, 1024, 1024)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs [m, k]`` rows sorted by group, ``rhs [groups, k, n]``,
+    ``group_sizes [groups]`` int32 summing to at most ``m`` ->
+    ``[m, n]`` in ``lhs``'s dtype (fp32 accumulation); rows past the sum
+    hold nothing meaningful.  The Pallas grouped matmul of
+    ``jax.experimental.pallas.ops.tpu.megablox``: a tile of rows meets only
+    its own group's weights, and tiles past the last group are not visited.
+    (``lax.ragged_dot`` computes the same; on a v5e at the decode shape it
+    took 1.15 ms where this takes 0.60, PERF.md §6, and is not kept.)"""
+    import importlib
+
+    from apex_tpu.observability.spans import named_span
+    from apex_tpu.utils import platform
+
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tiles = tuple(min(t, d) for t, d in zip(_GMM_TILES, (m, k, n)))
+    # the kernel traced in place (not through the library's own jit, whose
+    # name would be the instruction's), under the scope that names it in a
+    # device trace: ``%moe_experts.<n>``
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    with named_span("moe_experts"):
+        return megablox.gmm.__wrapped__(
+            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+            tiling=tiles, interpret=platform.pallas_interpret())
+
+
+def _chunk_rows(pairs: int, share: float) -> int:
+    """Rows of one pass of the grouped matmuls: one and a half times the
+    pairs expected on the held experts, in steps of 512, at most all
+    (rounded up to the row tile, or to 8 where one tile takes them)."""
+    want = -(-int(1.5 * pairs * share) // 512) * 512
+    rows = min(pairs, max(512, want))
+    step = _GMM_TILES[0] if rows > _GMM_TILES[0] else 8
+    return -(-rows // step) * step
+
+
+def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
+                     top_k: int, held: Tuple[int, int],
+                     live=None):
+    """The held experts' part of a top-k expert feed-forward.
+
+    ``x [T, h]``; ``router [h, E]`` and ``router_bias [E]`` over all ``E``
+    experts; ``w_gate_up [count, h, 2 f]`` (gate columns, then up) and
+    ``w_down [count, f, h]`` of the ``count`` experts from ``held[0]`` on.
+    Returns ``(y [T, h] float32, pairs [count] int32, experts [T, top_k]
+    int32)``: ``y`` sums, over each token's chosen experts that are held
+    here, weight times ``w_down(silu(gate) * up)``; ``pairs`` counts the
+    ``(token, expert)`` pairs routed to each held expert; ``experts`` are
+    the ids each token's router chose among all ``E`` (a comparison with
+    another precision needs them: scores near the cut lie closer together
+    than bfloat16 rounds).  ``live [T]`` bool marks the rows
+    that are tokens (a fixed-shape batch carries padding): the others are
+    routed nowhere, cost nothing and add nothing.
+
+    The pairs on held experts are sorted by expert and handled
+    ``_chunk_rows`` at a time, as many passes as they need (one, unless the
+    routing is far more skewed towards this share than its size
+    suggests): no pair is dropped, and the work follows the pairs that are
+    here, not the ``T * top_k`` there could be."""
+    T, h = x.shape
+    n_experts = router.shape[1]
+    first, count = held
+    f = w_down.shape[1]
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        experts, weights = route_topk(
+            logits, router_bias.astype(jnp.float32), top_k)
+        local = experts - first
+        here = (local >= 0) & (local < count)
+        if live is not None:
+            here = here & live[:, None]
+        key = jnp.where(here, local, count).reshape(-1)       # [T * k]
+        order = jnp.argsort(key, stable=True)
+        pairs = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                          # [count]
+        ends = jnp.cumsum(pairs)
+        starts = ends - pairs
+        n_here = ends[-1]
+        token = (order // top_k).astype(jnp.int32)
+        weight = weights.reshape(-1)[order]
+    P = T * top_k
+    rows = _chunk_rows(P, count / n_experts)
+
+    def one_pass(i, y):
+        lo = i * rows
+        at = jnp.minimum(lo + jnp.arange(rows, dtype=jnp.int32), P - 1)
+        live = (lo + jnp.arange(rows, dtype=jnp.int32)) < n_here
+        sizes = (jnp.clip(ends, lo, lo + rows)
+                 - jnp.clip(starts, lo, lo + rows)).astype(jnp.int32)
+        tok = token[at]
+        xs = x[tok]
+        gate_up = grouped_matmul(xs, w_gate_up, sizes)
+        mid = (jax.nn.silu(gate_up[:, :f].astype(jnp.float32))
+               * gate_up[:, f:].astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(mid, w_down, sizes)
+        out = jnp.where(live[:, None],
+                        out.astype(jnp.float32) * weight[at][:, None], 0.0)
+        # rows past the pairs that are here add nothing, wherever they land
+        return y.at[tok].add(out)
+
+    with jax.named_scope("moe_experts"):
+        y = jax.lax.fori_loop(0, -(-n_here // rows), one_pass,
+                              jnp.zeros((T, h), jnp.float32))
+    return y, pairs, experts

@@ -1,8 +1,11 @@
 """Test/bench harness models — reference ``apex/transformer/testing``."""
 
 from apex_tpu.transformer.testing.standalone_transformer_lm import (
+    AttentionKind,
     CoreAttention,
     Embedding,
+    ExpertSpec,
+    HybridSpec,
     ParallelAttention,
     ParallelMLP,
     ParallelTransformer,
@@ -25,6 +28,9 @@ from apex_tpu.transformer.testing.standalone_bert import (
 
 __all__ = [
     "TransformerConfig",
+    "AttentionKind",
+    "ExpertSpec",
+    "HybridSpec",
     "ParallelMLP",
     "CoreAttention",
     "ParallelAttention",

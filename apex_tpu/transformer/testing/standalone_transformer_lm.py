@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -63,6 +63,9 @@ from apex_tpu.transformer.tensor_parallel.utils import divide
 
 __all__ = [
     "TransformerConfig",
+    "AttentionKind",
+    "ExpertSpec",
+    "HybridSpec",
     "ParallelMLP",
     "CoreAttention",
     "ParallelAttention",
@@ -73,6 +76,96 @@ __all__ = [
     "parallel_lm_logits",
     "Pooler",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer of a :class:`HybridSpec`: its head
+    counts, the q/k width beside the v width, the rotated leading channels
+    and their base, an optional sliding window (position ``i`` sees keys
+    ``i - window < j <= i``: the token itself counts) and whether each head
+    has a learned sink logit (one more term of the softmax denominator that
+    takes no value)."""
+
+    name: str
+    num_heads: int
+    kv_heads: int
+    k_dim: int
+    v_dim: int
+    rotary_dim: int
+    rotary_base: float = 10000.0
+    window: Optional[int] = None
+    sink: bool = False
+
+    def __post_init__(self):
+        if self.num_heads % self.kv_heads:
+            raise ValueError(
+                f"{self.name}: kv_heads ({self.kv_heads}) must divide "
+                f"num_heads ({self.num_heads})")
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.k_dim:
+            raise ValueError(
+                f"{self.name}: rotary_dim ({self.rotary_dim}) must be even "
+                f"and at most k_dim ({self.k_dim})")
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"{self.name}: window must be positive")
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """The expert feed-forward of a :class:`HybridSpec`: sigmoid scores
+    over ``n_experts``, the ``top_k`` largest ``score + bias`` chosen (the
+    bias selects and does not weigh), their scores normalised to sum to
+    one, each expert a SwiGLU of width ``ffn_size``.  ``held = (first,
+    count)`` are the experts whose weights this process holds: the layer
+    routes over all ``n_experts`` and computes the held experts' part of
+    the result (:func:`apex_tpu.transformer.moe.held_experts_ffn`)."""
+
+    n_experts: int
+    top_k: int
+    ffn_size: int
+    held: Tuple[int, int]
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(
+                f"held experts {self.held} lie outside 0..{self.n_experts}")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(
+                f"top_k ({self.top_k}) must lie in 1..{self.n_experts}")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSpec:
+    """A per-layer description: which :class:`AttentionKind` each layer
+    runs and whether its feed-forward is the dense SwiGLU
+    (``ffn_hidden_size`` wide) or the expert layer.  Layers of this family
+    are pre-norm RMSNorm blocks with no biases, rotary positions on the
+    leading ``rotary_dim`` channels of q and k (half rotation), values
+    scaled by ``value_scale``, a final RMSNorm and an untied output head.
+
+    A model whose layers are all alike needs none of this: it leaves
+    ``TransformerConfig.hybrid`` at ``None`` and is the program it always
+    was."""
+
+    kinds: Tuple[AttentionKind, ...]
+    layer_kinds: Tuple[int, ...]          # per layer: index into ``kinds``
+    layer_experts: Tuple[bool, ...]       # per layer: expert feed-forward
+    experts: Optional[ExpertSpec] = None
+    value_scale: float = 1.0
+
+    def __post_init__(self):
+        if len(self.layer_kinds) != len(self.layer_experts):
+            raise ValueError("layer_kinds and layer_experts differ in length")
+        if any(not 0 <= k < len(self.kinds) for k in self.layer_kinds):
+            raise ValueError("layer_kinds names a kind that is not there")
+        if any(self.layer_experts) and self.experts is None:
+            raise ValueError("expert layers need an ExpertSpec")
+
+    def layers_of(self, kind: int) -> Tuple[int, ...]:
+        """The layers that run attention kind ``kind``, in order."""
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +237,11 @@ class TransformerConfig:
                 f"rotary_percent must be in (0, 1], got "
                 f"{self.rotary_percent} (use position_embedding_type="
                 f"'none' for no position signal)")
+        if (self.hybrid is not None
+                and len(self.hybrid.layer_kinds) != self.num_layers):
+            raise ValueError(
+                f"hybrid describes {len(self.hybrid.layer_kinds)} layers, "
+                f"num_layers is {self.num_layers}")
         if (self.num_query_groups is not None
                 and (self.num_query_groups <= 0
                      or self.num_attention_heads % self.num_query_groups)):
@@ -176,6 +274,13 @@ class TransformerConfig:
     # column linears (TP-exact under any tp size; ffn_hidden_size is NOT
     # auto-scaled by 2/3 — set it explicitly for iso-params).
     swiglu: bool = False
+
+    # Layers of more than one kind (window beside full attention, an
+    # expert feed-forward beside a dense one): a :class:`HybridSpec`, whose
+    # layers are RMSNorm blocks (the uniform blocks are LayerNorm only).
+    # Served by ``serving.model.HybridDecodeModel``; ``None`` = every layer
+    # alike, as the fields above describe it.
+    hybrid: Optional[HybridSpec] = None
 
     dtype: Any = jnp.float32        # compute dtype (bf16 under the O2 policy)
     param_dtype: Any = jnp.float32

@@ -1,0 +1,8 @@
+"""The expert layers' grouped matmuls' share of the device's busy time: the
+own time of the kernels under the program's ``moe_experts`` scope."""
+
+from metrics import _common, _hybrid
+
+
+def read(view):
+    return _common.share_of_busy(view, _hybrid.named("moe_experts"))

@@ -61,6 +61,75 @@ def test_kernel_compiles_for_v5e(name, heads, v5e_device, monkeypatch):
     lowered.compile()
 
 
+# the cache-group kernels and the expert layer at the widths that brought
+# them (ISSUE 27): 64 query heads, q/k 192 beside v 128, blocks of 16,
+# a table of 384 blocks; full: 4 KV heads; window: 8, 128 tokens, sinks
+GROUP_KERNELS = [
+    pytest.param(kind, name, g, blocks, window, id=f"{kind}_{name}")
+    for kind in ("decode", "prefill")
+    for name, g, blocks, window in (("full", 4, 16384, None),
+                                    ("window", 8, 1152, 128))]
+
+
+@pytest.mark.parametrize("kind,name,g,blocks,window", GROUP_KERNELS)
+def test_group_kernel_compiles_for_v5e_at_published_widths(
+        kind, name, g, blocks, window, v5e_device, monkeypatch):
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import paged_attention as pa
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    b, n, dk, dv, bs, chunk = 64, 64, 192, 128, 16, 128
+    cache = (shape((blocks, bs, g * dk)), shape((blocks, bs, g * dv)),
+             shape((b, 384), jnp.int32), shape((b,), jnp.int32))
+    kw = dict(kv_heads=g, window=window)
+    sinks = shape((n,), jnp.float32)
+    if kind == "decode":
+        def call(q, k, v, tables, lengths, sinks):
+            return pa.paged_attention_decode(
+                q, k, v, tables, lengths,
+                sinks=sinks if window else None, **kw)
+        args = (shape((b, n, dk)),) + cache + (sinks,)
+    else:
+        def call(q, k, v, tables, lengths, limits, sinks):
+            return pa.paged_prefill_attention(
+                q, k, v, tables, lengths, limits,
+                sinks=sinks if window else None, **kw)
+        args = (shape((b, chunk, n, dk)),) + cache + (
+            shape((b, chunk), jnp.int32), sinks)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert f"%paged_{kind}_{name}" in compiled.as_text()
+
+
+def test_expert_layer_compiles_for_v5e_at_published_widths(
+        v5e_device, monkeypatch):
+    import jax.numpy as jnp
+
+    from apex_tpu.transformer.moe import held_experts_ffn
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    def layer(x, router, bias, gate_up, down):
+        return held_experts_ffn(x, router, bias, gate_up, down, top_k=8,
+                                held=(0, 16))
+
+    compiled = jax.jit(layer).lower(
+        shape((64, 4096)), shape((4096, 256)), shape((256,)),
+        shape((16, 4096, 4096)), shape((16, 2048, 4096))).compile()
+    # the two grouped matmuls, named by the scope the trace reads
+    assert compiled.as_text().count(" custom-call(") >= 2
+    assert "%moe_experts" in compiled.as_text()
+
+
 TOY = chip_smoke.Sizes(
     hidden=64, layers=4, heads=4, vocab=256, positions=64, batch=4,
     microbatches=2, train_steps=3, lr=1e-3, max_batch=4, max_seq=64,

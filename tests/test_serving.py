@@ -250,9 +250,16 @@ class TestPagedAttentionKernel:
             page_bytes, max_blocks)
 
     def test_decode_takes_no_new_argument(self):
-        assert list(inspect.signature(paged_attention_decode).parameters) \
+        # PR 26's ten, then what a cache group's flat arena brought (ISSUE
+        # 27), all keyword-only with defaults that leave the pooled arena's
+        # call as it was
+        params = inspect.signature(paged_attention_decode).parameters
+        assert list(params) \
             == ["q", "k_arena", "v_arena", "block_tables", "lengths",
-                "limits", "k_scales", "v_scales", "block_size", "scale"]
+                "limits", "k_scales", "v_scales", "block_size", "scale",
+                "kv_heads", "window", "sinks"]
+        assert [params[k].default for k in
+                ("kv_heads", "window", "sinks")] == [None, None, None]
 
     def test_vmem_page_bytes_count_the_tile_padding(self):
         vmem = paged_attention_module._vmem_bytes
