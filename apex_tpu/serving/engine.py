@@ -69,6 +69,10 @@ docs/serving.md):
   ``max_batch x prefill_len`` their fixed shape has room for
 - ``serving/decode_slot_steps`` counter — slots that took part in a
   decode call, summed over calls
+- ``serving/drawn_calls`` / ``serving/drawn_rows`` counters — decode and
+  prefill calls that held at least one slot of temperature > 0 (the
+  in-graph draw ran: ``sampling.sample_tokens``' ``cond`` was true), and
+  such slots summed over those calls (ISSUE 28)
 - ``serving/decode_kv_tokens`` / ``serving/decode_kv_pages`` counters —
   cache rows the decode calls' slots attended, and the KV blocks that
   hold them (``ceil(rows / block_size)`` per slot): what the paged
@@ -496,6 +500,8 @@ class ServingEngine:
             prefill_capacity=counter("serving/prefill_capacity_tokens"),
             decode_calls=counter("serving/decode_calls"),
             decode_slot_steps=counter("serving/decode_slot_steps"),
+            drawn_calls=counter("serving/drawn_calls"),
+            drawn_rows=counter("serving/drawn_rows"),
             decode_kv_tokens=counter("serving/decode_kv_tokens"),
             decode_kv_pages=counter("serving/decode_kv_pages"))
         if self.hybrid:
@@ -1013,9 +1019,12 @@ class ServingEngine:
         self._counters.moe_pairs.inc(total)
         self._counters.moe_experts_hit.inc(hit)
 
-    def _sampling_arrays(self):
+    def _sampling_arrays(self, phase: spans.span):
         """Per-slot sampling-policy data ([max_batch] each, rebuilt per
-        call — policies are data, never shape)."""
+        call — policies are data, never shape).  The slots of temperature
+        > 0 are what the call's in-graph draw is decided by: their count
+        goes on ``phase`` (the call's plan span) as ``drawn`` and into
+        the ``serving/drawn_*`` counters."""
         B = self.serving.max_batch
         temp = np.zeros((B,), np.float32)
         top_k = np.zeros((B,), np.int32)
@@ -1031,6 +1040,11 @@ class ServingEngine:
             # step_offset rebases the draw counter for fleet failover
             # replays (prompt already carries the emitted prefix)
             steps[req.slot] = s.step_offset + len(req.output_tokens)
+        drawn = int(np.count_nonzero(temp > 0.0))
+        phase.note(drawn=drawn)
+        if drawn:
+            self._counters.drawn_calls.inc()
+            self._counters.drawn_rows.inc(drawn)
         return temp, top_k, top_p, seeds, steps
 
     def _prefill_tick(self, tick: spans.span) -> None:
@@ -1043,7 +1057,7 @@ class ServingEngine:
             return      # no chunk to plan: the scan is the tick's own time
         B, T = self.serving.max_batch, self.prefill_len
         bs = self.cache.block_size
-        with self._span("serving/tick/prefill_plan"):
+        with self._span("serving/tick/prefill_plan") as phase:
             cands.sort(key=lambda r: r.admit_seq)
             plan: List[Tuple[Request, int]] = []
             for req in cands:
@@ -1091,7 +1105,7 @@ class ServingEngine:
                 if lo + chunk == req.prefill_target:
                     sample_index[s] = chunk - 1
             self._refresh_tables()
-            samp = self._sampling_arrays()
+            samp = self._sampling_arrays(phase)
             args = (tokens, pos_ids, self._device_tables(), lengths, limits)
             if self.hybrid:
                 args = (self.arenas, self.params) + args \
@@ -1242,7 +1256,7 @@ class ServingEngine:
                 active[req.slot] = True
                 n_draft[req.slot] = len(d)
             self._refresh_tables()
-            samp = self._sampling_arrays()
+            samp = self._sampling_arrays(phase)
 
             tables = self._device_tables()
             if self.adapter_arena is None:

@@ -10,15 +10,22 @@ changing between ticks (request churn) re-runs the same executable.
 Determinism is load-bearing twice over:
 
 - **greedy** (``temperature == 0``, the default) must be the exact
-  ``argmax`` the fleet's failover replay and the smoke's token-identity
-  checks rest on — the sampled branch is computed and discarded, the
-  ``where`` keeps greedy bit-for-bit;
+  fp32 ``argmax`` the fleet's failover replay and the smoke's
+  token-identity checks rest on;
 - **seeded sampling** keys each draw with
   ``fold_in(PRNGKey(seed), step)`` where ``step`` is the request's
   output-token index.  A preempted request replayed through prefill
   resumes at the same counter, so recompute-on-readmit (and the fleet's
   failover replay) reproduces the *same stochastic stream* — sampling
   does not break the bitwise-stitched-stream story, it joins it.
+
+What a call pays (:func:`sample_tokens`): one ``argmax`` over the
+batch, always.  The draw sits under one ``lax.cond`` on
+``any(temperature > 0)``, so an all-greedy batch runs nothing else.
+Where any row is sampled, every row goes through :func:`_sample_one`
+— one sort of the row and elementwise passes, nothing gathered from or
+scattered into a ``[vocab]`` row — and a ``where`` hands the greedy
+rows their ``argmax`` back.
 
 Filter order is the conventional temperature -> top-k -> top-p (p
 renormalizes over the k survivors).  ``top_k <= 0`` and
@@ -89,21 +96,37 @@ class SamplingParams:
 
 
 def _sample_one(logits, temperature, top_k, top_p, seed, step):
-    """One slot's draw; vmapped over the batch."""
+    """One slot's draw; vmapped over the batch.
+
+    The row is ordered once (values descending, equal values by token
+    id) and both filters are read off that order.  Top-k cuts at the
+    order's entry ``k - 1``.  Top-p keeps the entries whose exclusive
+    running probability is below ``p``: a prefix of the same order (what
+    top-k cut is its tail), so the kept set is the entries at or before
+    the prefix's last one, which every token id can tell by comparing
+    its own (value, id) with that entry's.  The draw itself runs over
+    the row in vocabulary order, where the noise is indexed by token id.
+    """
     vocab = logits.shape[0]
+    ids = jax.lax.iota(jnp.int32, vocab)
     x = logits / jnp.maximum(temperature, 1e-6)
+    neg, order = jax.lax.sort((-x, ids), num_keys=1, is_stable=True)
+    desc = -neg
     # top-k: threshold at the kth-largest logit (k <= 0 disables)
-    sorted_desc = jnp.sort(x)[::-1]
-    kth = sorted_desc[jnp.clip(top_k - 1, 0, vocab - 1)]
-    x = jnp.where((top_k > 0) & (x < kth), _NEG, x)
+    kth = desc[jnp.clip(top_k - 1, 0, vocab - 1)]
+    x, desc = (jnp.where((top_k > 0) & (v < kth), _NEG, v)
+               for v in (x, desc))
     # top-p (nucleus): keep the smallest prefix of the sorted
     # distribution whose mass reaches p; the argmax always survives
-    # (cumsum - own prob < p holds for the head token whenever p > 0)
-    probs = jax.nn.softmax(x)
-    order = jnp.argsort(-x)
-    csum = jnp.cumsum(probs[order])
-    keep_sorted = (csum - probs[order]) < top_p
-    keep = jnp.zeros_like(keep_sorted).at[order].set(keep_sorted)
+    # (cumsum - own prob < p holds for the head token whenever p > 0).
+    # The sorted probabilities are the row's softmax, entry for entry:
+    # same maximum, same sum, taken over the row as it lies
+    top = jnp.max(x)
+    probs = jnp.exp(desc - top) / jnp.sum(jnp.exp(x - top))
+    kept = jnp.sum((jnp.cumsum(probs) - probs) < top_p)
+    last = jnp.maximum(kept - 1, 0)
+    edge, edge_id = desc[last], order[last]
+    keep = (kept > 0) & ((x > edge) | ((x == edge) & (ids <= edge_id)))
     x = jnp.where(keep, x, _NEG)
     key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
     return jax.random.categorical(key, x).astype(jnp.int32)
@@ -113,15 +136,11 @@ def sample_tokens(logits, temperature, top_k, top_p, seeds, steps):
     """Sample one token per slot from ``logits [max_batch, vocab]``.
 
     All policy arguments are ``[max_batch]`` arrays (data, never
-    shape).  Slots with ``temperature == 0`` return the exact fp32
-    argmax — the sampled branch is fully masked out by the ``where``,
-    so greedy serving stays bitwise deterministic.  The whole drawn
-    branch sits under one ``lax.cond`` on ``any(temperature > 0)``
-    (a data predicate — still one compile): an all-greedy batch, the
-    common production shape and every token-identity contract, pays
-    one argmax and zero sort/scatter work per step.  The branch holds
-    no collectives (the logits arrive tp-gathered), so the cond is
-    APX102-clean by construction.
+    shape); fp32 throughout.  Slots with ``temperature == 0`` return
+    the exact fp32 argmax.  What a call pays is in the module
+    docstring; the predicate of the one ``lax.cond`` is data, so there
+    is still one compile.  The branch holds no collectives (the logits
+    arrive tp-gathered), so the cond is APX102-clean by construction.
     """
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -18,7 +18,12 @@ import pytest
 from apex_tpu import parallel
 from apex_tpu.observability import spans
 from apex_tpu.observability.metrics import MetricRegistry
-from apex_tpu.serving import ServingConfig, ServingEngine, paged_attention
+from apex_tpu.serving import (
+    SamplingParams,
+    ServingConfig,
+    ServingEngine,
+    paged_attention,
+)
 from apex_tpu.transformer.testing import TransformerConfig
 from apex_tpu.transformer.testing.gpt_parallel_train import build_gpt_3d
 
@@ -186,15 +191,20 @@ def build_engine(model, **serving):
 
 
 def serve(engine, prompt_lengths=(5, 11, 7, 16, 3, 9, 12, 4, 8, 6),
-          new_tokens=6, seed=0):
+          new_tokens=6, seed=0, sampled=()):
     """Submit, drain, and return the requests with this run's tick spans
-    and their children by phase."""
+    and their children by phase.  The requests whose index is in
+    ``sampled`` draw their tokens (temperature 0.8); the others are
+    greedy."""
     rng = np.random.default_rng(seed)
     t0 = spans.span("t/mark")
     with t0:
         pass
-    reqs = [engine.submit(rng.integers(0, VOCAB, n).tolist(), new_tokens)
-            for n in prompt_lengths]
+    reqs = [engine.submit(
+        rng.integers(0, VOCAB, n).tolist(), new_tokens,
+        sampling=SamplingParams(temperature=0.8, top_k=8, top_p=0.9,
+                                seed=i) if i in sampled else None)
+        for i, n in enumerate(prompt_lengths)]
     engine.run_until_drained()
     records = spans.recorded(since=t0.end)
     ticks = [s for s in records if s.name == "serving/tick"]
@@ -268,6 +278,21 @@ def test_tick_without_a_chunk_has_no_prefill_span(served):
         assert {"decode_plan", "decode_dispatch", "decode_fetch"} <= names
 
 
+class Spy:
+    """One of the engine's compiled programs, with ``note(*args)`` told of
+    each call before it runs."""
+
+    def __init__(self, real, note):
+        self._real, self._note = real, note
+
+    def __call__(self, *args):
+        self._note(*args)
+        return self._real(*args)
+
+    def __getattr__(self, name):              # lower, for the FLOPs probe
+        return getattr(self._real, name)
+
+
 def test_decode_plan_counts_the_kv_the_kernel_meets(model):
     """ISSUE 26: a decode tick's ``decode_plan`` span carries ``kv_tokens``
     (the history each decoding slot attends, its new row included) and
@@ -276,30 +301,26 @@ def test_decode_plan_counts_the_kv_the_kernel_meets(model):
     lengths the device program is given; the two counters grow by them."""
     engine = build_engine(model)
     bs = engine.cache.block_size
-    real, seen = engine._decode, []
+    seen = []
 
-    class Spy:
-        def __call__(self, *args):
-            decoding = [r for r in engine.scheduler.running()
-                        if not r.prefilling]
-            history = [r.cache_len + 1 for r in decoding]
-            positions, active = np.asarray(args[3]), np.asarray(args[5])
-            lengths = np.where(active, positions + 1, 0)
-            assert sorted(lengths[active]) == sorted(history)
-            seen.append((sum(history), sum(-(-h // bs) for h in history)))
-            return real(*args)
+    def note(*args):
+        decoding = [r for r in engine.scheduler.running()
+                    if not r.prefilling]
+        history = [r.cache_len + 1 for r in decoding]
+        positions, active = np.asarray(args[3]), np.asarray(args[5])
+        lengths = np.where(active, positions + 1, 0)
+        assert sorted(lengths[active]) == sorted(history)
+        seen.append((sum(history), sum(-(-h // bs) for h in history)))
 
-        def __getattr__(self, name):          # lower, for the FLOPs probe
-            return getattr(real, name)
-
-    engine._decode = Spy()
+    engine._decode = Spy(engine._decode, note)
     _, ticks, children = serve(engine)
     assert engine.scheduler.preemptions == 0
     plans = [k for t in ticks for k in children[t.id]
              if k.name == "serving/tick/decode_plan"]
     assert len(plans) == len(seen) > 4
     for plan, (tokens, pages) in zip(plans, seen):
-        assert set(plan.fields) == {"preempted", "kv_tokens", "kv_pages"}
+        assert set(plan.fields) == {"preempted", "kv_tokens", "kv_pages",
+                                    "drawn"}
         assert (plan.fields["kv_tokens"], plan.fields["kv_pages"]) == \
             (tokens, pages)
         assert 0 < pages <= tokens <= pages * bs
@@ -310,6 +331,59 @@ def test_decode_plan_counts_the_kv_the_kernel_meets(model):
     grid = snap["serving/decode_slot_steps"] * \
         engine.cache.max_blocks_per_request
     assert 0 < snap["serving/decode_kv_pages"] <= grid
+
+
+def _plans(ticks, children, phase):
+    return [k for t in ticks for k in children[t.id]
+            if k.name == f"serving/tick/{phase}" and "drawn" in k.fields]
+
+
+def test_all_greedy_engine_draws_nothing(served):
+    """ISSUE 28: no slot of temperature > 0, so no call's in-graph draw
+    ran: ``drawn`` is 0 on every plan and the counters stay at 0."""
+    engine, _, ticks, children = served
+    decode, prefill = (_plans(ticks, children, p)
+                       for p in ("decode_plan", "prefill_plan"))
+    snap = engine.registry.snapshot()
+    assert len(decode) == snap["serving/decode_calls"] > 4
+    assert len(prefill) == snap["serving/prefill_calls"] > 0
+    assert {p.fields["drawn"] for p in decode + prefill} == {0}
+    assert snap["serving/drawn_calls"] == snap["serving/drawn_rows"] == 0
+
+
+def test_drawn_counts_the_sampled_slots_of_each_call(model):
+    """Two sampled requests of four, in one wave: ``drawn`` on a decode or
+    prefill plan is the number of running slots of temperature > 0 at the
+    call (what the program's ``cond`` is decided by), a call counts in
+    ``serving/drawn_calls`` where that is not 0, and ``serving/drawn_rows``
+    sums it."""
+    engine = build_engine(model)
+    seen = {"decode": [], "prefill": []}
+
+    def sampled_slots(calls):
+        return lambda *args: calls.append(sum(
+            1 for r in engine.scheduler.running()
+            if r.sampling.temperature > 0))
+
+    engine._decode = Spy(engine._decode, sampled_slots(seen["decode"]))
+    engine._prefill = Spy(engine._prefill, sampled_slots(seen["prefill"]))
+    # the prompt of 20 takes two chunks, so its request is a tick behind:
+    # the calls hold two sampled slots at first and one at the end
+    reqs, ticks, children = serve(engine, prompt_lengths=(5, 20, 7, 3),
+                                  sampled=(1, 3))
+    assert all(len(r.output_tokens) == 6 for r in reqs)
+    decode, prefill = (_plans(ticks, children, p)
+                       for p in ("decode_plan", "prefill_plan"))
+    assert [p.fields["drawn"] for p in decode] == seen["decode"]
+    assert [p.fields["drawn"] for p in prefill] == seen["prefill"]
+    assert max(seen["decode"]) == 2 and max(seen["prefill"]) == 2
+    assert 1 in seen["decode"] + seen["prefill"]
+    snap = engine.registry.snapshot()
+    every = seen["decode"] + seen["prefill"]
+    assert snap["serving/drawn_calls"] == sum(1 for n in every if n)
+    assert snap["serving/drawn_rows"] == sum(every)
+    assert snap["serving/drawn_calls"] <= \
+        snap["serving/decode_calls"] + snap["serving/prefill_calls"]
 
 
 def test_counters_equal_what_was_submitted(served):
