@@ -36,7 +36,7 @@ diagnosis and odd trees:
 - per-leaf chunking costs one collective pair per tensor — hundreds of
   small collectives on a real transformer, which is exactly what the
   reference's buckets exist to avoid and why flat-bucket is the default
-  (bench row ``zero_adam_step``).
+  (not measured on the chip: ROADMAP W5, D5).
 
 In both shapes Adam state (``exp_avg``/``exp_avg_sq``) and the fp32
 master copy exist only for the local shard — the 1/dp state-memory

@@ -11,11 +11,8 @@ host work is a fancy-index gather out of a memory-mapped uint8 array —
 pure memcpy, no codec — and the *augmentation* runs on-device inside the
 jitted train step where it fuses with the input normalize.
 
-Measured context (bench_input_pipeline): one PIL/native-JPEG worker
-decodes ~110 img/s, so a 1-CPU host can never feed the ~8.8k img/s the
-single-chip RN50 step consumes; the packed path's gather costs
-~150 KB/image of memcpy (~1.3 GB/s at chip rate) which the same host
-sustains.
+Neither path's rate beside a chip's step is measured (ROADMAP W9); by
+shapes, the packed path's gather is ~150 KB/image of memcpy.
 
 Format (``<prefix>.data`` + ``<prefix>.labels.npy`` + ``<prefix>.json``):
 

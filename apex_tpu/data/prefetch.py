@@ -350,8 +350,8 @@ def prefetch_to_device(iterator: Iterable, mesh=None, depth: int = 2,
 
     Observability: each ``__next__`` records its blocking wait into the
     ``data/stall_ms`` gauge and the ``span_ms/data/next_wait`` histogram
-    of ``registry`` (default: the process registry) — the in-run stall
-    measurement ``bench.py input_pipeline`` cross-checks.
+    of ``registry`` (default: the process registry); no benchmark cell
+    runs an input pipeline yet (ROADMAP W9).
     """
     return DevicePrefetcher(iterator, place, depth, mesh=mesh,
                             registry=registry)

@@ -193,7 +193,7 @@ def segment_loss_mask(segments):
 def synthetic_token_documents(n_docs: int, vocab: int, *,
                               mean_len: int = 64, seed: int = 0):
     """Deterministic synthetic pre-tokenized corpus (list of int lists) —
-    the CI/bench stand-in for a real tokenized dataset."""
+    the CI stand-in for a real tokenized dataset."""
     rng = np.random.RandomState(seed)
     docs = []
     for _ in range(n_docs):

@@ -1,4 +1,4 @@
-"""apex_tpu.models — reference models for the example/benchmark workloads.
+"""apex_tpu.models — reference models for the example workloads.
 
 Mirrors the reference's app layer (``examples/imagenet``, ``examples/simple``,
 ``apex/transformer/testing/standalone_{gpt,bert}.py``): a ResNet family for

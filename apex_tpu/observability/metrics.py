@@ -295,7 +295,9 @@ def default_registry() -> MetricRegistry:
 # --- MFU -----------------------------------------------------------------
 
 # bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` string JAX
-# reports (the one table; bench.py reads it too).  Source: Google Cloud TPU
+# reports: what the program's own ``serving/mfu`` and :func:`mfu` gauges
+# read (the benchmark keeps its own, ``benchmark/peaks.json``; the program
+# may not import ``benchmark/``).  Source: Google Cloud TPU
 # documentation, the per-chip "peak compute (bf16)" figure of each
 # generation's system-architecture page (v4 275, v5e 197, v5p 459, v6e 918
 # TFLOP/s); the ``device_kind`` spellings are those of
@@ -368,7 +370,7 @@ def mfu_or_reason(flops_per_step: Optional[float], step_time_s: float, *,
     (:func:`compiled_flops` → ``None``) vs an unknown device peak
     (CPU / no device).  Callers that can only show a number keep using
     :func:`mfu`; callers with a text channel (serving ``/statusz``,
-    bench rows, reports) surface the reason."""
+    reports) surface the reason."""
     if step_time_s <= 0:
         return None, f"non-positive step time ({step_time_s})"
     if flops_per_step is None:

@@ -274,8 +274,7 @@ class MetricHistory:
         exceeds ``objective`` (0.0 with no data retained there).  This
         is the SLO evaluator's inner loop — three window scans per
         policy row per cadence tick — so it walks the ring in place
-        instead of materializing :meth:`bucket_points` tuples (~3x off
-        the armed-path cost the ``serving_slo_overhead`` bench gates)."""
+        instead of materializing :meth:`bucket_points` tuples."""
         rings = self._series.get(name)
         if not rings:
             return 0.0

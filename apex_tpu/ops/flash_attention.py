@@ -61,6 +61,8 @@ API boundary.  Segment ids ride as ``[b, s, 1]`` for the same reason.
 from __future__ import annotations
 
 import functools
+import os
+import warnings
 from typing import Optional
 
 import jax
@@ -77,74 +79,33 @@ __all__ = [
     "dkv_chunk",
 ]
 
-# Block-size defaults, overridable per-process for hardware sweeps
-# (examples/tune_flash_blocks.py runs each grid point in a subprocess).
-import os as _os
 
-
-def _env_block(name: str, default: int):
-    """(value, applied): ``applied`` is True only when the env var held a
-    valid positive int — an ignored/invalid value must NOT also suppress
-    the tuned-file lookup downstream."""
-    raw = _os.environ.get(name)
+def _env_block(name: str, default: int) -> int:
+    raw = os.environ.get(name)
     if raw is None:
-        return default, False
+        return default
     try:
         val = int(raw)
         if val <= 0:
             raise ValueError(f"must be positive, got {val}")
-        return val, True
+        return val
     except ValueError as e:
-        import warnings
-
         warnings.warn(f"ignoring {name}={raw!r} ({e}); "
                       f"using default {default}")
-        return default, False
-
-
-DEFAULT_BLOCK_Q, _Q_FROM_ENV = _env_block("APEX_TPU_FLASH_BLOCK_Q", 256)
-DEFAULT_BLOCK_K, _K_FROM_ENV = _env_block("APEX_TPU_FLASH_BLOCK_K", 512)
-_ENV_SET = (_Q_FROM_ENV, _K_FROM_ENV)
-_TUNED_CACHE: "tuple | None" = None
-
-
-def _tuned_blocks():
-    """(block_q, block_k) from ``bench_results/flash_blocks_tuned.json``
-    (written by ``examples/tune_flash_blocks.py`` when a TPU sweep at the
-    flagship seq finds a winner), or ``(None, None)``.
-
-    Read lazily at first kernel call (never at import: the gate needs a
-    live backend) and adopted ONLY when the record's ``device_kind``
-    matches the attached device — a winner swept on one TPU generation
-    must not leak onto another with a different VMEM budget."""
-    global _TUNED_CACHE
-    if _TUNED_CACHE is None:
-        from apex_tpu.utils.tuning import load_tuned_record
-
-        q = k = None
-        rec = load_tuned_record("flash_blocks_tuned.json", jax)
-        if rec is not None:
-            try:
-                q, k = int(rec["block_q"]), int(rec["block_k"])
-                if q <= 0 or k <= 0:
-                    q = k = None
-            except (KeyError, TypeError, ValueError):
-                q = k = None
-        _TUNED_CACHE = (q, k)
-    return _TUNED_CACHE
+        return default
 
 
 def resolve_default_blocks(block_q=None, block_k=None):
-    """Fill unset block sizes.  Precedence per dimension: explicit arg >
-    ``APEX_TPU_FLASH_BLOCK_Q/K`` env > hardware-matched tuned file >
-    built-in 256/512."""
+    """Fill unset block sizes: the explicit argument, else
+    ``APEX_TPU_FLASH_BLOCK_Q/K`` (the handle a block sweep on the chip has
+    on a whole train step, ROADMAP S1), else 256/512."""
     if block_q is None:
-        tuned = None if _ENV_SET[0] else _tuned_blocks()[0]
-        block_q = tuned or DEFAULT_BLOCK_Q
+        block_q = _env_block("APEX_TPU_FLASH_BLOCK_Q", 256)
     if block_k is None:
-        tuned = None if _ENV_SET[1] else _tuned_blocks()[1]
-        block_k = tuned or DEFAULT_BLOCK_K
+        block_k = _env_block("APEX_TPU_FLASH_BLOCK_K", 512)
     return block_q, block_k
+
+
 NEG_INF = -1e30
 _LANES = 128   # TPU lane count: minor-dim tile
 _SUBLANES = 8  # fp32 sublane tile

@@ -78,7 +78,7 @@ class FusedAdam:
         # buffer instead of per-leaf (equal to ~1 ulp of fma contraction) — one wide
         # kernel per op vs one small kernel per tensor, at the cost of a
         # pack/unpack copy.  Which side wins depends on how fragmented
-        # the tree is; bench_fused_adam_step measures both.
+        # the tree is; not measured on the chip (ROADMAP W11, D5).
         self.flat = flat
 
     def init(self, params) -> OptState:

@@ -21,7 +21,7 @@ from typing import Any, Callable, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from apex_tpu.parallel import mesh as mesh_lib
 
@@ -41,7 +41,6 @@ __all__ = [
     "send_recv_next",
     "send_recv_prev",
     "shard_over",
-    "named_sharding",
 ]
 
 AxisName = Union[str, Sequence[str]]
@@ -262,9 +261,3 @@ def shard_over(
         check_vma=check_vma,
     )
 
-
-def named_sharding(*spec, mesh: Optional[Mesh] = None) -> NamedSharding:
-    """Shorthand for ``NamedSharding(get_mesh(), PartitionSpec(*spec))``."""
-    if mesh is None:
-        mesh = mesh_lib.get_mesh()
-    return NamedSharding(mesh, P(*spec))

@@ -12,8 +12,9 @@ package turns those checkpoints into a *serving* runtime —
 - :mod:`.paged_attention` — the fused Pallas decode kernel:
   gather-from-block-table (scalar-prefetch index maps, so skipped and
   out-of-range blocks never move HBM bytes) + online-softmax attention
-  over the cache in ONE kernel, next to the unfused XLA lowering it is
-  A/B'd against (bench ``serving.vs_unfused``).
+  over the cache in ONE kernel, next to the unfused XLA lowering that is
+  its parity reference (``paged_decode_roofline`` of the serving cells
+  measures the kernel).
 - :mod:`.fused_ops` — the fused dequant/residual/norm epilogue on the
   decode hot path (one VMEM-resident kernel instead of three
   elementwise+reduction HLOs — the operation-fusion paper's decode

@@ -34,7 +34,7 @@ knobs travel as a broadcast command with acks (the PR 17
 
 **Canary** — every engine-knob change lands on ONE replica first and is
 judged over a bounded observation window by the paired
-median-of-ratios A/B machinery the bench uses: at each round boundary
+median-of-ratios A/B: at each round boundary
 the canary's windowed p99 TPOT is paired with the control replicas'
 median p99; the median of the per-round ratios is the verdict.  A
 regressing canary is rolled back automatically
@@ -739,8 +739,7 @@ class FleetAutopilot:
     def _judge_canary(self, now: float) -> None:
         """Advance the paired observation; at the window's end, the
         median of per-round (treated / control) p99 ratios is the
-        verdict — the bench's paired median-of-ratios machinery run
-        live."""
+        verdict."""
         c, cfg = self._canary, self.config
         if c["mode"] == "knob":
             view = self.router._views.get(c["canary"])

@@ -186,7 +186,7 @@ def init_adapter_weights(config, lora: LoRAConfig, *, seed: int = 0
     Both A and B are nonzero (unlike training-time LoRA init, which
     zeroes B) and deliberately LOUD (0.25-std entries) so two adapters
     seeded differently produce visibly different token streams even on
-    tiny test models — this is the test/bench fixture; production
+    tiny test models — this is the test fixture; production
     registers trained pairs via :func:`restore_adapter_for_serving`.
     """
     rng = np.random.default_rng(int(seed))

@@ -532,8 +532,8 @@ def paged_attention_decode_unfused(q, k_arena, v_arena, block_tables,
                                    kv_heads: Optional[int] = None,
                                    window: Optional[int] = None,
                                    sinks=None):
-    """The plain-XLA lowering of the same computation — the A/B baseline
-    (bench ``serving.vs_unfused``) and the parity reference.
+    """The plain-XLA lowering of the same computation — the parity
+    reference.
 
     Materialises the gathered ``[batch, max_blocks*block, heads, d]``
     K/V copies in HBM and lets XLA lower the softmax chain — the

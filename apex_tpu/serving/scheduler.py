@@ -44,7 +44,7 @@ that gap the way production engines do:
   whose worst case exceeds the WHOLE pool is rejected at the door (it
   could otherwise preempt the fleet forever and still never finish).
 - ``admission="reserve"`` keeps the PR 8 worst-case policy as the A/B
-  baseline (bench ``serving_occupancy.vs_reserve``): no sharing, no
+  baseline (never A/B'd on the chip: ROADMAP W4, D4): no sharing, no
   growth, no preemption — admission is the whole horizon or nothing.
 
 **Cache groups** (ISSUE 27): with more than one

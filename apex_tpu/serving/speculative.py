@@ -26,9 +26,8 @@ stops drafting — ``n_draft = 0`` is *data*, the step never recompiles —
 re-probes with a single-token proposal every ``probe_every`` quiet
 ticks, and one accepted probe re-arms it.  (The compiled step itself
 stays ``k+1`` wide; the extra query positions ride the same paged
-gather, nearly free on the memory-bound TPU decode and compute-visible
-on CPU — which is why bench ``serving_spec`` gates ``vs_baseline >= 1``
-there.)  The counters ride the
+gather; what they cost a decode tick on the chip is not measured: no
+benchmark cell speculates.)  The counters ride the
 :class:`~apex_tpu.serving.scheduler.Request`, so preemption and
 recompute-on-readmit keep a request's drafting posture.
 

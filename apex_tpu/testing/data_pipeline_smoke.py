@@ -7,10 +7,12 @@ process-pool decode, ``DataService`` loader process, double-buffered
 ``prefetch_to_device`` — and asserts the two properties a smoke can
 prove cheaply:
 
-- **nonzero overlap**: a paced consumer's steady-state stall through the
-  double-buffered prefetcher is well under the synchronous (depth=0)
-  pull time on the same loader — decode/transfer really do hide under
-  the consumer's step;
+- **overlap**: while a paced consumer is inside its step, the
+  double-buffered prefetcher pulls and places the batch that step will
+  take — every batch is counted ready before the consumer asks for it,
+  and none is on the same loader pulled synchronously (depth=0).  Counts,
+  not timings: the loader's own pool decodes ahead at either depth, so
+  the two stalls differ by a thread hand-off, which a loaded box drowns;
 - **clean shutdown**: after ``close()``, no loader worker processes and
   no service process survive (``multiprocessing.active_children()``
   empty), and the process exits 0 without leaked threads wedging
@@ -74,11 +76,11 @@ def main(work: str) -> int:
     ds = ImageFolder(jpeg_root)
 
     # --- image leg: process-pool decode + double-buffered prefetch -----
-    def stall_at(depth: int) -> float:
-        # 2 workers on a 16-image 224px batch: several ms of real decode
-        # per batch, so the overlap assertion has margin over timer
-        # jitter (a paced consumer hides it entirely at depth 2; a
-        # synchronous depth-0 pull pays it at next())
+    steps = 4
+
+    def ready_ahead(depth: int):
+        """(steps whose batch was pulled and placed before the consumer
+        asked for it, mean ms the consumer then waited in ``next``)."""
         reg = MetricRegistry(rank=0, world=1)
         with ImageFolderLoader(ds, local_batch=16, image_size=128, seed=1,
                                workers=2, backend="process") as loader:
@@ -86,24 +88,32 @@ def main(work: str) -> int:
             dev = prefetch_to_device(loader, depth=depth,
                                      place=lambda b: b, registry=reg)
             try:
-                next(dev)  # cold batch
-                total = 0.0
-                for _ in range(2):
+                next(dev)  # cold batch; starts the transfer thread
+                ready, waited = 0, 0.0
+                for _ in range(steps):
                     time.sleep(0.05)  # the "train step"
+                    # the step of a loaded box may have given the transfer
+                    # thread no core yet: give it time, without asking
+                    deadline = time.monotonic() + (30.0 if depth else 0.0)
+                    while not dev.in_flight and time.monotonic() < deadline:
+                        time.sleep(0.005)
+                    ready += dev.in_flight >= 1
                     t0 = time.perf_counter()
                     next(dev)
-                    total += time.perf_counter() - t0
-                return total / 2 * 1e3
+                    waited += time.perf_counter() - t0
+                return ready, waited / steps * 1e3
             finally:
                 dev.close(close_source=False)
 
-    sync_ms = stall_at(0)
-    overlapped_ms = stall_at(2)
-    print(f"image leg: stall {overlapped_ms:.2f} ms double-buffered vs "
-          f"{sync_ms:.2f} ms synchronous", file=sys.stderr)
-    assert overlapped_ms < sync_ms, (
-        "no overlap: double-buffered stall did not beat synchronous "
-        f"({overlapped_ms:.2f} >= {sync_ms:.2f} ms)")
+    sync_ready, sync_ms = ready_ahead(0)
+    ahead, overlapped_ms = ready_ahead(2)
+    print(f"image leg: {ahead}/{steps} batches ready ahead of their step "
+          f"double-buffered (next() {overlapped_ms:.2f} ms), "
+          f"{sync_ready}/{steps} synchronous (next() {sync_ms:.2f} ms)",
+          file=sys.stderr)
+    assert ahead == steps and sync_ready == 0, (
+        f"no overlap: {ahead}/{steps} batches were ready ahead of the "
+        f"step that took them ({sync_ready}/{steps} with no prefetch)")
 
     # --- LM leg: packed token stream through a DataService -------------
     prefix = os.path.join(work, "lm", "train")

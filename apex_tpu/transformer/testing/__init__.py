@@ -1,4 +1,4 @@
-"""Test/bench harness models — reference ``apex/transformer/testing``."""
+"""Test harness models — reference ``apex/transformer/testing``."""
 
 from apex_tpu.transformer.testing.standalone_transformer_lm import (
     AttentionKind,

@@ -39,7 +39,7 @@ class GPTModel(nn.Module):
     ``post_language_model_processing``: there the *data pipeline* pre-shifts
     labels; this framework has no mandatory data pipeline, so ``labels``
     are the **raw tokens** and the shift happens centrally in
-    :func:`gpt_next_token_loss` — every caller (tests, bench, 3D trainer)
+    :func:`gpt_next_token_loss` — every caller (tests, 3D trainer)
     gets the same non-degenerate objective.
     """
 
@@ -82,7 +82,7 @@ def gpt_loss(logits, labels, config: TransformerConfig):
     (``apex/contrib/xentropy``) otherwise.
 
     HBM-bandwidth note (the loss head is ~27 % of GPT-124M step FLOPs and
-    its logits tensor is ~0.8 GB at the bench shapes): the big ``[s, b,
+    its logits tensor is ~0.8 GB at batch 8 x 1024): the big ``[s, b,
     v]`` tensor is flattened **in its native s-major order** — only the
     int32 labels and the fp32 per-token losses (both [b, s], KBs) get
     transposed — and half logits enter the CE kernel in their storage

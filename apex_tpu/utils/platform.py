@@ -80,7 +80,7 @@ def enable_compilation_cache() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
     directory is set in code, so whoever runs the program places the
     cache.  Otherwise the cache sits at one fixed path inside the checkout
-    (``bench_results/.xla_cache``, git-ignored): the path is part of the
+    (``bench_results/.xla_cache``, git-ignored, made here): the path is part of the
     cache key, so a directory that moves between runs never hits.
     """
     import jax

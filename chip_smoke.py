@@ -77,8 +77,8 @@ def log(msg: str) -> None:
 
 
 def gpt_config(sizes: Sizes, tp: int, sequence_parallel: bool):
-    """``bench.gpt_flash_setup``'s configuration, tensor parallel when the
-    layout has a tp axis wider than one."""
+    """A GPT-2-shaped flash-attention configuration, tensor parallel when
+    the layout has a tp axis wider than one."""
     import jax.numpy as jnp
 
     from apex_tpu.transformer.testing import TransformerConfig

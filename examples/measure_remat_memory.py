@@ -11,9 +11,8 @@ compiled executable's XLA memory analysis.  Compile-only: nothing runs.
     python examples/measure_remat_memory.py            # default shapes
     python examples/measure_remat_memory.py --width 1024 --m 64
 
-Appends to ``bench_results/remat_memory.jsonl`` (every record carries
-its ``platform`` — the r4 VERDICT flagged a CPU record living under a
-``_tpu``-suffixed filename as misleading artifact naming).
+Prints one JSON record, which carries its ``platform``: a compile for the
+CPU says nothing about the chip's memory (not measured there; ROADMAP R10).
 """
 
 import argparse
@@ -103,10 +102,6 @@ def main():
                           / max(grouped["temp_bytes"], 1), 2),
         "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }
-    out = os.path.join(REPO, "bench_results", "remat_memory.jsonl")
-    os.makedirs(os.path.dirname(out), exist_ok=True)
-    with open(out, "a") as f:
-        f.write(json.dumps(rec) + "\n")
     print(json.dumps(rec))
 
 

@@ -3,9 +3,9 @@
 # apex_tpu.data end to end — synthetic JPEG tree through the
 # process-pool ImageFolderLoader + double-buffered prefetch_to_device,
 # and a packed LM token stream through a DataService loader process —
-# asserting NONZERO OVERLAP (double-buffered stall < synchronous pull on
-# the same loader) and CLEAN SHUTDOWN (no leaked worker/service
-# processes).  Wired into the fast tier like telemetry_smoke.sh
+# asserting OVERLAP (every batch pulled and placed ahead of the step
+# that takes it; none with the same loader pulled synchronously) and
+# CLEAN SHUTDOWN (no leaked worker/service processes).  Wired into the fast tier like telemetry_smoke.sh
 # (tests/test_aux_subsystems.py::test_data_pipeline_smoke_script).
 #
 # Usage: scripts/data_pipeline_smoke.sh [WORK_DIR]

@@ -112,8 +112,10 @@ def test_rank_logger_stamps_rank_info():
 def test_data_pipeline_smoke_script(tmp_path):
     """scripts/data_pipeline_smoke.sh end to end (the telemetry_smoke
     wiring pattern): process-pool decode + double-buffered prefetch must
-    show nonzero overlap, the packed LM stream must flow through a
-    DataService, and shutdown must leak no worker processes.  Subprocess
+    have every batch ready ahead of the step that takes it (a count, not
+    a comparison of two stall timings), the packed LM stream must flow
+    through a DataService, and shutdown must leak no worker processes.
+    Subprocess
     because the process-pool spawn re-imports __main__ and the smoke
     owns its own platform pinning."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

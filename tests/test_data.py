@@ -329,8 +329,7 @@ def test_host_dp_ranks_and_local_placement():
 
 def test_prefetch_records_stall_metric(image_root):
     """Every delivered batch records its blocking wait into the
-    data/stall_ms gauge + span_ms/data/next_wait histogram — the in-run
-    stall measurement the bench cross-checks."""
+    data/stall_ms gauge + span_ms/data/next_wait histogram."""
     from apex_tpu.data import prefetch_to_device
     from apex_tpu.observability.metrics import MetricRegistry
 

@@ -6,6 +6,12 @@ Pallas kernels (interpret mode on CPU) vs naive jnp attention, forward and
 gradients, then the ring/Ulysses composition vs single-device flash.
 """
 
+import json
+import os
+import subprocess
+import sys
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,14 +19,17 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu import parallel
-from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.flash_attention import (
+    flash_attention,
+    resolve_default_blocks,
+)
 from apex_tpu.parallel import collectives as cc
 from apex_tpu.transformer.context_parallel import (
     ring_attention,
     ulysses_attention,
 )
 
-pytestmark = pytest.mark.slow
+slow = pytest.mark.slow  # the numerics below; the block resolution is tier-1
 
 
 def naive_attention(q, k, v, causal, scale=None):
@@ -35,6 +44,7 @@ def naive_attention(q, k, v, causal, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [(1, 2, 32, 8), (2, 1, 48, 16)])
 def test_flash_matches_naive(causal, shape):
@@ -62,6 +72,7 @@ def test_flash_matches_naive(causal, shape):
                                    rtol=2e-4, atol=2e-4)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_flash(causal):
     """cp=4 ring == single-device flash on the full sequence, fwd + grads."""
@@ -98,6 +109,7 @@ def test_ring_attention_matches_flash(causal):
                                    rtol=2e-4, atol=2e-4)
 
 
+@slow
 def test_ulysses_attention_matches_flash():
     CP = 4
     parallel.initialize_model_parallel(context_parallel_size=CP)
@@ -131,6 +143,7 @@ def test_ulysses_attention_matches_flash():
                                rtol=2e-4, atol=2e-4)
 
 
+@slow
 def test_gpt_flash_attention_matches_fused_softmax():
     """CoreAttention flash path == fused-softmax path on the same params."""
     from apex_tpu.transformer.testing import GPTModel, TransformerConfig
@@ -169,6 +182,7 @@ def naive_attention_masked(q, k, v, causal, seg_q=None, seg_k=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_segment_ids_match_naive(causal):
     """Packed-varlen via segment ids (fmha cu_seqlens parity)."""
@@ -197,6 +211,7 @@ def test_flash_segment_ids_match_naive(causal):
                                    rtol=2e-4, atol=2e-4)
 
 
+@slow
 @pytest.mark.parametrize("s", [17, 100, 130])
 def test_flash_non_power_of_two_lengths(s):
     """Odd lengths pad to the block grid instead of degrading to block=s."""
@@ -214,6 +229,7 @@ def test_flash_non_power_of_two_lengths(s):
                                rtol=2e-4, atol=2e-4)
 
 
+@slow
 def test_flash_cross_attention_lengths():
     """sq != sk, both non-multiples of the block."""
     b, h, d = 2, 2, 8
@@ -226,6 +242,7 @@ def test_flash_cross_attention_lengths():
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 def test_flash_fully_masked_rows_zero():
     """A q shard strictly before the kv shard under causal masking must
     produce zero output / NEG_INF lse, not mean(V) (round-1 ADVICE)."""
@@ -252,6 +269,7 @@ def test_flash_fully_masked_rows_zero():
     assert np.allclose(np.asarray(dv), 0.0)
 
 
+@slow
 def test_flash_dropout_statistics_and_determinism():
     b, h, s, d = 2, 2, 64, 8
     ks = jax.random.split(jax.random.PRNGKey(6), 3)
@@ -279,6 +297,7 @@ def test_flash_dropout_statistics_and_determinism():
     np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
 
 
+@slow
 def test_flash_dropout_grad_matches_masked_reference():
     """Grads under dropout == grads of an explicitly-masked naive attention
     built from the kernel's own keep mask."""
@@ -316,3 +335,63 @@ def test_flash_dropout_grad_matches_masked_reference():
     for a, b_ in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
+
+
+_Q, _K = "APEX_TPU_FLASH_BLOCK_Q", "APEX_TPU_FLASH_BLOCK_K"
+
+# What a process on a v5e would see, with a tuned record planted where the
+# deleted loader looked (<checkout>/bench_results/flash_blocks_tuned.json).
+_PLANTED = """
+import jax
+class Dev:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+asked = []
+jax.devices = lambda *a, **k: asked.append(1) or [Dev()]
+from apex_tpu.ops.flash_attention import resolve_default_blocks
+print(list(resolve_default_blocks()), len(asked))
+"""
+
+
+@pytest.mark.parametrize("args,env,want,warns,planted", [
+    ((None, None), {}, (256, 512), False, False),
+    ((128, 64), {_Q: "32", _K: "32"}, (128, 64), False, False),
+    ((None, None), {_Q: "128", _K: "1024"}, (128, 1024), False, False),
+    ((None, 128), {_Q: "64"}, (64, 128), False, False),
+    ((None, None), {_Q: "wide"}, (256, 512), True, False),
+    ((None, None), {_Q: "128", _K: "0"}, (128, 512), True, False),
+    ((None, None), {_K: "-8"}, (256, 512), True, False),
+    ((None, None), {}, (256, 512), False, True),
+], ids=["nothing_set", "arguments_win", "env_wins_over_default",
+        "per_dimension", "malformed_env_warns", "zero_env_warns",
+        "negative_env_warns", "planted_tuned_file_ignored"])
+def test_resolve_default_blocks(args, env, want, warns, planted,
+                                monkeypatch, tmp_path):
+    """Argument, else the environment knob, else 256/512 — and nothing
+    else: no file in the checkout and no look at the device decides the
+    train cell's tiles."""
+    for name in (_Q, _K):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    if planted:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        os.symlink(os.path.join(repo, "apex_tpu"), tmp_path / "apex_tpu")
+        (tmp_path / "bench_results").mkdir()
+        (tmp_path / "bench_results" / "flash_blocks_tuned.json").write_text(
+            json.dumps({"block_q": 128, "block_k": 128,
+                        "device_kind": "TPU v5 lite"}))
+        proc = subprocess.run(
+            [sys.executable, "-c", _PLANTED], cwd=tmp_path,
+            env={**os.environ, "PYTHONPATH": str(tmp_path),
+                 "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr
+        assert proc.stdout.split("\n")[-2] == f"{list(want)} 0"
+        return
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        assert resolve_default_blocks(*args) == want
+    named = [w for w in caught
+             if any(name in str(w.message) for name in (_Q, _K))]
+    assert bool(named) == warns

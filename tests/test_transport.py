@@ -203,6 +203,28 @@ def sock_drive(router, served, *, clock=None, step=0.05, max_iters=4000,
         f"{[(r.rid, r.state) for r in router.requests.values() if not r.done]}")
 
 
+def pump_until(router, served, cond, what, *, hold=None, clock=None,
+               deadline_s=60.0):
+    """Pump the router and tick the replicas until ``cond()`` holds, under
+    a wall-clock deadline of its own: on a loaded box an iteration lasts
+    as long as the scheduler likes, so a count of them bounds nothing.
+    ``hold`` is ticked only until it has emitted its first token: the
+    same loop generates tokens and looks for them, and a replica ticked
+    at the loop's pace finishes the whole stream while the socket threads
+    wait for a core, so that one pump delivers it all and no look ever
+    finds it mid-stream."""
+    end = time.monotonic() + deadline_s
+    while not cond():
+        assert time.monotonic() < end, f"{what}: not within {deadline_s:.0f} s"
+        router.pump()
+        for s in served:
+            if s is not hold or s.fake.tokens_emitted == 0:
+                s.tick()
+        if clock is not None:
+            clock[0] += 0.05              # drive the detection ladder
+        time.sleep(0.001)
+
+
 def cleanup(router, served, proxies=()):
     router.close()
     for s in served:
@@ -338,18 +360,12 @@ def test_bad_frame_counted_and_classified_replica_failure(fault, reason):
     try:
         wait_states(router, tries=4000)
         req = router.submit([9, 1, 4], 6)
-        armed = False
-        for _ in range(6000):
-            router.pump()
-            if router.idle():
-                break
-            for s in (victim, survivor):
-                s.tick()
-            if not armed and req.output_tokens:
-                getattr(proxy, fault)()   # next replica→router frame
-                armed = True
-            time.sleep(0.001)
-        assert armed, "fault never armed mid-stream"
+        pump_until(router, [victim, survivor], lambda: req.output_tokens,
+                   "first token seen", hold=victim)
+        assert len(req.output_tokens) == 1 and not req.done  # mid-stream
+        getattr(proxy, fault)()           # next replica→router frame
+        pump_until(router, [victim, survivor], router.idle,
+                   "stream finished after the bad frame")
         assert req.state is RequestState.FINISHED
         assert req.output_tokens == reference([9, 1, 4], 6)
         view = router._views["victim"]
@@ -384,20 +400,12 @@ def test_partition_failover_replay_token_identity():
     try:
         wait_states(router, tries=4000)
         req = router.submit([9, 1, 4], 6)
-        cut = False
-        for _ in range(6000):
-            router.pump()
-            if router.idle():
-                break
-            for s in (victim, survivor):
-                s.tick()
-            if not cut and req.output_tokens:
-                proxy.partition()         # total silence from here
-                cut = True
-            if cut:
-                clock[0] += 0.05          # drive the detection ladder
-            time.sleep(0.001)
-        assert cut, "partition never engaged mid-stream"
+        pump_until(router, [victim, survivor], lambda: req.output_tokens,
+                   "first token seen", hold=victim)
+        assert len(req.output_tokens) == 1 and not req.done  # mid-stream
+        proxy.partition()                 # total silence from here
+        pump_until(router, [victim, survivor], router.idle,
+                   "failed over and finished on the survivor", clock=clock)
         assert req.state is RequestState.FINISHED
         assert req.output_tokens == reference([9, 1, 4], 6)
         assert router._views["victim"].down
@@ -425,21 +433,13 @@ def test_half_open_link_recovers_on_survivor():
     try:
         wait_states(router, tries=4000)
         req = router.submit([9, 1, 4], 6)
-        cut = False
-        for _ in range(6000):
-            router.pump()
-            if router.idle():
-                break
-            for s in (victim, survivor):
-                s.tick()
-            if not cut and req.output_tokens:
-                proxy.half_open()         # future accepts: black hole
-                proxy.drop_connections()  # force it onto them
-                cut = True
-            if cut:
-                clock[0] += 0.05
-            time.sleep(0.001)
-        assert cut
+        pump_until(router, [victim, survivor], lambda: req.output_tokens,
+                   "first token seen", hold=victim)
+        assert len(req.output_tokens) == 1 and not req.done  # mid-stream
+        proxy.half_open()                 # future accepts: black hole
+        proxy.drop_connections()          # force it onto them
+        pump_until(router, [victim, survivor], router.idle,
+                   "failed over and finished on the survivor", clock=clock)
         assert req.state is RequestState.FINISHED
         assert req.output_tokens == reference([9, 1, 4], 6)
         view = router._views["victim"]

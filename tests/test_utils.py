@@ -151,64 +151,3 @@ class TestModelParallelRng:
         # model-parallel stream unique per rank
         assert len({tuple(row) for row in mp}) == 8
 
-
-class TestTunedRecords:
-    """apex_tpu.utils.tuning.load_tuned_record — the sweep auto-land
-    adoption protocol (device-gated tuned defaults)."""
-
-    class _Dev:
-        platform = "tpu"
-        device_kind = "TPU v5 lite"
-
-    class _Jax:
-        @classmethod
-        def devices(cls):
-            return [TestTunedRecords._Dev()]
-
-    def _write(self, monkeypatch, tmp_path, payload):
-        import json
-
-        from apex_tpu.utils import tuning
-
-        monkeypatch.setattr(tuning, "_REPO", str(tmp_path))
-        d = tmp_path / "bench_results"
-        d.mkdir(exist_ok=True)
-        if payload is not None:
-            (d / "x_tuned.json").write_text(json.dumps(payload))
-
-    def test_adopts_on_matching_device_kind(self, monkeypatch, tmp_path):
-        from apex_tpu.utils.tuning import load_tuned_record
-
-        self._write(monkeypatch, tmp_path,
-                    {"base_batch": 16, "device_kind": "TPU v5 lite"})
-        rec = load_tuned_record("x_tuned.json", self._Jax)
-        assert rec and rec["base_batch"] == 16
-
-    def test_rejects_kind_mismatch_and_cpu(self, monkeypatch, tmp_path):
-        from apex_tpu.utils.tuning import load_tuned_record
-
-        self._write(monkeypatch, tmp_path,
-                    {"base_batch": 16, "device_kind": "TPU v4"})
-        assert load_tuned_record("x_tuned.json", self._Jax) is None
-
-        class CpuDev:
-            platform = "cpu"
-            device_kind = "TPU v5 lite"  # lying kind on a cpu backend
-
-        class CpuJax:
-            @classmethod
-            def devices(cls):
-                return [CpuDev()]
-
-        self._write(monkeypatch, tmp_path,
-                    {"base_batch": 16, "device_kind": "TPU v5 lite"})
-        assert load_tuned_record("x_tuned.json", CpuJax) is None
-
-    def test_missing_or_corrupt_degrades_to_none(self, monkeypatch,
-                                                 tmp_path):
-        from apex_tpu.utils import tuning
-
-        self._write(monkeypatch, tmp_path, None)
-        assert tuning.load_tuned_record("x_tuned.json", self._Jax) is None
-        (tmp_path / "bench_results" / "x_tuned.json").write_text("{broken")
-        assert tuning.load_tuned_record("x_tuned.json", self._Jax) is None
