@@ -7,25 +7,49 @@ core of ``apex/contrib/multihead_attn`` — rebuilt as a *blockwise
 online-softmax* kernel family with none of the shape limits (any seqlen,
 any head dim that tiles to the MXU, fp32/bf16).
 
-Design (the standard flash decomposition, mapped to TPU):
+Design (the standard flash decomposition, mapped to TPU), with two tile
+sizes, the tile a grid step *fetches* and the tile a loop trip *computes*:
 
-- forward: grid ``(batch*heads, q_blocks, k_blocks)`` with the k-block index
-  innermost; the running row-max ``m``, row-sum ``l`` and output accumulator
-  live in VMEM scratch that persists across the k sweep, so K/V *stream*
-  through VMEM one block at a time (Pallas double-buffers the HBM→VMEM
-  copies against the MXU work) and VMEM holds O(block) state regardless of
-  sequence length — the softmax never materialises the ``[sq, sk]`` score
-  matrix (the reason apex's fused softmax caps at 16384 keys disappears).
+- **fetched tile** (``block_q`` x ``block_k`` rows, the two knobs of
+  :func:`resolve_default_blocks`): what a grid step has in VMEM.  Where a
+  head's Q, K and V fit the byte cap (``_TILE_BYTES`` per operand: 2048
+  positions at head 128 in bf16, 4096 at head 64) the tile is the head, the
+  grid is ``(batch*heads, 1, 1)`` and no grid step is dead; longer
+  sequences keep grid ``(batch*heads, q_blocks, k_blocks)`` (k innermost;
+  q innermost for dk/dv) with the running row-max ``m``, row-sum ``l`` and
+  accumulators in VMEM scratch across the inner sweep, and under causal
+  the inner side's index maps clamp into the live range so Pallas elides
+  the HBM→VMEM copy of a tile with nothing to visit.  The softmax never
+  materialises the ``[sq, sk]`` score matrix in HBM (the reason apex's
+  fused softmax caps at 16384 keys disappears).
+- **computed tile** (up to 1024 x 1024 scores, by ``_SUB_BYTES``):
+  inside a step, rolled ``lax.fori_loop``s over the computed tiles of the
+  fetched one, their **trip counts bounded by causality** from the offsets
+  and program ids, so a tile wholly above the diagonal is never visited
+  and a tile wholly under it compares nothing.  A tile is straight-line
+  code in strips of q lanes (512 forward, 256 backward); in a tile the
+  diagonal crosses, a strip computes only the k rows at or under its
+  diagonal block and compares positions on that block alone.  Scores are
+  computed transposed (``[k, q]``), so the per-row statistics are
+  lane-dense rows and ``lse``/``delta`` travel as rows (a ``[.., s, 1]``
+  column costs 128 times its bytes in HBM).  The tile is this large
+  because the MXU, not the VPU, bounds these kernels at head 64 (every
+  product is half a pass wide) and only a large basic block keeps it fed:
+  loops over 128 x 128 sub-tiles measured 3 times slower (PERF.md §6).
+- the softmax scale is folded into q (k, for dk/dv) before the product
+  **where that is exact**, a power of two such as 1/8 at head 64; else it
+  multiplies the float32 scores; backward leaves it out of ``ds`` and
+  applies it to ``dq``/``dk`` once at the end.
 - saves ``(out, lse)`` only — the activation-memory profile of the fused
   kernels (``fmha`` saves the same) rather than O(s²) probabilities.
-- backward: one kernel recomputes scores per (q-block, k-block) pair to form
-  ``dq`` (k innermost, dq in scratch), a second forms ``dk/dv`` over the
-  transposed blocking (q innermost), both seeded with
-  ``delta = rowsum(do * o)`` computed in plain XLA.
-- **causal block skipping**: fully-masked (q-block, k-block) pairs are
-  skipped with ``pl.when`` (no MXU work) and their K/V block index maps are
-  clamped to the last live block so Pallas elides the HBM→VMEM copy —
-  the ~2× FLOP saving of a production causal kernel.
+- backward: one kernel recomputes the scores of a q block against the k
+  tiles to form ``dq``, a second recomputes them per k block against the q
+  tiles to form ``dk/dv``, both seeded with ``delta = rowsum(do * o)``
+  computed in plain XLA; backward masks once (``p``, after the exp).
+- at trace time each call records the gauges ``flash/fetch_tile_rows``,
+  ``flash/sub_tiles`` (128 x 128 sub-tiles computed per head),
+  ``flash/sub_tiles_masked`` (those that take a compare) and
+  ``flash/live_score_share`` on ``observability.default_registry()``.
 - **segment masking / varlen**: optional per-token integer segment ids
   (must be ≥ 0) mask attention across segment boundaries — the TPU-native
   form of fmha's ``cu_seqlens`` packed-varlen API (a packed batch is one
@@ -52,24 +76,29 @@ Design (the standard flash decomposition, mapped to TPU):
   (:func:`apex_tpu.utils.platform.pallas_interpret`) so the same code runs
   in the CPU test mesh.
 
-Layouts: ``q, k, v: [batch, heads, seq, head_dim]`` (BHSD).  ``lse`` rides
-as ``[b, h, s, 1]`` inside kernels (trailing singleton keeps the TPU
-(sublane, lane) tiling rule satisfied for any block) and is squeezed at the
-API boundary.  Segment ids ride as ``[b, s, 1]`` for the same reason.
+Layouts: ``q, k, v: [batch, heads, seq, head_dim]`` (BHSD).  ``lse`` and
+``delta`` ride as ``[b, h, s/sub_q, 1, sub_q]`` inside kernels (one
+lane-dense row per computed q block) and are reshaped at the API boundary;
+q segment ids likewise as ``[b, s/sub_q, 1, sub_q]``, k segment ids as a
+``[b, s, 1]`` column (the two sides of a ``[k, q]`` score tile).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
+import types
 import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.metrics import default_registry
 from apex_tpu.utils import platform
 
 __all__ = [
@@ -96,23 +125,23 @@ def _env_block(name: str, default: int) -> int:
 
 
 def resolve_default_blocks(block_q=None, block_k=None):
-    """Fill unset block sizes: the explicit argument, else
+    """Fill unset sizes of the fetched tiles: the explicit argument, else
     ``APEX_TPU_FLASH_BLOCK_Q/K`` (the handle a block sweep on the chip has
-    on a whole train step, ROADMAP S1), else 256/512."""
+    on a whole train step, ROADMAP S1), else 2048/2048: with the byte cap
+    of ``_pick_blocks``, a head of up to 2048 positions in one grid step."""
     if block_q is None:
-        block_q = _env_block("APEX_TPU_FLASH_BLOCK_Q", 256)
+        block_q = _env_block("APEX_TPU_FLASH_BLOCK_Q", 2048)
     if block_k is None:
-        block_k = _env_block("APEX_TPU_FLASH_BLOCK_K", 512)
+        block_k = _env_block("APEX_TPU_FLASH_BLOCK_K", 2048)
     return block_q, block_k
 
 
 NEG_INF = -1e30
-_LANES = 128   # TPU lane count: minor-dim tile
-_SUBLANES = 8  # fp32 sublane tile
-
-
-def _scratch(shape, dtype=jnp.float32):
-    return pltpu.VMEM(shape, dtype)
+_SUB = 128                 # one lane tile: the grain of fetched tiles
+_TILE_BYTES = 512 * 1024   # most one fetched operand tile may take of VMEM
+_SUB_BYTES = 128 * 1024    # most the rows of it that one loop trip computes
+_STRIP_FWD = 512           # q lanes of one strip of a computed tile: forward,
+_STRIP_BWD = 256           # dq and dkv (PERF.md §6 has the sweep)
 
 
 def _flash_compiler_params():
@@ -129,13 +158,35 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _pick_blocks(sq, sk, block_q, block_k):
-    """Block sizes + padded lengths.  Blocks shrink to the (tile-aligned)
-    sequence length; sequences pad up to a whole number of blocks, so
-    non-power-of-two lengths never degrade to ``block = s`` VMEM blowups."""
-    bq = min(block_q, _round_up(sq, _SUBLANES))
-    bk = min(block_k, _round_up(sk, _LANES))
-    return bq, bk, _round_up(sq, bq), _round_up(sk, bk)
+def _pick_blocks(sq, sk, d, itemsize, block_q, block_k):
+    """``(bq, bk, sub_q, sub_k, sq_p, sk_p)``: the fetched tiles, the edges
+    of the tile one loop trip computes (q rides the lanes of a score tile)
+    and the padded lengths.
+
+    A fetched tile is ``block_q``/``block_k`` rows in whole lane tiles,
+    capped by ``_TILE_BYTES`` for this ``d`` and dtype and by the sequence:
+    where a head fits, the tile is the head and that grid dimension is 1.
+    The computed tile is the largest whole number of lane tiles that
+    divides the fetched one within ``_SUB_BYTES`` (1024 rows at head 64 in
+    bf16, 512 at head 128: with its temporaries that fits Mosaic's default
+    VMEM scope).  Sequences pad up to a whole number of fetched tiles, so
+    any length compiles."""
+    row = d * itemsize
+
+    def side(s, block):
+        fetched = min(_round_up(block, _SUB), max(_SUB, _TILE_BYTES // row),
+                      _round_up(s, _SUB))
+        most = max(_SUB_BYTES // row // _SUB, 1)
+        computed = _SUB * max(i for i in range(1, most + 1)
+                              if fetched // _SUB % i == 0)
+        return fetched, computed, _round_up(s, fetched)
+
+    bq, sub_q, sq_p = side(sq, block_q)
+    if sk <= _SUB:
+        bk = sub_k = sk_p = _round_up(sk, 16)
+    else:
+        bk, sub_k, sk_p = side(sk, block_k)
+    return bq, bk, sub_q, sub_k, sq_p, sk_p
 
 
 def _pad_dim2(x, target):
@@ -145,10 +196,20 @@ def _pad_dim2(x, target):
     return jnp.pad(x, ((0, 0), (0, 0), (0, target - s), (0, 0)))
 
 
-def _prep_segments(seg_q, seg_k, b, sq, sk, sq_p, sk_p, need):
-    """Pad/create ``[b, s, 1]`` int32 segment-id arrays.  Pad sentinels
-    differ on the q (-1) and k (-2) side so padded q rows attend nothing
-    and real rows never attend padded keys."""
+def _q_rows(x, sq_p, sub_q):
+    """``[b, h, sq]`` per-row statistics -> ``[b, h, sq_p/sub_q, 1, sub_q]``:
+    one lane-dense row per computed q block (a ``[.., sq, 1]`` column pads
+    every value to a lane tile in HBM, 128 times its bytes)."""
+    b, h, s = x.shape
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, sq_p - s)))
+    return x.reshape(b, h, sq_p // sub_q, 1, sub_q)
+
+
+def _prep_segments(seg_q, seg_k, b, sq, sk, sq_p, sk_p, sub_q, need):
+    """Pad/create the int32 segment ids: q as ``[b, sq_p/sub_q, 1, sub_q]``
+    rows, k as a ``[b, sk_p, 1]`` column (the two sides of a score sub-tile
+    ``[k, q]``).  Pad sentinels differ on the q (-1) and k (-2) side so
+    padded q rows attend nothing and real rows never attend padded keys."""
     if not need:
         return None, None
     if seg_q is None:
@@ -159,7 +220,37 @@ def _prep_segments(seg_q, seg_k, b, sq, sk, sq_p, sk_p, need):
                     constant_values=-1)
     seg_k = jnp.pad(seg_k.astype(jnp.int32), ((0, 0), (0, sk_p - sk)),
                     constant_values=-2)
-    return seg_q[..., None], seg_k[..., None]
+    return seg_q.reshape(b, sq_p // sub_q, 1, sub_q), seg_k[..., None]
+
+
+def _record_tiling(sq, sk, bq, sub_q, sub_k, strip, sq_p, sk_p, causal,
+                   q_offset, kv_offset, every_masked):
+    """Trace-time gauges of the tiling one call runs (host only): rows of the
+    fetched q tile, the 128 x 128 sub-tiles computed and those that take a
+    compare, per head, and the scores that count over the scores computed
+    (the kernels' own plans walked over the head)."""
+    rows = np.arange(sq) + q_offset - kv_offset
+    live = int(np.clip(rows + 1, 0, sk).sum()) if causal else sq * sk
+    free, crossed = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
+    blk = min(_SUB, sub_k)
+    visited = masked = 0
+    for r0 in range(q_offset, q_offset + sq_p, sub_q):
+        for c0 in range(kv_offset, kv_offset + sk_p, sub_k):
+            if causal and c0 > r0 + sub_q - 1:
+                continue
+            plan = crossed if causal and c0 + sub_k - 1 > r0 else free
+            for _, lanes, n_rows, compare_from in plan:
+                if every_masked:
+                    compare_from = 0
+                visited += lanes // _SUB * (n_rows // blk)
+                if compare_from is not None:
+                    masked += lanes // _SUB * ((n_rows - compare_from) // blk)
+    reg = default_registry()
+    reg.gauge("flash/fetch_tile_rows").set(bq)
+    reg.gauge("flash/sub_tiles").set(visited)
+    reg.gauge("flash/sub_tiles_masked").set(masked)
+    reg.gauge("flash/live_score_share").set(
+        live / (visited * _SUB * blk) if visited else 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +269,11 @@ def _mix32(x):
 
 
 def _keep_mask(seed, bh, rows, cols, rate):
-    """Boolean keep mask over global (row, col) coordinates.
+    """Boolean keep mask over global (row, col) coordinates: q positions
+    ``rows`` against k positions ``cols``, broadcast against each other
+    (the kernels pass a ``[1, q]`` row of q positions and a ``[k, 1]``
+    column of k positions; the bit of a (row, col) pair is the same
+    whichever way the two are laid out).
 
     Pure uint32 arithmetic (no pltpu PRNG) so the identical mask is
     produced on TPU and in interpret mode, and the backward kernels can
@@ -186,31 +281,145 @@ def _keep_mask(seed, bh, rows, cols, rate):
     """
     h = _mix32(seed.astype(jnp.uint32) ^ jnp.uint32(0x9E3779B9))
     h = _mix32(h + jnp.uint32(bh))
-    h = _mix32(h + rows.astype(jnp.uint32))  # (bq, 1)
-    h = _mix32(h + cols.astype(jnp.uint32))  # (bq, bk)
+    h = _mix32(h + rows.astype(jnp.uint32))
+    h = _mix32(h + cols.astype(jnp.uint32))
     thresh = jnp.uint32(min(int(rate * 4294967296.0), 4294967295))
     return h >= thresh
 
 
-def _coords(iq, jk, bq, bk, q_offset, kv_offset):
-    rows = (q_offset + iq * bq
-            + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
-    cols = (kv_offset + jk * bk
-            + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
-    return rows, cols
+# ---------------------------------------------------------------------------
+# the score tile, shared by the three kernels
+# ---------------------------------------------------------------------------
+#
+# Every kernel computes scores transposed, ``s[k, q] = k . q^T``: q rides the
+# lanes, so the per-row statistics (``m``, ``l``, ``lse``, ``delta``) are
+# lane-dense rows that broadcast along sublanes for free, the row max and
+# sum are elementwise over vregs, and ``dk``/``dv`` need no transposed
+# operand.  One loop trip computes a *tile* of ``[sub_k, sub_q]`` scores as
+# straight-line code: the MXU takes a row of an operand a cycle whatever
+# the tile, and only a basic block of this size gives the scheduler enough
+# independent pushes, pops and exps to keep it fed (a loop over single
+# 128 x 128 sub-tiles runs one dependent chain at a time, PERF.md §6).
+# A tile is computed in strips of ``_STRIP_FWD``/``_STRIP_BWD`` q lanes: a
+# tile under the diagonal compares nothing; in a tile that the diagonal
+# crosses corner to corner a strip computes the k rows at or under its
+# diagonal block only and compares on that block only.  What is computed
+# is counted in 128 x 128 sub-tiles (the gauges of ``_record_tiling``).
 
 
-def _block_mask(iq, jk, bq, bk, causal, q_offset, kv_offset,
-                seg_q, seg_k):
-    """Combined causal+segment mask for one (q-block, k-block), or None."""
-    mask = None
-    if causal:
-        rows, cols = _coords(iq, jk, bq, bk, q_offset, kv_offset)
-        mask = rows >= cols
+def _folds(scale):
+    """Whether ``scale`` is a power of two, so that multiplying an operand
+    by it before the product is exact in any float dtype."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scores(k, q, scale):
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return s if _folds(scale) else s * scale
+
+
+def _prescale(x, scale):
+    return x * scale if _folds(scale) else x
+
+
+def _q_pos(first, n):
+    return first + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+
+def _k_pos(first, n):
+    return first + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+
+
+def _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip):
+    """How a tile under the diagonal and a tile the diagonal crosses are
+    computed: each a list of strips ``(lane0, lanes, rows, compare_from)``,
+    the q lanes of the strip, the k rows it computes and the row from which
+    it compares positions (None: nowhere).  Where every crossed tile has
+    the diagonal corner to corner (square tiles on a grid the offsets do
+    not shift) a strip stops at its diagonal block and compares there;
+    else a crossed tile computes and compares everything."""
+    w = math.gcd(strip, sub_q)
+    lanes = range(0, sub_q, w)
+    free = [(lane0, w, sub_k, None) for lane0 in lanes]
+    if not causal:
+        return free, None
+    if sub_q == sub_k and (q_offset - kv_offset) % sub_q == 0:
+        return free, [(lane0, w, lane0 + w, lane0) for lane0 in lanes]
+    return free, [(lane0, w, sub_k, 0) for lane0 in lanes]
+
+
+def _masked(x, fill, compare_from, q_pos, k_pos, seg_q, seg_k):
+    """``x`` (``[rows, lanes]``) with ``fill`` where a score does not
+    count: other segments anywhere, and q positions before k positions on
+    the rows from ``compare_from`` on."""
     if seg_q is not None:
-        sm = seg_q[:, None] == seg_k[None, :]
-        mask = sm if mask is None else mask & sm
-    return mask
+        keep = seg_q == seg_k
+        if compare_from is not None:
+            keep &= q_pos >= k_pos
+        return jnp.where(keep, x, fill)
+    if compare_from is None:
+        return x
+    low = jnp.where(q_pos >= k_pos[compare_from:], x[compare_from:], fill)
+    if compare_from == 0:
+        return low
+    return jnp.concatenate([x[:compare_from], low], axis=0)
+
+
+def _visit(tile, load, store, plans, free, diag):
+    """Run ``tile(index, carry, plan=...)`` over the tiles that lie wholly
+    under the diagonal (``free``: a ``(lo, hi)`` range) and then over those
+    that cross it (``diag``), each with its plan; tiles wholly above the
+    diagonal are in neither range.  ``load(plan)`` reads the carry of a
+    plan's strips from scratch and ``store(plan, carry)`` puts it back.
+    Both loops stay rolled."""
+    for plan, (lo, hi) in zip(plans, (free, diag)):
+        if plan is not None:
+            store(plan, jax.lax.fori_loop(
+                lo, hi, functools.partial(tile, plan=plan), load(plan)))
+
+
+def _k_ranges(causal, row0, col0, sub_q, sub_k, n):
+    """For the q block whose first global row is ``row0``: the ranges of the
+    ``n`` k tiles from global column ``col0`` that it sees whole and that
+    cross its diagonal."""
+    if not causal:
+        return (0, n), (n, n)
+    free = jnp.minimum(jnp.maximum(row0 - col0 + 1, 0) // sub_k, n)
+    live = jnp.minimum(
+        (jnp.maximum(row0 + sub_q - col0, 0) + sub_k - 1) // sub_k, n)
+    return (0, free), (free, live)
+
+
+def _q_ranges(causal, row0, col0, sub_q, sub_k, n):
+    """For the k block whose first global column is ``col0``: the ranges of
+    the ``n`` q tiles from global row ``row0`` that see it whole and that
+    cross the diagonal over it."""
+    if not causal:
+        return (0, n), (n, n)
+    dead = jnp.minimum(jnp.maximum(col0 - row0, 0) // sub_q, n)
+    free = jnp.minimum(
+        (jnp.maximum(col0 + sub_k - 1 - row0, 0) + sub_q - 1) // sub_q, n)
+    return (free, n), (dead, free)
+
+
+def _unpack(refs, n_in, has_segments, dropout_rate):
+    """Split a kernel's refs: the ``n_in`` dense inputs, the optional
+    segment-id pair and dropout seed, and whatever follows."""
+    i = n_in
+    seg_q_ref = seg_k_ref = seed_ref = None
+    if has_segments:
+        seg_q_ref, seg_k_ref = refs[i], refs[i + 1]
+        i += 2
+    if dropout_rate > 0.0:
+        seed_ref = refs[i]
+        i += 1
+    return refs[:n_in], seg_q_ref, seg_k_ref, seed_ref, refs[i:]
+
+
+def _at(first, n):
+    """``n`` rows from the traced ``first``, a multiple of ``n``."""
+    return pl.ds(pl.multiple_of(first, n), n)
 
 
 # ---------------------------------------------------------------------------
@@ -219,17 +428,10 @@ def _block_mask(iq, jk, bq, bk, causal, q_offset, kv_offset,
 
 
 def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-                dropout_rate):
-    i = 3
-    q_ref, k_ref, v_ref = refs[:3]
-    seg_q_ref = seg_k_ref = seed_ref = None
-    if has_segments:
-        seg_q_ref, seg_k_ref = refs[i], refs[i + 1]
-        i += 2
-    if dropout_rate > 0.0:
-        seed_ref = refs[i]
-        i += 1
-    o_ref, lse_ref, m_sc, l_sc, acc_sc = refs[i:i + 5]
+                dropout_rate, sub_q, sub_k, strip):
+    (q_ref, k_ref, v_ref), seg_q_ref, seg_k_ref, seed_ref, rest = _unpack(
+        refs, 3, has_segments, dropout_rate)
+    o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
 
     bq = q_ref.shape[2]
     bk = k_ref.shape[2]
@@ -237,6 +439,8 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     iq = pl.program_id(1)
     jk = pl.program_id(2)
     num_kb = pl.num_programs(2)
+    col0 = kv_offset + jk * bk
+    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
 
     @pl.when(jk == 0)
     def _init():
@@ -244,68 +448,80 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def _body():
+    def q_block(qi, _):
         # Matmuls run in the INPUT dtype with fp32 accumulation: a
         # bf16xbf16->f32 MXU pass is ~2x the fp32 rate, and upcasting
         # the operands first forfeits that (r4 finding; the softmax/
         # rescale math stays fp32 below).  fp32 inputs are unaffected.
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        seg_q = seg_q_ref[0, :, 0] if has_segments else None
-        seg_k = seg_k_ref[0, :, 0] if has_segments else None
-        mask = _block_mask(iq, jk, bq, bk, causal, q_offset, kv_offset,
-                           seg_q, seg_k)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
+        q = _prescale(q_ref[0, 0, _at(qi * sub_q, sub_q), :], scale)
+        row0 = q_offset + iq * bq + qi * sub_q
 
-        m = m_sc[:, 0]
-        l = l_sc[:, 0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        # Guard the all-masked row: with m_new == NEG_INF, exp(s - m_new)
-        # would be exp(0) = 1 per masked entry (phantom mean(V) mass);
-        # exp(s - 0) = exp(NEG_INF) = 0 is what we want.
-        m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
-        p = jnp.exp(s - m_safe[:, None])
-        alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        if dropout_rate > 0.0:
-            rows, cols = _coords(iq, jk, bq, bk, q_offset, kv_offset)
-            keep = _keep_mask(seed_ref[0], bh, rows, cols, dropout_rate)
-            p_acc = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        else:
-            p_acc = p
-        # p quantized to V's dtype for the PV matmul (the fmha/flash
-        # convention — the reference kernel holds P in fp16)
-        acc_new = acc_sc[...] * alpha[:, None] + jax.lax.dot_general(
-            p_acc.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new[:, None], l_sc.shape)
-        acc_sc[...] = acc_new
+        def tile(j, carry, plan):
+            at = _at(j * sub_k, sub_k)
+            k = k_ref[0, 0, at, :]
+            v = v_ref[0, 0, at, :]
+            k_pos = _k_pos(col0 + j * sub_k, sub_k)
+            seg_k = seg_k_ref[0, at, :] if has_segments else None
+            out = []
+            for (lane0, lanes, rows, compare_from), (m, l, acc) in zip(
+                    plan, carry):
+                q_pos = _q_pos(row0 + lane0, lanes)
+                s = _masked(
+                    _scores(k[:rows], q[lane0:lane0 + lanes], scale),
+                    NEG_INF, compare_from, q_pos, k_pos[:rows],
+                    (seg_q_ref[0, qi, :, lane0:lane0 + lanes]
+                     if has_segments else None),
+                    seg_k[:rows] if has_segments else None)
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                # Guard the all-masked row: with m_new == NEG_INF,
+                # exp(s - m_new) would be exp(0) = 1 per masked entry
+                # (phantom mean(V) mass); exp(s - 0) = exp(NEG_INF) = 0 is
+                # what we want.
+                m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+                p = jnp.exp(s - m_safe)
+                alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
+                l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                if dropout_rate > 0.0:
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                                      dropout_rate)
+                    p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)),
+                                  0.0)
+                # p quantized to V's dtype for the PV matmul (the
+                # fmha/flash convention — the reference kernel holds P in
+                # fp16)
+                acc_new = acc * alpha + jax.lax.dot_general(
+                    v[:rows], p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                out.append((m_new, l_new, acc_new))
+            return tuple(out)
 
-    if causal:
-        # Causal block skipping: a block whose max row < min col is fully
-        # masked — no MXU work (its K/V copy is also elided via the index
-        # map clamp in _fwd_call).
-        run = (q_offset + (iq + 1) * bq - 1) >= (kv_offset + jk * bk)
-        pl.when(run)(_body)
-    else:
-        _body()
+        def load(plan):
+            return tuple(
+                tuple(sc[qi, :, lane0:lane0 + lanes]
+                      for sc in (m_sc, l_sc, acc_sc))
+                for lane0, lanes, _, _ in plan)
+
+        def store(plan, carry):
+            for (lane0, lanes, _, _), strip in zip(plan, carry):
+                for sc, x in zip((m_sc, l_sc, acc_sc), strip):
+                    sc[qi, :, lane0:lane0 + lanes] = x
+
+        _visit(tile, load, store, plans,
+               *_k_ranges(causal, row0, col0, sub_q, sub_k, bk // sub_k))
+
+    jax.lax.fori_loop(0, bq // sub_q, q_block, None)
 
     @pl.when(jk == num_kb - 1)
     def _finalize():
-        l_fin = l_sc[:, 0]
-        m_fin = m_sc[:, 0]
-        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0, 0] = (acc_sc[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l_fin == 0.0, NEG_INF,
-                                  m_fin + jnp.log(l_safe))[:, None]
+        def q_block_out(qi, _):
+            l_fin = l_sc[qi]
+            l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+            o_ref[0, 0, _at(qi * sub_q, sub_q), :] = (
+                (acc_sc[qi] / l_safe).T.astype(o_ref.dtype))
+            lse_ref[0, 0, qi] = jnp.where(l_fin == 0.0, NEG_INF,
+                                          m_sc[qi] + jnp.log(l_safe))
+
+        jax.lax.fori_loop(0, bq // sub_q, q_block_out, None)
 
 
 # ---------------------------------------------------------------------------
@@ -313,27 +529,18 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_p(s, lse, mask):
-    """exp(s - lse) with the fully-masked-row guard (lse == NEG_INF)."""
-    lse_safe = jnp.where(lse <= NEG_INF * 0.5, 0.0, lse)
-    p = jnp.exp(s - lse_safe[:, None])
-    if mask is not None:
-        p = jnp.where(mask, p, 0.0)
-    return p
+def _bwd_p(s, lse):
+    """exp(s - lse) with the fully-masked-row guard (lse == NEG_INF).  The
+    backward pass masks ``p``, once: an unmasked ``s`` may overflow the exp
+    and the select drops it."""
+    return jnp.exp(s - jnp.where(lse <= NEG_INF * 0.5, 0.0, lse))
 
 
 def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-               dropout_rate):
-    i = 6
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    seg_q_ref = seg_k_ref = seed_ref = None
-    if has_segments:
-        seg_q_ref, seg_k_ref = refs[i], refs[i + 1]
-        i += 2
-    if dropout_rate > 0.0:
-        seed_ref = refs[i]
-        i += 1
-    dq_ref, dq_sc = refs[i:i + 2]
+               dropout_rate, sub_q, sub_k, strip):
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, seg_k_ref,
+     seed_ref, rest) = _unpack(refs, 6, has_segments, dropout_rate)
+    dq_ref, dq_sc = rest
 
     bq = q_ref.shape[2]
     bk = k_ref.shape[2]
@@ -341,68 +548,78 @@ def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     iq = pl.program_id(1)
     jk = pl.program_id(2)
     num_kb = pl.num_programs(2)
+    col0 = kv_offset + jk * bk
+    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
 
     @pl.when(jk == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def _body():
+    def q_block(qi, _):
         # input-dtype matmuls, fp32 accumulate (see _fwd_kernel note)
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
+        at_q = _at(qi * sub_q, sub_q)
+        q = _prescale(q_ref[0, 0, at_q, :], scale)
+        do = do_ref[0, 0, at_q, :]
+        row0 = q_offset + iq * bq + qi * sub_q
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        seg_q = seg_q_ref[0, :, 0] if has_segments else None
-        seg_k = seg_k_ref[0, :, 0] if has_segments else None
-        mask = _block_mask(iq, jk, bq, bk, causal, q_offset, kv_offset,
-                           seg_q, seg_k)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = _bwd_p(s, lse, mask)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dropout_rate > 0.0:
-            rows, cols = _coords(iq, jk, bq, bk, q_offset, kv_offset)
-            keep = _keep_mask(seed_ref[0], bh, rows, cols, dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_sc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def tile(j, carry, plan):
+            at = _at(j * sub_k, sub_k)
+            k = k_ref[0, 0, at, :]
+            v = v_ref[0, 0, at, :]
+            k_pos = _k_pos(col0 + j * sub_k, sub_k)
+            seg_k = seg_k_ref[0, at, :] if has_segments else None
+            out = []
+            for (lane0, lanes, rows, compare_from), dq in zip(plan, carry):
+                strip = slice(lane0, lane0 + lanes)
+                q_pos = _q_pos(row0 + lane0, lanes)
+                p = _masked(
+                    _bwd_p(_scores(k[:rows], q[strip], scale),
+                           lse_ref[0, 0, qi, :, strip]),
+                    0.0, compare_from, q_pos, k_pos[:rows],
+                    seg_q_ref[0, qi, :, strip] if has_segments else None,
+                    seg_k[:rows] if has_segments else None)
+                dp = jax.lax.dot_general(
+                    v[:rows], do[strip], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if dropout_rate > 0.0:
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                                      dropout_rate)
+                    dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)),
+                                   0.0)
+                # the softmax scale waits for _finalize
+                ds = p * (dp - delta_ref[0, 0, qi, :, strip])
+                out.append(dq + jax.lax.dot_general(
+                    k[:rows], ds.astype(k.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            return tuple(out)
 
-    if causal:
-        run = (q_offset + (iq + 1) * bq - 1) >= (kv_offset + jk * bk)
-        pl.when(run)(_body)
-    else:
-        _body()
+        def load(plan):
+            return tuple(dq_sc[qi, :, lane0:lane0 + lanes]
+                         for lane0, lanes, _, _ in plan)
+
+        def store(plan, carry):
+            for (lane0, lanes, _, _), dq in zip(plan, carry):
+                dq_sc[qi, :, lane0:lane0 + lanes] = dq
+
+        _visit(tile, load, store, plans,
+               *_k_ranges(causal, row0, col0, sub_q, sub_k, bk // sub_k))
+
+    jax.lax.fori_loop(0, bq // sub_q, q_block, None)
 
     @pl.when(jk == num_kb - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_sc[...].astype(dq_ref.dtype)
+        def q_block_out(qi, _):
+            dq_ref[0, 0, _at(qi * sub_q, sub_q), :] = (
+                (dq_sc[qi] * scale).T.astype(dq_ref.dtype))
+
+        jax.lax.fori_loop(0, bq // sub_q, q_block_out, None)
 
 
 def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-                dropout_rate):
-    i = 6
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    seg_q_ref = seg_k_ref = seed_ref = None
-    if has_segments:
-        seg_q_ref, seg_k_ref = refs[i], refs[i + 1]
-        i += 2
-    if dropout_rate > 0.0:
-        seed_ref = refs[i]
-        i += 1
-    dk_ref, dv_ref, dk_sc, dv_sc = refs[i:i + 4]
+                dropout_rate, sub_q, sub_k, strip):
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, seg_k_ref,
+     seed_ref, rest) = _unpack(refs, 6, has_segments, dropout_rate)
+    dk_ref, dv_ref, dk_sc, dv_sc = rest
 
     bk = k_ref.shape[2]
     bq = q_ref.shape[2]
@@ -410,63 +627,81 @@ def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     jk = pl.program_id(1)
     iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
+    row0 = q_offset + iq * bq
+    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
+    # dk and dv of a k block are carried in row blocks as tall as the
+    # shortest strip, so that a strip of a crossed tile adds to the rows it
+    # computed and to no others
+    blk = min(rows for plan in plans if plan for _, _, rows, _ in plan)
+    parts = [slice(r, r + blk) for r in range(0, sub_k, blk)]
 
     @pl.when(iq == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def _body():
+    def k_block(kj, _):
         # input-dtype matmuls, fp32 accumulate (see _fwd_kernel note)
-        q = q_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
+        at = _at(kj * sub_k, sub_k)
+        k = _prescale(k_ref[0, 0, at, :], scale)
+        v = v_ref[0, 0, at, :]
+        col0 = kv_offset + jk * bk + kj * sub_k
+        k_pos = _k_pos(col0, sub_k)
+        seg_k = seg_k_ref[0, at, :] if has_segments else None
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        seg_q = seg_q_ref[0, :, 0] if has_segments else None
-        seg_k = seg_k_ref[0, :, 0] if has_segments else None
-        mask = _block_mask(iq, jk, bq, bk, causal, q_offset, kv_offset,
-                           seg_q, seg_k)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = _bwd_p(s, lse, mask)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dropout_rate > 0.0:
-            rows, cols = _coords(iq, jk, bq, bk, q_offset, kv_offset)
-            keep = _keep_mask(seed_ref[0], bh, rows, cols, dropout_rate)
-            inv = 1.0 / (1.0 - dropout_rate)
-            p_drop = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        else:
-            p_drop = p
-        dv_sc[...] += jax.lax.dot_general(
-            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dk_sc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def tile(i, carry, plan):
+            dk, dv = (list(x) for x in carry)
+            at_q = _at(i * sub_q, sub_q)
+            q = q_ref[0, 0, at_q, :]
+            do = do_ref[0, 0, at_q, :]
+            for lane0, lanes, rows, compare_from in plan:
+                strip = slice(lane0, lane0 + lanes)
+                q_pos = _q_pos(row0 + i * sub_q + lane0, lanes)
+                p = _masked(
+                    _bwd_p(_scores(k[:rows], q[strip], scale),
+                           lse_ref[0, 0, i, :, strip]),
+                    0.0, compare_from, q_pos, k_pos[:rows],
+                    seg_q_ref[0, i, :, strip] if has_segments else None,
+                    seg_k[:rows] if has_segments else None)
+                dp = jax.lax.dot_general(
+                    v[:rows], do[strip], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if dropout_rate > 0.0:
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                                      dropout_rate)
+                    inv = 1.0 / (1.0 - dropout_rate)
+                    p_drop = jnp.where(keep, p * inv, 0.0)
+                    dp = jnp.where(keep, dp * inv, 0.0)
+                else:
+                    p_drop = p
+                dv_add = jnp.dot(p_drop.astype(do.dtype), do[strip],
+                                 preferred_element_type=jnp.float32)
+                # the softmax scale waits for _finalize
+                ds = p * (dp - delta_ref[0, 0, i, :, strip])
+                dk_add = jnp.dot(ds.astype(q.dtype), q[strip],
+                                 preferred_element_type=jnp.float32)
+                for r, part in enumerate(parts[:rows // blk]):
+                    dk[r] = dk[r] + dk_add[part]
+                    dv[r] = dv[r] + dv_add[part]
+            return tuple(dk), tuple(dv)
 
-    if causal:
-        run = (q_offset + (iq + 1) * bq - 1) >= (kv_offset + jk * bk)
-        pl.when(run)(_body)
-    else:
-        _body()
+        def load(plan):
+            return tuple(tuple(sc[_at(kj * sub_k + part.start, blk), :]
+                               for part in parts) for sc in (dk_sc, dv_sc))
+
+        def store(plan, carry):
+            for sc, blocks in zip((dk_sc, dv_sc), carry):
+                for part, x in zip(parts, blocks):
+                    sc[_at(kj * sub_k + part.start, blk), :] = x
+
+        _visit(tile, load, store, plans,
+               *_q_ranges(causal, row0, col0, sub_q, sub_k, bq // sub_q))
+
+    jax.lax.fori_loop(0, bk // sub_k, k_block, None)
 
     @pl.when(iq == num_qb - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_sc[...].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[...].astype(dv_ref.dtype)
 
 
@@ -487,66 +722,42 @@ def _causal_imin(j, bq, bk, q_offset, kv_offset, num_qb):
     return jnp.clip(imin, 0, num_qb - 1)
 
 
-def _specs_fwd(h, bq, bk, d, causal, q_offset, kv_offset, num_kb):
-    """Block specs for the (bh, i, j) grid (k innermost).  Under causal the
-    k/v (and seg_k) index maps clamp j into the live range so skipped
-    blocks re-reference the previous block and Pallas elides the copy."""
+def _specs(h, bq, bk, d, sub_q, q_inner, causal, q_offset, kv_offset,
+           n_inner):
+    """Block specs of the fetched tiles for the ``(bh, i, j)`` grid of the
+    forward and dq calls (k innermost) or, with ``q_inner``, the
+    ``(bh, j, i)`` grid of the dkv call.  Under causal the index maps of
+    the inner side clamp into the live range, so a fetched tile with
+    nothing to visit re-references its neighbour and Pallas elides the
+    copy (only where a head does not fit one tile: else ``n_inner`` is 1)."""
 
-    def q_idx(bh_, i, j):
-        return (bh_ // h, bh_ % h, i, 0)
-
-    def k_idx(bh_, i, j):
-        if causal:
+    def ij(a, b_):
+        i, j = (b_, a) if q_inner else (a, b_)
+        if causal and q_inner:
+            i = jnp.maximum(i, _causal_imin(j, bq, bk, q_offset, kv_offset,
+                                            n_inner))
+        elif causal:
             j = jnp.minimum(j, _causal_jmax(i, bq, bk, q_offset, kv_offset,
-                                            num_kb))
-        return (bh_ // h, bh_ % h, j, 0)
+                                            n_inner))
+        return i, j
 
-    def segq_idx(bh_, i, j):
-        return (bh_ // h, i, 0)
+    def q_idx(bh_, a, b_):
+        return (bh_ // h, bh_ % h, ij(a, b_)[0], 0)
 
-    def segk_idx(bh_, i, j):
-        if causal:
-            j = jnp.minimum(j, _causal_jmax(i, bq, bk, q_offset, kv_offset,
-                                            num_kb))
-        return (bh_ // h, j, 0)
+    def k_idx(bh_, a, b_):
+        return (bh_ // h, bh_ % h, ij(a, b_)[1], 0)
 
     return {
         "q": pl.BlockSpec((1, 1, bq, d), q_idx),
         "k": pl.BlockSpec((1, 1, bk, d), k_idx),
-        "q_lse": pl.BlockSpec((1, 1, bq, 1), q_idx),
-        "seg_q": pl.BlockSpec((1, bq, 1), segq_idx),
-        "seg_k": pl.BlockSpec((1, bk, 1), segk_idx),
-        "seed": pl.BlockSpec(memory_space=pltpu.SMEM),
-    }
-
-
-def _specs_dkv(h, bq, bk, d, causal, q_offset, kv_offset, num_qb):
-    """Block specs for the transposed (bh, j, i) grid (q innermost)."""
-
-    def q_idx(bh_, j, i):
-        if causal:
-            i = jnp.maximum(i, _causal_imin(j, bq, bk, q_offset, kv_offset,
-                                            num_qb))
-        return (bh_ // h, bh_ % h, i, 0)
-
-    def k_idx(bh_, j, i):
-        return (bh_ // h, bh_ % h, j, 0)
-
-    def segq_idx(bh_, j, i):
-        if causal:
-            i = jnp.maximum(i, _causal_imin(j, bq, bk, q_offset, kv_offset,
-                                            num_qb))
-        return (bh_ // h, i, 0)
-
-    def segk_idx(bh_, j, i):
-        return (bh_ // h, j, 0)
-
-    return {
-        "q": pl.BlockSpec((1, 1, bq, d), q_idx),
-        "k": pl.BlockSpec((1, 1, bk, d), k_idx),
-        "q_lse": pl.BlockSpec((1, 1, bq, 1), q_idx),
-        "seg_q": pl.BlockSpec((1, bq, 1), segq_idx),
-        "seg_k": pl.BlockSpec((1, bk, 1), segk_idx),
+        "q_row": pl.BlockSpec(
+            (1, 1, bq // sub_q, 1, sub_q),
+            lambda bh_, a, b_: (bh_ // h, bh_ % h, ij(a, b_)[0], 0, 0)),
+        "seg_q": pl.BlockSpec(
+            (1, bq // sub_q, 1, sub_q),
+            lambda bh_, a, b_: (bh_ // h, ij(a, b_)[0], 0, 0)),
+        "seg_k": pl.BlockSpec(
+            (1, bk, 1), lambda bh_, a, b_: (bh_ // h, ij(a, b_)[1], 0)),
         "seed": pl.BlockSpec(memory_space=pltpu.SMEM),
     }
 
@@ -566,52 +777,80 @@ def _seed_array(dropout_seed):
     return jnp.asarray(dropout_seed, jnp.int32).reshape((1,))
 
 
-def _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale, block_q, block_k,
-              q_offset, kv_offset, dropout_rate):
+def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, scale, block_q,
+          block_k, q_offset, kv_offset, dropout_rate, q_inner=False):
+    """What the three calls share: tiles, padded segment ids, block specs,
+    the kernel closed over its static arguments, the optional inputs with
+    their specs, the tiling's gauges, and the ``pallas_call`` itself as
+    ``plan.call(in_specs, out_specs, out_shape, scratch_shapes, *args)``."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq, bk, sq_p, sk_p = _pick_blocks(sq, sk, block_q, block_k)
+    bq, bk, sub_q, sub_k, sq_p, sk_p = _pick_blocks(
+        sq, sk, d, q.dtype.itemsize, block_q, block_k)
     seg_q, seg_k = _prep_segments(
-        seg_q, seg_k, b, sq, sk, sq_p, sk_p,
+        seg_q, seg_k, b, sq, sk, sq_p, sk_p, sub_q,
         need=(seg_q is not None or seg_k is not None
               or sq_p != sq or sk_p != sk))
     has_segments = seg_q is not None
-    qp, kp, vp = _pad_dim2(q, sq_p), _pad_dim2(k, sk_p), _pad_dim2(v, sk_p)
-    num_kb = sk_p // bk
-    sp = _specs_fwd(h, bq, bk, d, causal, q_offset, kv_offset, num_kb)
-
+    grid = ((b * h, sk_p // bk, sq_p // bq) if q_inner
+            else (b * h, sq_p // bq, sk_p // bk))
+    sp = _specs(h, bq, bk, d, sub_q, q_inner, causal, q_offset, kv_offset,
+                grid[2])
     kernel = functools.partial(
-        _fwd_kernel, scale=_resolve(scale, d), causal=causal,
+        kernel, scale=_resolve(scale, d), causal=causal,
         q_offset=q_offset, kv_offset=kv_offset, has_segments=has_segments,
-        dropout_rate=dropout_rate,
-    )
-    in_specs = [sp["q"], sp["k"], sp["k"]]
-    args = [qp, kp, vp]
+        dropout_rate=dropout_rate, sub_q=sub_q, sub_k=sub_k, strip=strip)
+    extra_specs, extra = [], []
     if has_segments:
-        in_specs += [sp["seg_q"], sp["seg_k"]]
-        args += [seg_q, seg_k]
+        extra_specs += [sp["seg_q"], sp["seg_k"]]
+        extra += [seg_q, seg_k]
     if dropout_rate > 0.0:
-        in_specs += [sp["seed"]]
-        args += [_seed_array(seed)]
+        extra_specs += [sp["seed"]]
+        extra += [_seed_array(seed)]
+    _record_tiling(sq, sk, bq, sub_q, sub_k, strip, sq_p, sk_p, causal,
+                   q_offset, kv_offset, has_segments or dropout_rate > 0.0)
 
-    out, lse4 = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq_p // bq, num_kb),
-        in_specs=in_specs,
-        out_specs=[sp["q"], sp["q_lse"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            _scratch((bq, _LANES)),
-            _scratch((bq, _LANES)),
-            _scratch((bq, d)),
-        ],
-        compiler_params=_flash_compiler_params(),
-        interpret=platform.pallas_interpret(),
-    )(*args)
-    return out[:, :, :sq], lse4[:, :, :sq, 0]
+    def call(in_specs, out_specs, out_shape, scratch_shapes, *args):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[sp[name] for name in in_specs] + extra_specs,
+            out_specs=jax.tree.map(lambda name: sp[name], out_specs),
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                            for shape in scratch_shapes],
+            compiler_params=_flash_compiler_params(),
+            interpret=platform.pallas_interpret(),
+        )(*args, *extra)
+
+    return types.SimpleNamespace(call=call, bq=bq, bk=bk, sub_q=sub_q,
+                                 sq_p=sq_p, sk_p=sk_p)
+
+
+def _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale, block_q, block_k,
+              q_offset, kv_offset, dropout_rate):
+    b, h, sq, d = q.shape
+    plan = _plan(_fwd_kernel, _STRIP_FWD, q, k, seg_q, seg_k, seed, causal,
+                 scale, block_q, block_k, q_offset, kv_offset, dropout_rate)
+    sub_q, sq_p, sk_p = plan.sub_q, plan.sq_p, plan.sk_p
+    nq = plan.bq // sub_q
+    out, lse = plan.call(
+        ["q", "k", "k"], ["q", "q_row"],
+        [jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
+         jax.ShapeDtypeStruct((b, h, sq_p // sub_q, 1, sub_q), jnp.float32)],
+        [(nq, 1, sub_q), (nq, 1, sub_q), (nq, d, sub_q)],
+        _pad_dim2(q, sq_p), _pad_dim2(k, sk_p), _pad_dim2(v, sk_p))
+    return out[:, :, :sq], lse.reshape(b, h, sq_p)[:, :, :sq]
+
+
+_BWD_IN = ["q", "k", "k", "q", "q_row", "q_row"]
+
+
+def _bwd_args(plan, q, k, v, do, lse, delta):
+    sub_q, sq_p, sk_p = plan.sub_q, plan.sq_p, plan.sk_p
+    return [_pad_dim2(q, sq_p), _pad_dim2(k, sk_p), _pad_dim2(v, sk_p),
+            _pad_dim2(do, sq_p), _q_rows(lse, sq_p, sub_q),
+            _q_rows(delta, sq_p, sub_q)]
 
 
 def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
@@ -626,44 +865,13 @@ def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
     """
     block_q, block_k = resolve_default_blocks(block_q, block_k)
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq, bk, sq_p, sk_p = _pick_blocks(sq, sk, block_q, block_k)
-    seg_q, seg_k = _prep_segments(
-        segment_ids_q, segment_ids_kv, b, sq, sk, sq_p, sk_p,
-        need=(segment_ids_q is not None or segment_ids_kv is not None
-              or sq_p != sq or sk_p != sk))
-    has_segments = seg_q is not None
-    num_kb = sk_p // bk
-    sp = _specs_fwd(h, bq, bk, d, causal, q_offset, kv_offset, num_kb)
-
-    kernel = functools.partial(
-        _dq_kernel, scale=_resolve(scale, d), causal=causal,
-        q_offset=q_offset, kv_offset=kv_offset, has_segments=has_segments,
-        dropout_rate=dropout_rate,
-    )
-    in_specs = [sp["q"], sp["k"], sp["k"], sp["q"], sp["q_lse"],
-                sp["q_lse"]]
-    args = [_pad_dim2(q, sq_p), _pad_dim2(k, sk_p), _pad_dim2(v, sk_p),
-            _pad_dim2(do, sq_p),
-            _pad_dim2(lse[..., None], sq_p),
-            _pad_dim2(delta[..., None], sq_p)]
-    if has_segments:
-        in_specs += [sp["seg_q"], sp["seg_k"]]
-        args += [seg_q, seg_k]
-    if dropout_rate > 0.0:
-        in_specs += [sp["seed"]]
-        args += [_seed_array(dropout_seed)]
-
-    dq = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq_p // bq, num_kb),
-        in_specs=in_specs,
-        out_specs=sp["q"],
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
-        scratch_shapes=[_scratch((bq, d))],
-        compiler_params=_flash_compiler_params(),
-        interpret=platform.pallas_interpret(),
-    )(*args)
+    plan = _plan(_dq_kernel, _STRIP_BWD, q, k, segment_ids_q, segment_ids_kv,
+                 dropout_seed, causal, scale, block_q, block_k, q_offset,
+                 kv_offset, dropout_rate)
+    dq = plan.call(
+        _BWD_IN, "q", jax.ShapeDtypeStruct((b, h, plan.sq_p, d), q.dtype),
+        [(plan.bq // plan.sub_q, d, plan.sub_q)],
+        *_bwd_args(plan, q, k, v, do, lse, delta))
     return dq[:, :, :sq]
 
 
@@ -673,48 +881,17 @@ def dkv_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
               segment_ids_kv=None, dropout_rate=0.0, dropout_seed=None):
     """(dk, dv) of one K/V chunk given the global ``lse``/``delta``."""
     block_q, block_k = resolve_default_blocks(block_q, block_k)
-    b, h, sq, d = q.shape
+    b, h, _, d = q.shape
     sk = k.shape[2]
-    bq, bk, sq_p, sk_p = _pick_blocks(sq, sk, block_q, block_k)
-    seg_q, seg_k = _prep_segments(
-        segment_ids_q, segment_ids_kv, b, sq, sk, sq_p, sk_p,
-        need=(segment_ids_q is not None or segment_ids_kv is not None
-              or sq_p != sq or sk_p != sk))
-    has_segments = seg_q is not None
-    num_qb = sq_p // bq
-    sp = _specs_dkv(h, bq, bk, d, causal, q_offset, kv_offset, num_qb)
-
-    kernel = functools.partial(
-        _dkv_kernel, scale=_resolve(scale, d), causal=causal,
-        q_offset=q_offset, kv_offset=kv_offset, has_segments=has_segments,
-        dropout_rate=dropout_rate,
-    )
-    in_specs = [sp["q"], sp["k"], sp["k"], sp["q"], sp["q_lse"],
-                sp["q_lse"]]
-    args = [_pad_dim2(q, sq_p), _pad_dim2(k, sk_p), _pad_dim2(v, sk_p),
-            _pad_dim2(do, sq_p),
-            _pad_dim2(lse[..., None], sq_p),
-            _pad_dim2(delta[..., None], sq_p)]
-    if has_segments:
-        in_specs += [sp["seg_q"], sp["seg_k"]]
-        args += [seg_q, seg_k]
-    if dropout_rate > 0.0:
-        in_specs += [sp["seed"]]
-        args += [_seed_array(dropout_seed)]
-
-    dk, dv = pl.pallas_call(
-        kernel,
-        grid=(b * h, sk_p // bk, num_qb),
-        in_specs=in_specs,
-        out_specs=[sp["k"], sp["k"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, sk_p, d), v.dtype),
-        ],
-        scratch_shapes=[_scratch((bk, d)), _scratch((bk, d))],
-        compiler_params=_flash_compiler_params(),
-        interpret=platform.pallas_interpret(),
-    )(*args)
+    plan = _plan(_dkv_kernel, _STRIP_BWD, q, k, segment_ids_q,
+                 segment_ids_kv, dropout_seed, causal, scale, block_q,
+                 block_k, q_offset, kv_offset, dropout_rate, q_inner=True)
+    dk, dv = plan.call(
+        _BWD_IN, ["k", "k"],
+        [jax.ShapeDtypeStruct((b, h, plan.sk_p, d), k.dtype),
+         jax.ShapeDtypeStruct((b, h, plan.sk_p, d), v.dtype)],
+        [(plan.bk, d), (plan.bk, d)],
+        *_bwd_args(plan, q, k, v, do, lse, delta))
     return dk[:, :, :sk], dv[:, :, :sk]
 
 

@@ -1,12 +1,17 @@
-"""Flash-attention vs XLA-softmax attention microbenchmark.
+"""Flash attention microbenchmark: the three Pallas calls timed apart.
 
-Times the Pallas flash kernels against the unfused BMM+softmax+BMM core
-(what ``CoreAttention`` uses when ``use_flash_attention=False``) for
-causal training shapes, fwd+bwd — the evidence for flipping the
-``use_flash_attention`` default (round-1 VERDICT "flash is never
-exercised where it matters").
+Times the forward call, ``dq_chunk`` and ``dkv_chunk`` of
+``apex_tpu/ops/flash_attention.py`` alone, causal, at the shape of a
+``gpt2-medium.train`` microbatch (``6, 16, 1024, 64``) and at head 128 /
+sequence 2048 (``4, 8, 2048, 128``), for each pair of fetched-tile sizes
+asked for, and beside them the unfused BMM+softmax+BMM core (what
+``CoreAttention`` runs when ``use_flash_attention=False``), forward and
+backward in one.  It prints the tiling gauges the module records at trace
+time.  No benchmark cell runs this: it is the block sweep of ROADMAP S1,
+kept so that the sweep can be repeated.
 
-    python examples/bench_flash_attention.py            # current device
+    python examples/bench_flash_attention.py                 # defaults
+    python examples/bench_flash_attention.py --blocks 256x512,1024x1024
 """
 
 import argparse
@@ -21,8 +26,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int, default=20)
+    ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--blocks", default="default",
+                    help="comma-separated BLOCK_QxBLOCK_K pairs; 'default' "
+                         "is what resolve_default_blocks gives")
+    ap.add_argument("--no-xla", action="store_true",
+                    help="skip the unfused comparison")
     args = ap.parse_args()
 
     if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
@@ -32,13 +42,35 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from apex_tpu.ops.flash_attention import flash_attention
+    from apex_tpu.observability.metrics import default_registry
+    from apex_tpu.ops.flash_attention import (
+        dkv_chunk,
+        dq_chunk,
+        flash_attention_with_lse,
+        resolve_default_blocks,
+    )
 
     on_tpu = jax.devices()[0].platform == "tpu"
     dtype = jnp.dtype(args.dtype)
-    shapes = ([(8, 12, 1024, 64), (4, 16, 2048, 64), (2, 16, 4096, 128)]
-              if on_tpu else [(1, 2, 256, 32)])
-    steps = args.steps if on_tpu else 3
+    shapes = ([(6, 16, 1024, 64), (4, 8, 2048, 128)]
+              if on_tpu else [(1, 2, 256, 64)])
+    steps = args.steps if on_tpu else 2
+    pairs = [resolve_default_blocks() if p == "default"
+             else tuple(int(x) for x in p.split("x"))
+             for p in args.blocks.split(",")]
+
+    def timed(fn, *xs):
+        fn = jax.jit(fn)
+        jax.block_until_ready(fn(*xs))
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = fn(*xs)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / steps * 1e3, 4)
+
+    def gauges():
+        snap = default_registry().snapshot()
+        return {k: v for k, v in snap.items() if k.startswith("flash/")}
 
     def xla_attn(q, k, v):
         d = q.shape[-1]
@@ -51,41 +83,45 @@ def main():
         return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
     results = []
-    for b, h, s, d in shapes:
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(kk, (b, h, s, d), dtype) for kk in ks)
-
-        def bench(fn):
-            loss = jax.jit(jax.grad(
-                lambda q, k, v: jnp.sum(
-                    fn(q, k, v).astype(jnp.float32) ** 2),
-                argnums=(0, 1, 2)))
-            out = loss(q, k, v)
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                out = loss(q, k, v)
-            jax.block_until_ready(out)
-            return (time.perf_counter() - t0) / steps
-
-        t_flash = bench(lambda q, k, v: flash_attention(q, k, v,
-                                                        causal=True))
-        try:
-            t_xla = bench(xla_attn)
-        except Exception as e:  # O(s^2) scores can OOM at long seqlens
-            t_xla = None
-            print(f"xla path failed at s={s}: {e!r}", file=sys.stderr)
-        results.append({
-            "shape": [b, h, s, d],
-            "t_flash_ms": round(t_flash * 1e3, 3),
-            "t_xla_ms": round(t_xla * 1e3, 3) if t_xla else None,
-            "speedup": round(t_xla / t_flash, 3) if t_xla else None,
-        })
+    for shape in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, do = (jax.random.normal(kk, shape, dtype) for kk in ks)
+        out, lse = flash_attention_with_lse(q, k, v, True)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        t_xla = None
+        if not args.no_xla:
+            try:
+                t_xla = timed(jax.grad(
+                    lambda q, k, v: jnp.sum(
+                        xla_attn(q, k, v).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2)), q, k, v)
+            except Exception as e:  # O(s^2) scores can OOM at long seqlens
+                print(f"xla path failed at {shape}: {e!r}", file=sys.stderr)
+        for bq, bk in pairs:
+            kw = dict(causal=True, block_q=bq, block_k=bk)
+            row = {
+                "shape": list(shape), "block_q": bq, "block_k": bk,
+                "fwd_ms": timed(lambda q, k, v: flash_attention_with_lse(
+                    q, k, v, True, None, bq, bk), q, k, v),
+                "dq_ms": timed(lambda *xs: dq_chunk(*xs, **kw),
+                               q, k, v, do, lse, delta),
+                "dkv_ms": timed(lambda *xs: dkv_chunk(*xs, **kw),
+                                q, k, v, do, lse, delta),
+                "xla_fwd_bwd_ms": t_xla,
+                "gauges": gauges(),
+            }
+            # a train step runs the forward twice (jax.checkpoint)
+            row["fwd_x2_dq_dkv_ms"] = round(
+                2 * row["fwd_ms"] + row["dq_ms"] + row["dkv_ms"], 4)
+            results.append(row)
+            print(json.dumps(row), flush=True)
 
     print(json.dumps({
         "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "dtype": str(dtype),
-        "fwd_bwd": True,
+        "steps": steps,
         "results": results,
     }))
 

@@ -165,21 +165,8 @@ def test_gpt_flash_attention_matches_fused_softmax():
                                rtol=2e-5, atol=2e-5)
 
 
-def naive_attention_masked(q, k, v, causal, seg_q=None, seg_k=None,
-                           scale=None):
-    d = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    sq, sk = s.shape[-2:]
-    mask = jnp.ones((q.shape[0], 1, sq, sk), bool)
-    if causal:
-        mask = mask & jnp.tril(jnp.ones((sq, sk), bool))
-    if seg_q is not None:
-        mask = mask & (seg_q[:, None, :, None] == seg_k[:, None, None, :])
-    s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows -> zero output
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
+def naive_attention_masked(q, k, v, causal, seg_q=None, seg_k=None):
+    return _reference(q, k, v, causal, seg_q=seg_q, seg_k=seg_k)
 
 
 @slow
@@ -337,6 +324,227 @@ def test_flash_dropout_grad_matches_masked_reference():
                                    rtol=2e-4, atol=2e-4)
 
 
+def _reference(q, k, v, causal, q_offset=0, kv_offset=0, seg_q=None,
+               seg_k=None, keep=None, rate=0.0):
+    """Naive attention in float32 with the masks at global positions (a q
+    shard at ``q_offset`` against a k shard at ``kv_offset``); fully masked
+    rows give zero; ``keep`` is a dropout keep mask applied after softmax."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    sq, sk = s.shape[-2:]
+    mask = jnp.ones((q.shape[0], 1, sq, sk), bool)
+    if causal:
+        mask &= ((jnp.arange(sq)[:, None] + q_offset)
+                 >= (jnp.arange(sk)[None, :] + kv_offset))
+    if seg_q is not None:
+        mask &= seg_q[:, None, :, None] == seg_k[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    p = jnp.where(jnp.isnan(p), 0.0, p)
+    if keep is not None:
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def _segments(b, s, edges):
+    """``[b, s]`` ids that step up at each of ``edges``."""
+    ids = sum((jnp.arange(s) >= e).astype(jnp.int32) for e in edges)
+    return jnp.broadcast_to(ids, (b, s))
+
+
+# sq, sk, d, dtype, causal, block_q, block_k, q_offset, kv_offset, segment
+# edges (q, k) or None.  A compute sub-tile is 128 x 128 and a fetched tile
+# block_q / block_k rows (the head, where the blocks allow it), so these
+# cross both kinds of edge.
+_TILE_CASES = {
+    "s130_pads_to_two_sub_tiles": (
+        130, 130, 64, "float32", True, None, None, 0, 0, None),
+    "s384_three_fetched_tiles_bf16": (
+        384, 384, 64, "bfloat16", True, 128, 128, 0, 0, None),
+    "s640_pads_to_three_tiles_of_two": (
+        640, 640, 64, "float32", True, 256, 256, 0, 0, None),
+    "s384_whole_head_one_step": (
+        384, 384, 64, "float32", True, 512, 512, 0, 0, None),
+    "d128_bf16_not_causal": (
+        256, 384, 128, "bfloat16", False, 128, 256, 0, 0, None),
+    "d128_float32_causal": (
+        256, 256, 128, "float32", True, 128, 128, 0, 0, None),
+    "ring_chunk_behind_the_diagonal": (
+        256, 256, 64, "float32", True, 128, 128, 256, 128, None),
+    "ring_chunk_half_ahead_of_the_diagonal": (
+        256, 256, 64, "float32", True, 128, 128, 128, 256, None),
+    "offset_off_the_sub_tile_grid": (
+        256, 256, 64, "float32", True, 128, 128, 5, 0, None),
+    "segments_change_inside_a_sub_tile_causal": (
+        256, 256, 64, "float32", True, 128, 128, 0, 0,
+        ((70, 200), (70, 200))),
+    "segments_change_inside_a_sub_tile_padding": (
+        200, 330, 64, "float32", False, 128, 128, 0, 0,
+        ((150,), (90, 300))),
+    "cross_lengths": (
+        200, 330, 64, "float32", False, 128, 128, 0, 0, None),
+    "cross_lengths_short_keys": (
+        140, 40, 64, "bfloat16", False, None, None, 0, 0, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES), ids=list(_TILE_CASES))
+def test_flash_matches_reference_across_tile_edges(case):
+    """Forward, dq, dk and dv against naive attention on shapes that cross
+    sub-tile and fetched-tile edges."""
+    from apex_tpu.ops.flash_attention import flash_attention_with_lse
+
+    (sq, sk, d, dtype, causal, block_q, block_k, q_offset, kv_offset,
+     edges) = _TILE_CASES[case]
+    b, h = 1, 2
+    ks = jax.random.split(jax.random.PRNGKey(sq + sk + d), 4)
+    q = jax.random.normal(ks[0], (b, h, sq, d), dtype)
+    k, v = (jax.random.normal(kk, (b, h, sk, d), dtype) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (b, h, sq, d))
+    seg_q = seg_k = None
+    if edges is not None:
+        seg_q, seg_k = _segments(b, sq, edges[0]), _segments(b, sk, edges[1])
+
+    def flash(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, causal, None, block_q, block_k, q_offset, kv_offset,
+            segment_ids_q=seg_q, segment_ids_kv=seg_k)[0]
+
+    def reference(q, k, v):
+        return _reference(q, k, v, causal, q_offset, kv_offset, seg_q, seg_k)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    # bf16: p, ds and the outputs round to 8 bits of mantissa
+    tol, gtol = (2e-5, 2e-4) if dtype == "float32" else (2e-2, 6e-2)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(reference(q, k, v)), rtol=tol, atol=tol)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b_, np.float32),
+            rtol=gtol, atol=gtol, err_msg=name)
+
+
+def test_flash_dropout_mask_and_masked_rows_are_the_parents():
+    """The keep mask is the one the kernels had before they computed in
+    sub-tiles, bit for bit (a digest of it taken then), the kernels apply it
+    at global coordinates across sub-tile and fetched-tile edges, and a q
+    shard wholly ahead of its kv shard still gives zero output, ``lse`` of
+    ``NEG_INF`` and zero gradients."""
+    import hashlib
+
+    from apex_tpu.ops.flash_attention import (
+        NEG_INF,
+        _keep_mask,
+        dkv_chunk,
+        dq_chunk,
+        flash_attention_with_lse,
+    )
+
+    rows = jnp.arange(300, dtype=jnp.int32)[:, None] + 7
+    cols = jnp.arange(260, dtype=jnp.int32)[None, :] + 3
+    mask = np.asarray(_keep_mask(jnp.int32(11), 3, rows, cols, 0.25))
+    assert int(mask.sum()) == 58601
+    assert hashlib.sha256(
+        np.packbits(mask).tobytes()).hexdigest()[:16] == "9cf3625d859595d1"
+    # laid out as the kernels do it: q positions a row, k positions a column
+    np.testing.assert_array_equal(
+        np.asarray(_keep_mask(jnp.int32(11), 3, rows.T, cols.T, 0.25)).T,
+        mask)
+
+    b, h, s, d = 1, 2, 256, 64
+    rate, seed, q_offset, kv_offset = 0.25, 11, 128, 0
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q, k, v, w = (jax.random.normal(kk, (b, h, s, d)) for kk in ks)
+    keep = jnp.stack([_keep_mask(
+        jnp.int32(seed), bh,
+        jnp.arange(s, dtype=jnp.int32)[:, None] + q_offset,
+        jnp.arange(s, dtype=jnp.int32)[None, :] + kv_offset, rate)
+        for bh in range(b * h)]).reshape(b, h, s, s)
+
+    def flash(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, True, None, 128, 128, q_offset, kv_offset,
+            dropout_rate=rate, dropout_seed=seed)[0]
+
+    def reference(q, k, v):
+        return _reference(q, k, v, True, q_offset, kv_offset, keep=keep,
+                          rate=rate)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(reference(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *x: jnp.sum(flash(*x) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *x: jnp.sum(reference(*x) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+    # the kv shard lies wholly after the q shard: every row fully masked
+    out, lse = flash_attention_with_lse(q, k, v, True, None, 128, 128,
+                                        0, s + 64)
+    assert np.all(np.asarray(out) == 0.0)
+    assert np.all(np.asarray(lse) <= NEG_INF * 0.5)
+    delta = jnp.sum(w * out, axis=-1)
+    kw = dict(causal=True, block_q=128, block_k=128, kv_offset=s + 64)
+    assert np.all(np.asarray(dq_chunk(q, k, v, w, lse, delta, **kw)) == 0.0)
+    for grad in dkv_chunk(q, k, v, w, lse, delta, **kw):
+        assert np.all(np.asarray(grad) == 0.0)
+
+
+@pytest.mark.parametrize("causal,want", [
+    # forward: two strips of 512 q lanes, 512 and 1024 k rows
+    (True, {"fwd": (48, 32), "dq": (40, 16), "dkv": (40, 16)}),
+    (False, {"fwd": (64, 0), "dq": (64, 0), "dkv": (64, 0)}),
+], ids=["causal", "not_causal"])
+def test_flash_tiling_gauges(causal, want):
+    """At ``(1, 2, 1024, 64)`` a head is 8 x 8 sub-tiles of 128 x 128 and
+    one fetched tile; causal, the strips of the one computed tile stop at
+    their diagonal blocks (36 sub-tiles lie on or under the diagonal, 8 of
+    them on it: strips one sub-tile wide would compute just those, and
+    measured slower, PERF.md §6).  Traced only, nothing runs."""
+    from apex_tpu.observability.metrics import default_registry
+    from apex_tpu.ops.flash_attention import dkv_chunk, dq_chunk
+
+    x = jax.ShapeDtypeStruct((1, 2, 1024, 64), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((1, 2, 1024), jnp.float32)
+    names = ("flash/sub_tiles", "flash/sub_tiles_masked",
+             "flash/live_score_share", "flash/fetch_tile_rows")
+    reg = default_registry()
+    calls = {
+        "fwd": lambda: jax.eval_shape(
+            lambda q, k, v: flash_attention(q, k, v, causal=causal),
+            x, x, x),
+        "dq": lambda: jax.eval_shape(
+            lambda *a: dq_chunk(*a, causal=causal), x, x, x, x, row, row),
+        "dkv": lambda: jax.eval_shape(
+            lambda *a: dkv_chunk(*a, causal=causal), x, x, x, x, row, row),
+    }
+    scores = 1024 * 1025 // 2 if causal else 1024 * 1024
+    for name, call in calls.items():
+        for gauge in names:
+            reg.gauge(gauge).set(-1.0)
+        call()
+        visited, masked, share, fetched = (
+            reg.gauge(gauge).value for gauge in names)
+        assert (visited, masked) == want[name], name
+        assert share == pytest.approx(scores / (visited * 128 * 128)), name
+        assert fetched == 1024, name
+
+    # one sub-tile wide strips, as a block of 128 forces them: the 36 and 8
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=128, block_k=128), x, x, x)
+    got = tuple(reg.gauge(gauge).value for gauge in names)
+    assert got[:2] == ((36, 8) if causal else (64, 0))
+    assert round(got[2], 2) == (0.89 if causal else 1.0)
+    assert got[3] == 128
+
+
 _Q, _K = "APEX_TPU_FLASH_BLOCK_Q", "APEX_TPU_FLASH_BLOCK_K"
 
 # What a process on a v5e would see, with a tuned record planted where the
@@ -354,20 +562,20 @@ print(list(resolve_default_blocks()), len(asked))
 
 
 @pytest.mark.parametrize("args,env,want,warns,planted", [
-    ((None, None), {}, (256, 512), False, False),
+    ((None, None), {}, (2048, 2048), False, False),
     ((128, 64), {_Q: "32", _K: "32"}, (128, 64), False, False),
     ((None, None), {_Q: "128", _K: "1024"}, (128, 1024), False, False),
     ((None, 128), {_Q: "64"}, (64, 128), False, False),
-    ((None, None), {_Q: "wide"}, (256, 512), True, False),
-    ((None, None), {_Q: "128", _K: "0"}, (128, 512), True, False),
-    ((None, None), {_K: "-8"}, (256, 512), True, False),
-    ((None, None), {}, (256, 512), False, True),
+    ((None, None), {_Q: "wide"}, (2048, 2048), True, False),
+    ((None, None), {_Q: "128", _K: "0"}, (128, 2048), True, False),
+    ((None, None), {_K: "-8"}, (2048, 2048), True, False),
+    ((None, None), {}, (2048, 2048), False, True),
 ], ids=["nothing_set", "arguments_win", "env_wins_over_default",
         "per_dimension", "malformed_env_warns", "zero_env_warns",
         "negative_env_warns", "planted_tuned_file_ignored"])
 def test_resolve_default_blocks(args, env, want, warns, planted,
                                 monkeypatch, tmp_path):
-    """Argument, else the environment knob, else 256/512 — and nothing
+    """Argument, else the environment knob, else 2048/2048 — and nothing
     else: no file in the checkout and no look at the device decides the
     train cell's tiles."""
     for name in (_Q, _K):
