@@ -73,6 +73,15 @@ class TrainStats(NamedTuple):
     ``moe_aux``          — per-microbatch MoE auxiliary loss ``[m]``
                            (``None`` for dense models / trainers without
                            microbatch structure).
+    ``moe_pairs``        — int32 ``[microbatches, expert layers, held
+                           experts]``: the ``(token, expert)`` pairs each
+                           held expert of each dropless expert layer took
+                           (``None`` for a model without such layers).
+    ``moe_choices``      — int32 ``[microbatches, expert layers, tokens,
+                           top_k]``: the experts each token's router chose
+                           (what a comparison with another precision
+                           follows; ``None`` likewise; the logger leaves
+                           it on the device).
     """
 
     loss: jnp.ndarray
@@ -82,6 +91,8 @@ class TrainStats(NamedTuple):
     loss_scale: jnp.ndarray
     skipped_steps: jnp.ndarray
     moe_aux: Optional[jnp.ndarray] = None
+    moe_pairs: Optional[jnp.ndarray] = None
+    moe_choices: Optional[jnp.ndarray] = None
 
 
 def stats_partition_specs(*, moe_aux: bool = False) -> TrainStats:
@@ -125,6 +136,8 @@ def train_stats(
     loss_scale=None,
     skipped_steps=None,
     moe_aux=None,
+    moe_pairs=None,
+    moe_choices=None,
 ) -> TrainStats:
     """Stats for **unsharded/replicated** global arrays (single-device
     trainers, host-side tests): everything is local arithmetic, so
@@ -150,6 +163,8 @@ def train_stats(
         skipped_steps=(jnp.int32(0) if skipped_steps is None
                        else jnp.asarray(skipped_steps, jnp.int32)),
         moe_aux=moe_aux,
+        moe_pairs=moe_pairs,
+        moe_choices=moe_choices,
     )
 
 
@@ -235,6 +250,8 @@ class PartialTrainStats(NamedTuple):
     loss_scale: jnp.ndarray
     skipped_steps: jnp.ndarray
     moe_aux: Optional[jnp.ndarray] = None
+    moe_pairs: Optional[jnp.ndarray] = None
+    moe_choices: Optional[jnp.ndarray] = None
 
     def finalize(self) -> TrainStats:
         """Host-side reduction of the partials matrix (numpy — call on
@@ -255,6 +272,8 @@ class PartialTrainStats(NamedTuple):
             loss_scale=np.float32(self.loss_scale),
             skipped_steps=np.int32(self.skipped_steps),
             moe_aux=self.moe_aux,
+            moe_pairs=self.moe_pairs,
+            moe_choices=self.moe_choices,
         )
 
 
@@ -328,6 +347,8 @@ def partial_train_stats(
     loss_scale=None,
     skipped_steps=None,
     moe_aux=None,
+    moe_pairs=None,
+    moe_choices=None,
 ) -> PartialTrainStats:
     """Assemble a :class:`PartialTrainStats` (defaults mirror
     :func:`train_stats`; ``grad_scale`` divides the reported grad norm
@@ -340,6 +361,8 @@ def partial_train_stats(
         skipped_steps=(jnp.int32(0) if skipped_steps is None
                        else jnp.asarray(skipped_steps, jnp.int32)),
         moe_aux=moe_aux,
+        moe_pairs=moe_pairs,
+        moe_choices=moe_choices,
     )
 
 
@@ -375,10 +398,12 @@ class TrainStatsLogger:
         """Blocking device→host fetch of one stats pytree
         (:class:`TrainStats` or :class:`PartialTrainStats` — partials
         are finalized here), flattened to plain floats/ints
-        (``moe_aux`` becomes a list)."""
+        (``moe_aux`` becomes a list, ``moe_pairs`` nested lists of ints).
+        ``moe_choices``, half a million ids a step, stays on the device:
+        whoever compares the choices reads ``stats.moe_choices`` itself."""
         import numpy as np
 
-        host = jax.device_get(stats)
+        host = jax.device_get(stats._replace(moe_choices=None))
         if hasattr(host, "finalize"):
             host = host.finalize()
         out = {}
@@ -391,6 +416,8 @@ class TrainStatsLogger:
             if arr.ndim == 0:
                 out[name] = (int(arr) if np.issubdtype(arr.dtype, np.integer)
                              else float(arr))
+            elif np.issubdtype(arr.dtype, np.integer):
+                out[name] = arr.tolist()
             else:
                 out[name] = [float(v) for v in arr.tolist()]
         return out
@@ -406,6 +433,20 @@ class TrainStatsLogger:
         """Unconditional fetch + record (the ``every_n`` hit path)."""
         values = self.fetch(stats)
         for name, val in values.items():
+            if name == "moe_pairs":
+                # pairs a step routed to the held experts, and how uneven:
+                # the busiest held expert over the mean, per layer and
+                # microbatch, averaged
+                import numpy as np
+
+                pairs = np.asarray(val, np.float64)
+                self.registry.gauge(f"{self.prefix}/moe_pairs").set(
+                    float(pairs.sum()))
+                mean = np.maximum(pairs.mean(-1), 1e-9)
+                self.registry.gauge(
+                    f"{self.prefix}/moe_expert_load_peak").set(
+                        float(np.mean(pairs.max(-1) / mean)))
+                continue
             if isinstance(val, list):  # per-microbatch vector: log the mean
                 if val:
                     self.registry.gauge(

@@ -85,6 +85,7 @@ q segment ids likewise as ``[b, s/sub_q, 1, sub_q]``, k segment ids as a
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
@@ -224,33 +225,57 @@ def _prep_segments(seg_q, seg_k, b, sq, sk, sq_p, sk_p, sub_q, need):
 
 
 def _record_tiling(sq, sk, bq, sub_q, sub_k, strip, sq_p, sk_p, causal,
-                   q_offset, kv_offset, every_masked):
+                   window, q_offset, kv_offset, every_masked):
     """Trace-time gauges of the tiling one call runs (host only): rows of the
     fetched q tile, the 128 x 128 sub-tiles computed and those that take a
     compare, per head, and the scores that count over the scores computed
-    (the kernels' own plans walked over the head)."""
+    (the kernels' own plans walked over the head, a window's tiles skipped
+    and compared as the kernels skip and compare them)."""
     rows = np.arange(sq) + q_offset - kv_offset
-    live = int(np.clip(rows + 1, 0, sk).sum()) if causal else sq * sk
-    free, crossed = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
+    live = (int((np.clip(rows + 1, 0, sk)
+                 - np.clip(rows + 1 - (window or sk + sq), 0, sk)).sum())
+            if causal else sq * sk)
+    plans = _plans(causal, window, q_offset, kv_offset, sub_q, sub_k, strip)
     blk = min(_SUB, sub_k)
     visited = masked = 0
     for r0 in range(q_offset, q_offset + sq_p, sub_q):
         for c0 in range(kv_offset, kv_offset + sk_p, sub_k):
-            if causal and c0 > r0 + sub_q - 1:
+            kind = _tile_kind(causal, window, r0, c0, sub_q, sub_k)
+            if kind is None:
                 continue
-            plan = crossed if causal and c0 + sub_k - 1 > r0 else free
-            for _, lanes, n_rows, compare_from in plan:
+            for st in plans[kind]:
+                n_rows = st.r1 - st.r0
+                compared = ((n_rows - st.diag if st.diag is not None else 0)
+                            + (st.edge if st.edge is not None else 0))
                 if every_masked:
-                    compare_from = 0
-                visited += lanes // _SUB * (n_rows // blk)
-                if compare_from is not None:
-                    masked += lanes // _SUB * ((n_rows - compare_from) // blk)
+                    compared = n_rows
+                visited += st.lanes // _SUB * (n_rows // blk)
+                masked += st.lanes // _SUB * (min(compared, n_rows) // blk)
     reg = default_registry()
     reg.gauge("flash/fetch_tile_rows").set(bq)
+    reg.gauge("flash/window").set(window or 0)
     reg.gauge("flash/sub_tiles").set(visited)
     reg.gauge("flash/sub_tiles_masked").set(masked)
     reg.gauge("flash/live_score_share").set(
         live / (visited * _SUB * blk) if visited else 0.0)
+
+
+def _tile_kind(causal, window, r0, c0, sub_q, sub_k):
+    """Which plan of :func:`_plans` the tile of q rows from ``r0`` and k
+    columns from ``c0`` is computed by (0 free, 1 the diagonal crosses it,
+    2 the window's edge does, 3 both), or None where it is not visited: the
+    rule the kernels' loop ranges follow, in plain integers."""
+    if not causal:
+        return 0
+    if c0 > r0 + sub_q - 1:
+        return None
+    diag = c0 + sub_k - 1 > r0
+    if window is None:
+        return 1 if diag else 0
+    if c0 + sub_k - 1 <= r0 - window:
+        return None
+    edge = c0 <= r0 + sub_q - 1 - window
+    return (3 if diag else 2) if edge else (1 if diag else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -331,33 +356,69 @@ def _k_pos(first, n):
     return first + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
 
 
-def _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip):
-    """How a tile under the diagonal and a tile the diagonal crosses are
-    computed: each a list of strips ``(lane0, lanes, rows, compare_from)``,
-    the q lanes of the strip, the k rows it computes and the row from which
-    it compares positions (None: nowhere).  Where every crossed tile has
-    the diagonal corner to corner (square tiles on a grid the offsets do
-    not shift) a strip stops at its diagonal block and compares there;
-    else a crossed tile computes and compares everything."""
+_Strip = collections.namedtuple("_Strip", "lane0 lanes r0 r1 diag edge")
+_Strip.__doc__ = """One strip of a computed tile: its ``lanes`` q lanes from
+``lane0``, the k rows ``[r0, r1)`` it computes, and among those rows (counted
+from ``r0``) the row ``diag`` from which it compares positions against the
+diagonal and the row ``edge`` up to which it compares them against the
+window's edge (None: nowhere)."""
+
+
+def _plans(causal, window, q_offset, kv_offset, sub_q, sub_k, strip):
+    """How a tile is computed, by what crosses it: ``(free, diagonal,
+    window's edge, both)``, each a list of :class:`_Strip` (None where no
+    tile of that kind can occur).  Where every tile that the diagonal
+    crosses has it corner to corner (square tiles on a grid the offsets do
+    not shift) a strip stops at its diagonal block and compares there; else
+    such a tile computes and compares everything.  Likewise the window's
+    edge: a strip starts at its edge block (and is no wider than a backward
+    strip, so that the forward skips what lies before the edge too).  A
+    tile that both cross computes and compares everything."""
     w = math.gcd(strip, sub_q)
     lanes = range(0, sub_q, w)
-    free = [(lane0, w, sub_k, None) for lane0 in lanes]
+    free = [_Strip(lane0, w, 0, sub_k, None, None) for lane0 in lanes]
     if not causal:
-        return free, None
-    if sub_q == sub_k and (q_offset - kv_offset) % sub_q == 0:
-        return free, [(lane0, w, lane0 + w, lane0) for lane0 in lanes]
-    return free, [(lane0, w, sub_k, 0) for lane0 in lanes]
+        return free, None, None, None
+    square = sub_q == sub_k
+    if square and (q_offset - kv_offset) % sub_q == 0:
+        diag = [_Strip(lane0, w, 0, lane0 + w, lane0, None)
+                for lane0 in lanes]
+    else:
+        diag = [_Strip(lane0, w, 0, sub_k, 0, None) for lane0 in lanes]
+    if window is None:
+        return free, diag, None, None
+    if square and (q_offset - kv_offset - window) % sub_q == 0:
+        we = math.gcd(w, _STRIP_BWD)
+        edge = [_Strip(lane0, we, lane0, sub_k, None, we)
+                for lane0 in range(0, sub_q, we)]
+    else:
+        edge = [_Strip(lane0, w, 0, sub_k, None, sub_k) for lane0 in lanes]
+    both = [_Strip(lane0, w, 0, sub_k, 0, sub_k) for lane0 in lanes]
+    return free, diag, edge, both
 
 
-def _masked(x, fill, compare_from, q_pos, k_pos, seg_q, seg_k):
-    """``x`` (``[rows, lanes]``) with ``fill`` where a score does not
-    count: other segments anywhere, and q positions before k positions on
-    the rows from ``compare_from`` on."""
+def _masked(x, fill, st, window, q_pos, k_pos, seg_q, seg_k):
+    """``x`` (the ``[rows, lanes]`` scores of strip ``st``, ``k_pos`` the
+    positions of its rows) with ``fill`` where a score does not count:
+    other segments anywhere, q positions before k positions on the rows
+    from ``st.diag`` on, and k positions before the window on the rows up
+    to ``st.edge``."""
     if seg_q is not None:
         keep = seg_q == seg_k
-        if compare_from is not None:
+        if st.diag is not None:
             keep &= q_pos >= k_pos
+        if st.edge is not None:
+            keep &= k_pos > q_pos - window
         return jnp.where(keep, x, fill)
+    if st.edge is not None:
+        n = st.edge
+        keep = k_pos[:n] > q_pos - window
+        if st.diag is not None:       # a tile both cross: every row, both
+            keep &= q_pos >= k_pos
+        top = jnp.where(keep, x[:n], fill)
+        return top if n == x.shape[0] else jnp.concatenate([top, x[n:]],
+                                                           axis=0)
+    compare_from = st.diag
     if compare_from is None:
         return x
     low = jnp.where(q_pos >= k_pos[compare_from:], x[compare_from:], fill)
@@ -366,41 +427,64 @@ def _masked(x, fill, compare_from, q_pos, k_pos, seg_q, seg_k):
     return jnp.concatenate([x[:compare_from], low], axis=0)
 
 
-def _visit(tile, load, store, plans, free, diag):
-    """Run ``tile(index, carry, plan=...)`` over the tiles that lie wholly
-    under the diagonal (``free``: a ``(lo, hi)`` range) and then over those
-    that cross it (``diag``), each with its plan; tiles wholly above the
-    diagonal are in neither range.  ``load(plan)`` reads the carry of a
-    plan's strips from scratch and ``store(plan, carry)`` puts it back.
-    Both loops stay rolled."""
-    for plan, (lo, hi) in zip(plans, (free, diag)):
+def _visit(tile, load, store, plans, ranges):
+    """Run ``tile(index, carry, plan=...)`` over each plan's range of tiles
+    (``ranges``: one ``(lo, hi)`` per plan of :func:`_plans`); tiles wholly
+    above the diagonal or before the window are in no range.
+    ``load(plan)`` reads the carry of a plan's strips from scratch and
+    ``store(plan, carry)`` puts it back.  The loops stay rolled."""
+    for plan, (lo, hi) in zip(plans, ranges):
         if plan is not None:
             store(plan, jax.lax.fori_loop(
                 lo, hi, functools.partial(tile, plan=plan), load(plan)))
 
 
-def _k_ranges(causal, row0, col0, sub_q, sub_k, n):
+def _ceil_div(x, m):
+    return (jnp.maximum(x, 0) + m - 1) // m
+
+
+def _k_ranges(causal, window, row0, col0, sub_q, sub_k, n):
     """For the q block whose first global row is ``row0``: the ranges of the
-    ``n`` k tiles from global column ``col0`` that it sees whole and that
-    cross its diagonal."""
+    ``n`` k tiles from global column ``col0`` that it sees whole, that cross
+    its diagonal, that the window's edge crosses and that both cross (the
+    order of :func:`_plans`)."""
     if not causal:
         return (0, n), (n, n)
     free = jnp.minimum(jnp.maximum(row0 - col0 + 1, 0) // sub_k, n)
     live = jnp.minimum(
         (jnp.maximum(row0 + sub_q - col0, 0) + sub_k - 1) // sub_k, n)
-    return (0, free), (free, live)
+    if window is None:
+        return (0, free), (free, live)
+    # tiles wholly before the window, and the first wholly inside it
+    dead = jnp.minimum(jnp.maximum(row0 - window - col0 + 1, 0) // sub_k,
+                       live)
+    whole = jnp.minimum(
+        _ceil_div(row0 + sub_q - window - col0, sub_k), live)
+    return ((whole, free), (jnp.maximum(whole, free), live),
+            (dead, jnp.minimum(whole, free)), (jnp.maximum(free, dead), whole))
 
 
-def _q_ranges(causal, row0, col0, sub_q, sub_k, n):
+def _q_ranges(causal, window, row0, col0, sub_q, sub_k, n):
     """For the k block whose first global column is ``col0``: the ranges of
-    the ``n`` q tiles from global row ``row0`` that see it whole and that
-    cross the diagonal over it."""
+    the ``n`` q tiles from global row ``row0`` that see it whole, that cross
+    the diagonal over it, that see it across the window's edge and that do
+    both (the order of :func:`_plans`)."""
     if not causal:
         return (0, n), (n, n)
     dead = jnp.minimum(jnp.maximum(col0 - row0, 0) // sub_q, n)
     free = jnp.minimum(
         (jnp.maximum(col0 + sub_k - 1 - row0, 0) + sub_q - 1) // sub_q, n)
-    return (free, n), (dead, free)
+    if window is None:
+        return (free, n), (dead, free)
+    # the first q tile that meets the window's edge, and the first past it
+    whole = jnp.minimum(
+        _ceil_div(col0 + window - sub_q + 1 - row0, sub_q), n)
+    last = jnp.minimum(
+        _ceil_div(col0 + sub_k - 1 + window - row0, sub_q), n)
+    whole = jnp.minimum(whole, last)
+    return ((free, whole), (dead, jnp.minimum(free, whole)),
+            (jnp.maximum(free, whole), last),
+            (jnp.maximum(dead, whole), jnp.minimum(free, last)))
 
 
 def _unpack(refs, n_in, has_segments, dropout_rate):
@@ -427,8 +511,8 @@ def _at(first, n):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-                dropout_rate, sub_q, sub_k, strip):
+def _fwd_kernel(*refs, scale, causal, window, q_offset, kv_offset,
+                has_segments, dropout_rate, sub_q, sub_k, strip):
     (q_ref, k_ref, v_ref), seg_q_ref, seg_k_ref, seed_ref, rest = _unpack(
         refs, 3, has_segments, dropout_rate)
     o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
@@ -440,7 +524,7 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     jk = pl.program_id(2)
     num_kb = pl.num_programs(2)
     col0 = kv_offset + jk * bk
-    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
+    plans = _plans(causal, window, q_offset, kv_offset, sub_q, sub_k, strip)
 
     @pl.when(jk == 0)
     def _init():
@@ -463,15 +547,16 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
             k_pos = _k_pos(col0 + j * sub_k, sub_k)
             seg_k = seg_k_ref[0, at, :] if has_segments else None
             out = []
-            for (lane0, lanes, rows, compare_from), (m, l, acc) in zip(
-                    plan, carry):
+            for st, (m, l, acc) in zip(plan, carry):
+                lane0, lanes = st.lane0, st.lanes
+                rows = slice(st.r0, st.r1)
                 q_pos = _q_pos(row0 + lane0, lanes)
                 s = _masked(
-                    _scores(k[:rows], q[lane0:lane0 + lanes], scale),
-                    NEG_INF, compare_from, q_pos, k_pos[:rows],
+                    _scores(k[rows], q[lane0:lane0 + lanes], scale),
+                    NEG_INF, st, window, q_pos, k_pos[rows],
                     (seg_q_ref[0, qi, :, lane0:lane0 + lanes]
                      if has_segments else None),
-                    seg_k[:rows] if has_segments else None)
+                    seg_k[rows] if has_segments else None)
                 m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                 # Guard the all-masked row: with m_new == NEG_INF,
                 # exp(s - m_new) would be exp(0) = 1 per masked entry
@@ -482,7 +567,7 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
                 alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
                 l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
                 if dropout_rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[rows],
                                       dropout_rate)
                     p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)),
                                   0.0)
@@ -490,24 +575,25 @@ def _fwd_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
                 # fmha/flash convention — the reference kernel holds P in
                 # fp16)
                 acc_new = acc * alpha + jax.lax.dot_general(
-                    v[:rows], p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    v[rows], p.astype(v.dtype), (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 out.append((m_new, l_new, acc_new))
             return tuple(out)
 
         def load(plan):
             return tuple(
-                tuple(sc[qi, :, lane0:lane0 + lanes]
+                tuple(sc[qi, :, st.lane0:st.lane0 + st.lanes]
                       for sc in (m_sc, l_sc, acc_sc))
-                for lane0, lanes, _, _ in plan)
+                for st in plan)
 
         def store(plan, carry):
-            for (lane0, lanes, _, _), strip in zip(plan, carry):
+            for st, strip in zip(plan, carry):
                 for sc, x in zip((m_sc, l_sc, acc_sc), strip):
-                    sc[qi, :, lane0:lane0 + lanes] = x
+                    sc[qi, :, st.lane0:st.lane0 + st.lanes] = x
 
         _visit(tile, load, store, plans,
-               *_k_ranges(causal, row0, col0, sub_q, sub_k, bk // sub_k))
+               _k_ranges(causal, window, row0, col0, sub_q, sub_k,
+                         bk // sub_k))
 
     jax.lax.fori_loop(0, bq // sub_q, q_block, None)
 
@@ -536,8 +622,8 @@ def _bwd_p(s, lse):
     return jnp.exp(s - jnp.where(lse <= NEG_INF * 0.5, 0.0, lse))
 
 
-def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-               dropout_rate, sub_q, sub_k, strip):
+def _dq_kernel(*refs, scale, causal, window, q_offset, kv_offset,
+               has_segments, dropout_rate, sub_q, sub_k, strip):
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, seg_k_ref,
      seed_ref, rest) = _unpack(refs, 6, has_segments, dropout_rate)
     dq_ref, dq_sc = rest
@@ -549,7 +635,7 @@ def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     jk = pl.program_id(2)
     num_kb = pl.num_programs(2)
     col0 = kv_offset + jk * bk
-    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
+    plans = _plans(causal, window, q_offset, kv_offset, sub_q, sub_k, strip)
 
     @pl.when(jk == 0)
     def _init():
@@ -569,40 +655,42 @@ def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
             k_pos = _k_pos(col0 + j * sub_k, sub_k)
             seg_k = seg_k_ref[0, at, :] if has_segments else None
             out = []
-            for (lane0, lanes, rows, compare_from), dq in zip(plan, carry):
-                strip = slice(lane0, lane0 + lanes)
-                q_pos = _q_pos(row0 + lane0, lanes)
+            for st, dq in zip(plan, carry):
+                strip = slice(st.lane0, st.lane0 + st.lanes)
+                rows = slice(st.r0, st.r1)
+                q_pos = _q_pos(row0 + st.lane0, st.lanes)
                 p = _masked(
-                    _bwd_p(_scores(k[:rows], q[strip], scale),
+                    _bwd_p(_scores(k[rows], q[strip], scale),
                            lse_ref[0, 0, qi, :, strip]),
-                    0.0, compare_from, q_pos, k_pos[:rows],
+                    0.0, st, window, q_pos, k_pos[rows],
                     seg_q_ref[0, qi, :, strip] if has_segments else None,
-                    seg_k[:rows] if has_segments else None)
+                    seg_k[rows] if has_segments else None)
                 dp = jax.lax.dot_general(
-                    v[:rows], do[strip], (((1,), (1,)), ((), ())),
+                    v[rows], do[strip], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 if dropout_rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[rows],
                                       dropout_rate)
                     dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)),
                                    0.0)
                 # the softmax scale waits for _finalize
                 ds = p * (dp - delta_ref[0, 0, qi, :, strip])
                 out.append(dq + jax.lax.dot_general(
-                    k[:rows], ds.astype(k.dtype), (((0,), (0,)), ((), ())),
+                    k[rows], ds.astype(k.dtype), (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))
             return tuple(out)
 
         def load(plan):
-            return tuple(dq_sc[qi, :, lane0:lane0 + lanes]
-                         for lane0, lanes, _, _ in plan)
+            return tuple(dq_sc[qi, :, st.lane0:st.lane0 + st.lanes]
+                         for st in plan)
 
         def store(plan, carry):
-            for (lane0, lanes, _, _), dq in zip(plan, carry):
-                dq_sc[qi, :, lane0:lane0 + lanes] = dq
+            for st, dq in zip(plan, carry):
+                dq_sc[qi, :, st.lane0:st.lane0 + st.lanes] = dq
 
         _visit(tile, load, store, plans,
-               *_k_ranges(causal, row0, col0, sub_q, sub_k, bk // sub_k))
+               _k_ranges(causal, window, row0, col0, sub_q, sub_k,
+                         bk // sub_k))
 
     jax.lax.fori_loop(0, bq // sub_q, q_block, None)
 
@@ -615,8 +703,8 @@ def _dq_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
         jax.lax.fori_loop(0, bq // sub_q, q_block_out, None)
 
 
-def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
-                dropout_rate, sub_q, sub_k, strip):
+def _dkv_kernel(*refs, scale, causal, window, q_offset, kv_offset,
+                has_segments, dropout_rate, sub_q, sub_k, strip):
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), seg_q_ref, seg_k_ref,
      seed_ref, rest) = _unpack(refs, 6, has_segments, dropout_rate)
     dk_ref, dv_ref, dk_sc, dv_sc = rest
@@ -628,11 +716,12 @@ def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
     iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
     row0 = q_offset + iq * bq
-    plans = _plans(causal, q_offset, kv_offset, sub_q, sub_k, strip)
-    # dk and dv of a k block are carried in row blocks as tall as the
-    # shortest strip, so that a strip of a crossed tile adds to the rows it
-    # computed and to no others
-    blk = min(rows for plan in plans if plan for _, _, rows, _ in plan)
+    plans = _plans(causal, window, q_offset, kv_offset, sub_q, sub_k, strip)
+    # dk and dv of a k block are carried in row blocks that every strip's
+    # rows begin and end on, so that a strip of a crossed tile adds to the
+    # rows it computed and to no others
+    blk = math.gcd(*(r for plan in plans if plan for st in plan
+                     for r in (st.r0, st.r1)))
     parts = [slice(r, r + blk) for r in range(0, sub_k, blk)]
 
     @pl.when(iq == 0)
@@ -654,20 +743,21 @@ def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
             at_q = _at(i * sub_q, sub_q)
             q = q_ref[0, 0, at_q, :]
             do = do_ref[0, 0, at_q, :]
-            for lane0, lanes, rows, compare_from in plan:
-                strip = slice(lane0, lane0 + lanes)
-                q_pos = _q_pos(row0 + i * sub_q + lane0, lanes)
+            for st in plan:
+                strip = slice(st.lane0, st.lane0 + st.lanes)
+                rows = slice(st.r0, st.r1)
+                q_pos = _q_pos(row0 + i * sub_q + st.lane0, st.lanes)
                 p = _masked(
-                    _bwd_p(_scores(k[:rows], q[strip], scale),
+                    _bwd_p(_scores(k[rows], q[strip], scale),
                            lse_ref[0, 0, i, :, strip]),
-                    0.0, compare_from, q_pos, k_pos[:rows],
+                    0.0, st, window, q_pos, k_pos[rows],
                     seg_q_ref[0, i, :, strip] if has_segments else None,
-                    seg_k[:rows] if has_segments else None)
+                    seg_k[rows] if has_segments else None)
                 dp = jax.lax.dot_general(
-                    v[:rows], do[strip], (((1,), (1,)), ((), ())),
+                    v[rows], do[strip], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 if dropout_rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[:rows],
+                    keep = _keep_mask(seed_ref[0], bh, q_pos, k_pos[rows],
                                       dropout_rate)
                     inv = 1.0 / (1.0 - dropout_rate)
                     p_drop = jnp.where(keep, p * inv, 0.0)
@@ -680,7 +770,9 @@ def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
                 ds = p * (dp - delta_ref[0, 0, i, :, strip])
                 dk_add = jnp.dot(ds.astype(q.dtype), q[strip],
                                  preferred_element_type=jnp.float32)
-                for r, part in enumerate(parts[:rows // blk]):
+                for r in range(st.r0 // blk, st.r1 // blk):
+                    part = slice(parts[r].start - st.r0,
+                                 parts[r].stop - st.r0)
                     dk[r] = dk[r] + dk_add[part]
                     dv[r] = dv[r] + dv_add[part]
             return tuple(dk), tuple(dv)
@@ -695,7 +787,8 @@ def _dkv_kernel(*refs, scale, causal, q_offset, kv_offset, has_segments,
                     sc[_at(kj * sub_k + part.start, blk), :] = x
 
         _visit(tile, load, store, plans,
-               *_q_ranges(causal, row0, col0, sub_q, sub_k, bq // sub_q))
+               _q_ranges(causal, window, row0, col0, sub_q, sub_k,
+                         bq // sub_q))
 
     jax.lax.fori_loop(0, bk // sub_k, k_block, None)
 
@@ -722,34 +815,59 @@ def _causal_imin(j, bq, bk, q_offset, kv_offset, num_qb):
     return jnp.clip(imin, 0, num_qb - 1)
 
 
-def _specs(h, bq, bk, d, sub_q, q_inner, causal, q_offset, kv_offset,
-           n_inner):
+def _window_jmin(i, bq, bk, window, q_offset, kv_offset, num_kb):
+    """First k-block index with any column inside the window of q-block i."""
+    jmin = (q_offset + i * bq - window + 1 - kv_offset) // bk
+    return jnp.clip(jmin, 0, num_kb - 1)
+
+
+def _window_imax(j, bq, bk, window, q_offset, kv_offset, num_qb):
+    """Last q-block index with any row whose window reaches k-block j."""
+    imax = (kv_offset + (j + 1) * bk + window - 2 - q_offset) // bq
+    return jnp.clip(imax, 0, num_qb - 1)
+
+
+def _specs(h, rep, bq, bk, d, sub_q, q_inner, causal, window, q_offset,
+           kv_offset, n_inner):
     """Block specs of the fetched tiles for the ``(bh, i, j)`` grid of the
     forward and dq calls (k innermost) or, with ``q_inner``, the
     ``(bh, j, i)`` grid of the dkv call.  Under causal the index maps of
-    the inner side clamp into the live range, so a fetched tile with
-    nothing to visit re-references its neighbour and Pallas elides the
-    copy (only where a head does not fit one tile: else ``n_inner`` is 1)."""
+    the inner side clamp into the live range (from the window's far side
+    too), so a fetched tile with nothing to visit re-references its
+    neighbour and Pallas elides the copy (only where a head does not fit
+    one tile: else ``n_inner`` is 1).  ``rep`` query heads read one K/V
+    head (grouped-query attention: K and V are fetched, never repeated in
+    HBM); ``"dk"`` is the dkv call's result, one per query head."""
 
     def ij(a, b_):
         i, j = (b_, a) if q_inner else (a, b_)
         if causal and q_inner:
             i = jnp.maximum(i, _causal_imin(j, bq, bk, q_offset, kv_offset,
                                             n_inner))
+            if window is not None:
+                i = jnp.minimum(i, _window_imax(j, bq, bk, window, q_offset,
+                                                kv_offset, n_inner))
         elif causal:
             j = jnp.minimum(j, _causal_jmax(i, bq, bk, q_offset, kv_offset,
                                             n_inner))
+            if window is not None:
+                j = jnp.maximum(j, _window_jmin(i, bq, bk, window, q_offset,
+                                                kv_offset, n_inner))
         return i, j
 
     def q_idx(bh_, a, b_):
         return (bh_ // h, bh_ % h, ij(a, b_)[0], 0)
 
     def k_idx(bh_, a, b_):
-        return (bh_ // h, bh_ % h, ij(a, b_)[1], 0)
+        return (bh_ // h, bh_ % h if rep == 1 else bh_ % h // rep,
+                ij(a, b_)[1], 0)
 
     return {
         "q": pl.BlockSpec((1, 1, bq, d), q_idx),
         "k": pl.BlockSpec((1, 1, bk, d), k_idx),
+        "dk": pl.BlockSpec(
+            (1, 1, bk, d),
+            lambda bh_, a, b_: (bh_ // h, bh_ % h, ij(a, b_)[1], 0)),
         "q_row": pl.BlockSpec(
             (1, 1, bq // sub_q, 1, sub_q),
             lambda bh_, a, b_: (bh_ // h, bh_ % h, ij(a, b_)[0], 0, 0)),
@@ -777,14 +895,20 @@ def _seed_array(dropout_seed):
     return jnp.asarray(dropout_seed, jnp.int32).reshape((1,))
 
 
-def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, scale, block_q,
-          block_k, q_offset, kv_offset, dropout_rate, q_inner=False):
+def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, window, scale,
+          block_q, block_k, q_offset, kv_offset, dropout_rate, q_inner=False):
     """What the three calls share: tiles, padded segment ids, block specs,
     the kernel closed over its static arguments, the optional inputs with
     their specs, the tiling's gauges, and the ``pallas_call`` itself as
     ``plan.call(in_specs, out_specs, out_shape, scratch_shapes, *args)``."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    if h % k.shape[1]:
+        raise ValueError(f"K/V heads ({k.shape[1]}) must divide the query "
+                         f"heads ({h})")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window (position i sees keys i - window < j <= "
+                         "i) needs causal=True and window >= 1")
     bq, bk, sub_q, sub_k, sq_p, sk_p = _pick_blocks(
         sq, sk, d, q.dtype.itemsize, block_q, block_k)
     seg_q, seg_k = _prep_segments(
@@ -794,10 +918,10 @@ def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, scale, block_q,
     has_segments = seg_q is not None
     grid = ((b * h, sk_p // bk, sq_p // bq) if q_inner
             else (b * h, sq_p // bq, sk_p // bk))
-    sp = _specs(h, bq, bk, d, sub_q, q_inner, causal, q_offset, kv_offset,
-                grid[2])
+    sp = _specs(h, h // k.shape[1], bq, bk, d, sub_q, q_inner, causal,
+                window, q_offset, kv_offset, grid[2])
     kernel = functools.partial(
-        kernel, scale=_resolve(scale, d), causal=causal,
+        kernel, scale=_resolve(scale, d), causal=causal, window=window,
         q_offset=q_offset, kv_offset=kv_offset, has_segments=has_segments,
         dropout_rate=dropout_rate, sub_q=sub_q, sub_k=sub_k, strip=strip)
     extra_specs, extra = [], []
@@ -808,7 +932,8 @@ def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, scale, block_q,
         extra_specs += [sp["seed"]]
         extra += [_seed_array(seed)]
     _record_tiling(sq, sk, bq, sub_q, sub_k, strip, sq_p, sk_p, causal,
-                   q_offset, kv_offset, has_segments or dropout_rate > 0.0)
+                   window, q_offset, kv_offset,
+                   has_segments or dropout_rate > 0.0)
 
     def call(in_specs, out_specs, out_shape, scratch_shapes, *args):
         return pl.pallas_call(
@@ -828,10 +953,11 @@ def _plan(kernel, strip, q, k, seg_q, seg_k, seed, causal, scale, block_q,
 
 
 def _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale, block_q, block_k,
-              q_offset, kv_offset, dropout_rate):
+              q_offset, kv_offset, dropout_rate, window=None):
     b, h, sq, d = q.shape
     plan = _plan(_fwd_kernel, _STRIP_FWD, q, k, seg_q, seg_k, seed, causal,
-                 scale, block_q, block_k, q_offset, kv_offset, dropout_rate)
+                 window, scale, block_q, block_k, q_offset, kv_offset,
+                 dropout_rate)
     sub_q, sq_p, sk_p = plan.sub_q, plan.sq_p, plan.sk_p
     nq = plan.bq // sub_q
     out, lse = plan.call(
@@ -856,7 +982,8 @@ def _bwd_args(plan, q, k, v, do, lse, delta):
 def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
              block_q=None, block_k=None,
              q_offset=0, kv_offset=0, segment_ids_q=None,
-             segment_ids_kv=None, dropout_rate=0.0, dropout_seed=None):
+             segment_ids_kv=None, dropout_rate=0.0, dropout_seed=None,
+             window=None):
     """dq contribution of one K/V chunk given the *global* ``lse``/``delta``.
 
     The flash-backward identity: each (q-block, k-block) pair's gradient
@@ -866,8 +993,8 @@ def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
     block_q, block_k = resolve_default_blocks(block_q, block_k)
     b, h, sq, d = q.shape
     plan = _plan(_dq_kernel, _STRIP_BWD, q, k, segment_ids_q, segment_ids_kv,
-                 dropout_seed, causal, scale, block_q, block_k, q_offset,
-                 kv_offset, dropout_rate)
+                 dropout_seed, causal, window, scale, block_q, block_k,
+                 q_offset, kv_offset, dropout_rate)
     dq = plan.call(
         _BWD_IN, "q", jax.ShapeDtypeStruct((b, h, plan.sq_p, d), q.dtype),
         [(plan.bq // plan.sub_q, d, plan.sub_q)],
@@ -878,21 +1005,28 @@ def dq_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
 def dkv_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
               block_q=None, block_k=None,
               q_offset=0, kv_offset=0, segment_ids_q=None,
-              segment_ids_kv=None, dropout_rate=0.0, dropout_seed=None):
-    """(dk, dv) of one K/V chunk given the global ``lse``/``delta``."""
+              segment_ids_kv=None, dropout_rate=0.0, dropout_seed=None,
+              window=None):
+    """(dk, dv) of one K/V chunk given the global ``lse``/``delta``.  Under
+    grouped-query attention the kernel writes one (dk, dv) per query head
+    and the heads of a group are summed here, in float32."""
     block_q, block_k = resolve_default_blocks(block_q, block_k)
     b, h, _, d = q.shape
-    sk = k.shape[2]
+    hk, sk = k.shape[1], k.shape[2]
     plan = _plan(_dkv_kernel, _STRIP_BWD, q, k, segment_ids_q,
-                 segment_ids_kv, dropout_seed, causal, scale, block_q,
+                 segment_ids_kv, dropout_seed, causal, window, scale, block_q,
                  block_k, q_offset, kv_offset, dropout_rate, q_inner=True)
     dk, dv = plan.call(
-        _BWD_IN, ["k", "k"],
+        _BWD_IN, ["dk", "dk"],
         [jax.ShapeDtypeStruct((b, h, plan.sk_p, d), k.dtype),
          jax.ShapeDtypeStruct((b, h, plan.sk_p, d), v.dtype)],
         [(plan.bk, d), (plan.bk, d)],
         *_bwd_args(plan, q, k, v, do, lse, delta))
-    return dk[:, :, :sk], dv[:, :, :sk]
+    dk, dv = dk[:, :, :sk], dv[:, :, :sk]
+    if hk != h:
+        dk, dv = (x.reshape(b, hk, h // hk, sk, d).sum(
+            axis=2, dtype=jnp.float32).astype(x.dtype) for x in (dk, dv))
+    return dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -900,31 +1034,32 @@ def dkv_chunk(q, k, v, do, lse, delta, *, causal, scale=None,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_core(q, k, v, seg_q, seg_k, seed,
                 causal, scale, block_q, block_k, q_offset, kv_offset,
-                dropout_rate):
+                dropout_rate, window):
     return _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale, block_q,
-                     block_k, q_offset, kv_offset, dropout_rate)
+                     block_k, q_offset, kv_offset, dropout_rate, window)
 
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_k, seed, causal, scale, block_q,
-                   block_k, q_offset, kv_offset, dropout_rate):
+                   block_k, q_offset, kv_offset, dropout_rate, window):
     out, lse = _fwd_call(q, k, v, seg_q, seg_k, seed, causal, scale,
                          block_q, block_k, q_offset, kv_offset,
-                         dropout_rate)
+                         dropout_rate, window)
     return (out, lse), (q, k, v, seg_q, seg_k, seed, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, q_offset, kv_offset,
-                   dropout_rate, res, cts):
+                   dropout_rate, window, res, cts):
     q, k, v, seg_q, seg_k, seed, out, lse = res
     do, _ = cts
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     kw = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
               q_offset=q_offset, kv_offset=kv_offset,
               segment_ids_q=seg_q, segment_ids_kv=seg_k,
-              dropout_rate=dropout_rate, dropout_seed=seed)
+              dropout_rate=dropout_rate, dropout_seed=seed, window=window)
     dq = dq_chunk(q, k, v, do, lse, delta, **kw)
     dk, dv = dkv_chunk(q, k, v, do, lse, delta, **kw)
     return dq, dk, dv, None, None, None
@@ -946,8 +1081,15 @@ def flash_attention_with_lse(
     segment_ids_kv=None,
     dropout_rate: float = 0.0,
     dropout_seed=None,
+    window: Optional[int] = None,
 ):
     """Attention returning ``(out, lse)``.
+
+    ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
+    attention: a divisor of ``q``'s; query head ``n`` reads K/V head ``n //
+    (heads / kv_heads)``).  ``window`` (with ``causal``): position ``i``
+    sees keys ``i - window < j <= i``, the token itself counts; tiles
+    wholly before the window are not visited.
 
     ``segment_ids_q/kv`` (int ≥ 0, ``[b, s]``) mask attention across
     segment boundaries — packed-varlen (fmha cu_seqlens) and padding masks
@@ -962,7 +1104,7 @@ def flash_attention_with_lse(
     seed = _seed_array(dropout_seed) if dropout_rate > 0.0 else None
     return _flash_core(q, k, v, segment_ids_q, segment_ids_kv, seed,
                        causal, scale, block_q, block_k, q_offset, kv_offset,
-                       float(dropout_rate))
+                       float(dropout_rate), window)
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -973,11 +1115,14 @@ def flash_attention(q, k, v, causal: bool = False,
                     segment_ids_q=None,
                     segment_ids_kv=None,
                     dropout_rate: float = 0.0,
-                    dropout_seed=None):
+                    dropout_seed=None,
+                    window: Optional[int] = None):
     """``softmax(q k^T * scale [+ masks]) v`` without materialising the
-    score matrix.  ``q,k,v: [batch, heads, seq, head_dim]``."""
+    score matrix.  ``q: [batch, heads, seq, head_dim]``, ``k, v: [batch,
+    kv_heads, seq, head_dim]``; ``window`` as in
+    :func:`flash_attention_with_lse`."""
     out, _ = flash_attention_with_lse(
         q, k, v, causal, scale, block_q, block_k, 0, 0,
         segment_ids_q=segment_ids_q, segment_ids_kv=segment_ids_kv,
-        dropout_rate=dropout_rate, dropout_seed=dropout_seed)
+        dropout_rate=dropout_rate, dropout_seed=dropout_seed, window=window)
     return out

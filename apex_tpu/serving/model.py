@@ -121,6 +121,7 @@ from apex_tpu.transformer.tensor_parallel import (
 from apex_tpu.transformer.tensor_parallel.utils import divide
 from apex_tpu.transformer.testing.standalone_transformer_lm import (
     Embedding,
+    HybridParams,
     ParallelMLP,
     TransformerConfig,
     parallel_lm_logits,
@@ -602,23 +603,24 @@ class DecodeModel:
 # ------------------------------------------------ layers of several kinds
 
 
-class HybridParams(NamedTuple):
-    """Parameters of a :class:`HybridDecodeModel`, in the model's dtype.
-
-    ``embedding [vocab, hidden]``; ``layers``: one dict per layer, in
-    order (``norm1``, ``wq [hidden, heads * k_dim]``, ``wk [hidden,
-    kv_heads * k_dim]``, ``wv [hidden, kv_heads * v_dim]``, ``wo [heads *
-    v_dim, hidden]``, ``sinks [heads]`` where the layer's kind has them,
-    ``norm2``, then ``ffn_gate_up [hidden, 2 f]`` and ``ffn_down [f,
-    hidden]`` for a dense layer or ``router [hidden, E]``, ``router_bias
-    [E]``, ``experts_gate_up [held, hidden, 2 f]`` and ``experts_down
-    [held, f, hidden]`` for an expert layer; gate columns come first);
-    ``final_norm [hidden]``; ``head [hidden, vocab]`` (untied)."""
-
-    embedding: Any
-    layers: Tuple[dict, ...]
-    final_norm: Any
-    head: Any
+def unserved_fields(spec) -> list:
+    """The fields of a :class:`HybridSpec` that ask for what
+    :class:`HybridDecodeModel` does not implement yet (the trainer does),
+    by name: it refuses them instead of serving another model."""
+    out = []
+    for kind in spec.kinds:
+        out += [f"{kind.name}.{name}" for name, used in (
+            ("rotary_dim=0", kind.rotary_dim == 0),
+            ("qk_norm", kind.qk_norm), ("gate", kind.gate)) if used]
+    out += [name for name, used in (
+        ("sandwich_norm", spec.sandwich_norm),
+        ("embedding_multiplier", spec.embedding_multiplier != 1.0)) if used]
+    if spec.experts is not None:
+        out += [f"experts.{name}" for name, used in (
+            ("shared_experts", spec.experts.shared_experts),
+            ("route_scale", spec.experts.route_scale != 1.0),
+            ("route_eps", spec.experts.route_eps != 0.0)) if used]
+    return out
 
 
 def decode_model(config: TransformerConfig, cache: KVCacheConfig, **kwargs):
@@ -646,6 +648,11 @@ class HybridDecodeModel:
                 and cc.bound_axis_size(cfg.tensor_axis) > 1:
             raise NotImplementedError(
                 "hybrid layers are served on one chip's share: no tp yet")
+        unserved = unserved_fields(cfg.hybrid)
+        if unserved:
+            raise NotImplementedError(
+                "serving does not implement these HybridSpec fields yet "
+                f"(the trainer does): {', '.join(unserved)}")
         self.cfg, self.cache = cfg, cache
         self.spec = cfg.hybrid
         self.fused_attention = fused_attention

@@ -1,15 +1,31 @@
-"""Mixture-of-Experts (Switch) MLP with expert parallelism.
+"""Mixture-of-experts feed-forward layers.
 
-Parity-plus: the reference *stubs* MoE out — ``standalone_transformer_lm.py:675``
-asserts ``args.num_experts is None`` with the ``SwitchMLP`` call commented —
-and SURVEY §2.5 lists expert parallelism as "absent in reference; optional
-extension".  Long-context/distributed being first-class here, EP gets the
-same treatment as the other strategies: experts shard over a mesh axis and
-tokens move with one ``all_to_all`` each way (the standard TPU MoE
-dispatch; the ``cp`` axis or the ``dp`` axis both work — whichever the
-caller binds).
+**The layer that serves and trains: top-k dropless routing over a share of
+the experts** (:func:`route_topk`, :func:`held_experts_ffn`): sigmoid
+scores, a selection bias that chooses and does not weigh, the ``top_k``
+chosen scores normalised (and scaled), no capacity and no token dropped,
+an optional shared expert that every token meets.  The layer is *told
+which experts it holds* (``held = (first, count)`` of ``n_experts``, the
+chip's share under wide expert parallelism): it routes over all of them,
+sorts the ``(token, expert)`` pairs that fall on held experts by expert,
+runs one grouped matmul per projection over the held experts' stacked
+weights, and scatters the weighted rows back.  What the absent experts
+would add is left out; nothing stands in for the other chips or their
+exchange.  Shapes are fixed by the token count, so churn in what is routed
+where never recompiles.  It is differentiable: :func:`grouped_matmul` has
+a VJP from the same Pallas library (the rows' gradient is a grouped matmul
+over the transposed weights, the weights' gradient the transposed grouped
+matmul), the passes over the sorted pairs have one of their own that walks
+the same passes again (both follow the pairs that are here), and gradients
+reach the router through the chosen scores, not through the choice.
 
-Routing is Switch-Transformer top-1 with capacity:
+**Beside it, Switch-Transformer top-1 routing with capacity**
+(:class:`SwitchMLP`, :func:`switch_route`; a training dry run, ROADMAP R3
+has its removal).  Parity-plus: the reference *stubs* MoE out,
+``standalone_transformer_lm.py:675`` asserts ``args.num_experts is None``
+with the ``SwitchMLP`` call commented, and SURVEY §2.5 lists expert
+parallelism as "absent in reference; optional extension".  Experts shard
+over a mesh axis and tokens move with one ``all_to_all`` each way:
 
 - router in fp32, top-1 expert + gate probability per token;
 - capacity ``C = ceil(T/E * capacity_factor)`` per expert; overflow
@@ -25,20 +41,6 @@ dispatch builds ``[E, C, h]``, one ``all_to_all`` regroups to
 tokens, and the reverse ``all_to_all`` brings outputs home — numerically
 identical to the dense path (tested).
 
-**Top-k dropless routing over a share of the experts**
-(:func:`route_topk`, :func:`held_experts_ffn`; ISSUE 27): sigmoid scores,
-a selection bias that chooses and does not weigh, the ``top_k`` chosen
-scores normalised, no capacity and no token dropped.  The layer is *told
-which experts it holds* (``held = (first, count)`` of ``n_experts``, the
-chip's share under wide expert parallelism): it routes over all of them,
-sorts the tick's ``(token, expert)`` pairs that fall on held experts by
-expert, runs one grouped matmul per projection over the held experts'
-stacked weights, and scatters the weighted rows back.  What the absent
-experts would add is left out; nothing stands in for the other chips or
-their exchange.  Shapes are fixed by the token count, so churn in what is
-routed where never recompiles.  :class:`SwitchMLP` (top-1, capacity drop,
-training dry run) stays as it is beside it.
-
 Memory honesty: under EP the expert stacks are declared at their **local**
 shape ``[E/ep, ...]`` (the same rank-folded-init convention as the
 tensor-parallel linears), with init rng folded by ``axis_index`` so expert
@@ -50,16 +52,20 @@ owns its experts).  The router stays replicated.
 
 from __future__ import annotations
 
+import functools
+import importlib
 from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.observability.spans import named_span
 from apex_tpu.parallel import collectives as cc
+from apex_tpu.utils import platform
 
 __all__ = ["SwitchMLP", "collect_moe_aux", "switch_route", "route_topk",
-           "held_experts_ffn", "grouped_matmul"]
+           "held_experts_ffn", "grouped_matmul", "swiglu"]
 
 
 def collect_moe_aux(mutated_collections) -> jnp.ndarray:
@@ -200,19 +206,23 @@ class SwitchMLP(nn.Module):
 # ------------------------------------------- top-k routing, held experts
 
 
-def route_topk(logits32, bias, top_k: int):
+def route_topk(logits32, bias, top_k: int, eps: float = 0.0,
+               scale: float = 1.0):
     """Sigmoid-scored top-k routing from fp32 router logits ``[T, E]``.
 
     The ``top_k`` experts with the largest ``sigmoid(logit) + bias`` are
     chosen (``bias [E]`` selects and does not weigh; ties go to the lower
-    expert id, ``lax.top_k``'s order); their weights are their scores
-    normalised to sum to one.  Returns ``(experts [T, k] int32, weights
-    [T, k] f32)``."""
+    expert id, ``lax.top_k``'s order); their weights are their scores over
+    their sum plus ``eps``, times ``scale``.  Gradients flow through the
+    chosen scores and not through the choice.  Returns ``(experts [T, k]
+    int32, weights [T, k] f32)``."""
     scores = jax.nn.sigmoid(logits32)
     _, experts = jax.lax.top_k(scores + bias, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=1)
-    return (experts.astype(jnp.int32),
-            picked / jnp.sum(picked, axis=1, keepdims=True))
+    total = jnp.sum(picked, axis=1, keepdims=True)
+    weights = picked / (total + eps if eps else total)
+    return experts.astype(jnp.int32), (weights * scale if scale != 1.0
+                                       else weights)
 
 
 # row tile of the Pallas grouped matmul; its k and n tiles are the weight
@@ -221,6 +231,16 @@ def route_topk(logits32, bias, top_k: int):
 _GMM_TILES = (128, 1024, 1024)
 
 
+def _megablox():
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _tiles(m, k, n):
+    return tuple(min(t, d) for t, d in zip(_GMM_TILES, (m, k, n)))
+
+
+@jax.custom_vjp
 def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs [m, k]`` rows sorted by group, ``rhs [groups, k, n]``,
     ``group_sizes [groups]`` int32 summing to at most ``m`` ->
@@ -229,24 +249,44 @@ def grouped_matmul(lhs, rhs, group_sizes):
     ``jax.experimental.pallas.ops.tpu.megablox``: a tile of rows meets only
     its own group's weights, and tiles past the last group are not visited.
     (``lax.ragged_dot`` computes the same; on a v5e at the decode shape it
-    took 1.15 ms where this takes 0.60, PERF.md §6, and is not kept.)"""
-    import importlib
+    took 1.15 ms where this takes 0.60, PERF.md §6, and is not kept.)
 
-    from apex_tpu.observability.spans import named_span
-    from apex_tpu.utils import platform
-
+    Differentiable in ``lhs`` and ``rhs``: the rows' gradient is the same
+    kernel over the transposed weights, the weights' gradient the library's
+    transposed grouped matmul (a group no row fell on gets zeros); the
+    gradient of rows past the sum holds nothing meaningful either."""
     m, k = lhs.shape
-    n = rhs.shape[2]
-    tiles = tuple(min(t, d) for t, d in zip(_GMM_TILES, (m, k, n)))
     # the kernel traced in place (not through the library's own jit, whose
     # name would be the instruction's), under the scope that names it in a
     # device trace: ``%moe_experts.<n>``
-    megablox = importlib.import_module(
-        "jax.experimental.pallas.ops.tpu.megablox.gmm")
     with named_span("moe_experts"):
-        return megablox.gmm.__wrapped__(
+        return _megablox().gmm.__wrapped__(
             lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=tiles, interpret=platform.pallas_interpret())
+            tiling=_tiles(m, k, rhs.shape[2]),
+            interpret=platform.pallas_interpret())
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes):
+    return grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_bwd(kept, g):
+    lhs, rhs, group_sizes = kept
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    lib, interpret = _megablox(), platform.pallas_interpret()
+    with named_span("moe_experts_bwd"):
+        d_lhs = lib.gmm.__wrapped__(
+            g, rhs, group_sizes, preferred_element_type=lhs.dtype,
+            tiling=_tiles(m, n, k), transpose_rhs=True, interpret=interpret)
+        d_rhs = lib.tgmm.__wrapped__(
+            lhs.swapaxes(0, 1), g, group_sizes,
+            preferred_element_type=rhs.dtype, tiling=_tiles(m, k, n),
+            num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 def _chunk_rows(pairs: int, share: float) -> int:
@@ -259,9 +299,110 @@ def _chunk_rows(pairs: int, share: float) -> int:
     return -(-rows // step) * step
 
 
+def swiglu(x, w_gate_up, w_down):
+    """``w_down(silu(gate) * up)`` of ``x [T, h]`` with ``w_gate_up [h, 2
+    f]`` (gate columns, then up) and ``w_down [f, h]``: float32 out of
+    matmuls on operands in ``x``'s dtype."""
+    f = w_down.shape[0]
+    gate_up = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
+    mid = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    return jnp.dot(mid.astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
+# The passes over the sorted pairs.  A pass takes ``rows`` of them
+# (``_chunk_rows``: one and a half times what the held experts expect, so
+# that one pass is the rule and more come only under a skew towards this
+# share), gathers their tokens' rows, runs the two grouped matmuls and adds
+# the weighted results to their tokens; as many passes run as the pairs that
+# are here need, so none is dropped and none that is elsewhere is paid for.
+# That trip count is traced, which reverse differentiation cannot take, so
+# the passes have a VJP of their own: it walks the same passes again, each
+# differentiated by itself (through ``grouped_matmul``'s VJP), and adds up
+# what they give.
+
+
+def _pass_rows(i, rows, x, w_gate_up, w_down, weight, token, ends, masked):
+    """Pass ``i``: the tokens of its ``rows`` sorted pairs and their
+    weighted expert outputs ``[rows, h]`` in float32, nought past the pairs
+    that are here.  ``masked`` also noughts the gathered rows there, so
+    that what the kernels leave in rows they do not visit reaches no
+    gradient."""
+    P = token.shape[0]
+    f = w_down.shape[1]
+    n_here = ends[-1]
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    lo = i * rows
+    at = jnp.minimum(lo + jnp.arange(rows, dtype=jnp.int32), P - 1)
+    live = (lo + jnp.arange(rows, dtype=jnp.int32)) < n_here
+    sizes = (jnp.clip(ends, lo, lo + rows)
+             - jnp.clip(starts, lo, lo + rows)).astype(jnp.int32)
+    tok = token[at]
+    xs = x[tok]
+    if masked:
+        xs = jnp.where(live[:, None], xs, 0)
+    gate_up = grouped_matmul(xs, w_gate_up, sizes)
+    mid = (jax.nn.silu(gate_up[:, :f].astype(jnp.float32))
+           * gate_up[:, f:].astype(jnp.float32)).astype(x.dtype)
+    out = grouped_matmul(mid, w_down, sizes).astype(jnp.float32)
+    if masked:
+        # the weight's gradient is a product with these rows: nought first
+        out = jnp.where(live[:, None], out, 0.0) * weight[at][:, None]
+    else:
+        out = jnp.where(live[:, None], out * weight[at][:, None], 0.0)
+    return tok, out
+
+
+def _n_passes(ends, rows):
+    return -(-ends[-1] // rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_passes(rows, x, w_gate_up, w_down, weight, token, ends):
+    """``y [T, h]`` float32: every pass's rows added to their tokens."""
+    def one_pass(i, y):
+        tok, out = _pass_rows(i, rows, x, w_gate_up, w_down, weight, token,
+                              ends, masked=False)
+        # rows past the pairs that are here add nothing, wherever they land
+        return y.at[tok].add(out)
+
+    return jax.lax.fori_loop(0, _n_passes(ends, rows), one_pass,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _held_passes_fwd(rows, x, w_gate_up, w_down, weight, token, ends):
+    return (_held_passes(rows, x, w_gate_up, w_down, weight, token, ends),
+            (x, w_gate_up, w_down, weight, token, ends))
+
+
+def _held_passes_bwd(rows, kept, dy):
+    x, w_gate_up, w_down, weight, token, ends = kept
+
+    def one_pass(i, grads):
+        def rows_of(x, w_gate_up, w_down, weight):
+            tok, out = _pass_rows(i, rows, x, w_gate_up, w_down, weight,
+                                  token, ends, masked=True)
+            return out, tok
+
+        _, pull, tok = jax.vjp(rows_of, x, w_gate_up, w_down, weight,
+                               has_aux=True)
+        return tuple(g + d.astype(g.dtype)
+                     for g, d in zip(grads, pull(dy[tok])))
+
+    zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_gate_up),
+             jnp.zeros_like(w_down), jnp.zeros_like(weight))
+    dx, d_gate_up, d_down, d_weight = jax.lax.fori_loop(
+        0, _n_passes(ends, rows), one_pass, zeros)
+    return dx.astype(x.dtype), d_gate_up, d_down, d_weight, None, None
+
+
+_held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
+
+
 def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
                      top_k: int, held: Tuple[int, int],
-                     live=None):
+                     live=None, route_eps: float = 0.0,
+                     route_scale: float = 1.0, shared=None):
     """The held experts' part of a top-k expert feed-forward.
 
     ``x [T, h]``; ``router [h, E]`` and ``router_bias [E]`` over all ``E``
@@ -275,22 +416,30 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
     another precision needs them: scores near the cut lie closer together
     than bfloat16 rounds).  ``live [T]`` bool marks the rows
     that are tokens (a fixed-shape batch carries padding): the others are
-    routed nowhere, cost nothing and add nothing.
+    routed nowhere, cost nothing and add nothing.  ``route_eps`` and
+    ``route_scale`` as :func:`route_topk` takes them.  ``shared``, a pair
+    ``(w_gate_up [h, 2 f'], w_down [f', h])``, is a shared expert: a dense
+    SwiGLU every token meets on its own chip, added to ``y``.
 
     The pairs on held experts are sorted by expert and handled
     ``_chunk_rows`` at a time, as many passes as they need (one, unless the
     routing is far more skewed towards this share than its size
     suggests): no pair is dropped, and the work follows the pairs that are
-    here, not the ``T * top_k`` there could be."""
+    here, not the ``T * top_k`` there could be.  Differentiable in ``x``
+    and every weight but ``router_bias`` (which only chooses)."""
     T, h = x.shape
     n_experts = router.shape[1]
     first, count = held
-    f = w_down.shape[1]
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        # a family with neither epsilon nor scale keeps the three-argument
+        # call that every earlier caller, and whatever wraps this name,
+        # makes
+        extra = (route_eps, route_scale) if (
+            route_eps or route_scale != 1.0) else ()
         experts, weights = route_topk(
-            logits, router_bias.astype(jnp.float32), top_k)
+            logits, router_bias.astype(jnp.float32), top_k, *extra)
         local = experts - first
         here = (local >= 0) & (local < count)
         if live is not None:
@@ -301,31 +450,12 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
             key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32)                          # [count]
         ends = jnp.cumsum(pairs)
-        starts = ends - pairs
-        n_here = ends[-1]
         token = (order // top_k).astype(jnp.int32)
         weight = weights.reshape(-1)[order]
-    P = T * top_k
-    rows = _chunk_rows(P, count / n_experts)
-
-    def one_pass(i, y):
-        lo = i * rows
-        at = jnp.minimum(lo + jnp.arange(rows, dtype=jnp.int32), P - 1)
-        live = (lo + jnp.arange(rows, dtype=jnp.int32)) < n_here
-        sizes = (jnp.clip(ends, lo, lo + rows)
-                 - jnp.clip(starts, lo, lo + rows)).astype(jnp.int32)
-        tok = token[at]
-        xs = x[tok]
-        gate_up = grouped_matmul(xs, w_gate_up, sizes)
-        mid = (jax.nn.silu(gate_up[:, :f].astype(jnp.float32))
-               * gate_up[:, f:].astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(mid, w_down, sizes)
-        out = jnp.where(live[:, None],
-                        out.astype(jnp.float32) * weight[at][:, None], 0.0)
-        # rows past the pairs that are here add nothing, wherever they land
-        return y.at[tok].add(out)
-
+    rows = _chunk_rows(T * top_k, count / n_experts)
     with jax.named_scope("moe_experts"):
-        y = jax.lax.fori_loop(0, -(-n_here // rows), one_pass,
-                              jnp.zeros((T, h), jnp.float32))
+        y = _held_passes(rows, x, w_gate_up, w_down, weight, token, ends)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(x, *shared)
     return y, pairs, experts

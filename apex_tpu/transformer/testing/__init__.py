@@ -5,6 +5,7 @@ from apex_tpu.transformer.testing.standalone_transformer_lm import (
     CoreAttention,
     Embedding,
     ExpertSpec,
+    HybridParams,
     HybridSpec,
     ParallelAttention,
     ParallelMLP,
@@ -31,6 +32,7 @@ __all__ = [
     "AttentionKind",
     "ExpertSpec",
     "HybridSpec",
+    "HybridParams",
     "ParallelMLP",
     "CoreAttention",
     "ParallelAttention",

@@ -19,6 +19,7 @@ schedule's chunk mapping, ``fwd_bwd_pipelining_with_interleaving.py:221``).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -117,6 +118,11 @@ def build_gpt_3d(
     ``config.num_layers`` must equal ``pp * num_chunks`` (one transformer
     layer per virtual stage); ``tokens: [global_batch, seq]`` sharded on dp.
 
+    A ``config.hybrid`` (layers of more than one kind: window beside full
+    attention, an expert layer beside a dense one) is trained by
+    :mod:`.hybrid_train` behind the same three results, on dp1 x pp1 x tp1;
+    what it cannot do yet it refuses by name.
+
     ``remat_ticks``: forward to :func:`pipeline_apply` for the 1F1B-class
     live-activation bound (grouped-tick remat); the train step must run
     under ``jax.jit`` (it should anyway).
@@ -148,6 +154,16 @@ def build_gpt_3d(
     (``tests/test_sequence_data.py``).
     """
     cfg = config
+    if cfg.hybrid is not None:
+        # layers of more than one kind cannot be one stacked tree: their
+        # trainer walks them in order, behind this same entry
+        from apex_tpu.transformer.testing.hybrid_train import (
+            build_hybrid_train)
+
+        return build_hybrid_train(
+            cfg, num_chunks=num_chunks, num_microbatches=num_microbatches,
+            mesh=mesh, packed_inputs=packed_inputs,
+            block_diagonal=block_diagonal, remat_ticks=remat_ticks)
     if block_diagonal:
         if not packed_inputs:
             raise ValueError(
@@ -456,99 +472,109 @@ def build_gpt_3d(
 
         return loss_fn
 
-    def make_train_step(opt, param_specs, scaler=None, grad_tap=None,
-                        collect_stats=False):
-        """``scaler=None``: the plain step.  With an ``amp`` scaler
-        algorithm the unified non-finite sentinel
-        (:mod:`apex_tpu.resilience.sentinel`) is threaded through: the
-        loss is scaled, gradients overflow-checked (on the *global*
-        grads, outside the shard_map — every rank sees the same flag),
-        and the optimizer apply runs under one ``lax.cond`` so an
-        overflow step leaves params and optimizer state bit-unchanged;
-        ``sentinel.skipped_steps`` surfaces the skip count.  Signature
-        becomes ``step(params, state, tokens, sentinel) -> (params,
-        state, sentinel, loss)`` (loss reported unscaled).
+    return init_fn, make_loss_fn, functools.partial(
+        make_train_step, mesh, make_loss_fn, make_aux_loss_fn)
 
-        ``grad_tap`` (sentinel path only): a ``grads -> grads`` hook
-        applied between the backward and the sentinel check — the seam
-        the fault harness (:mod:`apex_tpu.testing.faults`) uses to
-        inject non-finite gradients inside the compiled step.
 
-        ``collect_stats`` appends a jit-carried
-        :class:`apex_tpu.observability.PartialTrainStats` as the LAST
-        output (loss, grad/param global-norm partials, non-finite leaf
-        flags, loss scale, sentinel skip count, per-microbatch MoE aux).
-        The params/grads here are SHARDED global arrays, so the norms
-        leave the step as per-device partial sums
-        (``ts.device_partial_norms`` — a shard_map whose output keeps
-        the device axis, hence ZERO extra collectives; the host
-        finalizes the tiny partials matrix at fetch time) and the aux
-        vector rides the existing loss reductions
-        (``make_aux_loss_fn``).  Zero host syncs; params and optimizer
-        state stay bit-identical to the uninstrumented step (pinned by
-        tests/test_observability.py)."""
-        from apex_tpu.observability import trainstats as ts
+def make_train_step(mesh, make_loss_fn, make_aux_loss_fn, opt, param_specs,
+                    scaler=None, grad_tap=None, collect_stats=False,
+                    aux_stats=lambda aux: {"moe_aux": aux}):
+    """``scaler=None``: the plain step.  With an ``amp`` scaler
+    algorithm the unified non-finite sentinel
+    (:mod:`apex_tpu.resilience.sentinel`) is threaded through: the
+    loss is scaled, gradients overflow-checked (on the *global*
+    grads, outside the shard_map — every rank sees the same flag),
+    and the optimizer apply runs under one ``lax.cond`` so an
+    overflow step leaves params and optimizer state bit-unchanged;
+    ``sentinel.skipped_steps`` surfaces the skip count.  Signature
+    becomes ``step(params, state, tokens, sentinel) -> (params,
+    state, sentinel, loss)`` (loss reported unscaled).
 
-        loss_fn = (make_aux_loss_fn(param_specs) if collect_stats
-                   else make_loss_fn(param_specs))
-        if collect_stats:
-            partial_norms = ts.device_partial_norms(mesh, param_specs)
+    ``grad_tap`` (sentinel path only): a ``grads -> grads`` hook
+    applied between the backward and the sentinel check — the seam
+    the fault harness (:mod:`apex_tpu.testing.faults`) uses to
+    inject non-finite gradients inside the compiled step.
 
-        if scaler is None:
-            if not collect_stats:
-                def step(params, state, tokens):
-                    loss, grads = jax.value_and_grad(loss_fn)(
-                        params, tokens)
-                    new_p, new_state = opt.step(grads, state, params)
-                    return new_p, new_state, loss
+    ``collect_stats`` appends a jit-carried
+    :class:`apex_tpu.observability.PartialTrainStats` as the LAST
+    output (loss, grad/param global-norm partials, non-finite leaf
+    flags, loss scale, sentinel skip count, per-microbatch MoE aux).
+    The params/grads here are SHARDED global arrays, so the norms
+    leave the step as per-device partial sums
+    (``ts.device_partial_norms`` — a shard_map whose output keeps
+    the device axis, hence ZERO extra collectives; the host
+    finalizes the tiny partials matrix at fetch time) and the aux
+    vector rides the existing loss reductions
+    (``make_aux_loss_fn``).  Zero host syncs; params and optimizer
+    state stay bit-identical to the uninstrumented step (pinned by
+    tests/test_observability.py).
 
-                return step
+    What :func:`build_gpt_3d` returns is this function closed over its
+    first three arguments: the mesh and the builder's two loss makers
+    (``make_aux_loss_fn(param_specs)`` gives ``(loss, aux)``, and
+    ``aux_stats(aux)`` names the stats' fields ``aux`` fills).  The trainer
+    of layers of more than one kind (:mod:`.hybrid_train`) closes it over
+    its own."""
+    from apex_tpu.observability import trainstats as ts
 
-            def stats_step(params, state, tokens):
-                (loss, aux_mb), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, tokens)
-                new_p, new_state = opt.step(grads, state, params)
-                stats = ts.partial_train_stats(
-                    loss, partial_norms(grads, params), moe_aux=aux_mb)
-                return new_p, new_state, loss, stats
+    loss_fn = (make_aux_loss_fn(param_specs) if collect_stats
+               else make_loss_fn(param_specs))
+    if collect_stats:
+        partial_norms = ts.device_partial_norms(mesh, param_specs)
 
-            return stats_step
-
-        from apex_tpu.resilience.sentinel import sentinel_guarded_apply
-
-        def guarded_step(params, state, tokens, sent):
-            scale_used = sent.scaler.scale
-
-            if collect_stats:
-                def scaled_loss(p, t):
-                    loss, aux_mb = loss_fn(p, t)
-                    return loss * scale_used, aux_mb
-
-                (loss_s, aux_mb), grads = jax.value_and_grad(
-                    scaled_loss, has_aux=True)(params, tokens)
-            else:
-                def scaled_loss(p, t):
-                    return loss_fn(p, t) * scale_used
-
-                loss_s, grads = jax.value_and_grad(scaled_loss)(
+    if scaler is None:
+        if not collect_stats:
+            def step(params, state, tokens):
+                loss, grads = jax.value_and_grad(loss_fn)(
                     params, tokens)
-            if grad_tap is not None:
-                grads = grad_tap(grads)
-            # grads here are GLOBAL arrays (the shard_map lives inside
-            # loss_fn), so no cross-rank flag agreement is needed:
-            # axes=None.
-            new_p, new_state, new_sent = sentinel_guarded_apply(
-                scaler, opt, grads, state, params, sent,
-                grad_scale=scale_used)
-            loss = loss_s / scale_used
-            if not collect_stats:
-                return new_p, new_state, new_sent, loss
+                new_p, new_state = opt.step(grads, state, params)
+                return new_p, new_state, loss
+
+            return step
+
+        def stats_step(params, state, tokens):
+            (loss, aux_mb), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, tokens)
+            new_p, new_state = opt.step(grads, state, params)
             stats = ts.partial_train_stats(
-                loss, partial_norms(grads, params), grad_scale=scale_used,
-                loss_scale=scale_used,
-                skipped_steps=new_sent.skipped_steps, moe_aux=aux_mb)
-            return new_p, new_state, new_sent, loss, stats
+                loss, partial_norms(grads, params), **aux_stats(aux_mb))
+            return new_p, new_state, loss, stats
 
-        return guarded_step
+        return stats_step
 
-    return init_fn, make_loss_fn, make_train_step
+    from apex_tpu.resilience.sentinel import sentinel_guarded_apply
+
+    def guarded_step(params, state, tokens, sent):
+        scale_used = sent.scaler.scale
+
+        if collect_stats:
+            def scaled_loss(p, t):
+                loss, aux_mb = loss_fn(p, t)
+                return loss * scale_used, aux_mb
+
+            (loss_s, aux_mb), grads = jax.value_and_grad(
+                scaled_loss, has_aux=True)(params, tokens)
+        else:
+            def scaled_loss(p, t):
+                return loss_fn(p, t) * scale_used
+
+            loss_s, grads = jax.value_and_grad(scaled_loss)(
+                params, tokens)
+        if grad_tap is not None:
+            grads = grad_tap(grads)
+        # grads here are GLOBAL arrays (the shard_map lives inside
+        # loss_fn), so no cross-rank flag agreement is needed:
+        # axes=None.
+        new_p, new_state, new_sent = sentinel_guarded_apply(
+            scaler, opt, grads, state, params, sent,
+            grad_scale=scale_used)
+        loss = loss_s / scale_used
+        if not collect_stats:
+            return new_p, new_state, new_sent, loss
+        stats = ts.partial_train_stats(
+            loss, partial_norms(grads, params), grad_scale=scale_used,
+            loss_scale=scale_used,
+            skipped_steps=new_sent.skipped_steps, **aux_stats(aux_mb))
+        return new_p, new_state, new_sent, loss, stats
+
+    return guarded_step

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -66,6 +66,7 @@ __all__ = [
     "AttentionKind",
     "ExpertSpec",
     "HybridSpec",
+    "HybridParams",
     "ParallelMLP",
     "CoreAttention",
     "ParallelAttention",
@@ -82,10 +83,14 @@ __all__ = [
 class AttentionKind:
     """One kind of attention layer of a :class:`HybridSpec`: its head
     counts, the q/k width beside the v width, the rotated leading channels
-    and their base, an optional sliding window (position ``i`` sees keys
-    ``i - window < j <= i``: the token itself counts) and whether each head
-    has a learned sink logit (one more term of the softmax denominator that
-    takes no value)."""
+    (``0``: the kind has no position signal at all) and their base, an
+    optional sliding window (position ``i`` sees keys ``i - window < j <=
+    i``: the token itself counts), whether each head has a learned sink
+    logit (one more term of the softmax denominator that takes no value),
+    whether each q and k head is RMS-normed over its channels before the
+    rotation (``qk_norm``: one gain of ``k_dim`` each) and whether the
+    attention output is multiplied by the sigmoid of a projection of the
+    layer's input (``gate``)."""
 
     name: str
     num_heads: int
@@ -96,13 +101,15 @@ class AttentionKind:
     rotary_base: float = 10000.0
     window: Optional[int] = None
     sink: bool = False
+    qk_norm: bool = False
+    gate: bool = False
 
     def __post_init__(self):
         if self.num_heads % self.kv_heads:
             raise ValueError(
                 f"{self.name}: kv_heads ({self.kv_heads}) must divide "
                 f"num_heads ({self.num_heads})")
-        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.k_dim:
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.k_dim:
             raise ValueError(
                 f"{self.name}: rotary_dim ({self.rotary_dim}) must be even "
                 f"and at most k_dim ({self.k_dim})")
@@ -114,16 +121,23 @@ class AttentionKind:
 class ExpertSpec:
     """The expert feed-forward of a :class:`HybridSpec`: sigmoid scores
     over ``n_experts``, the ``top_k`` largest ``score + bias`` chosen (the
-    bias selects and does not weigh), their scores normalised to sum to
-    one, each expert a SwiGLU of width ``ffn_size``.  ``held = (first,
-    count)`` are the experts whose weights this process holds: the layer
-    routes over all ``n_experts`` and computes the held experts' part of
-    the result (:func:`apex_tpu.transformer.moe.held_experts_ffn`)."""
+    bias selects and does not weigh), their scores over their sum plus
+    ``route_eps``, times ``route_scale``, each expert a SwiGLU of width
+    ``ffn_size``.  ``held = (first, count)`` are the experts whose weights
+    this process holds: the layer routes over all ``n_experts`` and
+    computes the held experts' part of the result
+    (:func:`apex_tpu.transformer.moe.held_experts_ffn`).
+    ``shared_experts`` of them more are met by every token, as one dense
+    SwiGLU of width ``shared_experts * ffn_size`` that every chip computes
+    for its own tokens."""
 
     n_experts: int
     top_k: int
     ffn_size: int
     held: Tuple[int, int]
+    shared_experts: int = 0
+    route_scale: float = 1.0
+    route_eps: float = 0.0
 
     def __post_init__(self):
         first, count = self.held
@@ -134,6 +148,8 @@ class ExpertSpec:
         if not 1 <= self.top_k <= self.n_experts:
             raise ValueError(
                 f"top_k ({self.top_k}) must lie in 1..{self.n_experts}")
+        if self.shared_experts < 0:
+            raise ValueError("shared_experts must not be negative")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,11 +157,19 @@ class HybridSpec:
     """A per-layer description: which :class:`AttentionKind` each layer
     runs and whether its feed-forward is the dense SwiGLU
     (``ffn_hidden_size`` wide) or the expert layer.  Layers of this family
-    are pre-norm RMSNorm blocks with no biases, rotary positions on the
+    are RMSNorm residual blocks with no biases, rotary positions on the
     leading ``rotary_dim`` channels of q and k (half rotation), values
     scaled by ``value_scale``, a final RMSNorm and an untied output head.
+    They are pre-norm blocks, ``h + F(norm(h))``; with ``sandwich_norm``
+    each sublayer's output is normed once more before it is added, ``h +
+    norm_post(F(norm(h)))``.  The token embedding is multiplied by
+    ``embedding_multiplier``.
 
-    A model whose layers are all alike needs none of this: it leaves
+    One description drives the trainer
+    (``testing.hybrid_train``, reached through ``build_gpt_3d``), the
+    server (``serving.model.HybridDecodeModel``, which refuses by name
+    what it does not implement yet) and a benchmark's plain reference.  A
+    model whose layers are all alike needs none of this: it leaves
     ``TransformerConfig.hybrid`` at ``None`` and is the program it always
     was."""
 
@@ -154,6 +178,8 @@ class HybridSpec:
     layer_experts: Tuple[bool, ...]       # per layer: expert feed-forward
     experts: Optional[ExpertSpec] = None
     value_scale: float = 1.0
+    sandwich_norm: bool = False
+    embedding_multiplier: float = 1.0
 
     def __post_init__(self):
         if len(self.layer_kinds) != len(self.layer_experts):
@@ -166,6 +192,31 @@ class HybridSpec:
     def layers_of(self, kind: int) -> Tuple[int, ...]:
         """The layers that run attention kind ``kind``, in order."""
         return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+
+
+class HybridParams(NamedTuple):
+    """Parameters of a model described by a :class:`HybridSpec`, trained
+    (float32 leaves) or served (the model's dtype).
+
+    ``embedding [vocab, hidden]``; ``layers``: one dict per layer, in
+    order (``norm1``, ``wq [hidden, heads * k_dim]``, ``wk [hidden,
+    kv_heads * k_dim]``, ``wv [hidden, kv_heads * v_dim]``, ``wo [heads *
+    v_dim, hidden]``, ``sinks [heads]`` where the layer's kind has them,
+    ``q_norm`` and ``k_norm [k_dim]`` where it norms q and k, ``wg
+    [hidden, heads * v_dim]`` where it has a gate, ``norm2``,
+    ``post_attn_norm`` and ``post_ffn_norm [hidden]`` under
+    ``sandwich_norm``, then ``ffn_gate_up [hidden, 2 f]`` and ``ffn_down
+    [f, hidden]`` for a dense layer or ``router [hidden, E]``,
+    ``router_bias [E]``, ``experts_gate_up [held, hidden, 2 f]`` and
+    ``experts_down [held, f, hidden]`` for an expert layer, with
+    ``shared_gate_up [hidden, 2 f']`` and ``shared_down [f', hidden]``
+    where there are shared experts; gate columns come first);
+    ``final_norm [hidden]``; ``head [hidden, vocab]`` (untied)."""
+
+    embedding: Any
+    layers: Tuple[dict, ...]
+    final_norm: Any
+    head: Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -278,8 +329,9 @@ class TransformerConfig:
     # Layers of more than one kind (window beside full attention, an
     # expert feed-forward beside a dense one): a :class:`HybridSpec`, whose
     # layers are RMSNorm blocks (the uniform blocks are LayerNorm only).
-    # Served by ``serving.model.HybridDecodeModel``; ``None`` = every layer
-    # alike, as the fields above describe it.
+    # Served by ``serving.model.HybridDecodeModel``, trained by
+    # ``testing.hybrid_train`` through ``build_gpt_3d``; ``None`` = every
+    # layer alike, as the fields above describe it.
     hybrid: Optional[HybridSpec] = None
 
     dtype: Any = jnp.float32        # compute dtype (bf16 under the O2 policy)

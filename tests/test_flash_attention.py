@@ -428,6 +428,172 @@ def test_flash_matches_reference_across_tile_edges(case):
             rtol=gtol, atol=gtol, err_msg=name)
 
 
+def _window_reference(q, k, v, window, q_offset=0, kv_offset=0, seg_q=None,
+                      seg_k=None):
+    """Naive attention where position ``i`` sees keys ``i - window < j <=
+    i``; K and V with fewer heads are repeated for their query heads."""
+    rep = q.shape[1] // k.shape[1]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    k, v = (jnp.repeat(x, rep, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    i = jnp.arange(q.shape[2])[:, None] + q_offset
+    j = jnp.arange(k.shape[2])[None, :] + kv_offset
+    mask = jnp.broadcast_to(j <= i, (q.shape[0], 1) + s.shape[-2:])
+    if window is not None:
+        mask &= j > i - window
+    if seg_q is not None:
+        mask &= seg_q[:, None, :, None] == seg_k[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.where(jnp.isnan(p), 0.0, p), v)
+
+
+# s, d, dtype, heads, kv heads, window, block, q_offset, kv_offset, segment
+# edges.  A computed tile is ``block`` x ``block`` where the block is 128
+# (one tile a grid step) and 256 x 256 at d 128 in float32 under a block of
+# 512 (four tiles a grid step, two grid steps a side), so the windows below
+# end inside a tile, on a tile's edge and several tiles back, on both grids.
+_WINDOW_CASES = {
+    "window_1_sees_itself": (256, 64, "float32", 2, 2, 1, 128, 0, 0, None),
+    "window_inside_a_tile": (384, 64, "float32", 2, 2, 50, 128, 0, 0, None),
+    "window_is_a_tile": (384, 64, "float32", 2, 1, 128, 128, 0, 0, None),
+    "window_crosses_tiles": (512, 64, "float32", 4, 2, 200, 128, 0, 0, None),
+    "window_two_tiles_bf16": (512, 64, "bfloat16", 2, 2, 256, 128, 0, 0,
+                              None),
+    "window_in_a_whole_head": (512, 64, "float32", 2, 1, 130, None, 0, 0,
+                               None),
+    "window_aligned_tiles_in_a_step": (1024, 128, "float32", 2, 1, 256, 512,
+                                       0, 0, None),
+    "window_unaligned_tiles_in_a_step": (1024, 128, "float32", 1, 1, 300,
+                                         512, 0, 0, None),
+    "window_with_segments": (384, 64, "float32", 2, 1, 100, 128, 0, 0,
+                             (70, 200)),
+    "window_ring_chunk_behind": (256, 64, "float32", 2, 2, 200, 128, 256,
+                                 128, None),
+    "window_length_pads": (330, 64, "float32", 2, 1, 96, 128, 0, 0, None),
+    "grouped_heads_no_window": (256, 64, "float32", 8, 2, None, 128, 0, 0,
+                                None),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES), ids=list(_WINDOW_CASES))
+def test_flash_window_and_grouped_heads_match_reference(case):
+    """Forward, dq, dk and dv with a sliding window and with fewer K/V
+    heads than query heads, against naive attention."""
+    from apex_tpu.ops.flash_attention import flash_attention_with_lse
+
+    (s, d, dtype, h, hk, window, block, q_offset, kv_offset,
+     edges) = _WINDOW_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(s + d + h), 4)
+    q = jax.random.normal(ks[0], (1, h, s, d), dtype)
+    k, v = (jax.random.normal(kk, (1, hk, s, d), dtype) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (1, h, s, d))
+    seg = None if edges is None else _segments(1, s, edges)
+
+    def flash(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, True, None, block, block, q_offset, kv_offset,
+            segment_ids_q=seg, segment_ids_kv=seg, window=window)[0]
+
+    def reference(q, k, v):
+        return _window_reference(q, k, v, window, q_offset, kv_offset, seg,
+                                 seg)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    tol, gtol = (2e-5, 2e-4) if dtype == "float32" else (2e-2, 6e-2)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(reference(q, k, v)), rtol=tol, atol=tol)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b_, np.float32),
+            rtol=gtol, atol=gtol, err_msg=name)
+
+
+@pytest.mark.parametrize("block", [128, None], ids=["tiles", "whole_head"])
+def test_flash_window_past_the_sequence_is_no_window(block):
+    """A window that holds the whole sequence masks nothing: the output and
+    the three gradients equal ``window=None``'s bit for bit."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (1, 2, 256, 64))
+    k, v = (jax.random.normal(kk, (1, 1, 256, 64)) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], q.shape)
+
+    def run(window):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True, block_q=block,
+                                  block_k=block, window=window)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                             has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    for a, b_ in zip(run(None), run(256)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+    for a, b_ in zip(run(None), run(5000)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+def test_flash_window_refuses_what_it_cannot_mean():
+    x = jnp.zeros((1, 2, 128, 64))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(x, x, x, causal=False, window=4)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(x, x, x, causal=True, window=0)
+    with pytest.raises(ValueError, match="K/V heads"):
+        flash_attention(jnp.zeros((1, 3, 128, 64)), x, x, causal=True)
+
+
+def test_flash_window_gauges_at_the_training_shape():
+    """At ``(1, 32, 8192, 128)`` in bfloat16 (fetched tiles of 2048,
+    computed tiles of 512) a window of 2048 visits under half the sub-tiles
+    a full causal layer visits, in all three kernels, and few of them take
+    a compare.  Traced only, nothing runs."""
+    from apex_tpu.observability.metrics import default_registry
+    from apex_tpu.ops.flash_attention import dkv_chunk, dq_chunk
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32)
+    reg = default_registry()
+
+    def counts(window):
+        out = {}
+        calls = {
+            "fwd": lambda: jax.eval_shape(
+                lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                window=window), q, kv, kv),
+            "dq": lambda: jax.eval_shape(
+                lambda *a: dq_chunk(*a, causal=True, window=window),
+                q, kv, kv, q, row, row),
+            "dkv": lambda: jax.eval_shape(
+                lambda *a: dkv_chunk(*a, causal=True, window=window),
+                q, kv, kv, q, row, row)}
+        for name, call in calls.items():
+            call()
+            out[name] = tuple(reg.gauge(g).value for g in (
+                "flash/sub_tiles", "flash/sub_tiles_masked",
+                "flash/live_score_share", "flash/window"))
+        return out
+
+    full, windowed = counts(None), counts(2048)
+    # 16 q blocks of 512: block i meets i whole tiles and one on the diagonal
+    assert full["fwd"][:2] == (2176, 256) and full["dq"][:2] == (2112, 128)
+    # from block 4 on: one edge tile (12 sub-tiles, 8 compared), three
+    # whole ones, the diagonal one
+    assert windowed["fwd"][:2] == (1072, 352)
+    assert windowed["dq"][:2] == windowed["dkv"][:2] == (1008, 224)
+    for name in full:
+        assert windowed[name][0] < 0.5 * full[name][0], name
+        assert full[name][3] == 0 and windowed[name][3] == 2048
+    keys = sum(min(i + 1, 2048) for i in range(8192))
+    assert windowed["fwd"][2] == pytest.approx(keys / (1072 * 128 * 128))
+
+
 def test_flash_dropout_mask_and_masked_rows_are_the_parents():
     """The keep mask is the one the kernels had before they computed in
     sub-tiles, bit for bit (a digest of it taken then), the kernels apply it

@@ -130,6 +130,62 @@ def test_expert_layer_compiles_for_v5e_at_published_widths(
     assert "%moe_experts" in compiled.as_text()
 
 
+# the flash kernels with a window and grouped-query heads, and the grouped
+# matmul's backward, at the shapes that brought them (ISSUE 31): one
+# sequence of 8192, 32 query heads on 4 K/V heads of 128, window 2048;
+# 12288 sorted rows over 16 experts of 2048 x 2 * 1024 and 1024 x 2048
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_window_kernels_compile_for_v5e_at_published_widths(
+        window, v5e_device, monkeypatch):
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                              sharding=sharding)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    # forward, dq, dkv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_grouped_matmul_backward_compiles_for_v5e_at_published_widths(
+        v5e_device, monkeypatch):
+    import jax.numpy as jnp
+
+    from apex_tpu.transformer.moe import held_experts_ffn
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    def loss(x, router, gate_up, down, shared_up, shared_down, bias):
+        y, _, _ = held_experts_ffn(
+            x, router, bias, gate_up, down, top_k=8, held=(0, 16),
+            route_eps=1e-20, route_scale=2.826,
+            shared=(shared_up, shared_down))
+        return jnp.sum(y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        shape((8192, 2048)), shape((2048, 128), jnp.float32),
+        shape((16, 2048, 2048)), shape((16, 1024, 2048)),
+        shape((2048, 2048)), shape((1024, 2048)),
+        shape((128,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "%moe_experts" in text and "%moe_experts_bwd" in text
+
+
 TOY = chip_smoke.Sizes(
     hidden=64, layers=4, heads=4, vocab=256, positions=64, batch=4,
     microbatches=2, train_steps=3, lr=1e-3, max_batch=4, max_seq=64,

@@ -504,9 +504,31 @@ class TestStatsLoggerCadence:
     def test_fetch_flattens_trainstats(self):
         logger = TrainStatsLogger(MetricRegistry(rank=0), every_n=1)
         values = logger.fetch(self._stats())
-        assert set(TrainStats._fields) - {"moe_aux"} <= set(values)
+        optional = {"moe_aux", "moe_pairs", "moe_choices"}
+        assert set(TrainStats._fields) - optional <= set(values)
         assert isinstance(values["skipped_steps"], int)
         assert isinstance(values["loss"], float)
+
+    def test_fetch_and_log_of_the_expert_layers_pairs(self):
+        """``moe_pairs`` comes back as nested lists of ints and the logger
+        publishes their sum and the load peak; ``moe_choices`` is left on
+        the device, out of what is fetched, the gauges and the record."""
+        import numpy as np
+
+        pairs = np.asarray([[[4, 0, 2, 2]], [[1, 1, 1, 5]]], np.int32)
+        stats = self._stats()._replace(
+            moe_pairs=jnp.asarray(pairs),
+            moe_choices=jnp.zeros((2, 1, 3, 2), jnp.int32))
+        registry = MetricRegistry(rank=0)
+        logger = TrainStatsLogger(registry, every_n=1)
+        values = logger.fetch(stats)
+        assert values["moe_pairs"] == pairs.tolist()
+        assert "moe_choices" not in values
+        logger.log(0, stats)
+        assert registry.gauge("train/moe_pairs").value == 16
+        assert registry.gauge("train/moe_expert_load_peak").value == \
+            pytest.approx((4 / 2 + 5 / 2) / 2)
+        assert "train/moe_choices" not in registry.snapshot()
 
 
 class _FakeProfiler:
