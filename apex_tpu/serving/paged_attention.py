@@ -49,9 +49,24 @@ attention in one pass**:
   derived, not set (``_pages_per_step``): the VMEM bytes of a page
   against a fixed budget for the double-buffered K and V tiles, at most
   ``_MAX_PAGES`` operands per arena and at most ``max_blocks``.  (The
-  arenas cannot stay in HBM with the kernel issuing the page copies
-  itself: Mosaic refuses to slice a ``pl.ANY`` ref whose minor
-  dimension, ``head_dim`` 64, is not a multiple of 128.)
+  pooled arena cannot stay in HBM with the kernel issuing the page
+  copies itself: Mosaic refuses to slice a ``pl.ANY`` ref whose minor
+  dimension, ``head_dim`` 64, is not a multiple of 128.  So it stays on
+  page operands, and each page is computed as it lies.)
+- **decode over a cache group's flat arenas** (ISSUE 32): the same step
+  plan and grid, but a step's ``P`` pages are **one key tile**.  The
+  arenas' rows are whole lane tiles (``kv_heads * d``: 768 / 512 and
+  1536 / 1024 lanes at the widths that brought them), so they stay in
+  HBM (``pl.ANY``) and the kernel copies each live page of the plan into
+  its 16 rows of a ``[P * block, kv_heads * d]`` VMEM tile, double
+  buffered: the next step's pages travel under this step's products, a
+  dead page of a slot's last step is not copied and its rows are masked.
+  Per step: one ``q k^T`` a KV head over the whole tile (scores ``P *
+  block`` lanes wide), one mask, one update of the running max, sum and
+  accumulator for all heads, one ``p v`` a KV head.  ``P`` is derived
+  (``_flat_pages_per_step``): with a window the pages a window can touch
+  (``ceil(window / block) + 1``: a slot is one step), without one
+  ``_MAX_FLAT_PAGES``; at most what the same VMEM budget holds.
 - **multi-query** (prefill, verify): grid ``(batch, max_blocks)``, one
   page a step; blocks past the request's length are skipped with
   ``pl.when`` and their index maps **clamp to the last live block**, so
@@ -114,6 +129,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.metrics import default_registry
 from apex_tpu.observability.spans import named_span
 from apex_tpu.utils import platform
 
@@ -167,12 +183,14 @@ def _online_softmax(s, m_prev, l_prev):
     return p, alpha, m_new, l_new
 
 
-# VMEM the decode kernel gives to the double-buffered K and V pages of one
-# grid step (scale pages included), and the most pages it binds: each is
-# one operand of the call and one unrolled page of the kernel body.  On a
-# v5e the pipeline's bookkeeping costs 0.04 us per operand per step, so a
-# partly filled group costs with its width: 8 pages measured 4-6% faster
-# than 16 at gpt2-medium's shapes, and no slower than 4 (PERF.md §6).
+# VMEM the decode kernels give to the double-buffered K and V pages of one
+# grid step (scale pages included), and the most pages the pooled arena's
+# kernel binds: each is one operand of the call and one unrolled page of
+# the kernel body.  On a v5e the pipeline's bookkeeping costs 0.04 us per
+# operand per step, so a partly filled group costs with its width: 8 pages
+# measured 4-6% faster than 16 at gpt2-medium's shapes, and no slower than
+# 4 (PERF.md §6).  The cache groups' kernel copies its pages itself and
+# has its own cap (``_MAX_FLAT_PAGES``, below).
 _KV_TILE_BYTES = 4 * 1024 * 1024
 _MAX_PAGES = 8
 
@@ -769,8 +787,9 @@ def paged_prefill_attention_unfused(q, k_arena, v_arena, block_tables,
 # rows of all KV heads side by side on the lanes, dense whatever ``d`` is
 # (``4 * 192 = 768`` and ``8 * 192 = 1536`` lanes at the widths that
 # brought them), ``d_k`` beside another ``d_v``.  The kernels below are the
-# step-plan decode sweep and the multi-query block sweep again, reading a
-# KV head as a lane slice of the page, with two more mechanisms: a sliding
+# step-plan decode sweep (a step's pages joined into one key tile) and the
+# multi-query block sweep again, reading a KV head as a lane slice of the
+# tile or the page, with two more mechanisms: a sliding
 # ``window`` (pages wholly behind it are dropped from the decode plan and
 # from the prefill sweep, the edge pages are masked) and per-head ``sinks``
 # (one more logit of the softmax denominator, folded in when a row's sweep
@@ -816,59 +835,120 @@ def _check_flat(q, k_arena, v_arena, kv_heads, sinks):
     return dk, v_arena.shape[-1] // kv_heads
 
 
-def _decode_flat_kernel(plan_ref, slot_ref, group_ref, len_ref, first_ref,
-                        q_ref, *rest, scale: float, block_size: int,
-                        pages: int, g: int, hpg: int, dk: int, dv: int,
-                        window: Optional[int], has_sinks: bool,
-                        exact: bool):
-    """One grid step = ``pages`` pages of one slot, all heads; ``rest``: the
-    sinks (if any), the K arena bound ``pages`` times, the V arena
-    likewise, the output and the softmax state."""
-    del plan_ref
+# A grid step of the cache groups' decode kernel joins ``P`` pages of one
+# slot into one key tile of ``P * block_size`` rows (ISSUE 32): one score
+# product a KV head, one softmax update and one value product a KV head per
+# tile, where a page at a time was a chain of ``P * kv_heads`` dependent
+# updates of 16 keys each.  A window kernel's tile holds the pages a window
+# can touch, so a slot is one step.  A full kernel's tile is at most
+# ``_MAX_FLAT_PAGES`` pages, swept on a v5e at the ``mimo-v2-flash`` widths
+# (64 slots, histories 128-4200, 4 KV heads of 192 / 128, bfloat16;
+# ``examples/bench_paged_decode_groups.py``, ms a call, where a page at a
+# time took 9.53): 8 pages 1.18, 16 0.84, 24 0.77, 32 0.73, 40 0.70.  A step
+# costs about 0.55 us beside its bytes whatever its width, so the fewer the
+# better; past 32 a slot's last tile is mostly masked and the 4% left are
+# 0.04 ms of a 20 ms tick (PERF.md §6).
+_MAX_FLAT_PAGES = 32
+
+
+def _window_pages(window: int, block_size: int) -> int:
+    """The most pages the ``window`` rows a query reads can touch, wherever
+    they lie against the block edges."""
+    return pl.cdiv(window, block_size) + 1
+
+
+def _flat_pages_per_step(page_bytes: int, max_blocks: int, block_size: int,
+                         window: Optional[int]) -> int:
+    """How many pages a grid step of a cache group's decode kernel joins
+    into its key tile (``P``): the pages a window can touch where there is
+    one, ``_MAX_FLAT_PAGES`` where not; at most what ``_KV_TILE_BYTES``
+    holds twice over for K and for V, and a slot's whole table."""
+    want = (_MAX_FLAT_PAGES if window is None
+            else _window_pages(window, block_size))
+    return max(1, min(_KV_TILE_BYTES // (4 * page_bytes), want, max_blocks))
+
+
+def _decode_flat_kernel(steps_ref, plan_ref, slot_ref, group_ref, len_ref,
+                        first_ref, q_ref, *rest, scale: float,
+                        block_size: int, pages: int, g: int, hpg: int,
+                        dk: int, dv: int, window: Optional[int],
+                        has_sinks: bool, exact: bool):
+    """One grid step = one key tile of ``pages`` pages of one slot, all
+    heads.  ``rest``: the sinks (if any), the K and V arenas (in HBM), the
+    output, the double-buffered K and V tiles with their DMA semaphores and
+    the softmax state."""
     if has_sinks:
         sink_ref, rest = rest[0], rest[1:]
-    k_refs, v_refs = rest[:pages], rest[pages:2 * pages]
-    o_ref, m_sc, l_sc, acc_sc = rest[-4:]
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_sc, l_sc, acc_sc = rest
     step = pl.program_id(0)
-    slot = slot_ref[step]
-    j = group_ref[step]
-    length = len_ref[slot]
-    first_page = first_ref[slot]
+    buf = step % 2
 
-    @pl.when(j == 0)
+    def sweep(s):
+        """Step ``s``: its slot's length, its first page, its live pages."""
+        slot = slot_ref[s]
+        length = len_ref[slot]
+        page0 = first_ref[slot] + group_ref[s] * pages
+        n_live = jnp.clip(pl.cdiv(length, block_size) - page0, 0, pages)
+        return length, page0, n_live
+
+    def for_live_pages(s, into, act):
+        """``act`` on the copies of step ``s``'s live pages, each page's
+        block of both arenas into its rows of tile ``into``.  A dead page
+        has no copy: its rows keep what they held and are masked."""
+        n_live = sweep(s)[2]
+        for p in range(pages):
+            @pl.when(p < n_live)
+            def _page(p=p):
+                block = plan_ref[s * pages + p]
+                rows = pl.ds(p * block_size, block_size)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[block], k_buf.at[into, rows], sems.at[0, into]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[block], v_buf.at[into, rows], sems.at[1, into]))
+
+    @pl.when(step == 0)
+    def _first():
+        # rows no copy has reached weigh 0 in ``p v``: they must be finite
+        v_buf[...] = jnp.zeros_like(v_buf)
+        for_live_pages(0, 0, lambda copy: copy.start())
+
+    # the next step's pages travel under this step's products
+    @pl.when(step + 1 < steps_ref[0])
+    def _next():
+        for_live_pages(step + 1, 1 - buf, lambda copy: copy.start())
+
+    for_live_pages(step, buf, lambda copy: copy.wait())
+
+    @pl.when(group_ref[step] == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def page_step(k_ref, v_ref, first):
-        cols = first + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        live = cols < length
-        if window is not None:
-            live = live & (cols >= length - window)
-        for h in range(g):
-            rows = slice(h * hpg, (h + 1) * hpg)
-            q = q_ref[0, rows, :]                                # [hpg, dk]
-            k = k_ref[0, :, h * dk:(h + 1) * dk]                 # [bs, dk]
-            v = v_ref[0, :, h * dv:(h + 1) * dv]                 # [bs, dv]
-            s = _mxu(q, k, ((1,), (1,)), exact) * scale          # [hpg, bs]
-            s = jnp.where(live, s, NEG_INF)
-            p, alpha, m_new, l_new = _online_softmax(
-                s, m_sc[rows, :1], l_sc[rows, :1])
-            acc_sc[rows, :] = acc_sc[rows, :] * alpha + _mxu(
-                p, v, ((1,), (0,)), exact)
-            m_sc[rows, :] = jnp.broadcast_to(m_new, (hpg, _LANES))
-            l_sc[rows, :] = jnp.broadcast_to(l_new, (hpg, _LANES))
+    length, page0, _ = sweep(step)
+    cols = page0 * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, pages * block_size), 1)
+    live = cols < length
+    if window is not None:
+        live = live & (cols >= length - window)
+    k = k_buf[buf]                                      # [tile, g * dk]
+    v = v_buf[buf]                                      # [tile, g * dv]
+    # the heads' score products are independent of one another; their
+    # rows then share one mask and one update of the softmax state
+    s = jnp.concatenate([
+        _mxu(q_ref[0, h * hpg:(h + 1) * hpg, :], k[:, h * dk:(h + 1) * dk],
+             ((1,), (1,)), exact)
+        for h in range(g)], axis=0)                     # [n, tile]
+    s = jnp.where(live, s * scale, NEG_INF)
+    p, alpha, m_new, l_new = _online_softmax(s, m_sc[:, :1], l_sc[:, :1])
+    for h in range(g):
+        rows = slice(h * hpg, (h + 1) * hpg)
+        acc_sc[rows, :] = acc_sc[rows, :] * alpha[rows] + _mxu(
+            p[rows], v[:, h * dv:(h + 1) * dv], ((1,), (0,)), exact)
+    m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+    l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
 
-    for p in range(pages):
-        first = (first_page + j * pages + p) * block_size
-
-        @pl.when(first < length)
-        def _page(p=p, first=first):
-            page_step(k_refs[p], v_refs[p], first)
-
-    @pl.when((first_page + (j + 1) * pages) * block_size >= length)
+    @pl.when((page0 + pages) * block_size >= length)    # the slot's last
     def _finalize():
         l_fin, acc = l_sc[:, :1], acc_sc[...]
         if has_sinks:
@@ -879,7 +959,8 @@ def _decode_flat_kernel(plan_ref, slot_ref, group_ref, len_ref, first_ref,
 
 def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
                  window, sinks, scale):
-    name = "paged_decode_full" if window is None else "paged_decode_window"
+    kind = "full" if window is None else "window"
+    name = f"paged_decode_{kind}"
     b, n, _ = q.shape
     dk, dv = _check_flat(q, k_arena, v_arena, kv_heads, sinks)
     bs = k_arena.shape[1]
@@ -887,25 +968,29 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
     max_blocks = block_tables.shape[1]
     page_bytes = max(_vmem_bytes((bs, g * dk), k_arena.dtype),
                      _vmem_bytes((bs, g * dv), v_arena.dtype))
-    pages = _pages_per_step(page_bytes, max_blocks)
+    pages = _flat_pages_per_step(page_bytes, max_blocks, bs, window)
     lengths = lengths.astype(jnp.int32)
     if window is None:
         first_page = jnp.zeros((b,), jnp.int32)
+        span = max_blocks
     else:
         first_page = jnp.maximum(lengths - window, 0) // bs
+        span = min(max_blocks, _window_pages(window, bs))
+    # what the tiling is, for whoever reads a trace (host only, set when
+    # the call is traced); the tiles' fill is ``decode_plan``'s
+    # ``kv_tokens_*`` over steps x keys a step
+    reg = default_registry()
+    reg.gauge(f"paged_decode/keys_per_step/{kind}").set(pages * bs)
+    reg.gauge(f"paged_decode/steps_per_slot_max/{kind}").set(
+        pl.cdiv(span, pages))
     n_steps, plan, slot, group = _step_plan(
         block_tables, lengths, bs, pages, first_page)
     # a block handed back behind the window is never in the plan; an entry
     # that says so must still index the arena
     plan = jnp.maximum(plan, 0)
 
-    def row_idx(s, plan_ref, slot_ref, group_ref, len_ref, first_ref):
+    def row_idx(s, steps_ref, plan_ref, slot_ref, *refs):
         return (slot_ref[s], 0, 0)
-
-    def page_spec(p, lanes):
-        def idx(s, plan_ref, slot_ref, group_ref, len_ref, first_ref):
-            return (plan_ref[s * pages + p], 0, 0)
-        return pl.BlockSpec((1, bs, lanes), idx)
 
     in_specs = [pl.BlockSpec((1, n, dk), row_idx)]
     operands = [q]
@@ -913,15 +998,19 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
         in_specs.append(pl.BlockSpec(
             (n, 1), lambda s, *refs: (0, 0)))
         operands.append(sinks.astype(jnp.float32)[:, None])
-    in_specs += [page_spec(p, g * dk) for p in range(pages)]
-    in_specs += [page_spec(p, g * dv) for p in range(pages)]
-    operands += [k_arena] * pages + [v_arena] * pages
+    # the arenas stay in HBM: the kernel copies the plan's live pages
+    # (their minor dimensions, ``kv_heads * d``, are whole lane tiles)
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands += [k_arena, v_arena]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(n_steps,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n, dv), row_idx),
         scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, g * dk), k_arena.dtype),
+            pltpu.VMEM((2, pages * bs, g * dv), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((n, _LANES), jnp.float32),
             pltpu.VMEM((n, _LANES), jnp.float32),
             pltpu.VMEM((n, dv), jnp.float32),
@@ -937,11 +1026,14 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n, dv), q.dtype),
+            # in order: a slot's steps carry its softmax state, and a step
+            # starts the copies of the next
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=platform.pallas_interpret(),
             name=name,
-        )(plan, slot, group, lengths, first_page, *operands)
+        )(n_steps.reshape(1), plan, slot, group, lengths, first_page,
+          *operands)
 
 
 def _prefill_flat_kernel(tab_ref, len_ref, first_ref, q_ref, lim_ref, *rest,

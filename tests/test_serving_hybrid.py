@@ -29,7 +29,10 @@ from reference import mimo_v2_flash as reference          # noqa: E402
 
 from apex_tpu import parallel                              # noqa: E402
 from apex_tpu.observability import spans                   # noqa: E402
-from apex_tpu.observability.metrics import MetricRegistry  # noqa: E402
+from apex_tpu.observability.metrics import (               # noqa: E402
+    MetricRegistry,
+    default_registry,
+)
 from apex_tpu.serving import (                             # noqa: E402
     SamplingParams,
     ServingConfig,
@@ -41,8 +44,11 @@ from apex_tpu.serving.kv_cache import (                    # noqa: E402
     CacheGroup,
     KVCacheConfig,
 )
+from apex_tpu.serving import paged_attention as pa_module   # noqa: E402
 from apex_tpu.serving.paged_attention import (             # noqa: E402
+    _flat_pages_per_step,
     _step_plan,
+    _vmem_bytes,
     paged_attention_decode,
     paged_attention_decode_unfused,
     paged_prefill_attention,
@@ -429,6 +435,133 @@ def test_step_plan_drops_the_pages_behind_the_window():
     same = _step_plan(tables, lengths, BLOCK, 2, jnp.zeros((4,), jnp.int32))
     for x, y in zip(_step_plan(tables, lengths, BLOCK, 2), same):
         assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+# the decode kernels at the widths that brought them (ISSUE 27), bfloat16,
+# blocks of 16: a grid step's pages are one key tile (ISSUE 32)
+CELL_KINDS = {"full": dict(g=4, window=None, sink=False),
+              "window": dict(g=8, window=128, sink=True)}
+CELL_N, CELL_DK, CELL_DV, CELL_BLOCK, CELL_TABLE = 64, 192, 128, 16, 104
+
+
+def cell_pages(kind, dtype=jnp.bfloat16, max_blocks=CELL_TABLE):
+    """The pages of a key tile that the module derives at the cell's
+    widths."""
+    c = CELL_KINDS[kind]
+    page = max(_vmem_bytes((CELL_BLOCK, c["g"] * CELL_DK), dtype),
+               _vmem_bytes((CELL_BLOCK, c["g"] * CELL_DV), dtype))
+    return _flat_pages_per_step(page, max_blocks, CELL_BLOCK, c["window"])
+
+
+def cell_case(kind, lengths, dtype, seed=0):
+    """Arenas, a table of distinct blocks and queries at the cell's widths;
+    the values are the dtype's own, so a float64 reference over them differs
+    by the kernel's rounding alone."""
+    c = CELL_KINDS[kind]
+    rng = np.random.default_rng(seed)
+    b, nb = len(lengths), len(lengths) * CELL_TABLE
+
+    def draw(*shape):
+        x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        return x.astype(dtype)
+
+    return dict(
+        args=(draw(b, CELL_N, CELL_DK), draw(nb, CELL_BLOCK, c["g"] * CELL_DK),
+              draw(nb, CELL_BLOCK, c["g"] * CELL_DV),
+              jnp.asarray(rng.permutation(nb).reshape(b, CELL_TABLE),
+                          jnp.int32),
+              jnp.asarray(lengths, jnp.int32)),
+        kw=dict(kv_heads=c["g"], window=c["window"],
+                sinks=(jnp.asarray(4.0 + rng.normal(size=(CELL_N,)),
+                                   jnp.float32) if c["sink"] else None)))
+
+
+def cell_lengths(kind, dtype=jnp.bfloat16):
+    """0, 1, a row short of a page, exactly a step, a row past its edge and
+    a history of several steps (the table's end)."""
+    step = cell_pages(kind, dtype) * CELL_BLOCK
+    return [0, 1, CELL_BLOCK - 1, step, step + 1, CELL_TABLE * CELL_BLOCK]
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_decode_kernel_at_the_cells_widths(kind):
+    lengths = cell_lengths(kind)
+    case = cell_case(kind, lengths, jnp.bfloat16)
+    fused = np.asarray(paged_attention_decode(
+        *case["args"], **case["kw"]).astype(jnp.float32))
+    unfused = np.asarray(paged_attention_decode_unfused(
+        *case["args"], **case["kw"]).astype(jnp.float32))
+    q, k, v, tables, _ = (np.asarray(x.astype(jnp.float32))
+                          if x.dtype == jnp.bfloat16 else np.asarray(x)
+                          for x in case["args"])
+    sinks = case["kw"]["sinks"]
+    want = np.stack([dense_attention(
+        q[i], k, v, tables[i], lengths[i], case["kw"]["kv_heads"],
+        case["kw"]["window"], None if sinks is None else np.asarray(sinks))
+        for i in range(len(lengths))])
+    assert fused.shape == (len(lengths), CELL_N, CELL_DV)
+    assert not fused[0].any()                   # length 0: the zero row
+    # probabilities rounded to bfloat16 for ``p v``, a bfloat16 result
+    np.testing.assert_allclose(fused, want, atol=2e-2)
+    np.testing.assert_allclose(fused, unfused, atol=2e-2)
+
+
+@pytest.mark.parametrize("dtype, atol", [(jnp.float32, 1e-5),
+                                         (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_decode_result_does_not_depend_on_the_key_tile(kind, pages, dtype,
+                                                       atol, monkeypatch):
+    """The same inputs through key tiles of 1, 2 and 8 pages and through the
+    derived one: float32 arenas (the ``HIGHEST`` path) to rounding, bfloat16
+    to its tolerance."""
+    case = cell_case(kind, cell_lengths(kind, dtype)[1:], dtype, seed=1)
+    derived = np.asarray(paged_attention_decode(
+        *case["args"], **case["kw"]).astype(jnp.float32))
+    monkeypatch.setattr(pa_module, "_flat_pages_per_step", lambda *a: pages)
+    forced = np.asarray(paged_attention_decode(
+        *case["args"], **case["kw"]).astype(jnp.float32))
+    np.testing.assert_allclose(forced, derived, atol=atol)
+
+
+@pytest.mark.parametrize("lengths", [
+    [129, 130, 143, 144, 145, 160],       # just past the window
+    [1000, 2047, 2048, 2049, 4097, 6144],  # long histories, the table's end
+    [0, 1, 127, 128, 500, 6143],          # and with slots below it
+])
+def test_a_window_slots_sweep_is_one_grid_step(lengths):
+    """At window 128 over blocks of 16 the derived key tile holds the 9 pages
+    a window can touch, so the plan has one step a slot."""
+    pages = cell_pages("window", max_blocks=384)
+    assert pages == 9
+    lengths = jnp.asarray(lengths, jnp.int32)
+    b = lengths.shape[0]
+    tables = jnp.arange(b * 384, dtype=jnp.int32).reshape(b, 384)
+    first = jnp.maximum(lengths - 128, 0) // CELL_BLOCK
+    n_steps, plan, slot, group = _step_plan(tables, lengths, CELL_BLOCK,
+                                            pages, first)
+    assert int(n_steps) == b
+    assert np.asarray(slot)[:b].tolist() == list(range(b))
+    assert not np.asarray(group)[:b].any()
+    # and every row a query can read lies in its step's tile
+    last = -(-np.asarray(lengths) // CELL_BLOCK)
+    assert ((last - np.asarray(first)) <= pages).all()
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_key_tile_gauges_read_what_the_plan_says(kind):
+    case = cell_case(kind, [40, 300], jnp.bfloat16)
+    reg = default_registry()
+    for name in ("keys_per_step", "steps_per_slot_max"):
+        reg.gauge(f"paged_decode/{name}/{kind}").set(-1)
+    jax.jit(lambda *xs: paged_attention_decode(*xs, **case["kw"])).lower(
+        *case["args"])                          # traced, not run
+    snap = reg.snapshot()
+    pages = cell_pages(kind)
+    assert snap[f"paged_decode/keys_per_step/{kind}"] == pages * CELL_BLOCK
+    span = CELL_TABLE if kind == "full" else 9
+    assert snap[f"paged_decode/steps_per_slot_max/{kind}"] == -(-span // pages)
 
 
 def test_a_pooled_arena_takes_no_window(arena):

@@ -463,7 +463,9 @@ def test_all_unreachable_sheds_typed_rejected_after_deadline():
         clock=lambda: clock[0])
     try:
         wait_states(router, tries=4000)
-        req = router.submit([5, 5], 6)
+        # long enough that it cannot have finished when its first token is
+        # seen, however many ticks' tokens a starved proxy delivers at once
+        req = router.submit([5, 5], 200)
         cut = False
         for _ in range(6000):
             router.pump()
