@@ -82,7 +82,11 @@ docs/serving.md):
 - ``serving/moe_pairs`` / ``serving/moe_experts_hit`` counters — the
   ``(token, expert)`` pairs the decode and prefill calls routed to held
   experts, and the (layer, expert) entries they hit (ISSUE 27; a model
-  with expert layers only)
+  with expert layers only); ``serving/moe_group_tokens`` — the live
+  tokens, summed over expert layers, whose router kept a group of experts
+  held here (every live token without a group limit; ISSUE 33)
+- ``serving/kv_latent_bytes_per_token`` gauge — what the latent cache
+  groups' arenas hold over the tokens they can hold, all layers (ISSUE 33)
 - ``serving/window_blocks_freed`` counter — blocks a window cache group
   handed back behind the window
 
@@ -176,7 +180,9 @@ class ServingConfig:
     ``None`` keeps the engine byte-identical to the bare path.
     A model whose layers are of more than one kind
     (``TransformerConfig.hybrid``) gets one cache group per attention
-    kind: ``n_blocks`` sizes the groups without a window, and a group
+    kind: ``n_blocks`` sizes the groups without a window (a latent
+    kind's group among them: one arena a layer of ``latent_rank +
+    rotary_dim`` channels a token, no value arena), and a group
     with one gets what ``max_batch`` slots need for the window and one
     chunk; it takes ``prefix_caching=False`` and none of ``speculative``,
     ``lora`` or an int8 cache yet.
@@ -303,8 +309,9 @@ class ServingEngine:
                           timeline_tick_every)
 
     def _cache_groups(self, hybrid, serving: ServingConfig, n_blocks: int):
-        """One cache group per attention kind that has layers: those
-        without a window get ``n_blocks``, a window group what
+        """One cache group per attention kind that has layers, holding the
+        kind's rows (a latent kind's one row of latent and shared key):
+        those without a window get ``n_blocks``, a window group what
         ``max_batch`` slots need for the window and one chunk, wherever
         they lie against the block edges."""
         groups = []
@@ -312,9 +319,10 @@ class ServingEngine:
             layers = hybrid.layers_of(ki)
             if not layers:
                 continue
+            kv_heads, k_dim, v_dim, latent = kind.cache_row
             group = CacheGroup(
-                layers=layers, kv_heads=kind.kv_heads, k_dim=kind.k_dim,
-                v_dim=kind.v_dim, n_blocks=n_blocks, window=kind.window)
+                layers=layers, kv_heads=kv_heads, k_dim=k_dim, v_dim=v_dim,
+                n_blocks=n_blocks, window=kind.window, latent=latent)
             if kind.window is not None:
                 per_slot = group.blocks_spanned(
                     self.prefill_len, serving.block_size, n_blocks)
@@ -510,6 +518,16 @@ class ServingEngine:
                 "serving/moe_experts_hit")
             self._counters.window_blocks_freed = counter(
                 "serving/window_blocks_freed")
+            self._counters.moe_group_tokens = counter(
+                "serving/moe_group_tokens")
+            latent = [g for g in self.cache.cache_groups if g.latent]
+            if latent:
+                # what the latent groups' arenas hold over the tokens they
+                # can hold (all layers; a row's lane padding counts: it is
+                # held)
+                self.registry.gauge("serving/kv_latent_bytes_per_token").set(
+                    sum(g.row_lanes * len(g.layers) for g in latent)
+                    * np.dtype(self.cache.dtype).itemsize)
         self._counted_window_freed = 0
         self._queue_wait = self.registry.histogram(
             "serving/queue_wait_ms", keep_samples=4096)
@@ -1010,14 +1028,19 @@ class ServingEngine:
             return self._jnp.asarray(self._tables)
         return tuple(self._jnp.asarray(t) for t in self._group_tables)
 
-    def _note_routed(self, phase: spans.span, pairs) -> None:
+    def _note_routed(self, phase: spans.span, pairs, reached) -> None:
         """Record what a call routed to the held experts (``pairs [expert
-        layers, held experts]``) on its fetch span and the counters."""
+        layers, held experts]``; ``reached [expert layers]``, the live
+        tokens whose router kept a group held here) on its fetch span and
+        the counters."""
         total, hit = int(pairs.sum()), int(np.count_nonzero(pairs))
+        tokens = int(reached.sum())
         phase.note(moe_pairs=total, moe_experts_hit=hit,
-                   moe_peak_pairs=int(pairs.max()) if pairs.size else 0)
+                   moe_peak_pairs=int(pairs.max()) if pairs.size else 0,
+                   moe_group_tokens=tokens)
         self._counters.moe_pairs.inc(total)
         self._counters.moe_experts_hit.inc(hit)
+        self._counters.moe_group_tokens.inc(tokens)
 
     def _sampling_arrays(self, phase: spans.span):
         """Per-slot sampling-policy data ([max_batch] each, rebuilt per
@@ -1124,8 +1147,8 @@ class ServingEngine:
             routed = None
             with self._span("serving/tick/prefill_dispatch"):
                 if self.hybrid:
-                    self.arenas, next_tokens, _, routed, chosen = \
-                        self._prefill(*args)
+                    (self.arenas, next_tokens, _, routed, chosen,
+                     reached) = self._prefill(*args)
                     self._tick_choices.append((chosen, tuple(
                         (req.rid, req.slot * T, req.cache_len, chunk)
                         for req, chunk in plan)))
@@ -1139,8 +1162,9 @@ class ServingEngine:
                     next_np = np.asarray(next_tokens)
                 else:
                     # one transfer brings the tokens and the routing counts
-                    next_np, routed = self._fetch((next_tokens, routed))
-                    self._note_routed(fetch, routed)
+                    next_np, routed, reached = self._fetch(
+                        (next_tokens, routed, reached))
+                    self._note_routed(fetch, routed, reached)
 
         with self._span("serving/tick/prefill_deliver"):
             self._counters.prefill_calls.inc()
@@ -1276,8 +1300,8 @@ class ServingEngine:
         routed = None
         with self._span("serving/tick/decode_dispatch") as dispatch:
             if self.hybrid:
-                self.arenas, out_tokens, accepted, logits, routed, chosen = \
-                    self._decode(*args)
+                (self.arenas, out_tokens, accepted, logits, routed, chosen,
+                 reached) = self._decode(*args)
                 self._tick_choices.append((chosen, tuple(
                     (req.rid, req.slot, req.cache_len, 1) for req in reqs)))
             elif self.adapter_arena is None:
@@ -1293,9 +1317,9 @@ class ServingEngine:
                 out_np = np.asarray(out_tokens)
                 acc_np = np.asarray(accepted)
             else:
-                out_np, acc_np, routed = self._fetch(
-                    (out_tokens, accepted, routed))
-                self._note_routed(fetch, routed)
+                out_np, acc_np, routed, reached = self._fetch(
+                    (out_tokens, accepted, routed, reached))
+                self._note_routed(fetch, routed, reached)
         tick.note(decode_slots=len(reqs))
         return reqs, drafts, out_np, acc_np, dispatch.ms + fetch.ms
 
@@ -1312,9 +1336,10 @@ class ServingEngine:
         the window) and the blocks that hold them, summed over the groups
         of a kind, and what the window groups hold and handed back."""
         bs = self.cache.block_size
-        reads = {"full": [0, 0], "window": [0, 0]}
+        reads = {"full": [0, 0], "window": [0, 0], "latent": [0, 0]}
         for g in self.cache.cache_groups:
-            kind = reads["full" if g.window is None else "window"]
+            kind = reads["latent" if g.latent else
+                         "full" if g.window is None else "window"]
             for h in history:
                 first = g.first_needed_block(h - 1, bs)
                 kind[0] += h if g.window is None else min(h, g.window)
@@ -1327,6 +1352,8 @@ class ServingEngine:
                    kv_pages_full=reads["full"][1],
                    kv_tokens_window=reads["window"][0],
                    kv_pages_window=reads["window"][1],
+                   kv_tokens_latent=reads["latent"][0],
+                   kv_pages_latent=reads["latent"][1],
                    window_blocks_held=sched.window_blocks_held(),
                    window_blocks_freed=freed)
 

@@ -58,7 +58,11 @@ the kernels never reach them).  A group's arenas are one ``(k, v)`` pair
 ``[..., kv_heads * v_dim]``: rows dense on the lanes whatever the head
 width, so the kernels read them as they lie and no layer slices or
 converts an arena it shares with another (:func:`init_group_arenas`).  A
-configuration without ``groups`` is the one pooled arena described above,
+latent group (ISSUE 33: multi-head latent attention) keeps one arena per
+layer, ``[n_blocks, block_size, latent_rank + rotary_dim]`` in whole lane
+tiles (:attr:`CacheGroup.row_lanes`): the row every head scores against,
+whose leading channels are also every head's values.
+A configuration without ``groups`` is the one pooled arena described above,
 unchanged.
 
 The per-request *block table* (logical block index -> physical block
@@ -115,7 +119,10 @@ class CacheGroup:
     rows of ``k_dim`` (keys) beside ``v_dim`` (values) per token and layer,
     its pool of ``n_blocks`` blocks, and ``window``: ``None`` keeps every
     token, ``w`` only what a query at position ``i`` reads,
-    ``i - w < j <= i``."""
+    ``i - w < j <= i``.  A ``latent`` group keeps one row of ``k_dim``
+    channels per token and layer and nothing beside it: every head scores
+    against the whole row and takes its values from the row's leading
+    ``v_dim`` channels, so there is no value arena."""
 
     layers: Tuple[int, ...]
     kv_heads: int
@@ -123,6 +130,16 @@ class CacheGroup:
     v_dim: int
     n_blocks: int
     window: Optional[int] = None
+    latent: bool = False
+
+    @property
+    def row_lanes(self) -> int:
+        """Lanes of a latent group's arena row: ``k_dim`` rounded up to
+        whole 128-lane tiles.  HBM's tiling pads an array's minor dimension
+        to that whatever shape is declared (576 channels lie in 640 lanes
+        either way); declared, a kernel can copy a page as it lies.  The
+        lanes past ``k_dim`` hold zeros."""
+        return -(-self.k_dim // 128) * 128
 
     def first_needed_block(self, query_pos: int, block_size: int) -> int:
         """The first logical block a query at ``query_pos`` (and so any
@@ -271,20 +288,22 @@ def init_kv_arena(cfg: KVCacheConfig, mesh=None, tp_axis: Optional[str] = "tp"
 def init_group_arenas(cfg: KVCacheConfig) -> Tuple[Any, ...]:
     """The zeroed arenas of a configuration with ``groups``: for each group
     a tuple, over its layers, of ``(k [n_blocks, block_size, kv_heads *
-    k_dim], v [n_blocks, block_size, kv_heads * v_dim])``.  One pair per
-    layer, each an array of its own: a layer's kernel call takes its arena
-    whole and its appended rows land in place in the donated buffer."""
+    k_dim], v [n_blocks, block_size, kv_heads * v_dim])``, or of ``(rows
+    [n_blocks, block_size, row_lanes],)`` alone for a latent group.  One tuple
+    per layer, each an array of its own: a layer's kernel call takes its
+    arena whole and its appended rows land in place in the donated buffer."""
     import jax.numpy as jnp
 
     if cfg.quantized:
         raise NotImplementedError("cache groups hold no int8 arenas yet")
-    return tuple(
-        tuple((jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * g.k_dim),
-                         cfg.dtype),
-               jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * g.v_dim),
-                         cfg.dtype))
-              for _ in g.layers)
-        for g in cfg.groups)
+
+    def layer_arenas(g):
+        widths = (g.row_lanes,) if g.latent else (g.k_dim, g.v_dim)
+        return tuple(jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * w),
+                               cfg.dtype) for w in widths)
+
+    return tuple(tuple(layer_arenas(g) for _ in g.layers)
+                 for g in cfg.groups)
 
 
 class BlockAllocator:

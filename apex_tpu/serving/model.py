@@ -78,6 +78,17 @@ RMSNorm, bias-free, with every GEMM's operands, the activations between
 them and the cache in the model's dtype (bf16) and the residual stream,
 the norm statistics, the accumulation, the router and the logits in fp32;
 tokens are flattened to ``[tokens, hidden]``.
+
+**A latent kind** (ISSUE 33; multi-head latent attention) caches one row a
+token and layer, the normed latent beside the one rotated key all heads
+share, and is computed in the **absorbed** form: each head's no-position
+query is multiplied through ``W_UK`` into the latent's channels (scope
+``mla_absorb_q``), the paged kernel scores ``[q~ | q_rope]`` against the
+cached rows as they lie and takes the rows' latent channels as values, and
+``W_UV`` expands each head's result afterwards (scope ``mla_expand_o``).
+Nothing expanded is ever stored.  A prefill call's absorbed queries are
+``tokens x heads x row`` (a gigabyte at 8,192 tokens and 128 heads), so
+the attention of a call is walked a few slots at a time.
 :func:`decode_model` picks the class from the configuration;
 :func:`serving_config` no longer refuses experts, only the Switch layer,
 which remains a training dry run.
@@ -103,14 +114,19 @@ from apex_tpu.serving.lora import LoRAConfig, lora_delta
 from apex_tpu.serving.paged_attention import (
     paged_attention_decode,
     paged_attention_decode_unfused,
+    paged_decode_latent,
+    paged_decode_latent_unfused,
     paged_prefill_attention,
     paged_prefill_attention_unfused,
+    paged_prefill_latent,
+    paged_prefill_latent_unfused,
 )
 from apex_tpu.serving.sampling import sample_tokens
 from apex_tpu.transformer.layers.layer_norm import FusedLayerNorm
 from apex_tpu.transformer import moe
 from apex_tpu.transformer.rope import (
     apply_rotary_decode,
+    apply_rotary_interleaved,
     apply_rotary_packed,
     rotary_cos_sin,
 )
@@ -615,12 +631,22 @@ def unserved_fields(spec) -> list:
     out += [name for name, used in (
         ("sandwich_norm", spec.sandwich_norm),
         ("embedding_multiplier", spec.embedding_multiplier != 1.0)) if used]
-    if spec.experts is not None:
-        out += [f"experts.{name}" for name, used in (
-            ("shared_experts", spec.experts.shared_experts),
-            ("route_scale", spec.experts.route_scale != 1.0),
-            ("route_eps", spec.experts.route_eps != 0.0)) if used]
+    if spec.experts is not None and spec.experts.route_eps != 0.0:
+        out.append("experts.route_eps")
     return out
+
+
+# the absorbed queries of one walk of a latent prefill call's slots, at most
+_LATENT_WALK_BYTES = 256 << 20
+
+
+def _slots_a_walk(slots: int, bytes_a_slot: int) -> int:
+    """The most slots, a divisor of ``slots``, whose absorbed queries lie
+    within ``_LATENT_WALK_BYTES``."""
+    walk = max(1, min(slots, _LATENT_WALK_BYTES // max(bytes_a_slot, 1)))
+    while slots % walk:
+        walk -= 1
+    return walk
 
 
 def decode_model(config: TransformerConfig, cache: KVCacheConfig, **kwargs):
@@ -660,11 +686,13 @@ class HybridDecodeModel:
         self.place = {}
         for gi, group in enumerate(cache.groups):
             kind = self.spec.kinds[self.spec.layer_kinds[group.layers[0]]]
-            if (group.kv_heads, group.k_dim, group.v_dim, group.window) != (
-                    kind.kv_heads, kind.k_dim, kind.v_dim, kind.window):
+            if (group.kv_heads, group.k_dim, group.v_dim, group.latent,
+                    group.window) != kind.cache_row + (kind.window,):
                 raise ValueError(
                     f"cache group {gi} {group} does not hold the rows of "
-                    f"attention kind {kind}")
+                    f"attention kind {kind} (a latent kind keeps one row "
+                    "of latent_rank + rotary_dim channels, any other k_dim "
+                    "beside v_dim a KV head)")
             for li, layer in enumerate(group.layers):
                 self.place[layer] = (gi, li)
         if sorted(self.place) != list(range(cfg.num_layers)):
@@ -674,7 +702,7 @@ class HybridDecodeModel:
     # ----------------------------------------------------------------- util
 
     def _norm(self, x, weight):
-        return fused_rms_norm_affine(x, weight, self.cfg.hidden_size,
+        return fused_rms_norm_affine(x, weight, weight.shape[-1],
                                      self.cfg.layernorm_epsilon)
 
     def _mm(self, x, w):
@@ -688,13 +716,65 @@ class HybridDecodeModel:
         return apply_rotary_packed(x.astype(jnp.float32)[:, None],
                                    cos[:, None], sin[:, None])[:, 0]
 
-    def _walk(self, params, x, positions, live, arenas, attend):
+    def _latent_rows(self, lp, h1, positions, kind, lanes):
+        """A latent layer's projections of normed ``h1 [tokens, hidden]``:
+        ``(q_nope [tokens, heads, nope_dim], q_rope [tokens, heads,
+        rotary_dim], rows [tokens, lanes])`` in the model's dtype; ``rows``
+        are what the cache keeps, ``[normed latent | rotated key]`` and
+        zeros up to ``lanes``.  Both low-rank vectors are RMS-normed, the
+        rotation (YaRN-scaled where the kind says) is in fp32."""
+        dtype = self.cfg.dtype
+        rank, nope = kind.latent_rank, kind.nope_dim
+        c_q = self._norm(self._mm(h1, lp["wq_a"]), lp["q_a_norm"])
+        q = self._mm(c_q.astype(dtype), lp["wq_b"]).reshape(
+            -1, kind.num_heads, kind.k_dim)
+        c_kv = self._mm(h1, lp["wkv_a"])
+        cos, sin = rotary_cos_sin(positions, kind.rotary_dim,
+                                  kind.rotary_base, jnp.float32,
+                                  scaling=kind.rotary_scaling)
+        q_rope = apply_rotary_interleaved(q[..., nope:], cos, sin)
+        k_rope = apply_rotary_interleaved(c_kv[:, None, rank:], cos, sin)
+        rows = jnp.concatenate(
+            [self._norm(c_kv[:, :rank], lp["kv_a_norm"]), k_rope[:, 0],
+             jnp.zeros((c_kv.shape[0], lanes - c_kv.shape[1]), jnp.float32)],
+            axis=-1)
+        return (q[..., :nope].astype(dtype), q_rope.astype(dtype),
+                rows.astype(dtype))
+
+    def _latent_attention(self, kind, lp, q_nope, q_rope, lanes, kernel):
+        """The absorbed form round ``kernel(q [..., heads, lanes]) ->
+        [..., heads, latent_rank]``: ``W_UK`` into the query before it,
+        ``W_UV`` after.  Returns ``[..., heads * v_dim]`` in the model's
+        dtype."""
+        dtype = self.cfg.dtype
+        rank = kind.latent_rank
+        with jax.named_scope("mla_absorb_q"):
+            q = jnp.einsum("...nd,ncd->...nc", q_nope, lp["w_uk"],
+                           preferred_element_type=jnp.float32).astype(dtype)
+            q = jnp.concatenate(
+                [q, q_rope, jnp.zeros(q.shape[:-1] + (
+                    lanes - rank - kind.rotary_dim,), dtype)], axis=-1)
+        out = kernel(q)
+        with jax.named_scope("mla_expand_o"):
+            ctx = jnp.einsum("...nc,ncd->...nd", out, lp["w_uv"],
+                             preferred_element_type=jnp.float32)
+        return ctx.reshape(ctx.shape[:-2] + (-1,)).astype(dtype)
+
+    @staticmethod
+    def _latent_scale(kind):
+        return (kind.softmax_scale if kind.softmax_scale is not None
+                else kind.k_dim ** -0.5)
+
+    def _walk(self, params, x, positions, live, arenas, attend,
+              attend_latent):
         """The layers in order on ``x [tokens, hidden]`` (``live [tokens]``:
         the rows that are tokens, not padding).  ``attend(kind,
         group, q, k, v, layer_arenas, sinks) -> (ctx [tokens, heads *
         v_dim], layer_arenas)`` appends the rows and runs the paged
-        kernel.  Returns ``(x, arenas, pairs [expert layers, held], chosen
-        [expert layers, tokens, top_k])``."""
+        kernel; ``attend_latent(kind, group, lp, q_nope, q_rope, rows,
+        layer_arenas)`` likewise for a latent kind.  Returns ``(x, arenas,
+        pairs [expert layers, held], chosen [expert layers, tokens, top_k],
+        reached [expert layers])``."""
         cfg, spec = self.cfg, self.spec
         dtype = cfg.dtype
         # the residual stream stays in fp32 (each layer adds a small term to
@@ -702,30 +782,45 @@ class HybridDecodeModel:
         # operands are the model's dtype
         x = x.astype(jnp.float32)
         arenas = [list(group) for group in arenas]
-        pairs, chosen = [], []
+        pairs, chosen, reached = [], [], []
         for layer, lp in enumerate(params.layers):
             kind = spec.kinds[spec.layer_kinds[layer]]
             gi, li = self.place[layer]
             n, g = kind.num_heads, kind.kv_heads
             h1 = self._norm(x, lp["norm1"]).astype(dtype)
-            q = self._rotate(self._mm(h1, lp["wq"]).reshape(-1, n, kind.k_dim),
-                             positions, kind).astype(dtype)
-            k = self._rotate(self._mm(h1, lp["wk"]).reshape(-1, g, kind.k_dim),
-                             positions, kind).astype(dtype)
-            v = (self._mm(h1, lp["wv"]) * spec.value_scale).astype(dtype)
-            ctx, arenas[gi][li] = attend(
-                kind, gi, q, k.reshape(-1, g * kind.k_dim), v,
-                arenas[gi][li], lp["sinks"] if kind.sink else None)
+            if kind.latent:
+                ctx, arenas[gi][li] = attend_latent(
+                    kind, gi, lp, *self._latent_rows(
+                        lp, h1, positions, kind,
+                        self.cache.groups[gi].row_lanes), arenas[gi][li])
+            else:
+                q = self._rotate(
+                    self._mm(h1, lp["wq"]).reshape(-1, n, kind.k_dim),
+                    positions, kind).astype(dtype)
+                k = self._rotate(
+                    self._mm(h1, lp["wk"]).reshape(-1, g, kind.k_dim),
+                    positions, kind).astype(dtype)
+                v = (self._mm(h1, lp["wv"]) * spec.value_scale).astype(dtype)
+                ctx, arenas[gi][li] = attend(
+                    kind, gi, q, k.reshape(-1, g * kind.k_dim), v,
+                    arenas[gi][li], lp["sinks"] if kind.sink else None)
             x = x + self._mm(ctx, lp["wo"])
             h2 = self._norm(x, lp["norm2"]).astype(dtype)
             if spec.layer_experts[layer]:
-                y, routed, experts = moe.held_experts_ffn(
-                    h2, lp["router"], lp["router_bias"],
+                ex = spec.experts
+                more = dict(ex.routing)
+                if ex.route_scale != 1.0:
+                    more["route_scale"] = ex.route_scale
+                if ex.shared_experts:
+                    more["shared"] = (lp["shared_gate_up"],
+                                      lp["shared_down"])
+                y, routed, experts, tokens = moe.held_experts_ffn(
+                    h2, lp["router"], lp.get("router_bias"),
                     lp["experts_gate_up"], lp["experts_down"],
-                    top_k=spec.experts.top_k, held=spec.experts.held,
-                    live=live)
+                    top_k=ex.top_k, held=ex.held, live=live, **more)
                 pairs.append(routed)
                 chosen.append(experts)
+                reached.append(tokens)
             else:
                 f = lp["ffn_down"].shape[0]
                 gate_up = self._mm(h2, lp["ffn_gate_up"])
@@ -738,7 +833,10 @@ class HybridDecodeModel:
                  else jnp.zeros((0, held), jnp.int32))
         chosen = (jnp.stack(chosen) if chosen
                   else jnp.zeros((0, x.shape[0], top_k), jnp.int32))
-        return x, tuple(tuple(group) for group in arenas), pairs, chosen
+        reached = (jnp.stack(reached) if reached
+                   else jnp.zeros((0,), jnp.int32))
+        return (x, tuple(tuple(group) for group in arenas), pairs, chosen,
+                reached)
 
     def _logits(self, params, x):
         """fp32 logits ``[rows, vocab]`` of ``x [rows, hidden]``."""
@@ -759,10 +857,11 @@ class HybridDecodeModel:
         and must be zero), with a tuple of block tables and two more
         results: ``pairs [expert layers, held experts]`` int32, the
         ``(token, expert)`` pairs this step routed to each held expert,
-        and ``chosen [expert layers, max_batch, top_k]`` int32, the experts
-        each slot's router chose.  Returns ``(arenas, out_tokens
-        [max_batch, 1], accepted [max_batch], logits [max_batch, 1, vocab],
-        pairs, chosen)``."""
+        ``chosen [expert layers, max_batch, top_k]`` int32, the experts
+        each slot's router chose, and ``reached [expert layers]`` int32,
+        the live slots whose router kept a group of experts held here.
+        Returns ``(arenas, out_tokens [max_batch, 1], accepted [max_batch],
+        logits [max_batch, 1, vocab], pairs, chosen, reached)``."""
         del n_draft
         bs = self.cache.block_size
         B = tokens.shape[0]
@@ -772,15 +871,31 @@ class HybridDecodeModel:
         dest_offsets = positions % bs
         attend = (paged_attention_decode if self.fused_attention
                   else paged_attention_decode_unfused)
+        attend_rows = (paged_decode_latent if self.fused_attention
+                       else paged_decode_latent_unfused)
 
-        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+        def destination(gi):
             table = block_tables[gi]
             phys = jnp.take_along_axis(
                 table, jnp.clip(logical, 0, table.shape[1] - 1)[:, None],
                 axis=1)[:, 0]
             # inactive slots write out of range and the scatter drops them
-            dest = jnp.where(active, phys,
-                             self.cache.groups[gi].n_blocks)
+            return table, jnp.where(active, phys,
+                                    self.cache.groups[gi].n_blocks)
+
+        def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
+            table, dest = destination(gi)
+            arena = layer_arenas[0].at[dest, dest_offsets].set(
+                rows.astype(layer_arenas[0].dtype), mode="drop")
+            ctx = self._latent_attention(
+                kind, lp, q_nope, q_rope, arena.shape[-1],
+                lambda q: attend_rows(q, arena, table, lengths,
+                                      v_dim=kind.latent_rank,
+                                      scale=self._latent_scale(kind)))
+            return ctx, (arena,)
+
+        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+            table, dest = destination(gi)
             k_arena, v_arena = layer_arenas
             k_arena = k_arena.at[dest, dest_offsets].set(
                 k.astype(k_arena.dtype), mode="drop")
@@ -791,14 +906,14 @@ class HybridDecodeModel:
             return ctx.reshape(B, -1).astype(q.dtype), (k_arena, v_arena)
 
         x = params.embedding[tokens[:, 0]]
-        x, arenas, pairs, chosen = self._walk(params, x, positions, active,
-                                              arenas, attn_core)
+        x, arenas, pairs, chosen, reached = self._walk(
+            params, x, positions, active, arenas, attn_core, latent_core)
         logits = self._logits(params, x)                       # [B, vocab]
         sampled = sample_tokens(logits, temperature, top_k, top_p, seeds,
                                 steps)
         out = jnp.where(active, sampled, 0).astype(jnp.int32)[:, None]
         return (arenas, out, jnp.zeros((B,), jnp.int32), logits[:, None],
-                pairs, chosen)
+                pairs, chosen, reached)
 
     def prefill(self, arenas, params, tokens, position_ids, block_tables,
                 lengths, limits, sample_index, temperature, top_k, top_p,
@@ -809,7 +924,7 @@ class HybridDecodeModel:
         (``limits == 0`` marks padding), and the logits of the sampled row
         only.  Returns ``(arenas, next_tokens [max_batch], logits
         [max_batch, 1, vocab], pairs, chosen [expert layers, max_batch *
-        chunk, top_k])``."""
+        chunk, top_k], reached [expert layers])``."""
         bs = self.cache.block_size
         B, T = tokens.shape
         position_ids = position_ids.astype(jnp.int32)
@@ -820,12 +935,45 @@ class HybridDecodeModel:
         dest_offsets = position_ids % bs
         attend = (paged_prefill_attention if self.fused_attention
                   else paged_prefill_attention_unfused)
+        attend_rows = (paged_prefill_latent if self.fused_attention
+                       else paged_prefill_latent_unfused)
 
-        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+        def destination(gi):
             table = block_tables[gi]
             phys = jnp.take_along_axis(
                 table, jnp.clip(logical, 0, table.shape[1] - 1), axis=1)
-            dest = jnp.where(real, phys, self.cache.groups[gi].n_blocks)
+            return table, jnp.where(real, phys,
+                                    self.cache.groups[gi].n_blocks)
+
+        def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
+            table, dest = destination(gi)
+            arena = layer_arenas[0].at[dest, dest_offsets].set(
+                rows.reshape(B, T, -1).astype(layer_arenas[0].dtype),
+                mode="drop")
+            n = kind.num_heads
+            # a few slots at a time: the absorbed queries of the whole call
+            # would be ``B T n lanes`` elements
+            walk = _slots_a_walk(B, T * n * arena.shape[-1]
+                                 * arena.dtype.itemsize)
+
+            def some_slots(part):
+                q_nope, q_rope, table, lengths, limits = part
+                return self._latent_attention(
+                    kind, lp, q_nope, q_rope, arena.shape[-1],
+                    lambda q: attend_rows(q, arena, table, lengths, limits,
+                                          v_dim=kind.latent_rank,
+                                          scale=self._latent_scale(kind)))
+
+            parts = tuple(a.reshape((B // walk, walk) + a.shape[1:])
+                          for a in (q_nope.reshape(B, T, n, -1),
+                                    q_rope.reshape(B, T, n, -1), table,
+                                    lengths, limits))
+            ctx = (some_slots(tuple(a[0] for a in parts)) if walk == B
+                   else lax.map(some_slots, parts))
+            return ctx.reshape(B * T, -1), (arena,)
+
+        def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
+            table, dest = destination(gi)
             k_arena, v_arena = layer_arenas
             k_arena = k_arena.at[dest, dest_offsets].set(
                 k.reshape(B, T, -1).astype(k_arena.dtype), mode="drop")
@@ -838,9 +986,9 @@ class HybridDecodeModel:
                     (k_arena, v_arena))
 
         x = params.embedding[tokens.reshape(-1)]
-        x, arenas, pairs, chosen = self._walk(
+        x, arenas, pairs, chosen, reached = self._walk(
             params, x, position_ids.reshape(-1), real.reshape(-1), arenas,
-            attn_core)
+            attn_core, latent_core)
         idx = sample_index.astype(jnp.int32)
         last = x.reshape(B, T, -1)[jnp.arange(B), jnp.clip(idx, 0, T - 1)]
         logits = self._logits(params, last)                    # [B, vocab]
@@ -848,4 +996,4 @@ class HybridDecodeModel:
                                 steps)
         valid = (idx >= 0) & (idx < T)
         next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
-        return arenas, next_tokens, logits[:, None], pairs, chosen
+        return arenas, next_tokens, logits[:, None], pairs, chosen, reached

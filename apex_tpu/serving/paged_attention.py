@@ -138,6 +138,10 @@ __all__ = [
     "paged_attention_decode_unfused",
     "paged_prefill_attention",
     "paged_prefill_attention_unfused",
+    "paged_decode_latent",
+    "paged_decode_latent_unfused",
+    "paged_prefill_latent",
+    "paged_prefill_latent_unfused",
 ]
 
 NEG_INF = -1e30
@@ -872,14 +876,19 @@ def _decode_flat_kernel(steps_ref, plan_ref, slot_ref, group_ref, len_ref,
                         first_ref, q_ref, *rest, scale: float,
                         block_size: int, pages: int, g: int, hpg: int,
                         dk: int, dv: int, window: Optional[int],
-                        has_sinks: bool, exact: bool):
+                        has_sinks: bool, exact: bool, latent: bool = False):
     """One grid step = one key tile of ``pages`` pages of one slot, all
     heads.  ``rest``: the sinks (if any), the K and V arenas (in HBM), the
     output, the double-buffered K and V tiles with their DMA semaphores and
-    the softmax state."""
+    the softmax state.  A ``latent`` group has no V arena and no V tile:
+    the values are the leading ``dv`` lanes of the key tile."""
     if has_sinks:
         sink_ref, rest = rest[0], rest[1:]
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_sc, l_sc, acc_sc = rest
+    if latent:
+        k_hbm, o_ref, k_buf, sems, m_sc, l_sc, acc_sc = rest
+        v_hbm, v_buf = None, k_buf
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_sc, l_sc, acc_sc = rest
     step = pl.program_id(0)
     buf = step % 2
 
@@ -903,8 +912,10 @@ def _decode_flat_kernel(steps_ref, plan_ref, slot_ref, group_ref, len_ref,
                 rows = pl.ds(p * block_size, block_size)
                 act(pltpu.make_async_copy(
                     k_hbm.at[block], k_buf.at[into, rows], sems.at[0, into]))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[block], v_buf.at[into, rows], sems.at[1, into]))
+                if not latent:
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[block], v_buf.at[into, rows],
+                        sems.at[1, into]))
 
     @pl.when(step == 0)
     def _first():
@@ -958,17 +969,26 @@ def _decode_flat_kernel(steps_ref, plan_ref, slot_ref, group_ref, len_ref,
 
 
 def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
-                 window, sinks, scale):
-    kind = "full" if window is None else "window"
+                 window, sinks, scale, latent_v: Optional[int] = None):
+    """``latent_v``: ``k_arena`` is a latent group's one arena (``v_arena``
+    is ``None``), whose leading ``latent_v`` lanes are the values."""
+    latent = latent_v is not None
+    kind = "latent" if latent else "full" if window is None else "window"
     name = f"paged_decode_{kind}"
     b, n, _ = q.shape
-    dk, dv = _check_flat(q, k_arena, v_arena, kv_heads, sinks)
     bs = k_arena.shape[1]
-    g, hpg = kv_heads, n // kv_heads
     max_blocks = block_tables.shape[1]
-    page_bytes = max(_vmem_bytes((bs, g * dk), k_arena.dtype),
-                     _vmem_bytes((bs, g * dv), v_arena.dtype))
-    pages = _flat_pages_per_step(page_bytes, max_blocks, bs, window)
+    if latent:
+        dk, dv = _check_latent(q, k_arena, latent_v)
+        page_bytes = _vmem_bytes((bs, dk), k_arena.dtype)
+        pages = max(1, min(_KV_TILE_BYTES // (2 * page_bytes),
+                           _MAX_LATENT_PAGES, max_blocks))
+    else:
+        dk, dv = _check_flat(q, k_arena, v_arena, kv_heads, sinks)
+        page_bytes = max(_vmem_bytes((bs, kv_heads * dk), k_arena.dtype),
+                         _vmem_bytes((bs, kv_heads * dv), v_arena.dtype))
+        pages = _flat_pages_per_step(page_bytes, max_blocks, bs, window)
+    g, hpg = kv_heads, n // kv_heads
     lengths = lengths.astype(jnp.int32)
     if window is None:
         first_page = jnp.zeros((b,), jnp.int32)
@@ -1000,16 +1020,19 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
         operands.append(sinks.astype(jnp.float32)[:, None])
     # the arenas stay in HBM: the kernel copies the plan's live pages
     # (their minor dimensions, ``kv_heads * d``, are whole lane tiles)
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-    operands += [k_arena, v_arena]
+    arenas = [k_arena] if latent else [k_arena, v_arena]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(arenas)
+    operands += arenas
+    # the double-buffered key tile, and the value tile beside it
+    tiles = [pltpu.VMEM((2, pages * bs, g * dk), k_arena.dtype)]
+    if not latent:
+        tiles.append(pltpu.VMEM((2, pages * bs, g * dv), v_arena.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(n_steps,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n, dv), row_idx),
-        scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, g * dk), k_arena.dtype),
-            pltpu.VMEM((2, pages * bs, g * dv), v_arena.dtype),
+        scratch_shapes=tiles + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((n, _LANES), jnp.float32),
             pltpu.VMEM((n, _LANES), jnp.float32),
@@ -1020,7 +1043,8 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
         _decode_flat_kernel, scale=_resolve(scale, dk), block_size=bs,
         pages=pages, g=g, hpg=hpg, dk=dk, dv=dv, window=window,
         has_sinks=sinks is not None,
-        exact=k_arena.dtype != jnp.bfloat16)
+        exact=k_arena.dtype != jnp.bfloat16, **(
+            {"latent": True} if latent else {}))
     with named_span(name):
         return pl.pallas_call(
             kernel,
@@ -1161,3 +1185,236 @@ def _prefill_flat(q, k_arena, v_arena, block_tables, lengths, limits, *,
             name=name,
         )(block_tables.astype(jnp.int32), lengths, first_page, *operands)
     return out.transpose(0, 2, 1, 3)
+
+
+# ------------------------------------- a latent cache group (ISSUE 33)
+#
+# Multi-head latent attention keeps one row a token and layer, ``[normed
+# latent (dv) | rotated shared key]``, ``d`` lanes wide (512 + 64 at the
+# widths that brought it).  In the absorbed form every head's query is a
+# vector of those ``d`` lanes, its scores the products with the cached rows
+# as they lie, and its values the rows' leading ``dv`` lanes: one key head
+# that all ``n`` query heads share, so a key tile is multiplied by ``[n,
+# d]`` queries at decode (the cache groups' decode kernel above, with one
+# arena) and by a block of ``tokens x n`` query rows at prefill (the kernel
+# below).  Per cached row and query token: ``2 n (d + dv)`` FLOP over ``d``
+# elements read, 242 FLOP/B at 128 heads in bfloat16: both the MXU and the
+# HBM stream are close to busy, so the tile is wider than the other groups'
+# (fewer steps, each ~0.5 us of fixed cost beside 1.4 us of bytes).
+_MAX_LATENT_PAGES = 64
+# query rows (tokens x heads) a prefill grid step multiplies a key tile by,
+# and the pages of that tile, fetched in ``_LATENT_PARTS`` parts so that a
+# part's products run under the next part's copy
+_LATENT_PREFILL_ROWS = 2048
+_LATENT_PREFILL_PAGES = 64
+_LATENT_PARTS = 2
+
+
+def _check_latent(q, arena, v_dim):
+    d = q.shape[-1]
+    if arena.ndim != 3 or arena.shape[-1] != d:
+        raise ValueError(
+            f"a latent arena {arena.shape} holds no rows of q's width {d}")
+    if not 0 < v_dim <= d:
+        raise ValueError(f"values of {v_dim} lanes do not lie in rows of {d}")
+    return d, v_dim
+
+
+def paged_decode_latent(q, arena, block_tables, lengths, *, v_dim: int,
+                        scale: float):
+    """Decode over a latent group's arena ``[n_blocks, block, d]``: ``q
+    [batch, n_heads, d]`` (absorbed queries: every head reads the same
+    rows), values the rows' leading ``v_dim`` lanes, ``scale`` the softmax
+    scale (it is the model's, not ``d ** -0.5``).  Returns ``[batch,
+    n_heads, v_dim]``; in a device trace the call is
+    ``paged_decode_latent``."""
+    return _decode_flat(q, arena, None, block_tables, lengths, kv_heads=1,
+                        window=None, sinks=None, scale=scale,
+                        latent_v=v_dim)
+
+
+def _masked_softmax_rows(s, mask, rows, v_dim, dtype):
+    """softmax of masked scores ``s [..., keys]`` times ``rows[..., :v_dim]``
+    (the unfused twins'); a row with no live key gives zeros."""
+    s = jnp.where(mask, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - jnp.where(m <= NEG_INF * 0.5, 0.0, m))
+    p = jnp.where(mask, p, 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("b...s,bsd->b...d", p, rows[..., :v_dim])
+    return (out / jnp.where(l == 0.0, 1.0, l)).astype(dtype)
+
+
+def _gathered_rows(arena, block_tables):
+    b, max_blocks = block_tables.shape
+    rows = arena[jnp.maximum(block_tables, 0)]       # [b, blocks, bs, d]
+    return rows.reshape(b, max_blocks * arena.shape[1], -1).astype(
+        jnp.float32)
+
+
+def paged_decode_latent_unfused(q, arena, block_tables, lengths, *,
+                                v_dim: int, scale: float):
+    """Plain-XLA twin of :func:`paged_decode_latent`: gather each slot's
+    whole table, mask by length."""
+    _check_latent(q, arena, v_dim)
+    rows = _gathered_rows(arena, block_tables)
+    s = jnp.einsum("bnd,bsd->bns", q.astype(jnp.float32), rows) * scale
+    cols = jnp.arange(rows.shape[1])[None, None, :]
+    return _masked_softmax_rows(s, cols < lengths[:, None, None], rows,
+                                v_dim, q.dtype)
+
+
+def paged_prefill_latent_unfused(q, arena, block_tables, lengths, limits, *,
+                                 v_dim: int, scale: float):
+    """Plain-XLA twin of :func:`paged_prefill_latent`."""
+    del lengths
+    _check_latent(q, arena, v_dim)
+    rows = _gathered_rows(arena, block_tables)
+    s = jnp.einsum("btnd,bsd->btns", q.astype(jnp.float32), rows) * scale
+    cols = jnp.arange(rows.shape[1])[None, None, None, :]
+    return _masked_softmax_rows(s, cols < limits[:, :, None, None], rows,
+                                v_dim, q.dtype)
+
+
+def _prefill_latent_kernel(tab_ref, reach_ref, q_ref, lim_ref, rows_hbm,
+                           o_ref, buf, sems, m_sc, l_sc, acc_sc, *,
+                           scale: float, block_size: int, pages: int,
+                           dv: int, exact: bool):
+    """One grid step = one key tile of ``pages`` pages of slot ``i`` against
+    query block ``qb`` (``rows`` of tokens x heads, each with its causal
+    horizon in ``lim_ref``).  ``reach_ref [b, query blocks]`` is the
+    farthest horizon of a block's rows: tiles past it have no step body,
+    pages past it no copy."""
+    i, qb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    reach = reach_ref[i, qb]
+    part = pages // _LATENT_PARTS
+    n_live = jnp.clip(pl.cdiv(reach, block_size) - j * pages, 0, pages)
+
+    @pl.when((i == 0) & (qb == 0) & (j == 0))
+    def _first():
+        # rows no copy has reached weigh 0 in ``p v``: they must be finite
+        buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def for_live_pages(half, act):
+        for p in range(half * part, (half + 1) * part):
+            @pl.when(p < n_live)
+            def _page(p=p):
+                act(pltpu.make_async_copy(
+                    rows_hbm.at[tab_ref[i, j * pages + p]],
+                    buf.at[pl.ds(p * block_size, block_size)],
+                    sems.at[half]))
+
+    @pl.when(n_live > 0)
+    def _body():
+        for half in range(_LATENT_PARTS):
+            for_live_pages(half, lambda copy: copy.start())
+        lim = lim_ref[0, 0]                                   # [rows, 1]
+        q = q_ref[0, 0]
+        for half in range(_LATENT_PARTS):
+            @pl.when(half * part < n_live)
+            def _part(half=half):
+                for_live_pages(half, lambda copy: copy.wait())
+                keys = part * block_size
+                cols = (j * pages + half * part) * block_size \
+                    + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+                rows = buf[pl.ds(half * keys, keys)]          # [keys, d]
+                s = _mxu(q, rows, ((1,), (1,)), exact)
+                s = jnp.where(cols < lim, s * scale, NEG_INF)
+                p, alpha, m_new, l_new = _online_softmax(
+                    s, m_sc[:, :1], l_sc[:, :1])
+                acc_sc[...] = acc_sc[...] * alpha + _mxu(
+                    p, rows[:, :dv], ((1,), (0,)), exact)
+                m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+                l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        l_fin = l_sc[:, :1]
+        o_ref[0, 0] = (acc_sc[...] / jnp.where(l_fin == 0.0, 1.0, l_fin)
+                       ).astype(o_ref.dtype)
+
+
+def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
+                         v_dim: int, scale: float):
+    """Chunked prefill over a latent group's arena: ``q [batch, chunk,
+    n_heads, d]`` absorbed queries, ``limits [batch, chunk]`` each token's
+    causal horizon (0 = padding), the chunk's own rows already in the
+    arena.  Returns ``[batch, chunk, n_heads, v_dim]``; in a device trace
+    the call is ``paged_prefill_latent``.
+
+    The absorbed form: a chunk's ``chunk * n_heads`` query rows share every
+    cached row, so the kernel is one matrix product a key tile and query
+    block, ``2 n (d + v_dim)`` FLOP a (token, cached row).  Expanding the
+    cached rows to per-head keys and values first would cost ``2 rank n
+    (nope + v)`` FLOP a cached row *per call* and ``2 n (k_dim + v)`` a
+    pair after it: cheaper only past about 340 query tokens a call and
+    slot (2,176 against 640 + 33.5 M / tokens at DeepSeek-V2's widths), and
+    a chunk has 128."""
+    del lengths                     # the horizons say all the sweep needs
+    b, T, n, _ = q.shape
+    d, dv = _check_latent(q, arena, v_dim)
+    bs = arena.shape[1]
+    max_blocks = block_tables.shape[1]
+    # tokens a query block: ``_LATENT_PREFILL_ROWS`` rows of tokens x heads
+    tq = max(1, min(T, _LATENT_PREFILL_ROWS // n))
+    while T % tq:
+        tq -= 1
+    blocks, rows = T // tq, tq * n
+    pages = max(_LATENT_PARTS, min(_LATENT_PREFILL_PAGES, max_blocks)
+                // _LATENT_PARTS * _LATENT_PARTS)
+    limits = limits.astype(jnp.int32)
+    reach = jnp.max(limits.reshape(b, blocks, tq), axis=-1)
+    lim = jnp.repeat(limits.reshape(b, blocks, tq), n, axis=-1)[..., None]
+    tiles = pl.cdiv(max_blocks, pages)
+    # table columns a tile's last pages may name past the table's width
+    table = jnp.pad(block_tables.astype(jnp.int32),
+                    ((0, 0), (0, tiles * pages - max_blocks)))
+    table = jnp.maximum(table, 0)
+
+    def block_idx(i, qb, j, *refs):
+        return (i, qb, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, blocks, tiles),
+        in_specs=[pl.BlockSpec((1, 1, rows, d), block_idx),
+                  pl.BlockSpec((1, 1, rows, 1), block_idx),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, rows, dv), block_idx),
+        scratch_shapes=[
+            pltpu.VMEM((pages * bs, d), arena.dtype),
+            pltpu.SemaphoreType.DMA((_LATENT_PARTS,)),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _prefill_latent_kernel, scale=scale, block_size=bs, pages=pages,
+        dv=dv, exact=arena.dtype != jnp.bfloat16)
+    keys = pages // _LATENT_PARTS * bs
+    vmem = (2 * _vmem_bytes((rows, d), q.dtype)
+            + 2 * _vmem_bytes((rows, dv), q.dtype)
+            + 2 * _vmem_bytes((rows, 1), jnp.int32)
+            + 3 * _vmem_bytes((rows, _LANES), jnp.float32)
+            + 4 * _vmem_bytes((rows, max(keys, dv)), jnp.float32)
+            + (8 << 20))
+    name = "paged_prefill_latent"
+    with named_span(name):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, blocks, rows, dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 3,
+                vmem_limit_bytes=max(vmem, 32 << 20)),
+            interpret=platform.pallas_interpret(),
+            name=name,
+        )(table, reach, q.reshape(b, blocks, rows, d), lim, arena)
+    return out.reshape(b, T, n, dv)

@@ -1,9 +1,10 @@
 """Mixture-of-experts feed-forward layers.
 
 **The layer that serves and trains: top-k dropless routing over a share of
-the experts** (:func:`route_topk`, :func:`held_experts_ffn`): sigmoid
-scores, a selection bias that chooses and does not weigh, the ``top_k``
-chosen scores normalised (and scaled), no capacity and no token dropped,
+the experts** (:func:`route_topk`, :func:`held_experts_ffn`): sigmoid or
+softmax scores, a selection bias that chooses and does not weigh, an
+optional limit to the best few groups of experts, the ``top_k`` chosen
+scores normalised or not (and scaled), no capacity and no token dropped,
 an optional shared expert that every token meets.  The layer is *told
 which experts it holds* (``held = (first, count)`` of ``n_experts``, the
 chip's share under wide expert parallelism): it routes over all of them,
@@ -65,7 +66,7 @@ from apex_tpu.parallel import collectives as cc
 from apex_tpu.utils import platform
 
 __all__ = ["SwitchMLP", "collect_moe_aux", "switch_route", "route_topk",
-           "held_experts_ffn", "grouped_matmul", "swiglu"]
+           "kept_groups", "held_experts_ffn", "grouped_matmul", "swiglu"]
 
 
 def collect_moe_aux(mutated_collections) -> jnp.ndarray:
@@ -206,21 +207,51 @@ class SwitchMLP(nn.Module):
 # ------------------------------------------- top-k routing, held experts
 
 
-def route_topk(logits32, bias, top_k: int, eps: float = 0.0,
-               scale: float = 1.0):
-    """Sigmoid-scored top-k routing from fp32 router logits ``[T, E]``.
+def kept_groups(choose_by, n_groups: int, topk_groups: int):
+    """``[T, n_groups]`` bool: the ``topk_groups`` groups of consecutive
+    experts whose best entry of ``choose_by [T, E]`` is largest (ties to
+    the lower group)."""
+    T, E = choose_by.shape
+    best = jnp.max(choose_by.reshape(T, n_groups, E // n_groups), axis=-1)
+    _, kept = jax.lax.top_k(best, topk_groups)
+    return jnp.any(kept[:, :, None] == jnp.arange(n_groups)[None, None, :],
+                   axis=1)
 
-    The ``top_k`` experts with the largest ``sigmoid(logit) + bias`` are
-    chosen (``bias [E]`` selects and does not weigh; ties go to the lower
-    expert id, ``lax.top_k``'s order); their weights are their scores over
-    their sum plus ``eps``, times ``scale``.  Gradients flow through the
+
+def _scores(logits32, bias, scoring: str):
+    """``(scores, what the choice goes by)``: the second adds the bias."""
+    scores = (jax.nn.sigmoid(logits32) if scoring == "sigmoid"
+              else jax.nn.softmax(logits32, axis=-1))
+    return scores, scores if bias is None else scores + bias
+
+
+def route_topk(logits32, bias, top_k: int, eps: float = 0.0,
+               scale: float = 1.0, *, scoring: str = "sigmoid",
+               groups: Tuple[int, int] = (1, 1), normalize: bool = True):
+    """Top-k routing from fp32 router logits ``[T, E]``, scored by
+    ``scoring``: ``"sigmoid"`` of each logit, or ``"softmax"`` over the
+    experts.
+
+    The ``top_k`` experts with the largest ``score + bias`` are chosen
+    (``bias [E]`` selects and does not weigh, ``None`` is no bias; ties go
+    to the lower expert id, ``lax.top_k``'s order).  ``groups = (n_groups,
+    topk_groups)`` limits the choice to the experts of the ``topk_groups``
+    groups that :func:`kept_groups` names.  The weights are the chosen
+    scores over their sum plus ``eps`` (the scores as they are where
+    ``normalize`` is false), times ``scale``.  Gradients flow through the
     chosen scores and not through the choice.  Returns ``(experts [T, k]
     int32, weights [T, k] f32)``."""
-    scores = jax.nn.sigmoid(logits32)
-    _, experts = jax.lax.top_k(scores + bias, top_k)
-    picked = jnp.take_along_axis(scores, experts, axis=1)
-    total = jnp.sum(picked, axis=1, keepdims=True)
-    weights = picked / (total + eps if eps else total)
+    scores, choose_by = _scores(logits32, bias, scoring)
+    if groups[0] > 1:
+        kept = kept_groups(choose_by, *groups)
+        choose_by = jnp.where(
+            jnp.repeat(kept, scores.shape[1] // groups[0], axis=1),
+            choose_by, -jnp.inf)
+    _, experts = jax.lax.top_k(choose_by, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=1)
+    if normalize:
+        total = jnp.sum(weights, axis=1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
     return experts.astype(jnp.int32), (weights * scale if scale != 1.0
                                        else weights)
 
@@ -399,25 +430,50 @@ def _held_passes_bwd(rows, kept, dy):
 _held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
 
 
+def _group_tokens(logits32, bias, held, live, scoring: str = "sigmoid",
+                  groups: Tuple[int, int] = (1, 1), **_):
+    """How many live tokens' routers kept a group that holds one of the
+    ``held`` experts (:func:`held_experts_ffn`)."""
+    T, E = logits32.shape
+    reached = jnp.ones((T,), bool)
+    if groups[0] > 1:
+        kept = kept_groups(_scores(logits32, bias, scoring)[1], *groups)
+        size = E // groups[0]
+        mine = jnp.arange(groups[0])
+        mine = (mine >= held[0] // size) & (
+            mine <= (held[0] + held[1] - 1) // size)
+        reached = jnp.any(kept & mine[None, :], axis=1)
+    if live is not None:
+        reached = reached & live
+    return jnp.sum(reached, dtype=jnp.int32)
+
+
 def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
                      top_k: int, held: Tuple[int, int],
                      live=None, route_eps: float = 0.0,
-                     route_scale: float = 1.0, shared=None):
+                     route_scale: float = 1.0, shared=None, **routing):
     """The held experts' part of a top-k expert feed-forward.
 
     ``x [T, h]``; ``router [h, E]`` and ``router_bias [E]`` over all ``E``
     experts; ``w_gate_up [count, h, 2 f]`` (gate columns, then up) and
     ``w_down [count, f, h]`` of the ``count`` experts from ``held[0]`` on.
+    ``router_bias`` may be ``None`` (a family without one).
     Returns ``(y [T, h] float32, pairs [count] int32, experts [T, top_k]
-    int32)``: ``y`` sums, over each token's chosen experts that are held
-    here, weight times ``w_down(silu(gate) * up)``; ``pairs`` counts the
-    ``(token, expert)`` pairs routed to each held expert; ``experts`` are
+    int32, group_tokens int32)``: ``y`` sums, over each token's chosen
+    experts that are held here, weight times ``w_down(silu(gate) * up)``;
+    ``pairs`` counts the ``(token, expert)`` pairs routed to each held
+    expert; ``experts`` are
     the ids each token's router chose among all ``E`` (a comparison with
     another precision needs them: scores near the cut lie closer together
-    than bfloat16 rounds).  ``live [T]`` bool marks the rows
+    than bfloat16 rounds); ``group_tokens`` counts the live tokens whose
+    router kept a group that holds a held expert (all of them without a
+    group limit: only such a token can bring a pair here).  ``live [T]``
+    bool marks the rows
     that are tokens (a fixed-shape batch carries padding): the others are
     routed nowhere, cost nothing and add nothing.  ``route_eps`` and
-    ``route_scale`` as :func:`route_topk` takes them.  ``shared``, a pair
+    ``route_scale`` as :func:`route_topk` takes them, as are ``scoring``,
+    ``groups`` and ``normalize`` (``routing``, by keyword).  ``shared``, a
+    pair
     ``(w_gate_up [h, 2 f'], w_down [f', h])``, is a shared expert: a dense
     SwiGLU every token meets on its own chip, added to ``y``.
 
@@ -438,12 +494,14 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
         # makes
         extra = (route_eps, route_scale) if (
             route_eps or route_scale != 1.0) else ()
-        experts, weights = route_topk(
-            logits, router_bias.astype(jnp.float32), top_k, *extra)
+        bias = (None if router_bias is None
+                else router_bias.astype(jnp.float32))
+        experts, weights = route_topk(logits, bias, top_k, *extra, **routing)
         local = experts - first
         here = (local >= 0) & (local < count)
         if live is not None:
             here = here & live[:, None]
+        group_tokens = _group_tokens(logits, bias, held, live, **routing)
         key = jnp.where(here, local, count).reshape(-1)       # [T * k]
         order = jnp.argsort(key, stable=True)
         pairs = jnp.sum(
@@ -458,4 +516,4 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
     if shared is not None:
         with jax.named_scope("moe_shared"):
             y = y + swiglu(x, *shared)
-    return y, pairs, experts
+    return y, pairs, experts, group_tokens

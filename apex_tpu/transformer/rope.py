@@ -26,25 +26,83 @@ Design notes (TPU/XLA):
 
 from __future__ import annotations
 
-from typing import Tuple
+import dataclasses
+import math
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
 __all__ = ["rotary_cos_sin", "apply_rotary", "apply_rotary_decode",
-           "apply_rotary_packed"]
+           "apply_rotary_packed", "apply_rotary_interleaved", "YarnScaling",
+           "yarn_inv_freq", "yarn_mscale"]
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's stretch of a rotary base trained at ``original_max_position``
+    to ``factor`` times that context (arXiv:2309.00071, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` has it): channel pairs that turn more
+    than ``beta_fast`` times over the original context keep their frequency,
+    those that turn fewer than ``beta_slow`` times are slowed by ``factor``,
+    the pairs between blend linearly.  ``mscale`` scales cos and sin against
+    ``mscale_all_dim`` (their quotient; 1 where they are equal), and a model
+    multiplies its softmax scale by ``yarn_mscale(factor, mscale_all_dim)``
+    squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 * mscale * ln(factor) + 1`` (1 for no stretch)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(rotary_dim: int, base: float, scaling: YarnScaling):
+    """The ``rotary_dim / 2`` blended inverse frequencies (float32)."""
+    def correction_dim(rotations):
+        # the channel pair that turns ``rotations`` times over the
+        # original context
+        return (rotary_dim * math.log(scaling.original_max_position
+                                      / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), rotary_dim - 1)
+    extra = 1.0 / (base ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                            / rotary_dim))
+    inter = extra / scaling.factor
+    span = 0.001 if low == high else high - low
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / span, 0.0, 1.0)
+    keep = 1.0 - ramp                   # 1: the trained frequency is kept
+    return inter * (1.0 - keep) + extra * keep
 
 
 def rotary_cos_sin(positions, rotary_dim: int, base: float = 10000.0,
-                   dtype=jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   dtype=jnp.float32, scaling: Optional[YarnScaling] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """cos/sin tables for :func:`apply_rotary`.
 
     ``positions`` ``[s]`` (ints; global token indices), ``rotary_dim`` the
     even number of leading head channels to rotate -> ``(cos, sin)`` each
     ``[s, rotary_dim/2]``.  Computed in fp32 regardless of ``dtype``
-    (bf16 angles visibly wobble at long context), then cast.
+    (bf16 angles visibly wobble at long context), then cast.  ``scaling``
+    (a :class:`YarnScaling`) blends the frequencies and scales the tables.
     """
     if rotary_dim % 2:
         raise ValueError(f"rotary_dim must be even, got {rotary_dim}")
+    if scaling is not None:
+        inv_freq = yarn_inv_freq(rotary_dim, base, scaling)
+        gain = (yarn_mscale(scaling.factor, scaling.mscale)
+                / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+        return ((jnp.cos(angles) * gain).astype(dtype),
+                (jnp.sin(angles) * gain).astype(dtype))
     inv_freq = 1.0 / (
         base ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
                  / rotary_dim))
@@ -83,6 +141,17 @@ def apply_rotary_packed(x, cos, sin):
     along both the position and the batch dim and broadcast only over
     heads."""
     return _rotate(x, cos[:, :, None, :], sin[:, :, None, :])
+
+
+def apply_rotary_interleaved(x, cos, sin):
+    """Rotation of all of ``x [tokens, heads, d]`` in which channel ``2 t``
+    pairs with ``2 t + 1`` (the layout DeepSeek's checkpoints keep), ``cos``
+    / ``sin [tokens, d / 2]``.  The pairs are pulled apart first, so the
+    result lies in half-rotation order (the ``d / 2`` first members, then
+    the second ones): a fixed permutation of the channels that q and k
+    share, which no score sees."""
+    return _rotate(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                   cos[:, None, :], sin[:, None, :])
 
 
 def apply_rotary_decode(x, cos, sin):

@@ -106,6 +106,13 @@ def _refusals(cfg: TransformerConfig, mesh, packed_inputs, block_diagonal,
         if kind.k_dim != kind.v_dim:
             yield (f"{kind.name}.k_dim != v_dim (the flash kernels take one "
                    "head width)")
+        if kind.latent:
+            yield (f"{kind.name}.latent_rank (latent attention is served, "
+                   "not trained)")
+    if spec.experts is not None and spec.experts.routing:
+        yield ("experts." + ", experts.".join(sorted(spec.experts.routing))
+               + " (softmax and group-limited routing are served, not "
+               "trained)")
 
 
 def hybrid_layer_shapes(cfg: TransformerConfig, layer: int) -> dict:
@@ -256,7 +263,7 @@ def build_hybrid_train(cfg: TransformerConfig, *, num_chunks: int = 1,
             shared = ((lp["shared_gate_up"].astype(dtype),
                        lp["shared_down"].astype(dtype))
                       if ex.shared_experts else None)
-            out, pairs, chosen = moe.held_experts_ffn(
+            out, pairs, chosen, _ = moe.held_experts_ffn(
                 m, lp["router"], lp["router_bias"],
                 lp["experts_gate_up"].astype(dtype),
                 lp["experts_down"].astype(dtype), top_k=ex.top_k,

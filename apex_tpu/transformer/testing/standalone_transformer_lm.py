@@ -53,6 +53,7 @@ from apex_tpu.parallel.collectives import bound_axis_size
 from apex_tpu.parallel.mesh import TENSOR_AXIS
 from apex_tpu.transformer.enums import AttnType, LayerType
 from apex_tpu.transformer.layers.layer_norm import FusedLayerNorm
+from apex_tpu.transformer.rope import YarnScaling
 from apex_tpu.transformer.tensor_parallel import (
     ColumnParallelLinear,
     RowParallelLinear,
@@ -90,7 +91,20 @@ class AttentionKind:
     whether each q and k head is RMS-normed over its channels before the
     rotation (``qk_norm``: one gain of ``k_dim`` each) and whether the
     attention output is multiplied by the sigmoid of a projection of the
-    layer's input (``gate``)."""
+    layer's input (``gate``).
+
+    A **latent** kind (``latent_rank > 0``; multi-head latent attention,
+    arXiv:2405.04434) projects its input down to ``q_rank`` channels for
+    the queries and to ``latent_rank + rotary_dim`` for keys and values,
+    RMS-norms both low-rank vectors, and expands per head: ``k_dim =
+    nope_dim + rotary_dim`` query channels of which the *trailing*
+    ``rotary_dim`` rotate (channel ``2 t`` paired with ``2 t + 1``), one
+    rotary key shared by all heads (``kv_heads`` 1), ``nope_dim`` key and
+    ``v_dim`` value channels a head expanded from the latent.  A token's
+    cached row is the normed latent beside the rotated key
+    (:attr:`cache_row`), whatever the head count.  ``rotary_scaling``
+    stretches the rotary base (:class:`~apex_tpu.transformer.rope.
+    YarnScaling`) and ``softmax_scale`` replaces ``k_dim ** -0.5``."""
 
     name: str
     num_heads: int
@@ -103,6 +117,11 @@ class AttentionKind:
     sink: bool = False
     qk_norm: bool = False
     gate: bool = False
+    latent_rank: int = 0
+    q_rank: int = 0
+    nope_dim: int = 0
+    rotary_scaling: Optional[YarnScaling] = None
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
         if self.num_heads % self.kv_heads:
@@ -115,17 +134,44 @@ class AttentionKind:
                 f"and at most k_dim ({self.k_dim})")
         if self.window is not None and self.window < 1:
             raise ValueError(f"{self.name}: window must be positive")
+        if self.latent and (
+                self.kv_heads != 1 or self.q_rank < 1 or self.window
+                or self.nope_dim + self.rotary_dim != self.k_dim
+                or self.sink or self.qk_norm or self.gate):
+            raise ValueError(
+                f"{self.name}: a latent kind has one shared key row "
+                "(kv_heads 1), a q_rank, k_dim = nope_dim + rotary_dim, and "
+                "no window, sink, qk_norm or gate")
+
+    @property
+    def latent(self) -> bool:
+        return self.latent_rank > 0
+
+    @property
+    def cache_row(self) -> Tuple[int, int, int, bool]:
+        """What a token and layer of this kind keep in the cache:
+        ``(kv_heads, key width, value width, latent)``.  A latent row is
+        one vector of ``latent_rank + rotary_dim`` channels whose leading
+        ``latent_rank`` are also the values; any other kind keeps ``k_dim``
+        beside ``v_dim`` per KV head."""
+        if self.latent:
+            return (1, self.latent_rank + self.rotary_dim, self.latent_rank,
+                    True)
+        return (self.kv_heads, self.k_dim, self.v_dim, False)
 
 
 @dataclasses.dataclass(frozen=True)
 class ExpertSpec:
-    """The expert feed-forward of a :class:`HybridSpec`: sigmoid scores
-    over ``n_experts``, the ``top_k`` largest ``score + bias`` chosen (the
-    bias selects and does not weigh), their scores over their sum plus
-    ``route_eps``, times ``route_scale``, each expert a SwiGLU of width
-    ``ffn_size``.  ``held = (first, count)`` are the experts whose weights
-    this process holds: the layer routes over all ``n_experts`` and
-    computes the held experts' part of the result
+    """The expert feed-forward of a :class:`HybridSpec`: ``scoring``
+    (``"sigmoid"`` or ``"softmax"``) scores over ``n_experts``, the
+    ``top_k`` largest ``score + bias`` chosen (the bias selects and does
+    not weigh) among the ``topk_groups`` of ``n_groups`` groups of
+    consecutive experts whose best ``score + bias`` is largest (one group:
+    no limit), their scores over their sum plus ``route_eps`` (as they are
+    where ``normalize`` is false), times ``route_scale``, each expert a
+    SwiGLU of width ``ffn_size``.  ``held = (first, count)`` are the experts
+    whose weights this process holds: the layer routes over all
+    ``n_experts`` and computes the held experts' part of the result
     (:func:`apex_tpu.transformer.moe.held_experts_ffn`).
     ``shared_experts`` of them more are met by every token, as one dense
     SwiGLU of width ``shared_experts * ffn_size`` that every chip computes
@@ -138,6 +184,10 @@ class ExpertSpec:
     shared_experts: int = 0
     route_scale: float = 1.0
     route_eps: float = 0.0
+    scoring: str = "sigmoid"
+    n_groups: int = 1
+    topk_groups: int = 1
+    normalize: bool = True
 
     def __post_init__(self):
         first, count = self.held
@@ -150,6 +200,28 @@ class ExpertSpec:
                 f"top_k ({self.top_k}) must lie in 1..{self.n_experts}")
         if self.shared_experts < 0:
             raise ValueError("shared_experts must not be negative")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r} is not known")
+        if (self.n_experts % self.n_groups
+                or not 1 <= self.topk_groups <= self.n_groups
+                or self.top_k > self.topk_groups
+                * (self.n_experts // self.n_groups)):
+            raise ValueError(
+                f"{self.topk_groups} of {self.n_groups} groups do not hold "
+                f"{self.top_k} of {self.n_experts} experts")
+
+    @property
+    def routing(self) -> dict:
+        """What :func:`~apex_tpu.transformer.moe.route_topk` takes beyond
+        its defaults, by keyword (empty for the sigmoid family)."""
+        out = {}
+        if self.scoring != "sigmoid":
+            out["scoring"] = self.scoring
+        if self.n_groups != 1:
+            out["groups"] = (self.n_groups, self.topk_groups)
+        if not self.normalize:
+            out["normalize"] = False
+        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,11 +275,17 @@ class HybridParams(NamedTuple):
     kv_heads * k_dim]``, ``wv [hidden, kv_heads * v_dim]``, ``wo [heads *
     v_dim, hidden]``, ``sinks [heads]`` where the layer's kind has them,
     ``q_norm`` and ``k_norm [k_dim]`` where it norms q and k, ``wg
-    [hidden, heads * v_dim]`` where it has a gate, ``norm2``,
+    [hidden, heads * v_dim]`` where it has a gate; a latent kind has, in
+    place of ``wq``, ``wk`` and ``wv``, ``wq_a [hidden, q_rank]``,
+    ``q_a_norm [q_rank]``, ``wq_b [q_rank, heads * k_dim]``, ``wkv_a
+    [hidden, latent_rank + rotary_dim]``, ``kv_a_norm [latent_rank]``,
+    ``w_uk [heads, latent_rank, nope_dim]`` and ``w_uv [heads, latent_rank,
+    v_dim]``; ``norm2``,
     ``post_attn_norm`` and ``post_ffn_norm [hidden]`` under
     ``sandwich_norm``, then ``ffn_gate_up [hidden, 2 f]`` and ``ffn_down
     [f, hidden]`` for a dense layer or ``router [hidden, E]``,
-    ``router_bias [E]``, ``experts_gate_up [held, hidden, 2 f]`` and
+    ``router_bias [E]`` (may be absent: no bias), ``experts_gate_up [held,
+    hidden, 2 f]`` and
     ``experts_down [held, f, hidden]`` for an expert layer, with
     ``shared_gate_up [hidden, 2 f']`` and ``shared_down [f', hidden]``
     where there are shared experts; gate columns come first);

@@ -71,6 +71,43 @@ GROUP_KERNELS = [
                                     ("window", 8, 1152, 128))]
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_latent_kernel_compiles_for_v5e_at_published_widths(
+        kind, v5e_device, monkeypatch):
+    """The latent group's kernels at the widths that brought them (ISSUE
+    33): 128 heads over rows of 512 + 64 channels in 640 lanes, blocks of
+    16, a table of 1,280 blocks; prefill a walk of 8 slots."""
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import paged_attention as pa
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    b, n, lanes, bs, chunk = (64, 128, 640, 16, 128)
+    kw = dict(v_dim=512, scale=0.1147)
+    arena = shape((53248, bs, lanes))
+    if kind == "decode":
+        def call(q, rows, tables, lengths):
+            return pa.paged_decode_latent(q, rows, tables, lengths, **kw)
+        args = (shape((b, n, lanes)), arena, shape((b, 1280), jnp.int32),
+                shape((b,), jnp.int32))
+    else:
+        b = 8
+
+        def call(q, rows, tables, lengths, limits):
+            return pa.paged_prefill_latent(q, rows, tables, lengths, limits,
+                                           **kw)
+        args = (shape((b, chunk, n, lanes)), arena,
+                shape((b, 1280), jnp.int32), shape((b,), jnp.int32),
+                shape((b, chunk), jnp.int32))
+    compiled = jax.jit(call).lower(*args).compile()
+    assert f"%paged_{kind}_latent" in compiled.as_text()
+
+
 @pytest.mark.parametrize("kind,name,g,blocks,window", GROUP_KERNELS)
 def test_group_kernel_compiles_for_v5e_at_published_widths(
         kind, name, g, blocks, window, v5e_device, monkeypatch):
@@ -171,7 +208,7 @@ def test_grouped_matmul_backward_compiles_for_v5e_at_published_widths(
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
     def loss(x, router, gate_up, down, shared_up, shared_down, bias):
-        y, _, _ = held_experts_ffn(
+        y, _, _, _ = held_experts_ffn(
             x, router, bias, gate_up, down, top_k=8, held=(0, 16),
             route_eps=1e-20, route_scale=2.826,
             shared=(shared_up, shared_down))

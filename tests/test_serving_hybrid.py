@@ -242,7 +242,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(sizes):
                      experts_down=lw["experts_down"][first:first + 4])
         want = np.asarray(reference.expert_layer(x, share, sz,
                                                  held=(first, 4)))
-        got, pairs, chosen = moe.held_experts_ffn(
+        got, pairs, chosen, _ = moe.held_experts_ffn(
             x, share["router"], share["router_bias"],
             share["experts_gate_up"], share["experts_down"],
             top_k=sz["top_k"], held=(first, 4))
@@ -302,11 +302,11 @@ def test_padding_rows_are_routed_nowhere():
     gate_up = jax.random.normal(jax.random.fold_in(key, 2), (8, 16, 8))
     down = jax.random.normal(jax.random.fold_in(key, 3), (8, 4, 16))
     live = jnp.arange(12) < 5
-    y, pairs, _ = moe.held_experts_ffn(x, router, jnp.zeros((8,)), gate_up,
+    y, pairs, _, _ = moe.held_experts_ffn(x, router, jnp.zeros((8,)), gate_up,
                                        down, top_k=2, held=(0, 8), live=live)
     assert int(pairs.sum()) == 5 * 2
     assert float(jnp.abs(y[5:]).max()) == 0.0
-    full, _, _ = moe.held_experts_ffn(x, router, jnp.zeros((8,)), gate_up,
+    full, _, _, _ = moe.held_experts_ffn(x, router, jnp.zeros((8,)), gate_up,
                                       down, top_k=2, held=(0, 8))
     np.testing.assert_allclose(y[:5], full[:5], atol=1e-6)
 
