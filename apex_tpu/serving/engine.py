@@ -33,6 +33,16 @@ Step anatomy (:meth:`ServingEngine.step`)::
                                                length never advances —
                                                O(1), no KV copies)
 
+A model with cache groups runs one call ahead (ISSUE 34): its tick
+dispatches decode call n+1 and only then fetches and delivers call n, so
+the plan, the way back and the delivery run under the device's work.  The
+sampled token stays on the device between the two calls (the compiled
+decode entry selects it by a mask), and the plan works from the host's
+counts as they will be once the call in flight is delivered.
+:meth:`ServingEngine.settle` brings the host's view up to date; the engine
+calls it wherever that view has to be current (docs/serving.md, "One call
+ahead").  Every other model settles in the tick that dispatched.
+
 Metric catalog (rank-aware registry, docs/observability.md +
 docs/serving.md):
 
@@ -69,6 +79,12 @@ docs/serving.md):
   ``max_batch x prefill_len`` their fixed shape has room for
 - ``serving/decode_slot_steps`` counter — slots that took part in a
   decode call, summed over calls
+- ``serving/decode_calls_ahead`` counter — decode calls dispatched while
+  the call before was still unfetched (over ``serving/decode_calls``: the
+  share of calls that overlapped the host's work), and
+  ``serving/decode_rows_discarded`` — rows of such a call thrown away
+  because the delivery before it ended their request on its ``eos_id``
+  (a model with cache groups only; ISSUE 34)
 - ``serving/drawn_calls`` / ``serving/drawn_rows`` counters — decode and
   prefill calls that held at least one slot of temperature > 0 (the
   in-graph draw ran: ``sampling.sample_tokens``' ``cond`` was true), and
@@ -213,6 +229,23 @@ class ServingConfig:
         return self.max_batch * max_blocks_per_request
 
 
+@dataclasses.dataclass
+class _DecodeCall:
+    """A dispatched decode call whose results are still on the device:
+    what a settle fetches and delivers."""
+
+    rows: List[Tuple[Request, int]]     # each request with its slot
+    rids: frozenset                     # the requests' ids
+    drafts: dict
+    out_tokens: Any                     # [max_batch, spec_width]
+    accepted: Any
+    logits: Any
+    routed: Any                         # expert layers only, else None
+    reached: Any
+    dispatch: spans.span                # its ``decode_dispatch`` span
+    step: int                           # the tick that dispatched it
+
+
 class ServingEngine:
     """Continuous-batching decode runtime over a GPT checkpoint.
 
@@ -228,7 +261,9 @@ class ServingEngine:
 
     ``heartbeat``: an optional :class:`~apex_tpu.observability.metrics.
     HeartbeatMonitor` — the engine beats it at the end of every
-    :meth:`step` (after the decode results materialize), so a hung
+    :meth:`step` (after the decode results it fetched materialize: the
+    tick's own call, or the call before's where the engine runs one call
+    ahead), so a hung
     device step (dead collective, wedged transfer) stops the beats, the
     monitor's ``on_hang`` fires the guard, and the engine's next alive
     moment **drains** — delivering in-flight responses — instead of the
@@ -342,8 +377,31 @@ class ServingEngine:
         self.lora = None
         self.adapter_arena = None
         self.adapters = None
-        self._decode = jax.jit(self.model.decode_step, donate_argnums=(0,))
+        model = self.model
+
+        def decode_step(arenas, params, tokens, carried, from_carried,
+                        *rest):
+            # a slot carried over from the call before takes the token that
+            # call sampled, which has not left the device
+            tokens = jax.numpy.where(from_carried[:, None], carried, tokens)
+            return model.decode_step(arenas, params, tokens, *rest)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(0,))
         self._prefill = jax.jit(self.model.prefill, donate_argnums=(0,))
+        # nothing on this lowering's host (no proposer, no adapters, no KV
+        # export) needs a call's tokens before the next plan, so the next
+        # call is dispatched before they are fetched.  A call's tokens are
+        # handed to the next as they are; the first call takes zeros placed
+        # as a call's results will be (committed to a device if an argument
+        # is), so that there is one compiled decode program.
+        self._runs_ahead = True
+        self._no_tokens = jax.numpy.zeros((self.serving.max_batch, 1),
+                                          jax.numpy.int32)
+        placed = [leaf for leaf in jax.tree_util.tree_leaves(
+            (self.arenas, self.params)) if getattr(leaf, "committed", False)]
+        if placed:
+            self._no_tokens = jax.device_put(self._no_tokens,
+                                             placed[0].sharding)
 
     def _init_uniform(self, config, params) -> None:
         """Every layer alike: the stacked arena and the tensor-parallel
@@ -355,6 +413,9 @@ class ServingEngine:
         from apex_tpu.transformer.tensor_parallel import infer_param_specs
 
         serving, tp_axis = self.serving, self.tp_axis
+        # the proposer, the adapters and KV export read a call's tokens on
+        # the host before the next plan: fetch in the tick that dispatched
+        self._runs_ahead = False
         # [vpp, pp, ...] -> [L, ...] (row-major merge == virtual-stage
         # major == plain layer order; gpt3d_logical_folds rationale)
         L = config.num_layers
@@ -528,14 +589,25 @@ class ServingEngine:
                 self.registry.gauge("serving/kv_latent_bytes_per_token").set(
                     sum(g.row_lanes * len(g.layers) for g in latent)
                     * np.dtype(self.cache.dtype).itemsize)
+        if self._runs_ahead:
+            self._counters.decode_calls_ahead = counter(
+                "serving/decode_calls_ahead")
+            self._counters.decode_rows_discarded = counter(
+                "serving/decode_rows_discarded")
         self._counted_window_freed = 0
         self._queue_wait = self.registry.histogram(
             "serving/queue_wait_ms", keep_samples=4096)
         self._decode_calls = 0         # device decode/verify invocations
         self._slot_steps = 0           # per-slot verify participations
         #                                (mean accept length denominator)
-        # the last decode call's logits and the slots that decoded in it
+        # the last delivered decode call's logits and the slots whose row
+        # was delivered
         self._last_logits: Optional[Tuple[Any, Tuple[int, ...]]] = None
+        # the decode call dispatched and not yet fetched (between ticks:
+        # only where the engine runs one call ahead)
+        self._in_flight: Optional[_DecodeCall] = None
+        self._delivered_at = 0.0       # end of the last decode_fetch
+        self._tick_tokens = 0          # what this tick's deliveries emitted
         self._tick_choices: List[Tuple[Any, Tuple[Tuple[int, ...], ...]]] = []
         self._counted_preempts = 0     # flushed-so-far deltas
         self._counted_hits = 0
@@ -549,8 +621,9 @@ class ServingEngine:
         # MFU bookkeeping (ISSUE 10 satellite): FLOPs of the decode
         # program probed once (lazily, pre-donation); the last decode
         # call's wall time is that of its decode_dispatch and
-        # decode_fetch spans; serving/mfu flushed as a gauge when
-        # defined, else the reason string is kept for /statusz.
+        # decode_fetch spans, or for a call fetched in a later tick the
+        # time since the delivery before it; serving/mfu flushed as a
+        # gauge when defined, else the reason string is kept for /statusz.
         self._decode_flops: Optional[float] = None
         self._decode_ms: Optional[float] = None
         self._flops_probed = False
@@ -687,6 +760,7 @@ class ServingEngine:
     def drain(self) -> List[Request]:
         """Preemption path: cancel the queue, keep decoding the running
         requests until their responses are delivered."""
+        self.settle()
         timeline.emit("preemption", wall_ts=time.time())
         cancelled = self.scheduler.drain()
         if cancelled:
@@ -866,14 +940,21 @@ class ServingEngine:
         step.  The tick and its phases are host spans
         (:class:`~apex_tpu.observability.spans.span`; the span map is in
         docs/observability.md): disjoint, in this order, and a phase
-        that did not run records none."""
+        that did not run records none.  Where the engine runs one call
+        ahead, the tick's ``decode_fetch`` and ``deliver`` are the call
+        before's, and come first in a tick that has to settle before it
+        plans."""
         sched = self.scheduler
         self._tick_choices = []
+        self._tick_tokens = 0
         with self._span("serving/tick", step=self._steps,
                         live=len(sched.running()),
                         waiting=len(sched.waiting), prefill_rows=0,
                         prefill_tokens=0, prefill_capacity=0,
                         decode_slots=0) as tick:
+            if (self._in_flight is not None and self.guard is not None
+                    and self.guard.triggered and not self.draining):
+                self.settle()       # the drain below sees a current view
             with self._span("serving/tick/admit") as phase:
                 if (self.guard is not None and self.guard.triggered
                         and not self.draining):
@@ -892,11 +973,15 @@ class ServingEngine:
                                   hit_blocks=req.hit_blocks,
                                   **trace_fields(req))
                 phase.note(admitted=len(admitted))
+            if self._in_flight is not None and self._growth_may_preempt():
+                # a request is not preempted with a row of it in flight
+                self.settle()
             self._prefill_tick(tick)
-            decoded = self._decode_once(tick)
+            fetched = self._decode_once(tick)
             with self._span("serving/tick/deliver") as phase:
-                tokens = self._deliver(*decoded) if decoded else 0
-                phase.note(tokens=tokens)
+                if fetched:
+                    self._deliver(*fetched)
+                phase.note(tokens=self._tick_tokens)
                 self._steps += 1
                 self._counters.ticks.inc()
                 self.registry.gauge("serving/active_slots").set(
@@ -906,10 +991,12 @@ class ServingEngine:
                 self.registry.gauge("serving/kv_occupancy").set(
                     sched.kv_occupancy())
                 self._flush_occupancy_counters()
-                # the beat lands only after this tick's device work
-                # materialized — a wedged decode stops the beats and the
-                # monitor fires the guard, turning a scheduler wedge into
-                # an ordinary drain
+                # the beat lands only after the device work this tick
+                # fetched materialized (its own call's, or the call
+                # before's where the engine runs ahead) — a wedged decode
+                # stops the beats, one tick later there, and the monitor
+                # fires the guard, turning a scheduler wedge into an
+                # ordinary drain
                 if self.heartbeat is not None:
                     self.heartbeat.beat(self._steps)
 
@@ -932,9 +1019,10 @@ class ServingEngine:
 
     def run_until_drained(self, max_steps: int = 100_000) -> None:
         """Drive :meth:`step` until no request is waiting or running
-        (under drain: until the running ones have delivered)."""
+        (under drain: until the running ones have delivered) and no call
+        is in flight."""
         for _ in range(max_steps):
-            if self.scheduler.idle:
+            if self.scheduler.idle and self._in_flight is None:
                 return
             self.step()
         raise RuntimeError(f"not drained after {max_steps} steps")
@@ -1061,8 +1149,10 @@ class ServingEngine:
             top_p[req.slot] = s.top_p
             seeds[req.slot] = s.seed & 0xFFFFFFFF
             # step_offset rebases the draw counter for fleet failover
-            # replays (prompt already carries the emitted prefix)
-            steps[req.slot] = s.step_offset + len(req.output_tokens)
+            # replays (prompt already carries the emitted prefix); a token
+            # in flight counts: it will have been emitted
+            steps[req.slot] = (s.step_offset + len(req.output_tokens)
+                               + self._ahead(req))
         drawn = int(np.count_nonzero(temp > 0.0))
         phase.note(drawn=drawn)
         if drawn:
@@ -1207,121 +1297,222 @@ class ServingEngine:
             return []
         return list(self.proposer.propose(req, max_k))[:max_k]
 
-    def _decode_once(self, tick: spans.span):
-        """Plan, dispatch and fetch this tick's one decode call; returns
-        what :meth:`_deliver` takes, or ``None`` where no slot decodes."""
-        if not self.scheduler.running():
-            return None
-        B, S = self.serving.max_batch, self.spec_width
-        with self._span("serving/tick/decode_plan") as phase:
-            preempted = self.scheduler.preemptions
-            # a request at the context cap cannot write another token:
-            # deliver what it has (truncation is a response, not a hang)
-            for req in list(self.scheduler.running()):
-                if (not req.prefilling
-                        and req.cache_len >= self.cache.max_seq):
-                    self._finish(req)
-            # grow this tick's write blocks oldest-first (evict cached
-            # LRU, then preempt strictly newer requests); a newer request
-            # that cannot grow just sits this tick out — it keeps its cache
-            decoding = sorted(
-                (r for r in self.scheduler.running() if not r.prefilling),
-                key=lambda r: r.admit_seq)
-            reqs: List[Request] = []
-            drafts: dict = {}
-            for req in decoding:
-                if (req.slot is None
-                        or req.state is not RequestState.RUNNING):
-                    continue    # preempted by an older request's growth
-                if self._windowed:
-                    self.scheduler.free_behind_window(req, req.cache_len)
-                covered = self.scheduler.try_grow_to(
-                    req, req.cache_len + 1)
-                if covered < req.cache_len + 1:
-                    continue
-                draft = self._propose_drafts(req)
-                if draft:
-                    # blocks for drafted rows come from the free list or
-                    # the cache LRU only, NEVER preemption: speculation is
-                    # an optimization and must not evict a neighbour's real
-                    # KV.  A short grow just truncates the draft (data, not
-                    # shape).
-                    covered = self.scheduler.try_grow_to(
-                        req, req.cache_len + 1 + len(draft),
-                        preempt=False)
-                    draft = draft[:max(0, covered - (req.cache_len + 1))]
-                drafts[req.rid] = draft
-                reqs.append(req)
-            # what the attention kernel meets this tick: each decoding
-            # slot attends its history, the row it writes and its drafts
-            bs = self.cache.block_size
-            history = [req.cache_len + 1 + len(drafts[req.rid])
-                       for req in reqs]
-            kv_tokens = sum(history)
-            kv_pages = sum(-(-h // bs) for h in history)
-            phase.note(preempted=self.scheduler.preemptions - preempted,
-                       kv_tokens=kv_tokens, kv_pages=kv_pages)
-            self._counters.decode_kv_tokens.inc(kv_tokens)
-            self._counters.decode_kv_pages.inc(kv_pages)
-            if self.hybrid:
-                self._note_group_reads(phase, history)
-            if not reqs:
-                return None
-            tokens = np.zeros((B, S), np.int32)
-            positions = np.zeros((B,), np.int32)
-            active = np.zeros((B,), bool)
-            n_draft = np.zeros((B,), np.int32)
-            for req in reqs:
-                d = drafts[req.rid]
-                tokens[req.slot, 0] = req.last_token
-                if d:
-                    tokens[req.slot, 1:1 + len(d)] = d
-                positions[req.slot] = req.cache_len
-                active[req.slot] = True
-                n_draft[req.slot] = len(d)
-            self._refresh_tables()
-            samp = self._sampling_arrays(phase)
+    def _ahead(self, req: Request) -> int:
+        """Rows of ``req`` that the call in flight computes: tokens, and
+        cache rows, that the host's counts do not hold yet."""
+        call = self._in_flight
+        return int(call is not None and req.rid in call.rids)
 
-            tables = self._device_tables()
-            if self.adapter_arena is None:
-                args = (self.arenas, self.params, tokens, positions,
-                        tables, active, n_draft) + samp
+    def _growth_may_preempt(self) -> bool:
+        """Whether the blocks this tick's plans ask for (a chunk for each
+        prefilling slot, a row for each decoding one) could outrun the free
+        ones, so that growing would preempt.  Counts nothing a window hands
+        back this tick, so it errs towards yes."""
+        blocks_for = self.cache.blocks_for
+        cap = self.prefill_len
+        if self.live_prefill_chunk is not None:
+            cap = min(cap, self.live_prefill_chunk)
+        need = 0
+        for req in self.scheduler.running():
+            if req.prefilling:
+                upto = min(req.cache_len + cap, req.prefill_target)
+                # a prompt that completes decodes in the same tick
+                upto += upto == req.prefill_target
             else:
-                args = (self.arenas, self.adapters, self.params, tokens,
-                        positions, tables, active, n_draft,
-                        self._adapter_slot_array()) + samp
-            if not self._flops_probed:
-                # One-time FLOPs probe for the MFU gauge: lowering traces
-                # the decode body (no second XLA compile, no execution —
-                # the arenas are not donated by a trace) and the HLO cost
-                # pass reports the program's FLOPs.  Must happen BEFORE
-                # the call below consumes the donated arenas.
-                self._probe_decode_flops(args)
-        routed = None
+                upto = req.cache_len + self._ahead(req) + 1
+            need += max(0, blocks_for(upto) - len(req.blocks))
+        return need > min(a.n_free for a in self.scheduler.allocators)
+
+    def settle(self) -> None:
+        """Fetch and deliver the decode call in flight, if there is one:
+        afterwards the requests hold every token the device has computed.
+        The engine settles by itself before a growth that could preempt,
+        before a drain, and when a tick has nothing left to dispatch; call
+        it before reading a request's tokens between ticks where the newest
+        has to be among them."""
+        if self._in_flight is not None:
+            self._deliver(*self._fetch_call(self._in_flight))
+
+    def _decode_once(self, tick: spans.span):
+        """Plan and dispatch this tick's one decode call, and fetch the
+        call that is due: the one just dispatched, or where the engine runs
+        one call ahead the call before it (fetched at once when nothing
+        could be dispatched).  Returns what :meth:`_deliver` takes, or
+        ``None`` where nothing was fetched."""
+        before = self._in_flight
+        args = None
+        if self.scheduler.running():
+            with self._span("serving/tick/decode_plan") as phase:
+                args = self._plan_decode(phase)
+        if args is None:
+            return self._fetch_call(before) if before is not None else None
+        reqs, drafts, positions, args = args
         with self._span("serving/tick/decode_dispatch") as dispatch:
+            routed = reached = None
             if self.hybrid:
                 (self.arenas, out_tokens, accepted, logits, routed, chosen,
                  reached) = self._decode(*args)
                 self._tick_choices.append((chosen, tuple(
-                    (req.rid, req.slot, req.cache_len, 1) for req in reqs)))
+                    (req.rid, req.slot, int(positions[req.slot]), 1)
+                    for req in reqs)))
             elif self.adapter_arena is None:
                 self.arenas, out_tokens, accepted, logits = \
                     self._decode(*args)
             else:
                 self.arenas, self.adapters, out_tokens, accepted, logits = \
                     self._decode(*args)
-            # replaces, and so frees, the call before's
-            self._last_logits = (logits, tuple(r.slot for r in reqs))
+            if self._runs_ahead:
+                dispatch.note(ahead=int(before is not None))
+                if before is not None:
+                    self._counters.decode_calls_ahead.inc()
+        tick.note(decode_slots=len(reqs))
+        call = self._in_flight = _DecodeCall(
+            rows=[(req, req.slot) for req in reqs],
+            rids=frozenset(req.rid for req in reqs), drafts=drafts,
+            out_tokens=out_tokens, accepted=accepted, logits=logits,
+            routed=routed, reached=reached, dispatch=dispatch,
+            step=self._steps)
+        if not self._runs_ahead:
+            return self._fetch_call(call)
+        if before is not None:
+            return self._fetch_call(before)
+        if self._delivered_at < tick.start:
+            # nothing to fetch, and the tick has not settled before it
+            # planned: every tick with a dispatch has the span
+            with self._span("serving/tick/decode_fetch"):
+                pass
+        return None
+
+    def _plan_decode(self, phase: spans.span):
+        """The ``decode_plan`` phase: grow the decoding slots' blocks and
+        build the call's arguments from the host's counts as they will be
+        once the call in flight, if any, is delivered.  Returns ``(requests,
+        drafts, positions [max_batch], arguments)``, or ``None`` where no
+        slot decodes."""
+        B, S = self.serving.max_batch, self.spec_width
+        preempted = self.scheduler.preemptions
+        # a request at the context cap cannot write another token:
+        # deliver what it has (truncation is a response, not a hang)
+        for req in list(self.scheduler.running()):
+            if (not req.prefilling and not self._ahead(req)
+                    and req.cache_len >= self.cache.max_seq):
+                self._finish(req)
+        # grow this tick's write blocks oldest-first (evict cached
+        # LRU, then preempt strictly newer requests); a newer request
+        # that cannot grow just sits this tick out — it keeps its cache
+        decoding = sorted(
+            (r for r in self.scheduler.running() if not r.prefilling),
+            key=lambda r: r.admit_seq)
+        reqs: List[Request] = []
+        drafts: dict = {}
+        positions = np.zeros((B,), np.int32)
+        for req in decoding:
+            if (req.slot is None
+                    or req.state is not RequestState.RUNNING):
+                continue    # preempted by an older request's growth
+            ahead = self._ahead(req)
+            pos = req.cache_len + ahead     # the row this call writes
+            if ahead and (pos >= self.cache.max_seq
+                          or len(req.output_tokens) + ahead
+                          >= req.max_new_tokens):
+                continue    # the call in flight ends it: no further row
+            if self._windowed:
+                # behind the delivered length: the call in flight reads
+                # nothing that is handed back
+                self.scheduler.free_behind_window(req, req.cache_len)
+            covered = self.scheduler.try_grow_to(req, pos + 1)
+            if covered < pos + 1:
+                continue
+            draft = self._propose_drafts(req)
+            if draft:
+                # blocks for drafted rows come from the free list or
+                # the cache LRU only, NEVER preemption: speculation is
+                # an optimization and must not evict a neighbour's real
+                # KV.  A short grow just truncates the draft (data, not
+                # shape).
+                covered = self.scheduler.try_grow_to(
+                    req, pos + 1 + len(draft), preempt=False)
+                draft = draft[:max(0, covered - (pos + 1))]
+            drafts[req.rid] = draft
+            positions[req.slot] = pos
+            reqs.append(req)
+        # what the attention kernel meets this tick: each decoding
+        # slot attends its history, the row it writes and its drafts
+        bs = self.cache.block_size
+        history = [int(positions[req.slot]) + 1 + len(drafts[req.rid])
+                   for req in reqs]
+        kv_tokens = sum(history)
+        kv_pages = sum(-(-h // bs) for h in history)
+        phase.note(preempted=self.scheduler.preemptions - preempted,
+                   kv_tokens=kv_tokens, kv_pages=kv_pages)
+        self._counters.decode_kv_tokens.inc(kv_tokens)
+        self._counters.decode_kv_pages.inc(kv_pages)
+        if self.hybrid:
+            self._note_group_reads(phase, history)
+        if not reqs:
+            return None
+        tokens = np.zeros((B, S), np.int32)
+        active = np.zeros((B,), bool)
+        n_draft = np.zeros((B,), np.int32)
+        from_carried = np.zeros((B,), bool)
+        for req in reqs:
+            d = drafts[req.rid]
+            tokens[req.slot, 0] = req.last_token
+            if d:
+                tokens[req.slot, 1:1 + len(d)] = d
+            active[req.slot] = True
+            n_draft[req.slot] = len(d)
+            from_carried[req.slot] = self._ahead(req)
+        self._refresh_tables()
+        samp = self._sampling_arrays(phase)
+
+        tables = self._device_tables()
+        rest = (positions, tables, active, n_draft)
+        if self._runs_ahead:
+            before = self._in_flight
+            carried = (self._no_tokens if before is None
+                       else before.out_tokens)
+            args = (self.arenas, self.params, tokens, carried,
+                    from_carried) + rest + samp
+        elif self.adapter_arena is None:
+            args = (self.arenas, self.params, tokens) + rest + samp
+        else:
+            args = (self.arenas, self.adapters, self.params, tokens) \
+                + rest + (self._adapter_slot_array(),) + samp
+        if not self._flops_probed:
+            # One-time FLOPs probe for the MFU gauge: lowering traces
+            # the decode body (no second XLA compile, no execution —
+            # the arenas are not donated by a trace) and the HLO cost
+            # pass reports the program's FLOPs.  Must happen BEFORE
+            # the dispatch consumes the donated arenas.
+            self._probe_decode_flops(args)
+        return reqs, drafts, positions, args
+
+    def _fetch_call(self, call: _DecodeCall):
+        """The fetch half of a settle, in a ``decode_fetch`` span: the
+        call's tokens (and what it routed) to the host in one round trip.
+        Returns what :meth:`_deliver` takes; the last of it is the call's
+        wall time, that of its two spans when it is fetched in the tick
+        that dispatched it, else the time since the delivery before it (or
+        since its dispatch, if that came later)."""
+        if self._in_flight is call:
+            self._in_flight = None
         with self._span("serving/tick/decode_fetch") as fetch:
-            if routed is None:
-                out_np = np.asarray(out_tokens)
-                acc_np = np.asarray(accepted)
+            if call.routed is None:
+                out_np = np.asarray(call.out_tokens)
+                acc_np = np.asarray(call.accepted)
             else:
                 out_np, acc_np, routed, reached = self._fetch(
-                    (out_tokens, accepted, routed, reached))
+                    (call.out_tokens, call.accepted, call.routed,
+                     call.reached))
                 self._note_routed(fetch, routed, reached)
-        tick.note(decode_slots=len(reqs))
-        return reqs, drafts, out_np, acc_np, dispatch.ms + fetch.ms
+        if call.step == self._steps:
+            decode_ms = call.dispatch.ms + fetch.ms
+        else:
+            decode_ms = (fetch.end - max(call.dispatch.start,
+                                         self._delivered_at)) * 1e3
+        self._delivered_at = fetch.end
+        return call, out_np, acc_np, decode_ms
 
     @staticmethod
     def _fetch(arrays):
@@ -1357,21 +1548,31 @@ class ServingEngine:
                    window_blocks_held=sched.window_blocks_held(),
                    window_blocks_freed=freed)
 
-    def _deliver(self, reqs: List[Request], drafts: dict, out_np, acc_np,
-                 decode_ms: float) -> int:
-        """Hand the decode call's tokens to their requests (the accepted
-        prefix of each slot's verify); returns how many were emitted."""
+    def _deliver(self, call: _DecodeCall, out_np, acc_np,
+                 decode_ms: float) -> None:
+        """Hand a fetched decode call's tokens to their requests (the
+        accepted prefix of each slot's verify) and add them to the tick's
+        count.  A row whose request the delivery before ended (on its
+        ``eos_id``: the one end the plan cannot foresee) is discarded."""
+        rows = [(req, slot) for req, slot in call.rows
+                if req.state is RequestState.RUNNING and req.slot == slot]
+        if len(rows) < len(call.rows):
+            self._counters.decode_rows_discarded.inc(
+                len(call.rows) - len(rows))
+        drafts = call.drafts
+        # replaces, and so frees, the delivered call before's
+        self._last_logits = (call.logits, tuple(slot for _, slot in rows))
         self._decode_calls += 1
-        self._slot_steps += len(reqs)
+        self._slot_steps += len(rows)
         self._counters.decode_calls.inc()
-        self._counters.decode_slot_steps.inc(len(reqs))
+        self._counters.decode_slot_steps.inc(len(rows))
         self._refresh_mfu(decode_ms)
 
         now = time.monotonic()
         emitted = proposed_total = accepted_total = 0
-        for req in reqs:
+        for req, slot in rows:
             d = drafts[req.rid]
-            acc = int(acc_np[req.slot])
+            acc = int(acc_np[slot])
             if d:
                 proposed_total += len(d)
                 accepted_total += acc
@@ -1396,7 +1597,7 @@ class ServingEngine:
                 if j > 0:
                     req.cache_len += 1    # draft j == the token just
                     #                       emitted — its row is real
-                self._emit(req, int(out_np[req.slot, j]), now)
+                self._emit(req, int(out_np[slot, j]), now)
                 emitted += 1
                 if req.state is not RequestState.RUNNING:
                     break                 # eos/budget: drop the rest
@@ -1411,7 +1612,7 @@ class ServingEngine:
         if self.spec_proposed:
             self.registry.gauge("serving/spec_acceptance").set(
                 self.spec_accepted / self.spec_proposed)
-        return emitted
+        self._tick_tokens += emitted
 
     # ------------------------------------------------------------------ mfu
 
@@ -1428,10 +1629,12 @@ class ServingEngine:
         self._decode_flops = compiled_flops(lowered)
 
     def _refresh_mfu(self, decode_ms: float) -> None:
-        """Derive MFU from the last decode call's wall time (its
-        dispatch and fetch spans); flush the gauge when defined, keep the
-        None-reason (unknown device peak vs missing cost analysis) for
-        ``/statusz`` and logs otherwise."""
+        """Derive MFU from the last delivered decode call's wall time
+        (:meth:`_fetch_call`: its dispatch and fetch spans, or the time
+        between consecutive deliveries where the engine runs one call
+        ahead); flush the gauge when defined, keep the None-reason (unknown
+        device peak vs missing cost analysis) for ``/statusz`` and logs
+        otherwise."""
         self._decode_ms = decode_ms
         if self._probe_fail_reason is not None:
             # keep the specific probe failure — the generic "no
@@ -1504,11 +1707,15 @@ class ServingEngine:
         }
 
     def last_logits(self) -> Optional[Tuple[Any, Tuple[int, ...]]]:
-        """The last decode call's logits (the device array
+        """The last delivered decode call's logits (the device array
         ``[max_batch, spec_width, vocab]``, as the program returned it)
-        and the slots that decoded in that call (the other rows are
-        padding); ``None`` before the first call.  Held by reference
-        until the next decode call replaces it, never copied."""
+        and the slots whose row was delivered (the other rows are padding,
+        or were discarded); ``None`` before the first delivery.  Each named
+        slot's request has read ``sequence_tokens()[:cache_len]`` when the
+        row was made.  Replaced at delivery, so where the engine runs one
+        call ahead these are the call before's, not those of the call in
+        flight.  Held by reference until the next delivery replaces it,
+        never copied."""
         return self._last_logits
 
     def last_expert_choices(self) -> List[Tuple[Any, Tuple[Tuple[int, ...],
@@ -1517,7 +1724,9 @@ class ServingEngine:
         expert layers; else empty): per call, prefill before decode, the
         device array ``[expert layers, rows of the call, top_k]`` of expert
         ids as the program returned it, and which of its rows were tokens:
-        ``(request id, first row, first position, count)`` per request.
+        ``(request id, first row, first position, count)`` per request (per
+        dispatch: a decode call's position is the one it writes, also
+        while the call before it is in flight).
         Held by reference until the next tick, never fetched: a
         comparison with another precision reads them afterwards, since
         scores near the cut lie closer than bfloat16 rounds."""

@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
+import ahead_scenario                                      # noqa: E402
 from drivers import mimo_program                          # noqa: E402
 from reference import mimo_v2_flash as reference          # noqa: E402
 
@@ -145,7 +146,8 @@ def test_expert_choices_of_every_call_are_the_reference_own(sizes, weights):
     while not eng.scheduler.idle:
         eng.step()
         calls = eng.last_expert_choices()
-        assert 1 <= len(calls) <= 2
+        # a tick's prefill and decode dispatches; the last tick only fetches
+        assert len(calls) <= 2 and (calls or eng.scheduler.idle)
         for chosen, rows in calls:
             assert chosen.shape[0] == sum(sizes["experts"])
             assert chosen.shape[2] == sizes["top_k"]
@@ -188,14 +190,24 @@ def test_one_compile_each_under_churn(sizes, weights):
             done.append(eng.submit(
                 rng.integers(0, sizes["vocab"], n).tolist(), m,
                 sampling=sampling))
+        preempted, in_flight = eng.scheduler.preemptions, eng._in_flight
         eng.step()
         eng.scheduler.check()
+        if eng.scheduler.preemptions > preempted and in_flight is not None:
+            # no request is preempted with a row in flight: the tick settled
+            # before it planned, and its dispatch ran on a current view
+            dispatch = [s for s in spans.recorded()
+                        if s.name == "serving/tick/decode_dispatch"][-1]
+            assert dispatch.fields["ahead"] == 0
         if not pending and eng.scheduler.idle:
             break
     assert eng.scheduler.idle
     assert all(len(r.output_tokens) == r.max_new_tokens for r in done)
     assert eng.scheduler.preemptions > 0
     assert eng.decode_compile_count() == eng.prefill_compile_count() == 1
+    snap = eng.registry.snapshot()
+    assert snap["serving/decode_rows_discarded"] == 0
+    assert 0 < snap["serving/decode_calls_ahead"] < snap["serving/decode_calls"]
 
 
 def test_preempted_request_resumes_with_the_same_stream(sizes, weights):
@@ -717,6 +729,9 @@ def test_spans_and_counters_of_both_caches_and_the_router(sizes, weights):
     records = [s for s in spans.recorded() if s.start >= t0]
     plans = [s for s in records if s.name == "serving/tick/decode_plan"]
     fetches = [s for s in records if s.name == "serving/tick/decode_fetch"]
+    # the tick of the first dispatch has nothing to fetch yet: an empty span
+    assert not fetches[0].fields
+    fetches = [s for s in fetches if s.fields]
     assert fetches and len(plans) >= len(fetches)  # a tick may only prefill
     last = max(plans, key=lambda s: s.fields["kv_tokens_full"])
     for field in ("kv_tokens_full", "kv_tokens_window", "kv_pages_full",
@@ -741,3 +756,77 @@ def test_spans_and_counters_of_both_caches_and_the_router(sizes, weights):
     assert pairs > 0
     assert eng.registry.counter("serving/window_blocks_freed").value \
         == sum(s.fields["window_blocks_freed"] for s in plans)
+
+
+# ---------------------------------------------------- one decode call ahead
+
+
+@pytest.fixture(scope="module")
+def ahead_runs(sizes, weights):
+    return ahead_scenario.runs(
+        build_engine(sizes, weights, **ahead_scenario.ENGINE),
+        sizes["vocab"])
+
+
+@pytest.mark.parametrize("i", range(len(ahead_scenario.SCRIPT)),
+                         ids=ahead_scenario.KINDS)
+def test_running_ahead_serves_the_settled_engines_tokens(ahead_runs, i):
+    """Greedy and seeded sampled requests across a slot turning over, a
+    prompt chunk arriving mid-stream, window blocks handed back, an end on
+    ``eos_id``, a budget and the context cap: token for token the stream of
+    the same engine settled after every tick."""
+    ahead_scenario.assert_same_stream(ahead_runs, i)
+
+
+def test_the_drivers_contract_holds_with_a_call_in_flight(ahead_runs):
+    """``ahead_scenario.Contract`` ran after every ``step()`` of both
+    runs; here what the counters and the ``ahead`` field counted.  The
+    window group handed blocks back all the while (``check()`` held with a
+    call in flight after every tick)."""
+    ahead_scenario.assert_counted(ahead_runs)
+    assert ahead_runs.eng.scheduler.window_blocks_freed > 0
+    assert ahead_runs.eng.scheduler.preemptions == 0
+
+
+def test_last_logits_are_the_delivered_calls_and_a_drain_settles(
+        ahead_runs, sizes):
+    """On the scenario's engine, idle again (the last to use it: a drain
+    is for good)."""
+    eng = ahead_runs.eng
+    calls = eng.registry.snapshot()["serving/decode_calls"]
+    before = eng.last_logits()
+    prompt = np.random.default_rng(6).integers(0, sizes["vocab"], 7)
+    req = eng.submit(prompt.tolist(), 6)
+    eng.step()                  # the prompt and the first decode dispatch
+    assert eng._in_flight is not None and eng.last_logits() is before
+    assert len(req.output_tokens) == 1 and req.cache_len == 7
+    eng.step()                  # dispatches the second, delivers the first
+    logits, slots = eng.last_logits()
+    assert slots == (req.slot,) and len(req.output_tokens) == 2
+    assert eng._in_flight.logits is not logits
+    assert int(np.argmax(np.asarray(logits)[req.slot, 0])) \
+        == req.output_tokens[-1]
+    # the host's counts are the delivered ones; the call in flight writes
+    # the position after them
+    assert req.cache_len == 8
+    (chosen, rows), = eng.last_expert_choices()
+    assert rows == ((req.rid, req.slot, 8, 1),)
+    eng.settle()
+    assert eng._in_flight is None and len(req.output_tokens) == 3
+    assert eng.last_logits()[0] is not logits
+    eng.settle()                # nothing in flight: nothing happens
+    assert len(req.output_tokens) == 3
+    eng.run_until_drained()
+    assert eng._in_flight is None and len(req.output_tokens) == 6
+    assert eng.registry.snapshot()["serving/decode_calls"] == calls + 5
+    # a drain delivers what is in flight before it cancels the queue
+    first = eng.submit([1, 2, 3], 8)
+    for _ in range(3):
+        eng.step()
+    waiting = eng.submit([4, 5, 6, 7], 8)
+    seen = len(first.output_tokens)
+    assert eng._in_flight is not None
+    assert eng.drain() == [waiting]
+    assert eng._in_flight is None and len(first.output_tokens) == seen + 1
+    eng.run_until_drained()
+    assert len(first.output_tokens) == 8 and eng._in_flight is None

@@ -27,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
+import ahead_scenario                                       # noqa: E402
 from drivers import deepseek_program                       # noqa: E402
 from drivers.serve_hybrid import row_gaps                  # noqa: E402
 from reference import deepseek_v2 as reference             # noqa: E402
@@ -471,7 +472,8 @@ def test_spans_and_counters_of_the_latent_cache_and_the_router(sizes,
     drive(eng, ((14, 8), (11, 8), (20, 8)), seed=4)
     records = [s for s in spans.recorded() if s.start >= t0]
     plans = [s for s in records if s.name == "serving/tick/decode_plan"]
-    fetches = [s for s in records if s.name in (
+    # (the tick of the first dispatch has nothing to fetch: an empty span)
+    fetches = [s for s in records if s.fields and s.name in (
         "serving/tick/decode_fetch", "serving/tick/prefill_fetch")]
     last = max(plans, key=lambda s: s.fields["kv_tokens_latent"])
     assert last.fields["kv_tokens_latent"] == last.fields["kv_tokens"] > 0
@@ -492,3 +494,47 @@ def test_spans_and_counters_of_the_latent_cache_and_the_router(sizes,
     # 3 layers x 128 lanes x 4 bytes
     assert eng.registry.snapshot()["serving/kv_latent_bytes_per_token"] \
         == 3 * 128 * 4
+
+
+# ---------------------------------------------------- one decode call ahead
+
+
+@pytest.fixture(scope="module")
+def ahead_runs(sizes, weights):
+    return ahead_scenario.runs(
+        build_engine(sizes, weights, **ahead_scenario.ENGINE),
+        sizes["vocab"])
+
+
+@pytest.mark.parametrize("i", range(len(ahead_scenario.SCRIPT)),
+                         ids=ahead_scenario.KINDS)
+def test_running_ahead_serves_the_settled_engines_tokens(ahead_runs, i):
+    """Through the latent cache: greedy and seeded sampled requests across
+    a slot turning over, a prompt chunk arriving mid-stream, an end on
+    ``eos_id``, a budget and the context cap, token for token the stream of
+    the same engine settled after every tick."""
+    ahead_scenario.assert_same_stream(ahead_runs, i)
+
+
+def test_the_drivers_contract_holds_with_a_call_in_flight(ahead_runs):
+    """``ahead_scenario.Contract`` ran after every ``step()`` of both
+    runs; here what the counters and the ``ahead`` field counted."""
+    ahead_scenario.assert_counted(ahead_runs)
+    assert ahead_runs.eng.scheduler.preemptions == 0
+
+
+def test_the_ahead_engines_logits_match_the_reference(sizes, weights):
+    """``drive`` pairs ``last_logits()`` with ``sequence_tokens()[:cache_len]``
+    as the benchmark's driver does, one call behind the dispatch: against
+    the reference in the expanded form, on an engine that ran ahead on all
+    but its first call."""
+    eng = build_engine(sizes, weights)
+    reqs, rows, _ = drive(eng, ((9, 12), (17, 10)))
+    worst = 0.0
+    for got, _, seq in rows[::2]:
+        want = np.asarray(reference.last_logits(weights, [seq], sizes))[0]
+        worst = max(worst, float(np.abs(got - want).max()))
+    assert len(rows) >= 15 and worst < 2e-5, worst
+    snap = eng.registry.snapshot()
+    assert snap["serving/decode_calls_ahead"] >= snap["serving/decode_calls"] - 2
+    assert eng._in_flight is None
