@@ -19,6 +19,13 @@ different clocks:
   its start, end, parent and integer fields, in one bounded process-wide
   ring that :func:`recorded` reads and :func:`self_ms` reduces.
 
+Traced scopes become **device time by layer** through the scope tables:
+:func:`register_program` keeps a lowered program by name,
+:func:`program_scopes` compiles it on the first ask and reduces its
+optimized HLO with :func:`scopes_of` to ``{instruction: (scope, text)}``,
+which is what names each operation of a captured profile (the profiler's
+events carry the instruction's text and no metadata).
+
 Plus the two step-level tools the real-TPU ``overlap_comm`` A/B needs
 (ROADMAP S8/D7):
 
@@ -40,16 +47,18 @@ import collections
 import itertools
 import logging
 import os
+import re
 import threading
 import time
 import weakref
-from typing import Dict, Iterable, List, Optional
+from typing import Callable, Dict, Iterable, List, Optional, Tuple, Union
 
 import jax
 
 from apex_tpu.observability.metrics import default_registry
 
-__all__ = ["named_span", "span", "recorded", "self_ms", "step_trace",
+__all__ = ["named_span", "span", "recorded", "self_ms", "scopes_of",
+           "register_program", "program_scopes", "step_trace",
            "TraceWindow"]
 
 logger = logging.getLogger(__name__)
@@ -167,6 +176,168 @@ def self_ms(records: Iterable[span]) -> Dict[int, float]:
         if s.parent in own:
             own[s.parent] -= s.ms
     return own
+
+
+# ------------------------------------------------- device time by layer
+
+# the first part of a traced scope's name, wherever the scope sits in an
+# ``op_name``: at its start, after a ``/``, or inside a transform's
+# brackets (``transpose(jvp(apex/flash_full))``)
+_SCOPE = re.compile(rf"(?:^|[/(]){_PREFIX}/([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OPERANDS = re.compile(r"\s[a-z][a-z0-9\-]*\(")
+# an attribute that names one computation the instruction calls, or several
+_CALLED = re.compile(
+    r"\b(calls|to_apply|body|condition|true_computation"
+    r"|false_computation)=([^,\s]+)"
+    r"|\b(branch_computations|called_computations)=\{([^}]*)\}")
+
+
+def _without(line: str, attribute: str) -> Tuple[str, str]:
+    """``line`` less its ``, <attribute>=<value>``, and the value: a
+    ``{...}`` (braces nest, quoted strings may hold any) or a ``"..."``;
+    ``(line, "")`` where the line has none."""
+    at = line.find(f", {attribute}=")
+    if at < 0:
+        return line, ""
+    i = start = at + len(attribute) + 3
+    depth, quoted = 0, False
+    while i < len(line):
+        c = line[i]
+        if quoted:
+            if c == "\\":
+                i += 1
+            elif c == '"':
+                quoted = False
+        elif c == '"':
+            quoted = True
+        elif c == "{":
+            depth += 1
+        elif c == "}":
+            depth -= 1
+        if not quoted and depth == 0:
+            break
+        i += 1
+    return line[:at] + line[i + 1:], line[start:i + 1]
+
+
+def instruction_text(line: str) -> str:
+    """What tells one program's ``fusion.12`` from another's, from the
+    instruction's line in a module's text or from the name the profiler
+    gives its event: ``name = result opcode(operand names)``.  The event
+    prints each operand's shape and the module's text does not, and their
+    attributes differ, so neither is kept."""
+    line = line.strip().removeprefix("ROOT ").replace("%", "")
+    head = _OPERANDS.search(line, line.find(" = ") + 2)
+    if head is None:
+        return line
+    # an operand is the last word of its entry (``bf16[8,128]{1,0} copy.3``)
+    # between the commas that lie inside no further bracket
+    operands, depth, at = [], 1, head.end()
+    for i in range(at, len(line)):
+        c = line[i]
+        depth += (c in "([{") - (c in ")]}")
+        if depth == 0 or (depth == 1 and c == ","):
+            operands += line[at:i].split()[-1:]
+            at = i + 1
+            if depth == 0:
+                break
+    return f"{line[:head.end()]}{', '.join(operands)})"
+
+
+def scopes_of(hlo_text: str) -> Dict[str, Tuple[Optional[str], str]]:
+    """``{instruction name: (scope, text)}`` of an optimized HLO module's
+    text (``compiled.as_text()``), fused computations' own instructions
+    included.
+
+    ``scope`` is the innermost :func:`named_span` round the primitive the
+    instruction came from: the last ``apex/<name>`` in its ``op_name``,
+    also inside ``transpose(jvp(..))``, ``checkpoint`` or a nested
+    ``jit(..)``.  A name of several parts reads as its first
+    (``zero/reduce_scatter/bucket3`` is ``zero``): a rendered path does
+    not say where a name ends.  XLA gives a fusion its root's ``op_name``,
+    so a fusion that spans two scopes counts under its root's.  An
+    instruction whose path has no scope takes one from its neighbours: a
+    fusion (XLA's own scatter, with no path at all) the scope most of the
+    instructions fused into it carry; then any instruction (a copy XLA
+    put into a loop's body, a reduction's adder) that of the instruction
+    that calls its computation; ``None`` where that has none either.
+    ``text`` is :func:`instruction_text` of the line."""
+    # [(computation, [(instruction, scope, text, callees, fused into it)])]
+    computations = []
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header is not None:
+            computations.append((header.group(1), []))
+            continue
+        found = _INSTRUCTION.match(line)
+        if found is None or not computations:
+            continue
+        line, metadata = _without(line, "metadata")
+        line, _ = _without(line, "backend_config")
+        op_name = _OP_NAME.search(metadata)
+        inside = _SCOPE.findall(op_name.group(1)) if op_name else ()
+        called = [(one or several, name.strip().lstrip("%"))
+                  for one, name, several, names in _CALLED.findall(line)
+                  for name in (name or names).split(",")]
+        computations[-1][1].append((
+            found.group(1), inside[-1] if inside else None,
+            instruction_text(line), [name for _, name in called],
+            next((name for how, name in called if how == "calls"), None)))
+    most = {}               # computation -> the scope most of it carries
+    for name, instructions in computations:
+        own = collections.Counter(
+            scope for _, scope, _, _, _ in instructions if scope)
+        most[name] = own.most_common(1)[0][0] if own else None
+    table, inherited = {}, {}
+    # a module's text lists a computation before the ones that call it
+    for name, instructions in reversed(computations):
+        outer = inherited.get(name)
+        for instruction, scope, text, callees, fused in instructions:
+            scope = scope or most.get(fused) or outer
+            table[instruction] = (scope, text)
+            for callee in callees:
+                inherited.setdefault(callee, scope)
+    return table
+
+
+# name -> a ``Lowered``, a callable that makes one, or the table built from
+# it.  Process-wide like the ring: a second engine's programs take the
+# names over (two replicas of one model compile the same programs).
+_PROGRAMS: Dict[str, object] = {}
+
+
+def register_program(name: str,
+                     lowered: Union[object, Callable[[], object]]) -> None:
+    """Keep a program for :func:`program_scopes`: ``jitted.lower(...)``'s
+    result, or a callable that returns it (where lowering is better left
+    to whoever asks).  Costs nothing until someone asks.  What is handed
+    in must hold no device array and no owner of one: a ``Lowered`` holds
+    the module's text, avals and shardings; a callable should close over
+    the jitted function and ``jax.ShapeDtypeStruct`` arguments."""
+    _PROGRAMS[name] = lowered
+
+
+def program_scopes() -> Dict[str, Dict[str, Tuple[Optional[str], str]]]:
+    """``{program name: scopes_of(its optimized HLO)}`` of every registered
+    program.  The first ask compiles each (a hit where JAX's persistent
+    compile cache is on: the program ran before) and keeps the table in
+    the program's place (an empty one, and a warning, where a program no
+    longer lowers or compiles).  For whoever reads a capture of this
+    process by layer; not for a status page, which must never start a
+    compilation."""
+    for name, kept in list(_PROGRAMS.items()):
+        if isinstance(kept, dict):
+            continue
+        try:
+            lowered = kept() if callable(kept) else kept
+            _PROGRAMS[name] = scopes_of(lowered.compile().as_text())
+        except Exception as e:  # telemetry never takes the process down
+            logger.warning("no scope table of %s: %r", name, e)
+            _PROGRAMS[name] = {}
+    return dict(_PROGRAMS)
 
 
 def step_trace(step_num: int, name: str = "train_step"):

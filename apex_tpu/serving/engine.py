@@ -383,7 +383,9 @@ class ServingEngine:
                         *rest):
             # a slot carried over from the call before takes the token that
             # call sampled, which has not left the device
-            tokens = jax.numpy.where(from_carried[:, None], carried, tokens)
+            with spans.named_span("embed"):
+                tokens = jax.numpy.where(from_carried[:, None], carried,
+                                         tokens)
             return model.decode_step(arenas, params, tokens, *rest)
 
         self._decode = jax.jit(decode_step, donate_argnums=(0,))
@@ -627,6 +629,7 @@ class ServingEngine:
         self._decode_flops: Optional[float] = None
         self._decode_ms: Optional[float] = None
         self._flops_probed = False
+        self._prefill_registered = False
         self._probe_fail_reason: Optional[str] = None
         self.mfu: Optional[float] = None
         self.mfu_reason: Optional[str] = "decode step has not run yet"
@@ -1231,6 +1234,8 @@ class ServingEngine:
                     + (dest_b, dest_o, sample_index,
                        self._adapter_slot_array()) + samp
             n_tokens = int(sum(c for _, c in plan))
+            if not self._prefill_registered:
+                self._register_prefill(args)
 
         with timeline.scope("prefill", rids=[r.rid for r, _ in plan],
                             tokens=n_tokens):
@@ -1617,7 +1622,9 @@ class ServingEngine:
     # ------------------------------------------------------------------ mfu
 
     def _probe_decode_flops(self, args) -> None:
-        """Fill ``self._decode_flops`` (or the reason it is unknown)."""
+        """Fill ``self._decode_flops`` (or the reason it is unknown), and
+        keep the lowered program for ``spans.program_scopes()``: it holds
+        the module and the arguments' shapes, no array and not the engine."""
         self._flops_probed = True
         try:
             lowered = self._decode.lower(*args)
@@ -1627,6 +1634,25 @@ class ServingEngine:
             self.mfu_reason = self._probe_fail_reason
             return
         self._decode_flops = compiled_flops(lowered)
+        spans.register_program("serving/decode", lowered)
+
+    def _register_prefill(self, args) -> None:
+        """The prefill program for ``spans.program_scopes()``, from its
+        first call: the jitted function and the call's abstract arguments,
+        lowered when someone asks (the model is traced a second time only
+        then)."""
+        import jax
+
+        self._prefill_registered = True
+        prefill = self._prefill
+        # a sharding where the call's argument is committed to one, as
+        # the call sees it: the same module, so the same compile-cache key
+        abstract = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                np.shape(a), a.dtype, sharding=a.sharding if getattr(
+                    a, "committed", False) else None), args)
+        spans.register_program("serving/prefill",
+                               lambda: prefill.lower(*abstract))
 
     def _refresh_mfu(self, decode_ms: float) -> None:
         """Derive MFU from the last delivered decode call's wall time

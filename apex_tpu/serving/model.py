@@ -59,6 +59,20 @@ Both entry points are **shard_map bodies**: run them under
 this) — the parallel linears then shard exactly as in training, and
 the K/V arena rows a rank touches are the heads it owns.
 
+**Every operation belongs to a named layer** (ISSUE 35).  Both models put
+their layers under :func:`~apex_tpu.observability.spans.named_span`, one
+catalog for both: ``embed``, ``norm``, ``attn_proj`` (q/k/v/o and the latent
+down- and up-projections; ``mla_absorb_q`` and ``mla_expand_o`` inside it),
+``rope``, ``cache_write`` (the rows appended to the arenas, and where they
+go), ``attention`` (the paged kernels' step plans and the glue round them;
+a kernel itself keeps its own scope, ``paged_decode*`` / ``paged_prefill*``,
+which names its Mosaic call), ``dense_ffn``, ``moe_router``,
+``moe_experts``, ``moe_shared``, ``lm_head``, ``sample``, and
+``layer_scan`` (what the uniform model's ``lax.scan`` itself costs: the
+slices of the stacked weights and arenas and their way back).  Scopes are
+metadata: the programs are the same instructions with and without them.
+``spans.program_scopes()`` reads them back from the compiled programs.
+
 **Layers of more than one kind** (ISSUE 27).  :class:`DecodeModel` above
 is the case of one group: every layer alike, one ``lax.scan`` over the
 stacked layers, one arena.  A configuration with a
@@ -109,6 +123,7 @@ from apex_tpu.serving.fused_ops import (
     residual_norm_unfused,
 )
 from apex_tpu.normalization.fused_layer_norm import fused_rms_norm_affine
+from apex_tpu.observability.spans import named_span
 from apex_tpu.serving.kv_cache import KVCacheConfig
 from apex_tpu.serving.lora import LoRAConfig, lora_delta
 from apex_tpu.serving.paged_attention import (
@@ -351,42 +366,50 @@ class DecodeModel:
             lp, rest = xs[0], xs[1:]
             layer_arenas = rest[:n_ar]
             layer_adapters = rest[n_ar:]
-            ln1 = self.ln.apply({"params": lp["input_layernorm"]}, x)
-            qkv = self.qkv.apply(
-                {"params": lp["self_attention"]["query_key_value"]}, ln1)
-            if layer_adapters:
-                (qkv_a, qkv_b, dense_a, dense_b,
-                 fc1_a, fc1_b, fc2_a, fc2_b) = layer_adapters
-                qkv = qkv + self._lora_delta(ln1, qkv_a, qkv_b,
-                                             adapter_slots)
-            q, k, v = self._split_qkv(qkv)
+            with named_span("norm"):
+                ln1 = self.ln.apply({"params": lp["input_layernorm"]}, x)
+            with named_span("attn_proj"):
+                qkv = self.qkv.apply(
+                    {"params": lp["self_attention"]["query_key_value"]}, ln1)
+                if layer_adapters:
+                    (qkv_a, qkv_b, dense_a, dense_b,
+                     fc1_a, fc1_b, fc2_a, fc2_b) = layer_adapters
+                    qkv = qkv + self._lora_delta(ln1, qkv_a, qkv_b,
+                                                 adapter_slots)
+                q, k, v = self._split_qkv(qkv)
             ctx, layer_arenas = attn_core(q, k, v, layer_arenas)
-            y, y_bias = self.dense.apply(
-                {"params": lp["self_attention"]["dense"]}, ctx)
-            if layer_adapters:
-                y = y + self._lora_psum(self._lora_delta(
-                    ctx, dense_a, dense_b, adapter_slots))
+            with named_span("attn_proj"):
+                y, y_bias = self.dense.apply(
+                    {"params": lp["self_attention"]["dense"]}, ctx)
+                if layer_adapters:
+                    y = y + self._lora_psum(self._lora_delta(
+                        ctx, dense_a, dense_b, adapter_slots))
             ln2 = lp["post_attention_layernorm"]
-            if self.fuse_epilogue:
-                ln2_out, h = fused_residual_norm(
-                    y, x, ln2["scale"], ln2["bias"], bias=y_bias,
-                    eps=self.cfg.layernorm_epsilon)
-            else:
-                ln2_out, h = residual_norm_unfused(
-                    y, x, ln2["scale"], ln2["bias"], bias=y_bias,
-                    eps=self.cfg.layernorm_epsilon)
-            if layer_adapters:
-                m, m_bias = self._mlp_with_adapter(
-                    lp["mlp"], ln2_out, fc1_a, fc1_b, fc2_a, fc2_b,
-                    adapter_slots)
-            else:
-                m, m_bias = self.mlp.apply({"params": lp["mlp"]}, ln2_out)
-            return h + m + m_bias, layer_arenas + tuple(layer_adapters)
+            with named_span("norm"):
+                if self.fuse_epilogue:
+                    ln2_out, h = fused_residual_norm(
+                        y, x, ln2["scale"], ln2["bias"], bias=y_bias,
+                        eps=self.cfg.layernorm_epsilon)
+                else:
+                    ln2_out, h = residual_norm_unfused(
+                        y, x, ln2["scale"], ln2["bias"], bias=y_bias,
+                        eps=self.cfg.layernorm_epsilon)
+            with named_span("dense_ffn"):
+                if layer_adapters:
+                    m, m_bias = self._mlp_with_adapter(
+                        lp["mlp"], ln2_out, fc1_a, fc1_b, fc2_a, fc2_b,
+                        adapter_slots)
+                else:
+                    m, m_bias = self.mlp.apply({"params": lp["mlp"]},
+                                               ln2_out)
+                out = h + m + m_bias
+            return out, layer_arenas + tuple(layer_adapters)
 
         xs = (params.layers,) + tuple(arenas)
         if adapters is not None:
             xs = xs + tuple(adapters)
-        x, out = lax.scan(body, x, xs)
+        with named_span("layer_scan"):
+            x, out = lax.scan(body, x, xs)
         if adapters is None:
             return x, out, None
         return x, out[:n_ar], out[n_ar:]
@@ -398,20 +421,33 @@ class DecodeModel:
         so the in-graph sampler — and the host — see one consistent id
         space)."""
         cfg = self.cfg
-        hidden = self.ln.apply({"params": params.final_ln}, x)
-        logits = parallel_lm_logits(
-            hidden, params.embedding["word_embeddings"]["embedding"], cfg)
-        if cfg.tensor_axis is not None \
-                and cc.bound_axis_size(cfg.tensor_axis) > 1:
-            logits = cc.all_gather(logits, cfg.tensor_axis, concat_axis=-1)
+        with named_span("norm"):
+            hidden = self.ln.apply({"params": params.final_ln}, x)
+        with named_span("lm_head"):
+            logits = parallel_lm_logits(
+                hidden, params.embedding["word_embeddings"]["embedding"],
+                cfg)
+            if cfg.tensor_axis is not None \
+                    and cc.bound_axis_size(cfg.tensor_axis) > 1:
+                logits = cc.all_gather(logits, cfg.tensor_axis,
+                                       concat_axis=-1)
         return logits
 
     def _rope_tables(self, positions, dtype):
         cfg = self.cfg
         if cfg.position_embedding_type != "rope":
             return None
-        return rotary_cos_sin(positions, cfg.rotary_dim, cfg.rotary_base,
-                              dtype)
+        with named_span("rope"):
+            return rotary_cos_sin(positions, cfg.rotary_dim,
+                                  cfg.rotary_base, dtype)
+
+    def _embed(self, params, tokens, position_ids):
+        """``[s, b, hidden]`` of ``tokens [b, s]``."""
+        with named_span("embed"):
+            if self.cfg.position_embedding_type == "learned":
+                return self.embed.apply({"params": params.embedding},
+                                        tokens, position_ids)
+            return self.embed.apply({"params": params.embedding}, tokens)
 
     # ---------------------------------------------------------------- entry
 
@@ -454,34 +490,32 @@ class DecodeModel:
         offsets = lax.broadcasted_iota(jnp.int32, (B, S), 1)
         pos_ids = positions[:, None] + offsets          # [B, S]
         live = active[:, None] & (offsets <= n_draft[:, None])
-        # per-position causal horizon: verify token t sees cache
-        # positions < pos + t + 1 (its own row included — scattered
-        # below, before the attention, the prefill convention)
-        limits = jnp.where(live, pos_ids + 1, 0).astype(jnp.int32)
-        lengths = jnp.where(active, positions + n_draft + 1,
-                            0).astype(jnp.int32)
-        # cache write destinations; inactive slots and padding columns
-        # write out of range and the scatter drops them
-        logical = jnp.clip(pos_ids // bs, 0, block_tables.shape[1] - 1)
-        phys = jnp.take_along_axis(block_tables, logical, axis=1)
-        dest_blocks = jnp.where(live, phys,
-                                cache.n_blocks).astype(jnp.int32)
-        dest_offsets = (pos_ids % bs).astype(jnp.int32)
+        with named_span("attention"):
+            # per-position causal horizon: verify token t sees cache
+            # positions < pos + t + 1 (its own row included — scattered
+            # below, before the attention, the prefill convention)
+            limits = jnp.where(live, pos_ids + 1, 0).astype(jnp.int32)
+            lengths = jnp.where(active, positions + n_draft + 1,
+                                0).astype(jnp.int32)
+        with named_span("cache_write"):
+            # cache write destinations; inactive slots and padding columns
+            # write out of range and the scatter drops them
+            logical = jnp.clip(pos_ids // bs, 0, block_tables.shape[1] - 1)
+            phys = jnp.take_along_axis(block_tables, logical, axis=1)
+            dest_blocks = jnp.where(live, phys,
+                                    cache.n_blocks).astype(jnp.int32)
+            dest_offsets = (pos_ids % bs).astype(jnp.int32)
 
-        if cfg.position_embedding_type == "learned":
-            x = self.embed.apply({"params": params.embedding}, tokens,
-                                 pos_ids)
-        else:
-            x = self.embed.apply({"params": params.embedding}, tokens)
-        # x: [S, max_batch, hidden]
+        x = self._embed(params, tokens, pos_ids)   # [S, max_batch, hidden]
         rope = None
         if cfg.position_embedding_type == "rope":
             if S == 1:
                 rope = self._rope_tables(positions, x.dtype)
             else:
                 cos, sin = self._rope_tables(pos_ids.reshape(-1), x.dtype)
-                rope = (cos.reshape(B, S, -1).transpose(1, 0, 2),
-                        sin.reshape(B, S, -1).transpose(1, 0, 2))
+                with named_span("rope"):
+                    rope = (cos.reshape(B, S, -1).transpose(1, 0, 2),
+                            sin.reshape(B, S, -1).transpose(1, 0, 2))
 
         attend = (paged_attention_decode if self.fused_attention
                   else paged_attention_decode_unfused)
@@ -491,45 +525,53 @@ class DecodeModel:
             if rope is not None:
                 cos, sin = rope
                 rot = apply_rotary_decode if S == 1 else apply_rotary_packed
-                q = rot(q, cos, sin)
-                k = rot(k, cos, sin)
+                with named_span("rope"):
+                    q = rot(q, cos, sin)
+                    k = rot(k, cos, sin)
             # append the K/V rows, then attend over the paged cache
-            layer_arenas = self._append_rows(
-                layer_arenas, dest_blocks, dest_offsets,
-                k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
+            with named_span("cache_write"):
+                layer_arenas = self._append_rows(
+                    layer_arenas, dest_blocks, dest_offsets,
+                    k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
             kv, sc = self._attend_kwargs(layer_arenas)
-            if S == 1:
-                # the single-token kernel: the non-speculative engine
-                # keeps exactly the PR 8 decode program
-                ctx = attend(q[0], *kv, block_tables, lengths, **sc)
-            else:
-                ctx = attend(q.transpose(1, 0, 2, 3), *kv, block_tables,
-                             lengths, limits=limits, **sc)  # [B, S, n, d]
-                ctx = ctx.transpose(1, 0, 2, 3)
-            return (ctx.reshape(S, B, -1).astype(q.dtype), layer_arenas)
+            with named_span("attention"):
+                if S == 1:
+                    # the single-token kernel: the non-speculative engine
+                    # keeps exactly the PR 8 decode program
+                    ctx = attend(q[0], *kv, block_tables, lengths, **sc)
+                else:
+                    ctx = attend(q.transpose(1, 0, 2, 3), *kv,
+                                 block_tables, lengths, limits=limits,
+                                 **sc)                      # [B, S, n, d]
+                    ctx = ctx.transpose(1, 0, 2, 3)
+                return (ctx.reshape(S, B, -1).astype(q.dtype),
+                        layer_arenas)
 
         x, arenas, adapters = self._layer_stack(
             params, x, arenas, attn_core, adapters, adapter_slots)
         logits = self._head(params, x)             # [S, B, vocab]
-        logits = logits.transpose(1, 0, 2)         # [B, S, vocab]
+        with named_span("lm_head"):
+            logits = logits.transpose(1, 0, 2)     # [B, S, vocab]
         # every position samples with its slot's policy at its own
         # output counter — accepted draws are the draws the sequential
         # path would have made (same key, same teacher-forced logits)
         rep = lambda a: jnp.repeat(a, S, axis=0)   # noqa: E731
-        sampled = sample_tokens(
-            logits.reshape(B * S, -1), rep(temperature), rep(top_k),
-            rep(top_p), rep(seeds),
-            (steps[:, None] + offsets).reshape(-1))
-        out = jnp.where(live, sampled.reshape(B, S), 0).astype(jnp.int32)
-        if S > 1:
-            # accepted = longest prefix with draft t == output t-1
-            match = (tokens[:, 1:].astype(jnp.int32) == out[:, :-1]) \
-                & (offsets[:, 1:] <= n_draft[:, None])
-            accepted = jnp.cumprod(
-                match.astype(jnp.int32), axis=1).sum(axis=1)
-        else:
-            accepted = jnp.zeros((B,), jnp.int32)
-        accepted = jnp.where(active, accepted, 0).astype(jnp.int32)
+        with named_span("sample"):
+            sampled = sample_tokens(
+                logits.reshape(B * S, -1), rep(temperature), rep(top_k),
+                rep(top_p), rep(seeds),
+                (steps[:, None] + offsets).reshape(-1))
+            out = jnp.where(live, sampled.reshape(B, S),
+                            0).astype(jnp.int32)
+            if S > 1:
+                # accepted = longest prefix with draft t == output t-1
+                match = (tokens[:, 1:].astype(jnp.int32) == out[:, :-1]) \
+                    & (offsets[:, 1:] <= n_draft[:, None])
+                accepted = jnp.cumprod(
+                    match.astype(jnp.int32), axis=1).sum(axis=1)
+            else:
+                accepted = jnp.zeros((B,), jnp.int32)
+            accepted = jnp.where(active, accepted, 0).astype(jnp.int32)
         if adapters is not None:
             return arenas, adapters, out, accepted, logits
         return arenas, out, accepted, logits
@@ -568,18 +610,15 @@ class DecodeModel:
         dest_blocks = dest_blocks.astype(jnp.int32)
         dest_offsets = dest_offsets.astype(jnp.int32)
 
-        if cfg.position_embedding_type == "learned":
-            x = self.embed.apply({"params": params.embedding}, tokens,
-                                 position_ids)
-        else:
-            x = self.embed.apply({"params": params.embedding}, tokens)
+        x = self._embed(params, tokens, position_ids)
         # x: [chunk, max_batch, hidden]
         rope = None
         if cfg.position_embedding_type == "rope":
             cos, sin = self._rope_tables(
                 position_ids.reshape(-1), x.dtype)
-            rope = (cos.reshape(B, T, -1).transpose(1, 0, 2),
-                    sin.reshape(B, T, -1).transpose(1, 0, 2))
+            with named_span("rope"):
+                rope = (cos.reshape(B, T, -1).transpose(1, 0, 2),
+                        sin.reshape(B, T, -1).transpose(1, 0, 2))
 
         attend = (paged_prefill_attention if self.fused_attention
                   else paged_prefill_attention_unfused)
@@ -588,29 +627,34 @@ class DecodeModel:
             # q [T, B, n_local, d]; k/v [T, B, g_local, d] (compact GQA)
             if rope is not None:
                 cos, sin = rope
-                q = apply_rotary_packed(q, cos, sin)
-                k = apply_rotary_packed(k, cos, sin)
-            layer_arenas = self._append_rows(
-                layer_arenas, dest_blocks, dest_offsets,
-                k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
+                with named_span("rope"):
+                    q = apply_rotary_packed(q, cos, sin)
+                    k = apply_rotary_packed(k, cos, sin)
+            with named_span("cache_write"):
+                layer_arenas = self._append_rows(
+                    layer_arenas, dest_blocks, dest_offsets,
+                    k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
             kv, sc = self._attend_kwargs(layer_arenas)
-            ctx = attend(q.transpose(1, 0, 2, 3), *kv, block_tables,
-                         lengths, limits, **sc)   # [B, T, n, d]
-            return (ctx.transpose(1, 0, 2, 3).reshape(T, B, -1)
-                    .astype(q.dtype), layer_arenas)
+            with named_span("attention"):
+                ctx = attend(q.transpose(1, 0, 2, 3), *kv, block_tables,
+                             lengths, limits, **sc)   # [B, T, n, d]
+                return (ctx.transpose(1, 0, 2, 3).reshape(T, B, -1)
+                        .astype(q.dtype), layer_arenas)
 
         x, arenas, adapters = self._layer_stack(
             params, x, arenas, attn_core, adapters, adapter_slots)
         logits = self._head(params, x)             # [T, B, vocab]
-        logits = logits.transpose(1, 0, 2)         # [B, T, vocab]
-        idx = jnp.clip(sample_index.astype(jnp.int32), 0, T - 1)
-        last = jnp.take_along_axis(
-            logits, idx[:, None, None], axis=1)[:, 0]   # [B, vocab]
-        sampled = sample_tokens(last, temperature, top_k, top_p,
-                                seeds, steps)
-        valid = (sample_index.astype(jnp.int32) >= 0) & \
-            (sample_index.astype(jnp.int32) < T)
-        next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
+        with named_span("lm_head"):
+            logits = logits.transpose(1, 0, 2)     # [B, T, vocab]
+        with named_span("sample"):
+            idx = jnp.clip(sample_index.astype(jnp.int32), 0, T - 1)
+            last = jnp.take_along_axis(
+                logits, idx[:, None, None], axis=1)[:, 0]   # [B, vocab]
+            sampled = sample_tokens(last, temperature, top_k, top_p,
+                                    seeds, steps)
+            valid = (sample_index.astype(jnp.int32) >= 0) & \
+                (sample_index.astype(jnp.int32) < T)
+            next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
         if adapters is not None:
             return arenas, adapters, next_tokens, logits
         return arenas, next_tokens, logits
@@ -725,21 +769,31 @@ class HybridDecodeModel:
         rotation (YaRN-scaled where the kind says) is in fp32."""
         dtype = self.cfg.dtype
         rank, nope = kind.latent_rank, kind.nope_dim
-        c_q = self._norm(self._mm(h1, lp["wq_a"]), lp["q_a_norm"])
-        q = self._mm(c_q.astype(dtype), lp["wq_b"]).reshape(
-            -1, kind.num_heads, kind.k_dim)
-        c_kv = self._mm(h1, lp["wkv_a"])
-        cos, sin = rotary_cos_sin(positions, kind.rotary_dim,
-                                  kind.rotary_base, jnp.float32,
-                                  scaling=kind.rotary_scaling)
-        q_rope = apply_rotary_interleaved(q[..., nope:], cos, sin)
-        k_rope = apply_rotary_interleaved(c_kv[:, None, rank:], cos, sin)
-        rows = jnp.concatenate(
-            [self._norm(c_kv[:, :rank], lp["kv_a_norm"]), k_rope[:, 0],
-             jnp.zeros((c_kv.shape[0], lanes - c_kv.shape[1]), jnp.float32)],
-            axis=-1)
-        return (q[..., :nope].astype(dtype), q_rope.astype(dtype),
-                rows.astype(dtype))
+        with named_span("attn_proj"):
+            c_q = self._mm(h1, lp["wq_a"])
+        with named_span("norm"):
+            c_q = self._norm(c_q, lp["q_a_norm"])
+        with named_span("attn_proj"):
+            q = self._mm(c_q.astype(dtype), lp["wq_b"]).reshape(
+                -1, kind.num_heads, kind.k_dim)
+            c_kv = self._mm(h1, lp["wkv_a"])
+        with named_span("rope"):
+            cos, sin = rotary_cos_sin(positions, kind.rotary_dim,
+                                      kind.rotary_base, jnp.float32,
+                                      scaling=kind.rotary_scaling)
+            q_rope = apply_rotary_interleaved(q[..., nope:], cos, sin)
+            k_rope = apply_rotary_interleaved(c_kv[:, None, rank:], cos,
+                                              sin)
+        with named_span("norm"):
+            latent = self._norm(c_kv[:, :rank], lp["kv_a_norm"])
+        with named_span("cache_write"):
+            rows = jnp.concatenate(
+                [latent, k_rope[:, 0],
+                 jnp.zeros((c_kv.shape[0], lanes - c_kv.shape[1]),
+                           jnp.float32)], axis=-1).astype(dtype)
+        with named_span("attn_proj"):
+            q_nope = q[..., :nope].astype(dtype)
+        return q_nope, q_rope.astype(dtype), rows
 
     def _latent_attention(self, kind, lp, q_nope, q_rope, lanes, kernel):
         """The absorbed form round ``kernel(q [..., heads, lanes]) ->
@@ -748,17 +802,18 @@ class HybridDecodeModel:
         dtype."""
         dtype = self.cfg.dtype
         rank = kind.latent_rank
-        with jax.named_scope("mla_absorb_q"):
+        with named_span("attn_proj"), named_span("mla_absorb_q"):
             q = jnp.einsum("...nd,ncd->...nc", q_nope, lp["w_uk"],
                            preferred_element_type=jnp.float32).astype(dtype)
             q = jnp.concatenate(
                 [q, q_rope, jnp.zeros(q.shape[:-1] + (
                     lanes - rank - kind.rotary_dim,), dtype)], axis=-1)
-        out = kernel(q)
-        with jax.named_scope("mla_expand_o"):
+        with named_span("attention"):
+            out = kernel(q)
+        with named_span("attn_proj"), named_span("mla_expand_o"):
             ctx = jnp.einsum("...nc,ncd->...nd", out, lp["w_uv"],
                              preferred_element_type=jnp.float32)
-        return ctx.reshape(ctx.shape[:-2] + (-1,)).astype(dtype)
+            return ctx.reshape(ctx.shape[:-2] + (-1,)).astype(dtype)
 
     @staticmethod
     def _latent_scale(kind):
@@ -780,32 +835,37 @@ class HybridDecodeModel:
         # the residual stream stays in fp32 (each layer adds a small term to
         # it; in bf16 every add would round the whole stream); the GEMMs'
         # operands are the model's dtype
-        x = x.astype(jnp.float32)
+        with named_span("embed"):
+            x = x.astype(jnp.float32)
         arenas = [list(group) for group in arenas]
         pairs, chosen, reached = [], [], []
         for layer, lp in enumerate(params.layers):
             kind = spec.kinds[spec.layer_kinds[layer]]
             gi, li = self.place[layer]
             n, g = kind.num_heads, kind.kv_heads
-            h1 = self._norm(x, lp["norm1"]).astype(dtype)
+            with named_span("norm"):
+                h1 = self._norm(x, lp["norm1"]).astype(dtype)
             if kind.latent:
                 ctx, arenas[gi][li] = attend_latent(
                     kind, gi, lp, *self._latent_rows(
                         lp, h1, positions, kind,
                         self.cache.groups[gi].row_lanes), arenas[gi][li])
             else:
-                q = self._rotate(
-                    self._mm(h1, lp["wq"]).reshape(-1, n, kind.k_dim),
-                    positions, kind).astype(dtype)
-                k = self._rotate(
-                    self._mm(h1, lp["wk"]).reshape(-1, g, kind.k_dim),
-                    positions, kind).astype(dtype)
-                v = (self._mm(h1, lp["wv"]) * spec.value_scale).astype(dtype)
+                with named_span("attn_proj"):
+                    q = self._mm(h1, lp["wq"]).reshape(-1, n, kind.k_dim)
+                    k = self._mm(h1, lp["wk"]).reshape(-1, g, kind.k_dim)
+                    v = (self._mm(h1, lp["wv"])
+                         * spec.value_scale).astype(dtype)
+                with named_span("rope"):
+                    q = self._rotate(q, positions, kind).astype(dtype)
+                    k = self._rotate(k, positions, kind).astype(dtype)
                 ctx, arenas[gi][li] = attend(
                     kind, gi, q, k.reshape(-1, g * kind.k_dim), v,
                     arenas[gi][li], lp["sinks"] if kind.sink else None)
-            x = x + self._mm(ctx, lp["wo"])
-            h2 = self._norm(x, lp["norm2"]).astype(dtype)
+            with named_span("attn_proj"):
+                x = x + self._mm(ctx, lp["wo"])
+            with named_span("norm"):
+                h2 = self._norm(x, lp["norm2"]).astype(dtype)
             if spec.layer_experts[layer]:
                 ex = spec.experts
                 more = dict(ex.routing)
@@ -821,28 +881,34 @@ class HybridDecodeModel:
                 pairs.append(routed)
                 chosen.append(experts)
                 reached.append(tokens)
+                # the residual takes the experts' sum where it is made
+                with named_span("moe_experts"):
+                    x = x + y
             else:
-                f = lp["ffn_down"].shape[0]
-                gate_up = self._mm(h2, lp["ffn_gate_up"])
-                mid = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:])
-                y = self._mm(mid.astype(dtype), lp["ffn_down"])
-            x = x + y
+                with named_span("dense_ffn"):
+                    f = lp["ffn_down"].shape[0]
+                    gate_up = self._mm(h2, lp["ffn_gate_up"])
+                    mid = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:])
+                    x = x + self._mm(mid.astype(dtype), lp["ffn_down"])
         held, top_k = ((spec.experts.held[1], spec.experts.top_k)
                        if spec.experts is not None else (0, 0))
-        pairs = (jnp.stack(pairs) if pairs
-                 else jnp.zeros((0, held), jnp.int32))
-        chosen = (jnp.stack(chosen) if chosen
-                  else jnp.zeros((0, x.shape[0], top_k), jnp.int32))
-        reached = (jnp.stack(reached) if reached
-                   else jnp.zeros((0,), jnp.int32))
+        with named_span("moe_router"):
+            # what each call tells the host of its routing, in one array
+            pairs = (jnp.stack(pairs) if pairs
+                     else jnp.zeros((0, held), jnp.int32))
+            chosen = (jnp.stack(chosen) if chosen
+                      else jnp.zeros((0, x.shape[0], top_k), jnp.int32))
+            reached = (jnp.stack(reached) if reached
+                       else jnp.zeros((0,), jnp.int32))
         return (x, tuple(tuple(group) for group in arenas), pairs, chosen,
                 reached)
 
     def _logits(self, params, x):
         """fp32 logits ``[rows, vocab]`` of ``x [rows, hidden]``."""
-        return self._mm(
-            self._norm(x, params.final_norm).astype(self.cfg.dtype),
-            params.head)
+        with named_span("norm"):
+            x = self._norm(x, params.final_norm).astype(self.cfg.dtype)
+        with named_span("lm_head"):
+            return self._mm(x, params.head)
 
     def _kernel_kwargs(self, kind, sinks):
         return dict(kv_heads=kind.kv_heads, window=kind.window, sinks=sinks)
@@ -866,9 +932,11 @@ class HybridDecodeModel:
         bs = self.cache.block_size
         B = tokens.shape[0]
         positions = positions.astype(jnp.int32)
-        lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-        logical = positions // bs
-        dest_offsets = positions % bs
+        with named_span("attention"):
+            lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
+        with named_span("cache_write"):
+            logical = positions // bs
+            dest_offsets = positions % bs
         attend = (paged_attention_decode if self.fused_attention
                   else paged_attention_decode_unfused)
         attend_rows = (paged_decode_latent if self.fused_attention
@@ -884,9 +952,10 @@ class HybridDecodeModel:
                                     self.cache.groups[gi].n_blocks)
 
         def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
-            table, dest = destination(gi)
-            arena = layer_arenas[0].at[dest, dest_offsets].set(
-                rows.astype(layer_arenas[0].dtype), mode="drop")
+            with named_span("cache_write"):
+                table, dest = destination(gi)
+                arena = layer_arenas[0].at[dest, dest_offsets].set(
+                    rows.astype(layer_arenas[0].dtype), mode="drop")
             ctx = self._latent_attention(
                 kind, lp, q_nope, q_rope, arena.shape[-1],
                 lambda q: attend_rows(q, arena, table, lengths,
@@ -895,23 +964,28 @@ class HybridDecodeModel:
             return ctx, (arena,)
 
         def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
-            table, dest = destination(gi)
             k_arena, v_arena = layer_arenas
-            k_arena = k_arena.at[dest, dest_offsets].set(
-                k.astype(k_arena.dtype), mode="drop")
-            v_arena = v_arena.at[dest, dest_offsets].set(
-                v.astype(v_arena.dtype), mode="drop")
-            ctx = attend(q, k_arena, v_arena, table, lengths,
-                         **self._kernel_kwargs(kind, sinks))
-            return ctx.reshape(B, -1).astype(q.dtype), (k_arena, v_arena)
+            with named_span("cache_write"):
+                table, dest = destination(gi)
+                k_arena = k_arena.at[dest, dest_offsets].set(
+                    k.astype(k_arena.dtype), mode="drop")
+                v_arena = v_arena.at[dest, dest_offsets].set(
+                    v.astype(v_arena.dtype), mode="drop")
+            with named_span("attention"):
+                ctx = attend(q, k_arena, v_arena, table, lengths,
+                             **self._kernel_kwargs(kind, sinks))
+                return (ctx.reshape(B, -1).astype(q.dtype),
+                        (k_arena, v_arena))
 
-        x = params.embedding[tokens[:, 0]]
+        with named_span("embed"):
+            x = params.embedding[tokens[:, 0]]
         x, arenas, pairs, chosen, reached = self._walk(
             params, x, positions, active, arenas, attn_core, latent_core)
         logits = self._logits(params, x)                       # [B, vocab]
-        sampled = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps)
-        out = jnp.where(active, sampled, 0).astype(jnp.int32)[:, None]
+        with named_span("sample"):
+            sampled = sample_tokens(logits, temperature, top_k, top_p,
+                                    seeds, steps)
+            out = jnp.where(active, sampled, 0).astype(jnp.int32)[:, None]
         return (arenas, out, jnp.zeros((B,), jnp.int32), logits[:, None],
                 pairs, chosen, reached)
 
@@ -930,9 +1004,10 @@ class HybridDecodeModel:
         position_ids = position_ids.astype(jnp.int32)
         limits = limits.astype(jnp.int32)
         lengths = lengths.astype(jnp.int32)
-        real = limits > 0
-        logical = position_ids // bs
-        dest_offsets = position_ids % bs
+        with named_span("cache_write"):
+            real = limits > 0
+            logical = position_ids // bs
+            dest_offsets = position_ids % bs
         attend = (paged_prefill_attention if self.fused_attention
                   else paged_prefill_attention_unfused)
         attend_rows = (paged_prefill_latent if self.fused_attention
@@ -946,10 +1021,11 @@ class HybridDecodeModel:
                                     self.cache.groups[gi].n_blocks)
 
         def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
-            table, dest = destination(gi)
-            arena = layer_arenas[0].at[dest, dest_offsets].set(
-                rows.reshape(B, T, -1).astype(layer_arenas[0].dtype),
-                mode="drop")
+            with named_span("cache_write"):
+                table, dest = destination(gi)
+                arena = layer_arenas[0].at[dest, dest_offsets].set(
+                    rows.reshape(B, T, -1).astype(layer_arenas[0].dtype),
+                    mode="drop")
             n = kind.num_heads
             # a few slots at a time: the absorbed queries of the whole call
             # would be ``B T n lanes`` elements
@@ -964,36 +1040,44 @@ class HybridDecodeModel:
                                           v_dim=kind.latent_rank,
                                           scale=self._latent_scale(kind)))
 
-            parts = tuple(a.reshape((B // walk, walk) + a.shape[1:])
-                          for a in (q_nope.reshape(B, T, n, -1),
-                                    q_rope.reshape(B, T, n, -1), table,
-                                    lengths, limits))
-            ctx = (some_slots(tuple(a[0] for a in parts)) if walk == B
-                   else lax.map(some_slots, parts))
-            return ctx.reshape(B * T, -1), (arena,)
+            # the walk's own slices and their way back are the attention's
+            with named_span("attention"):
+                parts = tuple(a.reshape((B // walk, walk) + a.shape[1:])
+                              for a in (q_nope.reshape(B, T, n, -1),
+                                        q_rope.reshape(B, T, n, -1), table,
+                                        lengths, limits))
+                ctx = (some_slots(tuple(a[0] for a in parts)) if walk == B
+                       else lax.map(some_slots, parts))
+                return ctx.reshape(B * T, -1), (arena,)
 
         def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
-            table, dest = destination(gi)
             k_arena, v_arena = layer_arenas
-            k_arena = k_arena.at[dest, dest_offsets].set(
-                k.reshape(B, T, -1).astype(k_arena.dtype), mode="drop")
-            v_arena = v_arena.at[dest, dest_offsets].set(
-                v.reshape(B, T, -1).astype(v_arena.dtype), mode="drop")
-            ctx = attend(q.reshape(B, T, kind.num_heads, kind.k_dim),
-                         k_arena, v_arena, table, lengths, limits,
-                         **self._kernel_kwargs(kind, sinks))
-            return (ctx.reshape(B * T, -1).astype(q.dtype),
-                    (k_arena, v_arena))
+            with named_span("cache_write"):
+                table, dest = destination(gi)
+                k_arena = k_arena.at[dest, dest_offsets].set(
+                    k.reshape(B, T, -1).astype(k_arena.dtype), mode="drop")
+                v_arena = v_arena.at[dest, dest_offsets].set(
+                    v.reshape(B, T, -1).astype(v_arena.dtype), mode="drop")
+            with named_span("attention"):
+                ctx = attend(q.reshape(B, T, kind.num_heads, kind.k_dim),
+                             k_arena, v_arena, table, lengths, limits,
+                             **self._kernel_kwargs(kind, sinks))
+                return (ctx.reshape(B * T, -1).astype(q.dtype),
+                        (k_arena, v_arena))
 
-        x = params.embedding[tokens.reshape(-1)]
+        with named_span("embed"):
+            x = params.embedding[tokens.reshape(-1)]
         x, arenas, pairs, chosen, reached = self._walk(
             params, x, position_ids.reshape(-1), real.reshape(-1), arenas,
             attn_core, latent_core)
-        idx = sample_index.astype(jnp.int32)
-        last = x.reshape(B, T, -1)[jnp.arange(B), jnp.clip(idx, 0, T - 1)]
+        with named_span("lm_head"):
+            idx = sample_index.astype(jnp.int32)
+            last = x.reshape(B, T, -1)[jnp.arange(B),
+                                       jnp.clip(idx, 0, T - 1)]
         logits = self._logits(params, last)                    # [B, vocab]
-        sampled = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps)
-        valid = (idx >= 0) & (idx < T)
-        next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
+        with named_span("sample"):
+            sampled = sample_tokens(logits, temperature, top_k, top_p,
+                                    seeds, steps)
+            valid = (idx >= 0) & (idx < T)
+            next_tokens = jnp.where(valid, sampled, 0).astype(jnp.int32)
         return arenas, next_tokens, logits[:, None], pairs, chosen, reached

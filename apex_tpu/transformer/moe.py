@@ -486,7 +486,7 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
     T, h = x.shape
     n_experts = router.shape[1]
     first, count = held
-    with jax.named_scope("moe_router"):
+    with named_span("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         # a family with neither epsilon nor scale keeps the three-argument
@@ -511,9 +511,9 @@ def held_experts_ffn(x, router, router_bias, w_gate_up, w_down, *,
         token = (order // top_k).astype(jnp.int32)
         weight = weights.reshape(-1)[order]
     rows = _chunk_rows(T * top_k, count / n_experts)
-    with jax.named_scope("moe_experts"):
+    with named_span("moe_experts"):
         y = _held_passes(rows, x, w_gate_up, w_down, weight, token, ends)
     if shared is not None:
-        with jax.named_scope("moe_shared"):
+        with named_span("moe_shared"):
             y = y + swiglu(x, *shared)
     return y, pairs, experts, group_tokens
