@@ -1001,13 +1001,16 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
     # ``kv_tokens_*`` over steps x keys a step
     reg = default_registry()
     reg.gauge(f"paged_decode/keys_per_step/{kind}").set(pages * bs)
+    reg.gauge(f"paged_decode/copies_per_step/{kind}").set(
+        pages * (1 if latent else 2))
     reg.gauge(f"paged_decode/steps_per_slot_max/{kind}").set(
         pl.cdiv(span, pages))
     n_steps, plan, slot, group = _step_plan(
         block_tables, lengths, bs, pages, first_page)
-    # a block handed back behind the window is never in the plan; an entry
-    # that says so must still index the arena
-    plan = jnp.maximum(plan, 0)
+    # every page copy's source indexes the arena whatever the table holds
+    # (a block handed back behind the window reads -1), so the kernel
+    # issues its copies without Mosaic's run-time bounds checks
+    plan = jnp.clip(plan, 0, k_arena.shape[0] - 1)
 
     def row_idx(s, steps_ref, plan_ref, slot_ref, *refs):
         return (slot_ref[s], 0, 0)
@@ -1051,9 +1054,12 @@ def _decode_flat(q, k_arena, v_arena, block_tables, lengths, *, kv_heads,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n, dv), q.dtype),
             # in order: a slot's steps carry its softmax state, and a step
-            # starts the copies of the next
+            # starts the copies of the next; every copy's indices are in
+            # range by construction (the plan above, ``buf`` and static
+            # rows), and the checks were a third of a step's bundles
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
+                dimension_semantics=("arbitrary",),
+                disable_bounds_checks=True),
             interpret=platform.pallas_interpret(),
             name=name,
         )(n_steps.reshape(1), plan, slot, group, lengths, first_page,

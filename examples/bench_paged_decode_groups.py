@@ -6,14 +6,22 @@ Times ``paged_attention_decode`` over a cache group's flat arenas
 ``mimo-v2-flash.serve-long-answer`` cell: 64 slots, 64 query heads, q/k 192
 beside v 128, blocks of 16, a table of 384 blocks, bfloat16; full attention
 on 4 KV heads over histories uniform in 128..4200, window attention (128,
-sinks) on 8 KV heads.  A timing is one jitted program of ``--reps`` calls in
+sinks) on 8 KV heads; and ``paged_decode_latent`` at the shapes of the
+``deepseek-v2.serve-long-context`` cell: 64 slots, 128 heads over rows of
+576 channels in 640 lanes (values the leading 512), blocks of 16, a table of
+1,280 blocks, histories uniform in 4,096..14,336, on a table in runs of 8
+adjacent blocks (as the cell's set-up allocates them) and on one scattered
+over the arena.  A timing is one jitted program of ``--reps`` calls in
 a chain (the step plan is built once in it, as in a model whose layers share
 a table), so a call's time carries an eighth of the plan.  Beside each time:
 the share of the HBM roofline that the rows read stand for (rows, not padded
-pages: ``benchmark/kernels/paged_attention_groups.py``), the gauges the
-module records at trace time, and the largest gap to the unfused twin at a
-smaller batch.  ``--pages`` sweeps the full kernel's pages per key tile (the
-module's ``_MAX_FLAT_PAGES``; the window kernel's follow from its window).
+pages: ``benchmark/kernels/paged_attention_groups.py``, and for the latent
+kernel the larger of its FLOP and bytes as ``benchmark/kernels/
+latent_attention.py`` counts them), us a grid step (the call over the steps
+its plan has), the gauges the module records at trace time, and the largest
+gap to the unfused twin at a smaller batch.  ``--pages`` sweeps the full
+kernel's pages per key tile (the module's ``_MAX_FLAT_PAGES``; the window
+and latent kernels' follow from their window and ``_MAX_LATENT_PAGES``).
 No benchmark cell runs this: it is the sweep of ROADMAP S11, kept so that
 it can be repeated.
 
@@ -29,6 +37,7 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES_PER_S = 819e9          # one v5e chip (benchmark/peaks.json)
+FLOPS_PER_S = 197e12
 
 
 def main():
@@ -38,7 +47,7 @@ def main():
     ap.add_argument("--pages", default="default",
                     help="comma-separated pages per key tile of the full "
                          "kernel; 'default' is what the module derives")
-    ap.add_argument("--kinds", default="full,window")
+    ap.add_argument("--kinds", default="full,window,latent")
     ap.add_argument("--reps", type=int, default=8)
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
@@ -85,13 +94,52 @@ def main():
                           jnp.asarray(lengths, jnp.int32)),
                     kw=dict(kv_heads=g, window=window, sinks=sinks))
 
+    # the latent group: heads, rows in lanes, values, table, histories
+    ln, lanes, lv = 128, 640, 512
+    lb, lmax, lhist, lblocks = ((64, 1280, (4096, 14336), 53248) if on_tpu
+                                else (3, 24, (40, 300), 96))
+
+    def latent_case(b, lengths, runs):
+        """An arena of random rows (the padding lanes zero), a table whose
+        live pages are distinct blocks: in runs of ``runs`` adjacent ones,
+        the runs in random order (1: scattered)."""
+        keys = jax.random.split(jax.random.PRNGKey(2), 2)
+        pad = (jnp.arange(lanes) < 576).astype(jnp.bfloat16)
+        q = jax.random.normal(keys[0], (b, ln, lanes), jnp.bfloat16) * pad
+        rows = jax.random.normal(keys[1], (lblocks, bs, lanes),
+                                 jnp.bfloat16) * pad
+        order = (rng.permutation(lblocks // runs)[:, None] * runs
+                 + np.arange(runs)).reshape(-1)
+        tables = np.zeros((b, lmax), np.int32)
+        blocks = iter(order)
+        for i, length in enumerate(lengths):
+            tables[i, :-(-int(length) // bs)] = [
+                next(blocks) for _ in range(-(-int(length) // bs))]
+        return dict(args=(q, rows, jnp.asarray(tables),
+                          jnp.asarray(lengths, jnp.int32)),
+                    kw=dict(v_dim=lv, scale=0.1147))
+
+    def decode(q, k, v, tables, lengths, **kw):
+        if v is None:
+            return pa.paged_decode_latent(q, k, tables, lengths, **kw)
+        return pa.paged_attention_decode(q, k, v, tables, lengths, **kw)
+
     def chain(q, k, v, tables, lengths, **kw):
         total = jnp.zeros((), jnp.float32)
         for i in range(reps):
-            out = pa.paged_attention_decode(
-                q + jnp.asarray(i / 64, q.dtype), k, v, tables, lengths, **kw)
+            out = decode(q + jnp.asarray(i / 64, q.dtype), k, v, tables,
+                         lengths, **kw)
             total = total + out[0, 0, 0].astype(jnp.float32)
         return total
+
+    def steps_of(kind, lengths, window):
+        """The grid steps of one call: a slot's live pages over the key
+        tile's (the gauge), one at least."""
+        pages = gauges(kind)[f"paged_decode/keys_per_step/{kind}"] // bs
+        live = -(-np.asarray(lengths) // bs)
+        if window is not None:
+            live = live - np.maximum(np.asarray(lengths) - window, 0) // bs
+        return int(np.maximum(-(-live // pages), 1).sum())
 
     def timed(fn, *xs):
         jax.block_until_ready(fn(*xs))
@@ -101,20 +149,55 @@ def main():
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / steps * 1e3
 
-    def gauges():
+    def gauges(kind):
         snap = default_registry().snapshot()
         return {k: v for k, v in snap.items()
-                if k.startswith("paged_decode/")}
+                if k.startswith("paged_decode/") and k.endswith(f"/{kind}")}
+
+    def latent_rows():
+        out = []
+        lengths = rng.integers(lhist[0], lhist[1] + 1, lb)
+        rows = int(lengths.sum())
+        flops = rows * 2 * ln * (576 + lv)
+        least_ms = max(flops / FLOPS_PER_S,
+                       rows * 576 * 2 / HBM_BYTES_PER_S) * 1e3
+        check = np.asarray([0, 1, 15, 16, 17, 300])
+        for table, runs in (("runs_of_8", 8), ("scattered", 1)):
+            c = latent_case(lb, lengths, runs)
+            q, rows_, tables, lens = c["args"]
+            ms = timed(jax.jit(lambda q, r, t, n: chain(
+                q, r, None, t, n, **c["kw"])), q, rows_, tables, lens) / reps
+            small = latent_case(len(check), check, runs)
+            fused = jax.jit(lambda *xs: pa.paged_decode_latent(
+                *xs, **small["kw"]))(*small["args"])
+            twin = jax.jit(lambda *xs: pa.paged_decode_latent_unfused(
+                *xs, **small["kw"]))(*small["args"])
+            row = {"kind": "latent", "table": table,
+                   "ms_a_call": round(ms, 4),
+                   "us_a_step": round(
+                       1e3 * ms / steps_of("latent", lengths, None), 4),
+                   "rows": rows, "roofline_pct": round(100 * least_ms / ms, 2),
+                   "gap_to_unfused": float(jnp.abs(
+                       fused.astype(jnp.float32)
+                       - twin.astype(jnp.float32)).max()),
+                   "gauges": gauges("latent")}
+            out.append(row)
+            print(json.dumps(row), flush=True)
+        return out
 
     results = []
     lengths = rng.integers(128, longest + 1, b)
     check = np.asarray([0, 1, 15, 16, 17, 127, 128, 129, 143, 144, 145,
                         511, 512, 513, 1000, 1536][:max(b // 4, 3)])
+    kinds_asked = args.kinds.split(",")
     for pages in args.pages.split(","):
         if pages != "default":
             pa._MAX_FLAT_PAGES = int(pages)
-        for kind in args.kinds.split(","):
-            if kind == "window" and pages != args.pages.split(",")[0]:
+        for kind in kinds_asked:
+            if kind != "full" and pages != args.pages.split(",")[0]:
+                continue
+            if kind == "latent":
+                results += latent_rows()
                 continue
             c = case(kind, b, max_blocks, lengths)
             ms = timed(jax.jit(lambda *xs: chain(*xs, **c["kw"])),
@@ -128,11 +211,13 @@ def main():
             twin = jax.jit(lambda *xs: pa.paged_attention_decode_unfused(
                 *xs, **small["kw"]))(*small["args"])
             row = {"kind": kind, "pages": pages, "ms_a_call": round(ms, 4),
+                   "us_a_step": round(
+                       1e3 * ms / steps_of(kind, lengths, window), 4),
                    "rows": rows, "roofline_pct": round(100 * least_ms / ms, 2),
                    "gap_to_unfused": float(jnp.abs(
                        fused.astype(jnp.float32)
                        - twin.astype(jnp.float32)).max()),
-                   "gauges": gauges()}
+                   "gauges": gauges(kind)}
             results.append(row)
             print(json.dumps(row), flush=True)
 

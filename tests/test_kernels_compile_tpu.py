@@ -8,7 +8,9 @@ kernel cases and their shapes are ``chip_smoke.kernel_cases``: what is
 compiled here is what ``python chip_smoke.py`` runs on the chip.
 """
 
+import json
 import os
+import re
 import sys
 
 import jax
@@ -71,6 +73,32 @@ GROUP_KERNELS = [
                                     ("window", 8, 1152, 128))]
 
 
+def custom_call_configs(lowered):
+    """``{kernel name: custom_call_config}`` of a lowered program's Mosaic
+    calls."""
+    return {name: json.loads(config.replace("\\22", '"'))[
+                "custom_call_config"]
+            for config, name in re.findall(
+                r'@tpu_custom_call\(.*?backend_config = "(.*?)", '
+                r'kernel_name = "([\w.]+)"', lowered.as_text())}
+
+
+def assert_copies_unchecked(lowered, name, copies):
+    """The cache groups' decode kernel issues its page copies without
+    Mosaic's run-time bounds checks (ISSUE 36): the plan is clamped to the
+    arena, so every index is in range by construction.  ``copies``: the
+    page copies a step issues at these widths, as the gauge the trace set
+    says."""
+    from apex_tpu.observability.metrics import default_registry
+
+    configs = custom_call_configs(lowered)
+    assert list(configs) == [name]
+    assert configs[name]["disable_bounds_checks"] is True
+    kind = name.rsplit("_", 1)[1]
+    assert default_registry().snapshot()[
+        f"paged_decode/copies_per_step/{kind}"] == copies
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_latent_kernel_compiles_for_v5e_at_published_widths(
         kind, v5e_device, monkeypatch):
@@ -104,8 +132,10 @@ def test_latent_kernel_compiles_for_v5e_at_published_widths(
         args = (shape((b, chunk, n, lanes)), arena,
                 shape((b, 1280), jnp.int32), shape((b,), jnp.int32),
                 shape((b, chunk), jnp.int32))
-    compiled = jax.jit(call).lower(*args).compile()
-    assert f"%paged_{kind}_latent" in compiled.as_text()
+    lowered = jax.jit(call).lower(*args)
+    if kind == "decode":
+        assert_copies_unchecked(lowered, "paged_decode_latent", 64)
+    assert f"%paged_{kind}_latent" in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("kind,name,g,blocks,window", GROUP_KERNELS)
@@ -139,8 +169,11 @@ def test_group_kernel_compiles_for_v5e_at_published_widths(
                 sinks=sinks if window else None, **kw)
         args = (shape((b, chunk, n, dk)),) + cache + (
             shape((b, chunk), jnp.int32), sinks)
-    compiled = jax.jit(call).lower(*args).compile()
-    assert f"%paged_{kind}_{name}" in compiled.as_text()
+    lowered = jax.jit(call).lower(*args)
+    if kind == "decode":
+        assert_copies_unchecked(lowered, f"paged_decode_{name}",
+                                {"full": 64, "window": 18}[name])
+    assert f"%paged_{kind}_{name}" in lowered.compile().as_text()
 
 
 def test_expert_layer_compiles_for_v5e_at_published_widths(

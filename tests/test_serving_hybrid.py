@@ -25,6 +25,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
 import ahead_scenario                                      # noqa: E402
+import flat_plan                                           # noqa: E402
 from drivers import mimo_program                          # noqa: E402
 from reference import mimo_v2_flash as reference          # noqa: E402
 
@@ -359,6 +360,7 @@ def arena():
         sinks=rng.normal(size=(n,)).astype(np.float32), rng=rng)
 
 
+@pytest.mark.parametrize("spoil", [False, True], ids=["table", "spoiled"])
 @pytest.mark.parametrize("window, sink", [(None, False), (WINDOW, False),
                                           (WINDOW, True), (None, True)])
 @pytest.mark.parametrize("lengths", [
@@ -366,16 +368,27 @@ def arena():
     [8, 9, 7, 12, 13, 16],       # at the window, and just past it
     [17, 20, 21, 31, 32, 29],    # well past it, the table's end
 ])
-def test_decode_kernel_window_and_sinks(arena, window, sink, lengths):
+def test_decode_kernel_window_and_sinks(arena, window, sink, lengths, spoil,
+                                        monkeypatch):
+    """A ``spoil``ed table holds -1 and ids past the arena outside each
+    slot's live pages (past the length, behind the window): the kernel
+    reads the same rows, and every block its plan names lies in the
+    arena (ISSUE 36)."""
     a = arena
     q = a["rng"].normal(size=(a["b"], a["n"], a["dk"])).astype(np.float32)
     sinks = a["sinks"] if sink else None
     kw = dict(kv_heads=a["g"], window=window,
               sinks=None if sinks is None else jnp.asarray(sinks))
+    n_blocks = a["k"].shape[0]
+    tables = (flat_plan.spoiled(a["tables"], lengths, BLOCK, n_blocks, window)
+              if spoil else a["tables"])
     args = (jnp.asarray(q), jnp.asarray(a["k"]), jnp.asarray(a["v"]),
-            jnp.asarray(a["tables"]), jnp.asarray(lengths, jnp.int32))
+            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    plans = flat_plan.plans_handed_to_the_kernel(monkeypatch)
     fused = np.asarray(paged_attention_decode(*args, **kw))
-    unfused = np.asarray(paged_attention_decode_unfused(*args, **kw))
+    flat_plan.assert_in_arena(plans, n_blocks)
+    unfused = np.asarray(paged_attention_decode_unfused(
+        *args[:3], jnp.asarray(a["tables"]), args[4], **kw))
     want = np.stack([dense_attention(q[i], a["k"], a["v"], a["tables"][i],
                                      lengths[i], a["g"], window, sinks)
                      for i in range(a["b"])])
@@ -565,7 +578,7 @@ def test_a_window_slots_sweep_is_one_grid_step(lengths):
 def test_key_tile_gauges_read_what_the_plan_says(kind):
     case = cell_case(kind, [40, 300], jnp.bfloat16)
     reg = default_registry()
-    for name in ("keys_per_step", "steps_per_slot_max"):
+    for name in ("keys_per_step", "steps_per_slot_max", "copies_per_step"):
         reg.gauge(f"paged_decode/{name}/{kind}").set(-1)
     jax.jit(lambda *xs: paged_attention_decode(*xs, **case["kw"])).lower(
         *case["args"])                          # traced, not run
@@ -574,6 +587,34 @@ def test_key_tile_gauges_read_what_the_plan_says(kind):
     assert snap[f"paged_decode/keys_per_step/{kind}"] == pages * CELL_BLOCK
     span = CELL_TABLE if kind == "full" else 9
     assert snap[f"paged_decode/steps_per_slot_max/{kind}"] == -(-span // pages)
+    # a K and a V page a live page: 64 at the cell's widths, 18 a window
+    assert snap[f"paged_decode/copies_per_step/{kind}"] == 2 * pages
+    assert 2 * pages == {"full": 64, "window": 18}[kind]
+
+
+@pytest.mark.parametrize("kind", ["full", "window", "latent"])
+def test_the_plan_indexes_the_arena_whatever_the_table_holds(kind,
+                                                            monkeypatch):
+    """Table entries anywhere in [-3, 2 x n_blocks), live pages included:
+    the plan the kernel copies by is clamped into the arena, so a copy
+    issued without Mosaic's bounds checks never leaves it (ISSUE 36)."""
+    rng = np.random.default_rng(7)
+    b, n, g, d, nb, mb = 4, 8, 2, 128, 20, 8
+    tables = jnp.asarray(rng.integers(-3, 2 * nb, (b, mb)), jnp.int32)
+    lengths = jnp.asarray([0, 5, 17, mb * BLOCK], jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(nb, BLOCK, g * d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, n, d)), jnp.float32)
+    plans = flat_plan.plans_handed_to_the_kernel(monkeypatch)
+    if kind == "latent":
+        out = pa_module.paged_decode_latent(
+            jnp.concatenate([q, q], -1), rows, tables, lengths, v_dim=d,
+            scale=0.1)
+    else:
+        out = paged_attention_decode(
+            q, rows, rows, tables, lengths, kv_heads=g,
+            window=None if kind == "full" else WINDOW)
+    assert np.isfinite(np.asarray(out)).all()
+    flat_plan.assert_in_arena(plans, nb)
 
 
 def test_a_pooled_arena_takes_no_window(arena):

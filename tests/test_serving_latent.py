@@ -28,6 +28,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
 import ahead_scenario                                       # noqa: E402
+import flat_plan                                            # noqa: E402
 from drivers import deepseek_program                       # noqa: E402
 from drivers.serve_hybrid import row_gaps                  # noqa: E402
 from reference import deepseek_v2 as reference             # noqa: E402
@@ -233,23 +234,33 @@ def arena():
             "q": rng.normal(size=(b, 8, 4, 128))}
 
 
+@pytest.mark.parametrize("spoil", [False, True], ids=["table", "spoiled"])
 @pytest.mark.parametrize("dtype, atol", [(jnp.float32, 2e-6),
                                          (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("lengths", [(0, 1, 4, 5), (16, 17, 33, 48),
                                      (3, 0, 47, 8)])
-def test_decode_kernel_against_its_twin(arena, lengths, dtype, atol):
+def test_decode_kernel_against_its_twin(arena, lengths, dtype, atol, spoil,
+                                        monkeypatch):
     """Histories that are empty, end on a block edge, one past it, and fill
-    the table; values the leading 16 lanes of the 24-channel row."""
-    args = (jnp.asarray(arena["q"][:, 0], dtype),
-            jnp.asarray(arena["rows"], dtype), arena["tables"],
-            jnp.asarray(lengths, jnp.int32))
-    fused = np.asarray(pa.paged_decode_latent(*args, v_dim=16, scale=0.2),
-                       np.float32)
+    the table; values the leading 16 lanes of the 24-channel row.  A
+    ``spoil``ed table holds -1 and ids past the arena beyond each slot's
+    live pages: the kernel gives the clean table's twin, and every block
+    its plan names lies in the arena."""
+    rows = jnp.asarray(arena["rows"], dtype)
+    tables = (flat_plan.spoiled(arena["tables"], lengths, BLOCK,
+                                rows.shape[0]) if spoil else arena["tables"])
+    q, lengths = (jnp.asarray(arena["q"][:, 0], dtype),
+                  jnp.asarray(lengths, jnp.int32))
+    plans = flat_plan.plans_handed_to_the_kernel(monkeypatch)
+    fused = np.asarray(pa.paged_decode_latent(
+        q, rows, jnp.asarray(tables), lengths, v_dim=16, scale=0.2),
+        np.float32)
     twin = np.asarray(pa.paged_decode_latent_unfused(
-        *args, v_dim=16, scale=0.2), np.float32)
+        q, rows, arena["tables"], lengths, v_dim=16, scale=0.2), np.float32)
     assert fused.shape == (4, 4, 16)
     np.testing.assert_allclose(fused, twin, atol=atol)
     assert not fused[np.asarray(lengths) == 0].any()
+    flat_plan.assert_in_arena(plans, rows.shape[0])
 
 
 def test_decode_result_does_not_depend_on_the_key_tile(arena, monkeypatch):
