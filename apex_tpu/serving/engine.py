@@ -105,6 +105,11 @@ docs/serving.md):
   groups' arenas hold over the tokens they can hold, all layers (ISSUE 33)
 - ``serving/window_blocks_freed`` counter — blocks a window cache group
   handed back behind the window
+- ``dsa/rows_scanned`` / ``dsa/rows_selected`` counters — the cached
+  tokens the indexers of sparse latent layers scored, and those the
+  queries then attended, summed over those layers, of decode calls;
+  ``dsa/prefill_rows_scanned`` / ``dsa/prefill_rows_selected`` the same of
+  prefill calls (ISSUE 37; a model with sparse layers only)
 
 Host spans (ISSUE 25): every :meth:`ServingEngine.step` is one
 ``serving/tick`` span of :mod:`apex_tpu.observability.spans` with one
@@ -242,6 +247,7 @@ class _DecodeCall:
     logits: Any
     routed: Any                         # expert layers only, else None
     reached: Any
+    selected: Any                       # sparse layers only, else None
     dispatch: spans.span                # its ``decode_dispatch`` span
     step: int                           # the tick that dispatched it
 
@@ -357,7 +363,8 @@ class ServingEngine:
             kv_heads, k_dim, v_dim, latent = kind.cache_row
             group = CacheGroup(
                 layers=layers, kv_heads=kv_heads, k_dim=k_dim, v_dim=v_dim,
-                n_blocks=n_blocks, window=kind.window, latent=latent)
+                n_blocks=n_blocks, window=kind.window, latent=latent,
+                index_dim=kind.index_row)
             if kind.window is not None:
                 per_slot = group.blocks_spanned(
                     self.prefill_len, serving.block_size, n_blocks)
@@ -583,13 +590,25 @@ class ServingEngine:
                 "serving/window_blocks_freed")
             self._counters.moe_group_tokens = counter(
                 "serving/moe_group_tokens")
+            # (index_topk, layers) of the sparse latent groups
+            self._sparse_reads = [
+                (self.model.spec.kinds[self.model.spec.layer_kinds[
+                    g.layers[0]]].index_topk, len(g.layers))
+                for g in self.cache.cache_groups if g.index_dim]
+            if self._sparse_reads:
+                for name in ("rows_scanned", "rows_selected",
+                             "prefill_rows_scanned",
+                             "prefill_rows_selected"):
+                    setattr(self._counters, "dsa_" + name,
+                            counter("dsa/" + name))
             latent = [g for g in self.cache.cache_groups if g.latent]
             if latent:
                 # what the latent groups' arenas hold over the tokens they
                 # can hold (all layers; a row's lane padding counts: it is
                 # held)
                 self.registry.gauge("serving/kv_latent_bytes_per_token").set(
-                    sum(g.row_lanes * len(g.layers) for g in latent)
+                    sum((g.row_lanes + g.index_dim) * len(g.layers)
+                        for g in latent)
                     * np.dtype(self.cache.dtype).itemsize)
         if self._runs_ahead:
             self._counters.decode_calls_ahead = counter(
@@ -605,6 +624,7 @@ class ServingEngine:
         # the last delivered decode call's logits and the slots whose row
         # was delivered
         self._last_logits: Optional[Tuple[Any, Tuple[int, ...]]] = None
+        self._last_selected = None
         # the decode call dispatched and not yet fetched (between ticks:
         # only where the engine runs one call ahead)
         self._in_flight: Optional[_DecodeCall] = None
@@ -1114,10 +1134,14 @@ class ServingEngine:
 
     def _device_tables(self):
         """The block tables as the compiled steps take them: group 0's
-        array, or for a model with cache groups a tuple of them."""
+        array, or for a model with cache groups a tuple of them.  Copies:
+        the host rewrites its tables in place for the next plan while a
+        call may still be in flight, and on the CPU ``jnp.asarray`` of a
+        numpy array aliases its memory, so the call would read the next
+        plan's tables (a preempted or reassigned slot's rows)."""
         if not self.hybrid:
-            return self._jnp.asarray(self._tables)
-        return tuple(self._jnp.asarray(t) for t in self._group_tables)
+            return self._jnp.array(self._tables)
+        return tuple(self._jnp.array(t) for t in self._group_tables)
 
     def _note_routed(self, phase: spans.span, pairs, reached) -> None:
         """Record what a call routed to the held experts (``pairs [expert
@@ -1262,6 +1286,8 @@ class ServingEngine:
                     self._note_routed(fetch, routed, reached)
 
         with self._span("serving/tick/prefill_deliver"):
+            if self.hybrid and self._sparse_reads:
+                self._note_sparse_prefill(tick, plan)
             self._counters.prefill_calls.inc()
             self._counters.prefill_tokens.inc(n_tokens)
             self._counters.prefill_capacity.inc(B * T)
@@ -1353,10 +1379,11 @@ class ServingEngine:
             return self._fetch_call(before) if before is not None else None
         reqs, drafts, positions, args = args
         with self._span("serving/tick/decode_dispatch") as dispatch:
-            routed = reached = None
+            routed = reached = selected = None
             if self.hybrid:
                 (self.arenas, out_tokens, accepted, logits, routed, chosen,
-                 reached) = self._decode(*args)
+                 reached, *selected) = self._decode(*args)
+                selected = selected[0] if selected else None
                 self._tick_choices.append((chosen, tuple(
                     (req.rid, req.slot, int(positions[req.slot]), 1)
                     for req in reqs)))
@@ -1375,7 +1402,8 @@ class ServingEngine:
             rows=[(req, req.slot) for req in reqs],
             rids=frozenset(req.rid for req in reqs), drafts=drafts,
             out_tokens=out_tokens, accepted=accepted, logits=logits,
-            routed=routed, reached=reached, dispatch=dispatch,
+            routed=routed, reached=reached, selected=selected,
+            dispatch=dispatch,
             step=self._steps)
         if not self._runs_ahead:
             return self._fetch_call(call)
@@ -1526,6 +1554,23 @@ class ServingEngine:
 
         return jax.device_get(arrays)
 
+    def _note_sparse_prefill(self, tick: spans.span, plan) -> None:
+        """What the sparse layers' indexers scored and their queries
+        attended in a prefill call: a token at position ``p`` scores the
+        ``p + 1`` rows up to itself and attends ``min(p + 1,
+        index_topk)``, in each sparse layer."""
+        scanned = selected = 0
+        for req, chunk in plan:
+            first = req.cache_len + 1
+            rows = np.arange(first, first + chunk)
+            for k, n in self._sparse_reads:
+                scanned += n * int(rows.sum())
+                selected += n * int(np.minimum(rows, k).sum())
+        tick.note(dsa_prefill_rows_scanned=scanned,
+                  dsa_prefill_rows_selected=selected)
+        self._counters.dsa_prefill_rows_scanned.inc(scanned)
+        self._counters.dsa_prefill_rows_selected.inc(selected)
+
     def _note_group_reads(self, phase: spans.span, history) -> None:
         """What each kind of cache group must read this tick, on the
         ``decode_plan`` span: rows (the history, or what of it lies inside
@@ -1540,6 +1585,15 @@ class ServingEngine:
                 first = g.first_needed_block(h - 1, bs)
                 kind[0] += h if g.window is None else min(h, g.window)
                 kind[1] += -(-h // bs) - first
+        if self._sparse_reads:
+            # each sparse layer scores every row of a slot's history and
+            # attends the index_topk best
+            scanned = sum(n * sum(history) for _, n in self._sparse_reads)
+            selected = sum(n * sum(min(h, k) for h in history)
+                           for k, n in self._sparse_reads)
+            phase.note(dsa_rows_scanned=scanned, dsa_rows_selected=selected)
+            self._counters.dsa_rows_scanned.inc(scanned)
+            self._counters.dsa_rows_selected.inc(selected)
         sched = self.scheduler
         freed = sched.window_blocks_freed - self._counted_window_freed
         self._counted_window_freed = sched.window_blocks_freed
@@ -1567,6 +1621,7 @@ class ServingEngine:
         drafts = call.drafts
         # replaces, and so frees, the delivered call before's
         self._last_logits = (call.logits, tuple(slot for _, slot in rows))
+        self._last_selected = call.selected
         self._decode_calls += 1
         self._slot_steps += len(rows)
         self._counters.decode_calls.inc()
@@ -1743,6 +1798,15 @@ class ServingEngine:
         flight.  Held by reference until the next delivery replaces it,
         never copied."""
         return self._last_logits
+
+    def last_index_choices(self):
+        """The positions the last delivered decode call's queries attended
+        in each sparse latent layer (the device array ``[sparse layers,
+        max_batch, index_topk]``, ascending, ``-1`` where no token), the
+        same call whose :meth:`last_logits` are held; ``None`` for a model
+        with no sparse layer or before the first delivery.  Held by
+        reference, never fetched."""
+        return self._last_selected
 
     def last_expert_choices(self) -> List[Tuple[Any, Tuple[Tuple[int, ...],
                                                          ...]]]:

@@ -61,7 +61,11 @@ converts an arena it shares with another (:func:`init_group_arenas`).  A
 latent group (ISSUE 33: multi-head latent attention) keeps one arena per
 layer, ``[n_blocks, block_size, latent_rank + rotary_dim]`` in whole lane
 tiles (:attr:`CacheGroup.row_lanes`): the row every head scores against,
-whose leading channels are also every head's values.
+whose leading channels are also every head's values.  A latent group whose
+layers attend sparsely (ISSUE 37: learned sparse attention) keeps a second
+arena per layer, ``[n_blocks, block_size, index_dim]`` of index keys
+(:attr:`CacheGroup.index_dim`), under the same block table and allocator:
+one allocation covers both, so the two can never fall out of step.
 A configuration without ``groups`` is the one pooled arena described above,
 unchanged.
 
@@ -122,7 +126,9 @@ class CacheGroup:
     ``i - w < j <= i``.  A ``latent`` group keeps one row of ``k_dim``
     channels per token and layer and nothing beside it: every head scores
     against the whole row and takes its values from the row's leading
-    ``v_dim`` channels, so there is no value arena."""
+    ``v_dim`` channels, so there is no value arena.  ``index_dim`` (a
+    latent group of sparse layers) is the index key a token and layer
+    keeps beside its row, in an arena of its own under the same table."""
 
     layers: Tuple[int, ...]
     kv_heads: int
@@ -131,6 +137,7 @@ class CacheGroup:
     n_blocks: int
     window: Optional[int] = None
     latent: bool = False
+    index_dim: int = 0
 
     @property
     def row_lanes(self) -> int:
@@ -289,9 +296,11 @@ def init_group_arenas(cfg: KVCacheConfig) -> Tuple[Any, ...]:
     """The zeroed arenas of a configuration with ``groups``: for each group
     a tuple, over its layers, of ``(k [n_blocks, block_size, kv_heads *
     k_dim], v [n_blocks, block_size, kv_heads * v_dim])``, or of ``(rows
-    [n_blocks, block_size, row_lanes],)`` alone for a latent group.  One tuple
-    per layer, each an array of its own: a layer's kernel call takes its
-    arena whole and its appended rows land in place in the donated buffer."""
+    [n_blocks, block_size, row_lanes],)`` alone for a latent group, with
+    ``index_keys [n_blocks, block_size, index_dim]`` after it where the
+    group keeps index keys.  One tuple per layer, each an array of its own:
+    a layer's kernel call takes its arena whole and its appended rows land
+    in place in the donated buffer."""
     import jax.numpy as jnp
 
     if cfg.quantized:
@@ -299,6 +308,8 @@ def init_group_arenas(cfg: KVCacheConfig) -> Tuple[Any, ...]:
 
     def layer_arenas(g):
         widths = (g.row_lanes,) if g.latent else (g.k_dim, g.v_dim)
+        if g.index_dim:
+            widths += (g.index_dim,)
         return tuple(jnp.zeros((g.n_blocks, cfg.block_size, g.kv_heads * w),
                                cfg.dtype) for w in widths)
 

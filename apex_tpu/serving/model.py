@@ -103,6 +103,12 @@ cached rows as they lie and takes the rows' latent channels as values, and
 Nothing expanded is ever stored.  A prefill call's absorbed queries are
 ``tokens x heads x row`` (a gigabyte at 8,192 tokens and 128 heads), so
 the attention of a call is walked a few slots at a time.
+**A sparse latent kind** (ISSUE 37; learned sparse attention, GLM-5's)
+adds an indexer to a latent kind: its keys are cached beside the latent rows
+in a second arena under the same table, and each query attends only the
+``index_topk`` tokens its indexer scores highest (scopes ``dsa_index``,
+``dsa_select``, ``sparse_latent``; :mod:`.sparse_attention`), at decode and
+prefill alike; the decode program returns the positions it attended.
 :func:`decode_model` picks the class from the configuration;
 :func:`serving_config` no longer refuses experts, only the Switch layer,
 which remains a training dry run.
@@ -137,6 +143,15 @@ from apex_tpu.serving.paged_attention import (
     paged_prefill_latent_unfused,
 )
 from apex_tpu.serving.sampling import sample_tokens
+from apex_tpu.serving.sparse_attention import (
+    gather_rows,
+    index_weights_scale,
+    paged_index_scores,
+    paged_index_scores_unfused,
+    select_topk,
+    select_topk_mask,
+    sparse_latent_attention,
+)
 from apex_tpu.transformer.layers.layer_norm import FusedLayerNorm
 from apex_tpu.transformer import moe
 from apex_tpu.transformer.rope import (
@@ -731,7 +746,8 @@ class HybridDecodeModel:
         for gi, group in enumerate(cache.groups):
             kind = self.spec.kinds[self.spec.layer_kinds[group.layers[0]]]
             if (group.kv_heads, group.k_dim, group.v_dim, group.latent,
-                    group.window) != kind.cache_row + (kind.window,):
+                    group.window, group.index_dim) != kind.cache_row + (
+                        kind.window, kind.index_row):
                 raise ValueError(
                     f"cache group {gi} {group} does not hold the rows of "
                     f"attention kind {kind} (a latent kind keeps one row "
@@ -742,6 +758,8 @@ class HybridDecodeModel:
         if sorted(self.place) != list(range(cfg.num_layers)):
             raise ValueError("the cache groups do not cover the layers")
         self.n_expert_layers = sum(self.spec.layer_experts)
+        self.sparse = any(self.spec.kinds[k].sparse
+                          for k in self.spec.layer_kinds)
 
     # ----------------------------------------------------------------- util
 
@@ -763,10 +781,12 @@ class HybridDecodeModel:
     def _latent_rows(self, lp, h1, positions, kind, lanes):
         """A latent layer's projections of normed ``h1 [tokens, hidden]``:
         ``(q_nope [tokens, heads, nope_dim], q_rope [tokens, heads,
-        rotary_dim], rows [tokens, lanes])`` in the model's dtype; ``rows``
-        are what the cache keeps, ``[normed latent | rotated key]`` and
-        zeros up to ``lanes``.  Both low-rank vectors are RMS-normed, the
-        rotation (YaRN-scaled where the kind says) is in fp32."""
+        rotary_dim], rows [tokens, lanes], index)`` in the model's dtype;
+        ``rows`` are what the cache keeps, ``[normed latent | rotated key]``
+        and zeros up to ``lanes``.  Both low-rank vectors are RMS-normed,
+        the rotation (YaRN-scaled where the kind says) is in fp32.
+        ``index`` is the indexer's :meth:`_index_rows` where the kind is
+        sparse, else ``None``."""
         dtype = self.cfg.dtype
         rank, nope = kind.latent_rank, kind.nope_dim
         with named_span("attn_proj"):
@@ -793,7 +813,88 @@ class HybridDecodeModel:
                            jnp.float32)], axis=-1).astype(dtype)
         with named_span("attn_proj"):
             q_nope = q[..., :nope].astype(dtype)
-        return q_nope, q_rope.astype(dtype), rows
+        index = (self._index_rows(lp, h1, c_q.astype(dtype), positions,
+                                  kind) if kind.sparse else None)
+        return q_nope, q_rope.astype(dtype), rows, index
+
+    def _index_rows(self, lp, h1, c_q, positions, kind):
+        """The indexer's projections (a sparse kind): ``(q [tokens,
+        index_heads, index_dim], w [tokens, index_heads], k [tokens,
+        index_dim])``.  ``q`` from the normed compressed query ``c_q``,
+        ``k`` a LayerNorm (gain and bias) of a projection of ``h1``, their
+        leading ``index_rope_dim`` channels rotated (channel ``2 t`` with
+        ``2 t + 1``) in fp32, both in the model's dtype; ``w`` the heads'
+        weights in fp32, scaled by ``(heads * dim) ** -0.5``."""
+        dtype = self.cfg.dtype
+        r = kind.index_rope_dim
+        with named_span("attn_proj"):
+            q = self._mm(c_q, lp["wq_idx"]).reshape(
+                -1, kind.index_heads, kind.index_dim)
+            k = self._mm(h1, lp["wk_idx"])
+            w = self._mm(h1, lp["w_idx"]) * index_weights_scale(
+                kind.index_heads, kind.index_dim)
+        with named_span("norm"):
+            k = self._index_norm(k, lp, kind)
+        with named_span("rope"):
+            q = self._index_rope(q, positions, r, kind).astype(dtype)
+            k = self._index_rope(k[:, None], positions, r, kind)[:, 0]
+        return q, w, k.astype(dtype)
+
+    @staticmethod
+    def _index_norm(k, lp, kind):
+        """The index key's LayerNorm (gain and bias) in fp32."""
+        mean = jnp.mean(k, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+        return ((k - mean) * lax.rsqrt(var + kind.index_eps)
+                * lp["k_idx_norm"].astype(jnp.float32)
+                + lp["k_idx_norm_bias"].astype(jnp.float32))
+
+    @staticmethod
+    def _index_rope(x, positions, r, kind):
+        """Rotation of the leading ``r`` channels of ``x [tokens, heads,
+        d]`` (pairs ``2 t``, ``2 t + 1``), base ``rotary_base``, in fp32."""
+        cos, sin = rotary_cos_sin(positions, r, kind.rotary_base,
+                                  jnp.float32)
+        return jnp.concatenate(
+            [apply_rotary_interleaved(x[..., :r], cos, sin), x[..., r:]],
+            axis=-1)
+
+    def _sparse(self, kind, q, index, arena, keys, table, limits):
+        """Sparse absorbed attention of ``q [slots, Q, heads, lanes]``:
+        the indexer's scores against the cached index ``keys`` (scope
+        ``dsa_index``), each query's top ``index_topk`` tokens (scope
+        ``dsa_select``), and the absorbed attention over those rows of the
+        latent ``arena`` (scope ``sparse_latent``).  ``index = (q [slots,
+        Q, index_heads, index_dim], w [slots, Q, index_heads])``; ``limits
+        [slots, Q]`` each query's causal horizon.  Returns ``(out [slots,
+        Q, heads, latent_rank], selected [slots, Q, index_topk])``, the
+        selected positions, ``-1`` where no token."""
+        scores_of = (paged_index_scores if self.fused_attention
+                     else paged_index_scores_unfused)
+        with named_span("dsa_index"):
+            scores = scores_of(index[0], index[1], keys, table, limits)
+        with named_span("dsa_select"):
+            positions, live = select_topk(scores, kind.index_topk)
+        with named_span("sparse_latent"):
+            rows = gather_rows(arena, table, positions)
+            out = sparse_latent_attention(
+                q, rows, live, v_dim=kind.latent_rank,
+                scale=self._latent_scale(kind))
+            return out, jnp.where(live, positions, -1)
+
+    def _prefill_selection(self, kind, index, keys, table, limits):
+        """A prefill walk's selection ``[slots, chunk, max_seq]`` int8: the
+        indexer's scores (scope ``dsa_index``) and each token's top
+        ``index_topk`` as a mask (scope ``dsa_select``), which the latent
+        prefill kernel takes; a chunk's queries share most of their keys,
+        so the kernel visits every key once and masks, where gathering
+        each query's rows would read ``chunk x index_topk`` rows a slot."""
+        scores_of = (paged_index_scores if self.fused_attention
+                     else paged_index_scores_unfused)
+        with named_span("dsa_index"):
+            scores = scores_of(index[0], index[1], keys, table, limits)
+        with named_span("dsa_select"):
+            return select_topk_mask(scores, kind.index_topk)
 
     def _latent_attention(self, kind, lp, q_nope, q_rope, lanes, kernel):
         """The absorbed form round ``kernel(q [..., heads, lanes]) ->
@@ -827,9 +928,10 @@ class HybridDecodeModel:
         group, q, k, v, layer_arenas, sinks) -> (ctx [tokens, heads *
         v_dim], layer_arenas)`` appends the rows and runs the paged
         kernel; ``attend_latent(kind, group, lp, q_nope, q_rope, rows,
-        layer_arenas)`` likewise for a latent kind.  Returns ``(x, arenas,
-        pairs [expert layers, held], chosen [expert layers, tokens, top_k],
-        reached [expert layers])``."""
+        index, layer_arenas)`` likewise for a latent kind (``index``: the
+        indexer's rows of a sparse kind, else ``None``).  Returns ``(x,
+        arenas, pairs [expert layers, held], chosen [expert layers, tokens,
+        top_k], reached [expert layers])``."""
         cfg, spec = self.cfg, self.spec
         dtype = cfg.dtype
         # the residual stream stays in fp32 (each layer adds a small term to
@@ -927,7 +1029,10 @@ class HybridDecodeModel:
         each slot's router chose, and ``reached [expert layers]`` int32,
         the live slots whose router kept a group of experts held here.
         Returns ``(arenas, out_tokens [max_batch, 1], accepted [max_batch],
-        logits [max_batch, 1, vocab], pairs, chosen, reached)``."""
+        logits [max_batch, 1, vocab], pairs, chosen, reached)``, and where
+        a layer attends sparsely ``selected [sparse layers, max_batch,
+        index_topk]`` after them: the positions each slot's query attended
+        in each such layer, ascending, ``-1`` where no token."""
         del n_draft
         bs = self.cache.block_size
         B = tokens.shape[0]
@@ -951,17 +1056,35 @@ class HybridDecodeModel:
             return table, jnp.where(active, phys,
                                     self.cache.groups[gi].n_blocks)
 
-        def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
+        picked = []               # each sparse layer's selections
+
+        def latent_core(kind, gi, lp, q_nope, q_rope, rows, index,
+                        layer_arenas):
             with named_span("cache_write"):
                 table, dest = destination(gi)
                 arena = layer_arenas[0].at[dest, dest_offsets].set(
                     rows.astype(layer_arenas[0].dtype), mode="drop")
-            ctx = self._latent_attention(
-                kind, lp, q_nope, q_rope, arena.shape[-1],
-                lambda q: attend_rows(q, arena, table, lengths,
-                                      v_dim=kind.latent_rank,
-                                      scale=self._latent_scale(kind)))
-            return ctx, (arena,)
+                if index is not None:
+                    keys = layer_arenas[1].at[dest, dest_offsets].set(
+                        index[2].astype(layer_arenas[1].dtype), mode="drop")
+            if index is None:
+                ctx = self._latent_attention(
+                    kind, lp, q_nope, q_rope, arena.shape[-1],
+                    lambda q: attend_rows(q, arena, table, lengths,
+                                          v_dim=kind.latent_rank,
+                                          scale=self._latent_scale(kind)))
+                return ctx, (arena,)
+
+            def attend_sparse(q):
+                out, selected = self._sparse(
+                    kind, q[:, None], (index[0][:, None], index[1][:, None]),
+                    arena, keys, table, lengths[:, None])
+                picked.append(selected[:, 0])
+                return out[:, 0]
+
+            ctx = self._latent_attention(kind, lp, q_nope, q_rope,
+                                         arena.shape[-1], attend_sparse)
+            return ctx, (arena, keys)
 
         def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
             k_arena, v_arena = layer_arenas
@@ -986,8 +1109,11 @@ class HybridDecodeModel:
             sampled = sample_tokens(logits, temperature, top_k, top_p,
                                     seeds, steps)
             out = jnp.where(active, sampled, 0).astype(jnp.int32)[:, None]
-        return (arenas, out, jnp.zeros((B,), jnp.int32), logits[:, None],
+        outs = (arenas, out, jnp.zeros((B,), jnp.int32), logits[:, None],
                 pairs, chosen, reached)
+        if self.sparse:
+            outs += (jnp.stack(picked),)
+        return outs
 
     def prefill(self, arenas, params, tokens, position_ids, block_tables,
                 lengths, limits, sample_index, temperature, top_k, top_p,
@@ -1020,20 +1146,38 @@ class HybridDecodeModel:
             return table, jnp.where(real, phys,
                                     self.cache.groups[gi].n_blocks)
 
-        def latent_core(kind, gi, lp, q_nope, q_rope, rows, layer_arenas):
+        def latent_core(kind, gi, lp, q_nope, q_rope, rows, index,
+                        layer_arenas):
             with named_span("cache_write"):
                 table, dest = destination(gi)
                 arena = layer_arenas[0].at[dest, dest_offsets].set(
                     rows.reshape(B, T, -1).astype(layer_arenas[0].dtype),
                     mode="drop")
+                if index is not None:
+                    keys = layer_arenas[1].at[dest, dest_offsets].set(
+                        index[2].reshape(B, T, -1).astype(
+                            layer_arenas[1].dtype), mode="drop")
             n = kind.num_heads
             # a few slots at a time: the absorbed queries of the whole call
-            # would be ``B T n lanes`` elements
-            walk = _slots_a_walk(B, T * n * arena.shape[-1]
-                                 * arena.dtype.itemsize)
+            # would be ``B T n lanes`` elements (and a sparse kind's index
+            # scores and their images ``B T max_seq`` float32 each)
+            a_slot = T * n * arena.shape[-1] * arena.dtype.itemsize
+            if index is not None:
+                a_slot += 9 * T * table.shape[1] * self.cache.block_size
+            walk = _slots_a_walk(B, a_slot)
 
             def some_slots(part):
-                q_nope, q_rope, table, lengths, limits = part
+                q_nope, q_rope, table, lengths, limits = part[:5]
+                if index is not None:
+                    selected = self._prefill_selection(
+                        kind, part[5:], keys, table, limits)
+                    return self._latent_attention(
+                        kind, lp, q_nope, q_rope, arena.shape[-1],
+                        lambda q: attend_rows(
+                            q, arena, table, lengths, limits,
+                            v_dim=kind.latent_rank,
+                            scale=self._latent_scale(kind),
+                            selected=selected))
                 return self._latent_attention(
                     kind, lp, q_nope, q_rope, arena.shape[-1],
                     lambda q: attend_rows(q, arena, table, lengths, limits,
@@ -1042,13 +1186,17 @@ class HybridDecodeModel:
 
             # the walk's own slices and their way back are the attention's
             with named_span("attention"):
+                arrays = (q_nope.reshape(B, T, n, -1),
+                          q_rope.reshape(B, T, n, -1), table, lengths, limits)
+                if index is not None:
+                    arrays += (index[0].reshape((B, T) + index[0].shape[1:]),
+                               index[1].reshape(B, T, -1))
                 parts = tuple(a.reshape((B // walk, walk) + a.shape[1:])
-                              for a in (q_nope.reshape(B, T, n, -1),
-                                        q_rope.reshape(B, T, n, -1), table,
-                                        lengths, limits))
+                              for a in arrays)
                 ctx = (some_slots(tuple(a[0] for a in parts)) if walk == B
                        else lax.map(some_slots, parts))
-                return ctx.reshape(B * T, -1), (arena,)
+                out = (arena,) if index is None else (arena, keys)
+                return ctx.reshape(B * T, -1), out
 
         def attn_core(kind, gi, q, k, v, layer_arenas, sinks):
             k_arena, v_arena = layer_arenas

@@ -1271,26 +1271,33 @@ def paged_decode_latent_unfused(q, arena, block_tables, lengths, *,
 
 
 def paged_prefill_latent_unfused(q, arena, block_tables, lengths, limits, *,
-                                 v_dim: int, scale: float):
+                                 v_dim: int, scale: float, selected=None):
     """Plain-XLA twin of :func:`paged_prefill_latent`."""
     del lengths
     _check_latent(q, arena, v_dim)
     rows = _gathered_rows(arena, block_tables)
     s = jnp.einsum("btnd,bsd->btns", q.astype(jnp.float32), rows) * scale
     cols = jnp.arange(rows.shape[1])[None, None, None, :]
-    return _masked_softmax_rows(s, cols < limits[:, :, None, None], rows,
-                                v_dim, q.dtype)
+    live = cols < limits[:, :, None, None]
+    if selected is not None:
+        live = live & (selected[:, :, None, :rows.shape[1]] > 0)
+    return _masked_softmax_rows(s, live, rows, v_dim, q.dtype)
 
 
-def _prefill_latent_kernel(tab_ref, reach_ref, q_ref, lim_ref, rows_hbm,
-                           o_ref, buf, sems, m_sc, l_sc, acc_sc, *,
+def _prefill_latent_kernel(tab_ref, reach_ref, q_ref, lim_ref, *rest,
                            scale: float, block_size: int, pages: int,
-                           dv: int, exact: bool):
+                           dv: int, exact: bool, heads: int = 0):
     """One grid step = one key tile of ``pages`` pages of slot ``i`` against
     query block ``qb`` (``rows`` of tokens x heads, each with its causal
     horizon in ``lim_ref``).  ``reach_ref [b, query blocks]`` is the
     farthest horizon of a block's rows: tiles past it have no step body,
-    pages past it no copy."""
+    pages past it no copy.  With ``heads`` (a selection), ``rest`` begins
+    with ``sel_ref``, the block's tokens' selections of the tile's keys
+    ``[tokens, keys]``, and the rows are head-major (``heads`` x tokens):
+    a row attends a key where its token selected it."""
+    if heads:
+        sel_ref, rest = rest[0], rest[1:]
+    rows_hbm, o_ref, buf, sems, m_sc, l_sc, acc_sc = rest
     i, qb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     reach = reach_ref[i, qb]
     part = pages // _LATENT_PARTS
@@ -1331,7 +1338,15 @@ def _prefill_latent_kernel(tab_ref, reach_ref, q_ref, lim_ref, rows_hbm,
                     + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
                 rows = buf[pl.ds(half * keys, keys)]          # [keys, d]
                 s = _mxu(q, rows, ((1,), (1,)), exact)
-                s = jnp.where(cols < lim, s * scale, NEG_INF)
+                live = cols < lim
+                if heads:
+                    # each token's selection, once for each head's rows
+                    sel = sel_ref[0, 0, :, pl.ds(half * keys, keys)].astype(
+                        jnp.float32)
+                    live = live & (jnp.broadcast_to(
+                        sel[None], (heads,) + sel.shape).reshape(
+                            heads * sel.shape[0], keys) > 0.5)
+                s = jnp.where(live, s * scale, NEG_INF)
                 p, alpha, m_new, l_new = _online_softmax(
                     s, m_sc[:, :1], l_sc[:, :1])
                 acc_sc[...] = acc_sc[...] * alpha + _mxu(
@@ -1347,12 +1362,16 @@ def _prefill_latent_kernel(tab_ref, reach_ref, q_ref, lim_ref, rows_hbm,
 
 
 def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
-                         v_dim: int, scale: float):
+                         v_dim: int, scale: float, selected=None):
     """Chunked prefill over a latent group's arena: ``q [batch, chunk,
     n_heads, d]`` absorbed queries, ``limits [batch, chunk]`` each token's
     causal horizon (0 = padding), the chunk's own rows already in the
     arena.  Returns ``[batch, chunk, n_heads, v_dim]``; in a device trace
-    the call is ``paged_prefill_latent``.
+    the call is ``paged_prefill_latent``.  ``selected [batch, chunk,
+    max_blocks * block]`` (int8, a sparse kind's selection, ISSUE 37): a
+    token attends only the keys it selected; the kernel then takes its
+    rows head-major so that a token's selection is one block for all its
+    heads.
 
     The absorbed form: a chunk's ``chunk * n_heads`` query rows share every
     cached row, so the kernel is one matrix product a key tile and query
@@ -1376,7 +1395,11 @@ def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
                 // _LATENT_PARTS * _LATENT_PARTS)
     limits = limits.astype(jnp.int32)
     reach = jnp.max(limits.reshape(b, blocks, tq), axis=-1)
-    lim = jnp.repeat(limits.reshape(b, blocks, tq), n, axis=-1)[..., None]
+    if selected is None:
+        lim = jnp.repeat(limits.reshape(b, blocks, tq), n, axis=-1)[..., None]
+    else:
+        lim = jnp.tile(limits.reshape(b, blocks, tq), (1, 1, n))[..., None]
+        q = q.reshape(b, blocks, tq, n, d).swapaxes(2, 3)
     tiles = pl.cdiv(max_blocks, pages)
     # table columns a tile's last pages may name past the table's width
     table = jnp.pad(block_tables.astype(jnp.int32),
@@ -1386,12 +1409,20 @@ def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
     def block_idx(i, qb, j, *refs):
         return (i, qb, 0, 0)
 
+    in_specs = [pl.BlockSpec((1, 1, rows, d), block_idx),
+                pl.BlockSpec((1, 1, rows, 1), block_idx)]
+    operands = [q.reshape(b, blocks, rows, d), lim]
+    if selected is not None:
+        width = tiles * pages * bs
+        in_specs.append(pl.BlockSpec((1, 1, tq, pages * bs),
+                                     lambda i, qb, j, *refs: (i, qb, 0, j)))
+        operands.append(jnp.pad(
+            selected.astype(jnp.int8).reshape(b, blocks, tq, -1),
+            ((0, 0), (0, 0), (0, 0), (0, width - selected.shape[-1]))))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, blocks, tiles),
-        in_specs=[pl.BlockSpec((1, 1, rows, d), block_idx),
-                  pl.BlockSpec((1, 1, rows, 1), block_idx),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, rows, dv), block_idx),
         scratch_shapes=[
             pltpu.VMEM((pages * bs, d), arena.dtype),
@@ -1403,7 +1434,8 @@ def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
     )
     kernel = functools.partial(
         _prefill_latent_kernel, scale=scale, block_size=bs, pages=pages,
-        dv=dv, exact=arena.dtype != jnp.bfloat16)
+        dv=dv, exact=arena.dtype != jnp.bfloat16,
+        **({} if selected is None else {"heads": n}))
     keys = pages // _LATENT_PARTS * bs
     vmem = (2 * _vmem_bytes((rows, d), q.dtype)
             + 2 * _vmem_bytes((rows, dv), q.dtype)
@@ -1422,5 +1454,8 @@ def paged_prefill_latent(q, arena, block_tables, lengths, limits, *,
                 vmem_limit_bytes=max(vmem, 32 << 20)),
             interpret=platform.pallas_interpret(),
             name=name,
-        )(table, reach, q.reshape(b, blocks, rows, d), lim, arena)
+        )(table, reach, *operands, arena)
+    if selected is not None:
+        return out.reshape(b, blocks, n, tq, dv).swapaxes(2, 3).reshape(
+            b, T, n, dv)
     return out.reshape(b, T, n, dv)

@@ -104,7 +104,21 @@ class AttentionKind:
     cached row is the normed latent beside the rotated key
     (:attr:`cache_row`), whatever the head count.  ``rotary_scaling``
     stretches the rotary base (:class:`~apex_tpu.transformer.rope.
-    YarnScaling`) and ``softmax_scale`` replaces ``k_dim ** -0.5``."""
+    YarnScaling`) and ``softmax_scale`` replaces ``k_dim ** -0.5``.
+
+    A latent kind with ``index_topk > 0`` attends **sparsely** (learned
+    sparse attention, DeepSeek-V3.2's DSA): an indexer of ``index_heads``
+    query heads of ``index_dim`` channels (from the compressed query)
+    scores every cached token against one index key a token (a LayerNorm
+    of a projection of the layer's input, ``index_eps``), ``I_ts = sum_h
+    w_th ReLU(q_th . k_s)`` with per-head weights ``w_t`` projected from
+    the input and scaled by ``(index_heads * index_dim) ** -0.5``; the
+    leading ``index_rope_dim`` channels of the index queries and keys
+    rotate (channel ``2 t`` with ``2 t + 1``, base ``rotary_base``).  Each
+    query then attends the ``index_topk`` tokens of largest ``I`` (ties
+    to the lower position) and no other: a selection of tokens per query,
+    shared by every head.  The index key is cached beside the latent row
+    (:attr:`index_row`)."""
 
     name: str
     num_heads: int
@@ -122,6 +136,11 @@ class AttentionKind:
     nope_dim: int = 0
     rotary_scaling: Optional[YarnScaling] = None
     softmax_scale: Optional[float] = None
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope_dim: int = 0
+    index_topk: int = 0
+    index_eps: float = 1e-6
 
     def __post_init__(self):
         if self.num_heads % self.kv_heads:
@@ -142,10 +161,23 @@ class AttentionKind:
                 f"{self.name}: a latent kind has one shared key row "
                 "(kv_heads 1), a q_rank, k_dim = nope_dim + rotary_dim, and "
                 "no window, sink, qk_norm or gate")
+        if self.sparse and (
+                not self.latent or self.index_heads < 1
+                or self.index_rope_dim % 2
+                or not 0 < self.index_rope_dim <= self.index_dim):
+            raise ValueError(
+                f"{self.name}: an indexer serves a latent kind, with index "
+                "heads and an even rotary width within index_dim")
 
     @property
     def latent(self) -> bool:
         return self.latent_rank > 0
+
+    @property
+    def sparse(self) -> bool:
+        """Whether each query attends only the ``index_topk`` tokens its
+        indexer selects."""
+        return self.index_topk > 0
 
     @property
     def cache_row(self) -> Tuple[int, int, int, bool]:
@@ -153,11 +185,18 @@ class AttentionKind:
         ``(kv_heads, key width, value width, latent)``.  A latent row is
         one vector of ``latent_rank + rotary_dim`` channels whose leading
         ``latent_rank`` are also the values; any other kind keeps ``k_dim``
-        beside ``v_dim`` per KV head."""
+        beside ``v_dim`` per KV head (a sparse kind's index key beside:
+        :attr:`index_row`)."""
         if self.latent:
             return (1, self.latent_rank + self.rotary_dim, self.latent_rank,
                     True)
         return (self.kv_heads, self.k_dim, self.v_dim, False)
+
+    @property
+    def index_row(self) -> int:
+        """Channels of the index key a token and layer of a sparse kind
+        keeps beside its latent row; 0 for any other kind."""
+        return self.index_dim if self.sparse else 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,7 +319,10 @@ class HybridParams(NamedTuple):
     ``q_a_norm [q_rank]``, ``wq_b [q_rank, heads * k_dim]``, ``wkv_a
     [hidden, latent_rank + rotary_dim]``, ``kv_a_norm [latent_rank]``,
     ``w_uk [heads, latent_rank, nope_dim]`` and ``w_uv [heads, latent_rank,
-    v_dim]``; ``norm2``,
+    v_dim]``, and where it is sparse the indexer's ``wq_idx [q_rank,
+    index_heads * index_dim]``, ``wk_idx [hidden, index_dim]``,
+    ``k_idx_norm`` and ``k_idx_norm_bias [index_dim]`` (the index key's
+    LayerNorm) and ``w_idx [hidden, index_heads]``; ``norm2``,
     ``post_attn_norm`` and ``post_ffn_norm [hidden]`` under
     ``sandwich_norm``, then ``ffn_gate_up [hidden, 2 f]`` and ``ffn_down
     [f, hidden]`` for a dense layer or ``router [hidden, E]``,

@@ -138,6 +138,41 @@ def test_latent_kernel_compiles_for_v5e_at_published_widths(
     assert f"%paged_{kind}_latent" in lowered.compile().as_text()
 
 
+@pytest.mark.parametrize("kind,slots,queries", [("decode", 16, 1),
+                                                ("prefill", 1, 128)])
+def test_index_kernel_compiles_for_v5e_at_published_widths(
+        kind, slots, queries, v5e_device, monkeypatch):
+    """The sparse latent layers' index scan at GLM-5's widths (ISSUE 37):
+    32 index heads of 128 channels against one key a token in blocks of
+    16, a table of 3,328 blocks (53,248 tokens); decode 16 slots of one
+    query, prefill a walk of one slot's chunk of 128; the copies without
+    Mosaic's run-time bounds checks.  The select and the gathered
+    attention around it are XLA."""
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import sparse_attention as sa
+
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    sharding = SingleDeviceSharding(v5e_device)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    def call(q, w, keys, tables, limits):
+        scores = sa.paged_index_scores(q, w, keys, tables, limits)
+        return sa.select_topk(scores, 2048)
+
+    args = (shape((slots, queries, 32, 128)),
+            shape((slots, queries, 32), jnp.float32),
+            shape((48000, 16, 128)), shape((slots, 3328), jnp.int32),
+            shape((slots, queries), jnp.int32))
+    lowered = jax.jit(call).lower(*args)
+    configs = custom_call_configs(lowered)
+    assert list(configs) == [f"paged_index_{kind}"]
+    assert configs[f"paged_index_{kind}"]["disable_bounds_checks"] is True
+    assert f"%paged_index_{kind}" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("kind,name,g,blocks,window", GROUP_KERNELS)
 def test_group_kernel_compiles_for_v5e_at_published_widths(
         kind, name, g, blocks, window, v5e_device, monkeypatch):

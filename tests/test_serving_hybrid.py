@@ -232,6 +232,44 @@ def test_preempted_request_resumes_with_the_same_stream(sizes, weights):
     assert roomy == tight
 
 
+def test_a_call_in_flight_reads_the_tables_it_was_planned_with(sizes,
+                                                               weights):
+    """The engine rewrites its block tables in place as it plans the next
+    tick (a preempted slot's row among them) while the call it dispatched
+    may not have run: every decode call here waits behind a slow
+    computation on the arenas, so the host always plans first.  On the CPU
+    ``jnp.asarray`` of a numpy array aliases it, and the call read the next
+    plan's tables (the stream above then differed under load, ISSUE 37);
+    the engine hands each call a copy."""
+    @jax.jit
+    def slow(arenas):
+        x = jnp.ones((512, 512))
+        for _ in range(12):
+            x = jnp.tanh(x @ x / 512.0)
+        return jax.tree_util.tree_map(
+            lambda a: a + 0 * x[0, 0].astype(a.dtype), arenas)
+
+    def stream(n_blocks, hold):
+        eng = build_engine(sizes, weights, n_blocks=n_blocks)
+        if hold:
+            real = eng._decode
+            eng._decode = lambda arenas, *rest: real(slow(arenas), *rest)
+        rng = np.random.default_rng(3)
+        reqs = [eng.submit(rng.integers(0, sizes["vocab"], n).tolist(), 20)
+                for n in (20, 18, 22, 17)]
+        for _ in range(400):
+            eng.step()
+            eng.scheduler.check()
+            if eng.scheduler.idle:
+                break
+        return [r.output_tokens for r in reqs], eng.scheduler.preemptions
+
+    roomy, _ = stream(64, False)
+    held, some = stream(24, True)
+    assert some > 0
+    assert roomy == held
+
+
 # ------------------------------------------------------------ the share test
 
 
