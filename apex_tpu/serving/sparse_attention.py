@@ -21,7 +21,8 @@ Three steps, each in a scope of its own so that a device trace splits them:
   sort: the ``k``-th largest score is found by bisection on the
   order-preserving unsigned image of the float32 bits (32 counting passes
   over the row), the rows above it and the first ties are the set, and a
-  two-level prefix count turns the set into positions.
+  two-level prefix count of sums and 0/1 matrix products, with no gather,
+  turns the set into positions.
 - ``sparse_latent`` — :func:`gather_rows` and
   :func:`sparse_latent_attention`: the selected rows gathered through the
   block table (``k`` rows of the latent arena, not the pages round them)
@@ -261,31 +262,37 @@ def select_topk(scores, k: int):
             jnp.pad(x, ((0, 0), (0, k - n)), constant_values=-jnp.inf))
         return pos.reshape(lead + (k,)), live.reshape(lead + (k,))
     chosen = _chosen(x, k)
-    # positions of the chosen, in order: the chunk of the j-th, then its
-    # place among the chunk's chosen lanes
+    # 1 at a chosen position, 2 at a chosen one whose score is finite
+    codes = chosen.astype(jnp.int8) + (chosen & jnp.isfinite(x)).astype(
+        jnp.int8)
     pad = -n % _CHUNK
-    bits = jnp.pad(chosen, ((0, 0), (0, pad))).reshape(rows, -1, _CHUNK)
-    counts = jnp.sum(bits, axis=2, dtype=jnp.int32)          # [rows, C]
+    codes = jnp.pad(codes, ((0, 0), (0, pad))).reshape(rows, -1, _CHUNK)
+    counts = jnp.sum(codes > 0, axis=2, dtype=jnp.int32)     # [rows, C]
+    # positions of the chosen, in order: the chunk of the j-th and the
+    # chosen before that chunk, then its place among the chunk's chosen
+    # lanes; as sums and products of 0/1 matrices (exact in bf16 with f32
+    # sums), not gathers (an XLA gather of ``k`` scalars a row is slow on
+    # the TPU) and not scans
     ends = jnp.cumsum(counts, axis=1)
     j = jnp.arange(k, dtype=jnp.int32)
-    chunk = jnp.sum(ends[:, None, :] <= j[None, :, None], axis=2,
-                    dtype=jnp.int32)                           # [rows, k]
-    before = jnp.take_along_axis(ends - counts, chunk, axis=1)
-    # the j-th's chunk and the running count of its lanes as products of
-    # 0/1 matrices (exact in bf16 with f32 sums), not gathers and scans
-    pick = (chunk[..., None] == jnp.arange(bits.shape[1])).astype(
+    done = ends[:, None, :] <= j[None, :, None]                # [r, k, C]
+    chunk = jnp.sum(done, axis=2, dtype=jnp.int32)             # [rows, k]
+    before = jnp.sum(jnp.where(done, counts[:, None, :], 0), axis=2,
+                     dtype=jnp.int32)
+    pick = (chunk[..., None] == jnp.arange(codes.shape[1])).astype(
         jnp.bfloat16)                                          # [r, k, C]
-    lanes = jnp.einsum("rkc,rcl->rkl", pick, bits.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
+    lanes = jnp.einsum("rkc,rcl->rkl", pick, codes.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)    # the chunk's
     upper = (jnp.arange(_CHUNK)[:, None] <= jnp.arange(_CHUNK)[None, :])
-    seen = jnp.einsum("rkl,lm->rkm", lanes.astype(jnp.bfloat16),
+    seen = jnp.einsum("rkl,lm->rkm", (lanes > 0).astype(jnp.bfloat16),
                       upper.astype(jnp.bfloat16),
                       preferred_element_type=jnp.float32)
     lane = jnp.sum(seen <= (j - before)[..., None].astype(jnp.float32),
                    axis=2, dtype=jnp.int32)
     pos = chunk * _CHUNK + lane
-    live = jnp.isfinite(jnp.take_along_axis(x, jnp.minimum(pos, n - 1),
-                                            axis=1))
+    # the code the one-hot product carried to the position: 2 where live
+    live = jnp.sum(jnp.where(jnp.arange(_CHUNK) == lane[..., None], lanes,
+                             0.0), axis=2) == 2.0
     return pos.reshape(lead + (k,)), live.reshape(lead + (k,))
 
 

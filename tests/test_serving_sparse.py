@@ -253,11 +253,23 @@ def test_index_kernel_tiles_a_table_in_lane_tiles():
     assert sa._index_pages(32, 4, 12) == 12
 
 
-@pytest.mark.parametrize("case", ["random", "ties", "padded", "short",
-                                  "all_ties"])
-def test_select_is_lax_top_k_as_a_set_in_position_order(case):
-    rng = np.random.default_rng(hash(case) % 1000)
+# jitted, so that cases of one shape share a trace
+SELECT = jax.jit(sa.select_topk, static_argnums=1)
+SELECT_MASK = jax.jit(sa.select_topk_mask, static_argnums=1)
+SELECT_CASES = ["random", "ties", "padded", "short", "all_ties",
+                "mixed_sign", "neg_inf_row", "rows_13", "aligned",
+                "ties_at_cut"]
+
+
+def _select_case(case):
+    """``(scores [rows, L] float32, k)`` of a case: 6 rows of 300 (not a
+    whole number of 128-lane chunks) unless the case says otherwise."""
+    rng = np.random.default_rng(SELECT_CASES.index(case))
     rows, n, k = 6, 300, 8
+    if case == "rows_13":
+        rows = 13
+    if case == "aligned":
+        n = 384
     x = rng.normal(size=(rows, n)).astype(np.float32)
     limits = np.full(rows, n)
     if case == "ties":
@@ -268,8 +280,31 @@ def test_select_is_lax_top_k_as_a_set_in_position_order(case):
         limits = np.asarray([0, 1, 3, 7, 8, 9])
     if case == "all_ties":
         x[:] = 1.5
+    if case == "mixed_sign":
+        # negative rows, mixed rows, and zeros of both signs at the cut
+        x[0] = -np.abs(x[0])
+        x[1] = np.round(x[1]) * 0.0
+        x[2, ::7] = -0.0
+        x[2, 3::7] = 0.0
+        x[2, :5] = 1.0
+    if case == "neg_inf_row":
+        limits[[1, 4]] = 0
+    if case == "ties_at_cut":
+        # 5 above the cut, 11 ties at it spread over the row, room for 3
+        x = np.minimum(x, 0.5)
+        x[:, rng.choice(n, 16, replace=False)] = 2.0
+        for r in range(rows):
+            x[r, rng.choice(np.flatnonzero(x[r] == 2.0), 5,
+                            replace=False)] = 3.0
     x = np.where(np.arange(n)[None] < limits[:, None], x, -np.inf)
-    pos, live = sa.select_topk(jnp.asarray(x), k)
+    return x.astype(np.float32), k
+
+
+@pytest.mark.parametrize("case", SELECT_CASES)
+def test_select_is_lax_top_k_as_a_set_in_position_order(case):
+    x, k = _select_case(case)
+    rows = x.shape[0]
+    pos, live = SELECT(jnp.asarray(x), k)
     pos, live = np.asarray(pos), np.asarray(live)
     _, idx = jax.lax.top_k(jnp.asarray(x), k)
     for r in range(rows):
@@ -279,6 +314,35 @@ def test_select_is_lax_top_k_as_a_set_in_position_order(case):
         assert list(pos[r]) == sorted(pos[r])
     if case == "all_ties":
         assert (pos == np.arange(k)[None]).all()
+    if case == "neg_inf_row":
+        assert (pos[1] == np.arange(k)).all() and not live[1].any()
+    if case == "ties_at_cut":
+        assert (live.sum(axis=1) == k).all()
+
+
+@pytest.mark.parametrize("case", SELECT_CASES)
+def test_select_mask_is_the_set_of_live_positions(case):
+    """``select_topk_mask`` is 1 exactly at ``select_topk``'s live
+    positions, int8, leading dimensions kept."""
+    x, k = _select_case(case)
+    x = jnp.asarray(x).reshape((1,) + x.shape)
+    pos, live = SELECT(x, k)
+    mask = np.asarray(SELECT_MASK(x, k))
+    assert mask.dtype == np.int8 and mask.shape == x.shape
+    assert pos.shape == live.shape == (1, x.shape[1], k)
+    want = np.zeros(x.shape, np.int8)
+    for r in range(x.shape[1]):
+        want[0, r, np.asarray(pos)[0, r][np.asarray(live)[0, r]]] = 1
+    np.testing.assert_array_equal(mask, want)
+
+
+def test_select_counts_positions_without_a_gather():
+    """The prefix count turns the set into positions with sums and 0/1
+    products: an XLA gather of 2,048 scalars a row was most of the
+    select's time on the TPU, ahead of its 32 passes."""
+    text = jax.jit(sa.select_topk, static_argnums=1).lower(
+        jax.ShapeDtypeStruct((16, 53248), jnp.float32), 2048).as_text()
+    assert "gather" not in text
 
 
 def test_select_of_fewer_columns_than_k_keeps_them_all():
